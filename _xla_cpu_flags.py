@@ -5,15 +5,11 @@ init). Single source for the collective-watchdog timeouts: the CPU
 in-process collective rendezvous ABORTS the process ("Termination
 timeout ... Expected N threads to join") when virtual-device threads
 are slow to arrive — which on an oversubscribed CI host is load, not
-deadlock. That abort was round 3's flagship-example SIGABRT.
+deadlock.
 
-The watchdog flags do not exist in every jaxlib, and XLA fatally
-aborts the process on *unknown* XLA_FLAGS — the cure must not be
-worse than the disease. So before injecting them we scan the
-installed jaxlib's xla_extension shared object for the flag name:
-the registered flag string is embedded in the binary iff the flag is
-parseable. The verdict is cached in the environment so subprocesses
-(and re-imports) skip the scan.
+XLA fatally aborts on *unknown* XLA_FLAGS; both timeout flags are known
+to the installed jaxlib (0.9.0: a CPU backend starts and runs a
+collective with them set), so they are always added.
 """
 from __future__ import annotations
 
@@ -23,34 +19,6 @@ _TIMEOUT_FLAGS = (
     " --xla_cpu_collective_call_warn_stuck_timeout_seconds=300"
     " --xla_cpu_collective_call_terminate_timeout_seconds=1200")
 
-_PROBE_CACHE_VAR = "PADDLE_TPU_XLA_WATCHDOG_FLAGS_OK"
-_PROBE_NEEDLE = b"xla_cpu_collective_call_terminate_timeout_seconds"
-
-
-def _watchdog_flags_supported() -> bool:
-    cached = os.environ.get(_PROBE_CACHE_VAR)
-    if cached in ("0", "1"):
-        return cached == "1"
-    ok = False
-    try:
-        import importlib.util
-        import mmap
-
-        spec = importlib.util.find_spec("jaxlib")
-        so = os.path.join(os.path.dirname(spec.origin), "xla_extension.so")
-        with open(so, "rb") as f:
-            mm = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
-            try:
-                ok = mm.find(_PROBE_NEEDLE) != -1
-            finally:
-                mm.close()
-    except Exception:
-        # Can't find/scan the binary (different layout, no jaxlib):
-        # don't risk an unknown-flag abort.
-        ok = False
-    os.environ[_PROBE_CACHE_VAR] = "1" if ok else "0"
-    return ok
-
 
 def ensure(device_count: int | None = None) -> None:
     """Idempotently add the watchdog timeouts (and optionally the
@@ -58,7 +26,6 @@ def ensure(device_count: int | None = None) -> None:
     flags = os.environ.get("XLA_FLAGS", "")
     if device_count and "host_platform_device_count" not in flags:
         flags += f" --xla_force_host_platform_device_count={device_count}"
-    if ("collective_call_terminate_timeout" not in flags
-            and _watchdog_flags_supported()):
+    if "collective_call_terminate_timeout" not in flags:
         flags += _TIMEOUT_FLAGS
     os.environ["XLA_FLAGS"] = flags.strip()

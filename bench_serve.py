@@ -10,9 +10,9 @@ and prints ONE line::
 with tokens/sec/chip, TTFT p50/p95 and request-latency p50/p95 — the
 Gemma-on-Cloud-TPU serving comparison's headline numbers (PAPERS.md).
 Percentiles come from the ``serve_*`` histograms in the metrics
-registry (enabled for the run).  Real numbers on CPU via the jnp
-reference path; on TPU the Pallas kernel path compiles through the
-persistent XLA cache.
+registry (enabled for the run).  Every line names the platform, device
+kind and device count it ran on; a failure prints an error line without
+a value and exits 1 — no earlier result is carried forward.
 
 Env knobs (all optional): PADDLE_TPU_BENCH_SERVE_PRESET (default
 llama-debug), _REQUESTS, _PROMPT (max prompt len), _NEW (tokens per
@@ -60,8 +60,9 @@ line — feed it to ``tools/trace_report.py`` for per-request timelines
 whose breakdown sums exactly to the measured TTFT.
 
 ``--ledger-out [PATH]`` (or PADDLE_TPU_BENCH_LEDGER_OUT) appends the
-normalized provenance-stamped row to the perf ledger (default
-``PERF_LEDGER.jsonl``; gate it with ``tools/perf_ledger.py check``).
+normalized provenance-stamped row to the repo's own perf ledger (default
+``runs/perf_ledger.jsonl``, never the driver's ``PERF_LEDGER.jsonl``;
+gate it with ``tools/perf_ledger.py check``).
 With ``FLAGS_tpu_metrics_port`` set the run is scrapeable live at
 ``/metrics`` and ``/slo`` (``paddle_tpu/profiler/exporter.py``) and the
 JSON line carries the bound ``metrics_port``.
@@ -74,10 +75,6 @@ import sys
 import time
 
 _REPO = os.path.dirname(os.path.abspath(__file__))
-# scratch record of the last successful run lives under runs/ (untracked)
-# — the durable artifact is the perf ledger row (--ledger-out)
-_LAST_FILE = os.path.join(_REPO, "runs", "bench_serve_last.json")
-_LAST_FILE_LEGACY = os.path.join(_REPO, ".bench_serve_last.json")
 _T0 = time.monotonic()
 
 
@@ -90,27 +87,21 @@ def _ledger_out():
         if i + 1 < len(sys.argv) and not sys.argv[i + 1].startswith("--"):
             path = sys.argv[i + 1]
         else:
-            path = os.path.join(_REPO, "PERF_LEDGER.jsonl")
+            path = os.path.join(_REPO, "runs", "perf_ledger.jsonl")
     return path
 
 
 def _ledger_append(result):
-    """Append the normalized row (success or error) to the perf ledger;
-    a ledger failure must never break the BENCH_SERVE line."""
+    """Append the normalized row (success or error) to the perf ledger."""
     path = _ledger_out()
     if not path:
         return
-    try:
-        from paddle_tpu.profiler import ledger as _ledger
-        cmd = "python " + " ".join(
-            [os.path.basename(sys.argv[0] or "bench_serve.py")]
-            + sys.argv[1:])
-        row = _ledger.from_bench_serve_result(result, ts=time.time(),
-                                              cmd=cmd)
-        _ledger.append(path, row)
-        _log(f"ledger row appended to {path}")
-    except Exception as e:
-        _log(f"ledger append failed: {e}")
+    from paddle_tpu.profiler import ledger as _ledger
+    cmd = "python " + " ".join(
+        [os.path.basename(sys.argv[0] or "bench_serve.py")] + sys.argv[1:])
+    row = _ledger.from_bench_serve_result(result, ts=time.time(), cmd=cmd)
+    _ledger.append(path, row)
+    _log(f"ledger row appended to {path}")
 
 
 def _log(msg):
@@ -148,11 +139,6 @@ def main():
     from paddle_tpu.serving import workloads as _workloads
 
     _flags.set_flags({"FLAGS_tpu_metrics": True})
-    from paddle_tpu.core import compile_cache
-    try:
-        compile_cache.ensure(force=True)
-    except Exception as e:
-        _log(f"compilation cache unavailable: {e}")
 
     preset = os.environ.get("PADDLE_TPU_BENCH_SERVE_PRESET",
                             "llama-debug")
@@ -185,7 +171,7 @@ def main():
     n_new = _env_int("NEW", 16)
     max_running = _env_int("MAX_RUNNING", 8)
     chunk = _env_int("CHUNK", 8)
-    page = _env_int("PAGE", 16)
+    page = _env_int("PAGE", 128)
     n_sys = _env_int("SYS_PROMPTS", 2)
     spec_k = _env_int("SPEC_K", 3)
     max_queue = _env_int("MAX_QUEUE", 8 * max_running)
@@ -455,7 +441,8 @@ def main():
                 (eng.num_pages - 1) // eng.max_blocks,
         },
         "preset": preset,
-        "device": getattr(dev, "device_kind", dev.platform),
+        "platform": dev.platform,
+        "device": dev.device_kind,
         "chips": n_chips,
     }
     if trace_sidecar is not None:
@@ -463,78 +450,60 @@ def main():
     exp = _exporter_active()
     if exp is not None:
         result["metrics_port"] = exp.port
-    try:
-        os.makedirs(os.path.dirname(_LAST_FILE), exist_ok=True)
-        with open(_LAST_FILE, "w") as f:
-            json.dump(result, f)
-    except OSError:
-        pass
     return result
 
 
 def _exporter_active():
     """The live exporter, if FLAGS_tpu_metrics_port started one when the
     engine was constructed."""
+    from paddle_tpu.profiler import exporter
+    return exporter.active()
+
+
+def _error_result(msg):
+    """An error line: the cause, the device and the runtime health
+    layer's last incident — never a value."""
+    import jax
+    from paddle_tpu.runtime.watchdog import last_incident
+    out = {"metric": "serve_tokens_per_sec_chip", "error": msg[-1500:]
+           or "unknown"}
     try:
-        from paddle_tpu.profiler import exporter
-        return exporter.active()
-    except Exception:
-        return None
-
-
-def _error_result(msg, incident=None):
-    out = {
-        "metric": "serve_tokens_per_sec_chip",
-        "value": 0.0,
-        "unit": "tokens/s/chip",
-        "error": msg[-1500:] or "unknown",
-    }
-    if incident is None:
-        try:
-            from paddle_tpu.runtime.watchdog import last_incident
-            incident = last_incident()
-        except Exception:
-            incident = None
+        dev = jax.devices()[0]
+        out.update(platform=dev.platform, device=dev.device_kind,
+                   chips=jax.device_count())
+    except RuntimeError:      # no backend came up at all
+        out.update(platform=None, device=None, chips=0)
+    incident = last_incident()
     if incident is not None:
         out["incident"] = incident
-    for path in (_LAST_FILE, _LAST_FILE_LEGACY):
-        try:
-            with open(path) as f:
-                out["last_measured"] = json.load(f)
-            break
-        except Exception:
-            continue
     return out
 
 
 def run():
-    """Never exit without the BENCH_SERVE line (same contract as
-    bench.py): failures and hangs print value 0.0 with the error and
-    the runtime health layer's incident record attached."""
+    """Print the BENCH_SERVE line. A failure or a hang prints an error
+    line (no value) with the runtime health layer's incident record
+    attached, and the exit code is 1."""
     from paddle_tpu.runtime.watchdog import (PhaseTimeout,
                                              persist_incidents,
                                              run_with_deadline)
+
+    def emit(result):
+        print("BENCH_SERVE " + json.dumps(result))
+        sys.stdout.flush()
+        _ledger_append(result)
 
     timeout_s = float(os.environ.get("PADDLE_TPU_BENCH_TIMEOUT", "900"))
     try:
         result = run_with_deadline(main, timeout_s, phase="serve_measure")
     except PhaseTimeout:
-        result = _error_result(
-            f"bench_serve timed out after {timeout_s:.0f}s "
-            "(compile or execute hang)")
-        print("BENCH_SERVE " + json.dumps(result))
-        sys.stdout.flush()
-        _ledger_append(result)
-        try:
-            # os._exit skips atexit — flush the incident sidecar now
-            persist_incidents()
-        except OSError as e:
-            _log(f"incident persist failed: {e}")
-        os._exit(0)  # the hung measure thread would block a clean exit
-    except BaseException as e:  # noqa: BLE001 — the line must print
-        result = _error_result(str(e) or repr(e))
-    print("BENCH_SERVE " + json.dumps(result))
-    _ledger_append(result)
+        emit(_error_result(f"bench_serve timed out after {timeout_s:.0f}s "
+                           "(compile or execute hang)"))
+        persist_incidents()   # os._exit skips atexit
+        os._exit(1)           # the hung measure thread would block exit
+    except Exception as e:  # noqa: BLE001 — reported, then exit 1
+        emit(_error_result(str(e) or repr(e)))
+        return 1
+    emit(result)
     return 0
 
 

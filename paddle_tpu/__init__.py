@@ -12,9 +12,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-from .core import jax_compat as _jax_compat
-_jax_compat.ensure()
-
 from .core import (Tensor, to_tensor, no_grad, enable_grad, is_grad_enabled,
                    set_grad_enabled, CPUPlace, TPUPlace, CustomPlace,
                    set_flags, get_flags)

@@ -270,18 +270,16 @@ def walk_eqns(jaxpr, in_loop: bool = False, path: str = ""):
 
 
 def eqn_site(eqn) -> Tuple[Optional[str], Optional[int], str]:
-    """(file, line, "file:line (fn)") attribution of an eqn, best effort
-    (same source_info path as profiler/numerics)."""
-    where = "<unknown>"
-    try:
-        from jax._src import source_info_util
-        where = source_info_util.summarize(eqn.source_info)
-        fr = source_info_util.user_frame(eqn.source_info)
-        if fr is not None:
-            return fr.file_name, int(fr.start_line), where
-    except Exception:  # tpu-lint: disable=except-pass — best-effort attribution
-        pass
-    return None, None, where
+    """(file, line, "file:line (fn)") attribution of an eqn — the one
+    place that reads jax's source info (profiler/numerics uses it too).
+    jax exposes no public accessor; this is the 0.9.0 layout:
+    ``user_frame`` takes the traceback, not the SourceInfo."""
+    from jax._src import source_info_util
+    where = source_info_util.summarize(eqn.source_info)
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
+    if fr is None:
+        return None, None, where
+    return fr.file_name, int(fr.start_line), where
 
 
 # ---------------------------------------------------------------------------

@@ -365,13 +365,6 @@ def _check_axis_misuse(closed, axis_names, name, out: List[Finding]):
                 "twice over the same ranks",
                 eqn=eqn, name=name, axes=list(axes)))
         elif not axes:
-            # psum with an EMPTY axis tuple is jax's own identity
-            # marker: shard_map's transpose inserts psum(x, ()) for
-            # unmentioned-axis bookkeeping, so grad-of-shard_map jaxprs
-            # legitimately contain it. Only hand-written collectives
-            # with no axes are the no-op footgun.
-            if eqn.primitive.name == "psum":
-                return
             out.append(_finding(
                 "spmd-axis-misuse",
                 f"{eqn.primitive.name} names no axes — the collective "

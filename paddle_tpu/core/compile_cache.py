@@ -1,89 +1,48 @@
-"""Persistent XLA compilation cache, framework-wide.
+"""Persistent XLA compilation cache for the framework's compile chokepoints.
 
-Reference analog: paddle/fluid/framework/ir/ + the CINN compilation cache
-directory knobs; on the jax stack this is the built-in persistent
-compilation cache (``jax_compilation_cache_dir``), which keys entries by
-serialized HLO + jaxlib version + device topology — a cache written on one
-toolchain/topology never mis-hits on another.
+JAX's built-in persistent cache keys entries by serialized HLO + jaxlib
+version + device topology, so a cache written on one toolchain or topology
+never mis-hits on another. The directory is part of the key too: a cache
+that moves never hits, so it is placed once and from outside the program.
 
-``ensure()`` turns it on process-wide, idempotently, honoring
-``FLAGS_tpu_persistent_cache``. It is called from every compile chokepoint
-the framework owns — ``profiler/xmem.py::aot_compile`` (the AOT
-``lower().compile()`` path that ``jit/api.py``'s per-signature ``_aot_cache``
-and the Executor/Predictor funnel through), ``bench.py``, and
-``tools/pod_report.py`` — so tests, examples, and tools all get warm starts,
-not just bench.
+* ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; this module sets
+  no directory, only the thresholds.
+* unset: ``<repo>/.jax_cache`` (gitignored).
 
-The cache dir defaults to ``<repo>/.jax_cache`` (the directory bench.py has
-always used, so existing warm caches keep hitting) and can be overridden
-with ``PADDLE_TPU_COMPILE_CACHE_DIR``.
+``ensure()`` is called where the framework compiles the programs that take
+minutes — ``Plan.compile`` / ``Plan.train_step``, ``LLMEngine``'s step
+functions, ``profiler/xmem.py::aot_compile`` and ``tools/pod_report.py`` —
+so a second process on the same machine starts warm. Turn the cache off
+with JAX's own switch (``JAX_ENABLE_COMPILATION_CACHE=false``).
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-__all__ = ["ensure", "cache_dir", "enabled"]
+__all__ = ["ensure", "cache_dir"]
 
-# module state: None = never attempted, str path = active, False = off/failed
-_STATE = None
-
-
-def _repo_root() -> str:
-    # paddle_tpu/core/compile_cache.py -> paddle_tpu/core -> paddle_tpu -> repo
-    return os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+_done = False
 
 
 def cache_dir() -> str:
-    """The directory the persistent cache lives in (whether or not active)."""
-    return os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR") or \
-        os.path.join(_repo_root(), ".jax_cache")
+    """The directory the persistent cache lives in."""
+    return os.environ.get(_ENV) or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enabled() -> bool:
-    """Is the persistent cache active in this process?"""
-    return isinstance(_STATE, str)
-
-
-def ensure(force: bool = False) -> Optional[str]:
-    """Activate the persistent XLA compilation cache if the flag asks for
-    it. Idempotent and cheap on repeat calls (one module-global check).
-
-    ``force=True`` activates regardless of ``FLAGS_tpu_persistent_cache``
-    (bench.py's behavior since PR 2 — it always wants the cache).
-    Returns the cache dir when active, None otherwise. Best effort: any
-    failure (read-only FS, headless jax) deactivates quietly — a missing
-    cache is a slow start, never an error.
-    """
-    global _STATE
-    if _STATE is not None and not (force and _STATE is False):
-        return _STATE if isinstance(_STATE, str) else None
-    if not force:
-        try:
-            from paddle_tpu.core.flags import flag
-            if not flag("FLAGS_tpu_persistent_cache"):
-                _STATE = False
-                return None
-        except Exception:
-            _STATE = False
-            return None
-    try:
+def ensure() -> str:
+    """Turn the persistent cache on for this process (idempotent) and
+    return its directory."""
+    global _done
+    if not _done:
         import jax
-        path = cache_dir()
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # bench-proven thresholds: skip sub-2s compiles (cache overhead
-        # dominates), keep everything else regardless of size
+        if not os.environ.get(_ENV):
+            jax.config.update("jax_compilation_cache_dir", cache_dir())
+        # skip sub-2s compiles (the cache round trip costs more than it
+        # saves); keep everything else regardless of size
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _STATE = path
-        return path
-    except Exception:
-        _STATE = False
-        return None
-
-
-def _reset_for_tests():
-    global _STATE
-    _STATE = None
+        _done = True
+    return cache_dir()

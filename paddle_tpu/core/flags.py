@@ -81,32 +81,6 @@ define_flag("FLAGS_tpu_lint", False,
             "section and lint_findings_total metrics. Off: zero per-call "
             "overhead (the check sits inside the new-signature branch; "
             "its gate is one dict lookup + bool check).")
-define_flag("FLAGS_tpu_fused_blocks", "auto",
-            "Fused decoder-block Pallas kernels (ops.pallas_ops."
-            "fused_attention_block / fused_mlp_block): 'auto' uses them "
-            "on TPU for qualifying shapes and never on CPU (except under "
-            "the Pallas interpreter in tests), 'on' forces the fused "
-            "path wherever the kernels can run, 'off' keeps the unfused "
-            "reference composition everywhere.")
-define_flag("FLAGS_tpu_quantized", "auto",
-            "int8 weight path for serving (ops.pallas_ops.int8_matmul "
-            "behind models.llama quantize_params): 'auto' engages the "
-            "Pallas int8 kernels on TPU only (CPU always serves the "
-            "jnp dequant oracle — same math, so 'auto' == 'on' "
-            "numerically wherever the kernel qualifies), 'on' forces "
-            "the quantized weight path everywhere incl. CPU, 'off' "
-            "keeps dense weights. LlamaConfig.quantized overrides "
-            "per-model; this flag is the default for configs that "
-            "leave it None. The quantized KV cache is a separate knob "
-            "(LLMEngine kv_dtype / bench_serve --kv-dtype).")
-define_flag("FLAGS_tpu_persistent_cache", False,
-            "Persistent XLA compilation cache for every compile in the "
-            "process: jit/to_static AOT compiles (via profiler.xmem), "
-            "bench.py, examples, tools/pod_report.py. Cache dir defaults "
-            "to <repo>/.jax_cache (override with "
-            "PADDLE_TPU_COMPILE_CACHE_DIR). Warm starts skip XLA "
-            "compilation entirely; safe to leave on — entries are keyed "
-            "by HLO + jaxlib + topology.")
 define_flag("FLAGS_tpu_watchdog", False,
             "Runtime health layer (paddle_tpu.runtime): phase watchdogs "
             "with faulthandler dumps on expiry, cross-rank heartbeat "

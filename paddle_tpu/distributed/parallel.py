@@ -44,14 +44,8 @@ def shutdown():
     ``jax.distributed.initialize`` instead of init_parallel_env.
     """
     _INITIALIZED[0] = False
-    try:
-        from jax._src.distributed import global_state as _state
-        if getattr(_state, "client", None) is None and \
-                getattr(_state, "service", None) is None:
-            return  # single-process or already shut down
-    except ImportError:  # private path moved: let shutdown() decide
-        pass
-    jax.distributed.shutdown()
+    if jax.distributed.is_initialized():
+        jax.distributed.shutdown()
 
 
 def init_parallel_env(strategy=None):

@@ -74,20 +74,28 @@ def _as_sharding_tree(tree, mesh):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    def seq(x):
+        return isinstance(x, (list, tuple)) and not isinstance(x, P)
+
+    def json_spec(leaf):
+        # reshard JSON form: per dim None or a list of axis names. Any
+        # other list/tuple is a container of specs (one per argument)
+        return seq(leaf) and all(
+            d is None or (seq(d) and all(isinstance(a, str) for a in d))
+            for d in leaf)
+
     def bind(leaf):
-        if leaf is None or isinstance(leaf, NamedSharding):
-            return leaf
         if isinstance(leaf, P):
             return NamedSharding(mesh, leaf)
-        if isinstance(leaf, (list, tuple)):  # reshard JSON form
+        if json_spec(leaf):
             from .reshard import _rebind_spec, spec_from_json
             return NamedSharding(
                 mesh, spec_from_json(_rebind_spec(list(leaf), mesh)))
-        return leaf
+        return leaf   # None, or a Sharding built by the caller
 
     return jax.tree_util.tree_map(
         bind, tree,
-        is_leaf=lambda l: l is None or isinstance(l, (P, list, tuple)))
+        is_leaf=lambda l: l is None or isinstance(l, P) or json_spec(l))
 
 
 def _error_findings(findings):
@@ -143,8 +151,8 @@ def _wrap_step_tracing(plan: "Plan", step_fn: Callable) -> Callable:
                          schedule=plan.schedule, overlap=plan.overlap):
             return step_fn(params, opt_state, batch)
 
-    for attr in ("jitted", "abstract_state", "batch_shardings", "plan",
-                 "plan_topology"):
+    for attr in ("jitted", "lower", "abstract_state", "batch_shardings",
+                 "plan", "plan_topology"):
         if hasattr(step_fn, attr):
             setattr(traced, attr, getattr(step_fn, attr))
     return traced
@@ -238,7 +246,9 @@ class Plan:
         'shard_map' | 'jit'), ``.mesh`` and ``.jitted``.
         """
         import jax
+        from ..core import compile_cache
 
+        compile_cache.ensure()
         topo = None
         if mesh is None:
             topo = self.topology(devices)
@@ -290,10 +300,14 @@ class Plan:
         from ..core.flags import flag
         do_verify = flag("FLAGS_tpu_lint") if verify is None else verify
 
+        # jax.set_mesh, not the legacy ``with mesh``: a trace reads the
+        # mesh from this context to place Pallas kernels
+        # (pallas_ops.kernel_axes), here as in train_step
         def _lint(args, kwargs):
-            self.verify_callable(traceable, *args, mesh=mesh,
-                                 name=getattr(fn, "__name__", "plan_fn"),
-                                 **kwargs)
+            with jax.set_mesh(mesh):
+                self.verify_callable(
+                    traceable, *args, mesh=mesh,
+                    name=getattr(fn, "__name__", "plan_fn"), **kwargs)
 
         state = {"checked": not do_verify}
         if do_verify and example_args is not None:
@@ -304,7 +318,7 @@ class Plan:
             if not state["checked"]:
                 _lint(args, kwargs)
                 state["checked"] = True
-            with mesh:
+            with jax.set_mesh(mesh):
                 return inner(*args, **kwargs)
 
         compiled.path = path
@@ -337,9 +351,12 @@ class Plan:
         schedule/microbatching/overlap, optionally gated through the
         SPMD checker on first call (verify=None → ``FLAGS_tpu_lint``).
         """
+        import jax
         from ..models.llama import build_train_step
+        from ..core import compile_cache
         from ..core.flags import flag
 
+        compile_cache.ensure()
         topo = self.topology(devices)
         use_pp = self.pp > 1 and self.schedule != "none"
         schedule = self.schedule if use_pp else "gpipe"
@@ -360,7 +377,7 @@ class Plan:
 
         def verified_step(params, opt_state, batch):
             if not state["checked"]:
-                with topo.mesh:
+                with jax.set_mesh(topo.mesh):
                     self.verify_callable(inner.jitted, params, opt_state,
                                          batch, mesh=topo.mesh,
                                          name="train_step")
@@ -368,6 +385,7 @@ class Plan:
             return inner(params, opt_state, batch)
 
         verified_step.jitted = inner.jitted
+        verified_step.lower = inner.lower
         verified_step.abstract_state = inner.abstract_state
         verified_step.batch_shardings = inner.batch_shardings
         verified_step.plan = self

@@ -68,27 +68,27 @@ class LlamaConfig:
     # (near-zero extra FLOPs — the right default when activations fit)
     remat_policy: str = "dots"
     # fused decoder-block Pallas kernels (ops.pallas_ops
-    # fused_attention_block / fused_mlp_block): None follows
-    # FLAGS_tpu_fused_blocks; "auto" = TPU-only, "on" = wherever the
-    # kernels can run (incl. the interpreter — what parity tests use),
-    # "off" = always the unfused composition
-    fused_blocks: Any = None
+    # fused_attention_block / fused_mlp_block) — same math as the unfused
+    # composition, so a kernel choice: "auto" = on TPU where shape and
+    # mesh qualify (_fused_block_modes), "on" = wherever the kernels can
+    # run (incl. the interpreter — what parity tests use), "off" = always
+    # the unfused composition
+    fused_blocks: str = "auto"
     # int8 weight path for serving (quantize_params + the pallas_ops
-    # int8_matmul kernels): None follows FLAGS_tpu_quantized; "auto" =
-    # quantize weights on TPU only, "on" = everywhere (CPU runs the jnp
-    # dequant oracle — same math, what parity tests use), "off" = dense
-    quantized: Any = None
+    # int8_matmul kernels): a different model, so never chosen from the
+    # platform — "on" quantizes at engine build everywhere (CPU runs the
+    # jnp oracle, the same integer math), "off" serves dense weights
+    quantized: str = "off"
 
     def __post_init__(self):
         assert self.remat_policy in ("full", "dots"), \
             f"remat_policy must be 'full' or 'dots', got " \
             f"{self.remat_policy!r}"
-        assert self.fused_blocks in (None, "auto", "on", "off"), \
-            f"fused_blocks must be None, 'auto', 'on' or 'off', got " \
+        assert self.fused_blocks in ("auto", "on", "off"), \
+            f"fused_blocks must be 'auto', 'on' or 'off', got " \
             f"{self.fused_blocks!r}"
-        assert self.quantized in (None, "auto", "on", "off"), \
-            f"quantized must be None, 'auto', 'on' or 'off', got " \
-            f"{self.quantized!r}"
+        assert self.quantized in ("on", "off"), \
+            f"quantized must be 'on' or 'off', got {self.quantized!r}"
 
     @property
     def head_dim(self):
@@ -226,6 +226,35 @@ def _rms_norm(x, w, eps):
     return (x32 * lax.rsqrt(ms + eps)).astype(x.dtype) * w
 
 
+def _batch_axes(axes):
+    """The batch-dim entry of a PartitionSpec over mesh ``axes``
+    (HybridTopology.batch_axes: dp, plus the ZeRO axis when carved)."""
+    return tuple(a for a in ("dp", "sharding") if a in axes) or None
+
+
+def _per_device(fn, args, replicated=(), heads_dim=None):
+    """``fn(*args, *replicated)`` holding Pallas kernels: called directly
+    where the program is unpartitioned, per device under one shard_map
+    over every mesh axis where it is partitioned (pallas_ops.kernel_axes)
+    — ``args`` split over the batch axes on dim 0 and, with ``heads_dim``,
+    over 'mp' on that dim; ``replicated`` whole on every device."""
+    from ..ops import pallas_ops
+    axes = pallas_ops.kernel_axes()
+    if not axes:
+        return fn(*args, *replicated)
+
+    def spec(a):
+        dims = [None] * a.ndim
+        dims[0] = _batch_axes(axes)
+        if heads_dim is not None and "mp" in axes:
+            dims[heads_dim] = "mp"
+        return P(*dims)
+
+    return jax.shard_map(
+        fn, in_specs=tuple(map(spec, args)) + (P(),) * len(replicated),
+        out_specs=spec(args[0]), check_vma=False)(*args, *replicated)
+
+
 def _attention(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
                cp_axis="sp", cp_axis_level=False):
     B, S, H = x.shape
@@ -254,9 +283,14 @@ def _attention(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
             rep = nh // nkv
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        # flash-attention via Pallas when available; jnp fallback
+        # flash attention via Pallas on TPU for qualifying shapes, XLA
+        # elsewhere; batch rides dp(+sharding), heads ride mp
         from ..ops import pallas_ops
-        out = pallas_ops.causal_attention(q, k, v)
+        if pallas_ops.flash_attention_available(q.shape, q.dtype):
+            out = _per_device(pallas_ops.causal_attention, (q, k, v),
+                              heads_dim=2)
+        else:
+            out = pallas_ops.causal_attention(q, k, v)
     return _qmm(out.reshape(B, S, H), lp["wo"])
 
 
@@ -279,27 +313,6 @@ def _qmm(x, w):
         from ..ops.pallas_ops import int8_matmul
         return int8_matmul(x, w["q"], w["scale"])
     return x @ w
-
-
-def _quantized_mode(cfg: LlamaConfig) -> bool:
-    """Resolved int8-weight policy: cfg.quantized, else
-    FLAGS_tpu_quantized. "auto" engages on TPU only (CPU keeps dense
-    weights — the jnp oracle exists for parity, not speed); "on"
-    quantizes everywhere including CPU (what parity tests use); "off"
-    never quantizes."""
-    from ..ops import pallas_ops
-    mode = cfg.quantized
-    if mode is None:
-        try:
-            from ..core.flags import flag
-            mode = flag("FLAGS_tpu_quantized")
-        except Exception:
-            mode = "auto"
-    if mode == "off":
-        return False
-    if mode == "auto" and not pallas_ops._on_tpu():
-        return False
-    return True
 
 
 # weight leaves quantize_params converts (per-layer stacked [L, K, N]);
@@ -404,21 +417,22 @@ def _moe_mlp(cfg: LlamaConfig, lp, x):
 
 def _fused_block_modes(cfg: LlamaConfig, x, cp_mesh, cp_axis_level):
     """(use_fused_attention, use_fused_mlp) — resolved at trace time from
-    the policy (cfg.fused_blocks, else FLAGS_tpu_fused_blocks) and shape
-    eligibility. "auto" engages only on real TPU (never the CPU jnp path
-    a test traces); "on" engages wherever the kernels can run, including
-    the Pallas interpreter — which is how parity tests exercise this."""
+    cfg.fused_blocks, the platform, the shape and the mesh. "auto" engages
+    only on real TPU (never the CPU jnp path a test traces); "on" engages
+    wherever the kernels can run, including the Pallas interpreter —
+    which is how parity tests exercise this. Mesh rule, on top of
+    pallas_ops.kernel_axes: the kernels take whole [H, H] / [H, I]
+    weights and add the residual inside, so under tensor parallelism
+    (mp > 1: column/row weight shards, a psum before the residual) they
+    are excluded; with mp == 1 they run per device over the batch axes."""
     from ..ops import pallas_ops
     mode = cfg.fused_blocks
-    if mode is None:
-        try:
-            from ..core.flags import flag
-            mode = flag("FLAGS_tpu_fused_blocks")
-        except Exception:
-            mode = "auto"
     if mode == "off":
         return False, False
     if mode == "auto" and not pallas_ops._on_tpu():
+        return False, False
+    if pallas_ops.kernel_axes() and \
+            jax.sharding.get_abstract_mesh().shape.get("mp", 1) > 1:
         return False, False
     attn_ok = (cp_mesh is None and not cp_axis_level
                and cfg.num_key_value_heads == cfg.num_attention_heads
@@ -443,9 +457,11 @@ def decoder_layer(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
         fused_attn = fused_mlp = False
     if fused_attn:
         # norm + qkv + rope + flash + wo + residual in two Pallas kernels
-        h = pallas_ops.fused_attention_block(
-            x, lp["ln1"], lp["wq"], lp["wk"], lp["wv"], lp["wo"],
-            sin, cos, head_dim=cfg.head_dim, eps=cfg.rms_norm_eps)
+        h = _per_device(
+            functools.partial(pallas_ops.fused_attention_block,
+                              head_dim=cfg.head_dim, eps=cfg.rms_norm_eps),
+            (x,), (lp["ln1"], lp["wq"], lp["wk"], lp["wv"], lp["wo"],
+                   sin, cos))
     else:
         h = x + _attention(cfg, lp,
                            _rms_norm(x, lp["ln1"], cfg.rms_norm_eps),
@@ -457,9 +473,10 @@ def decoder_layer(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
         return h + mlp_out, aux
     if fused_mlp:
         # norm + gate/up + silu + down + residual in one Pallas kernel
-        out = pallas_ops.fused_mlp_block(
-            h, lp["ln2"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            eps=cfg.rms_norm_eps)
+        out = _per_device(
+            functools.partial(pallas_ops.fused_mlp_block,
+                              eps=cfg.rms_norm_eps),
+            (h,), (lp["ln2"], lp["w_gate"], lp["w_up"], lp["w_down"]))
         return out, jnp.zeros((), jnp.float32)
     normed = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
     return h + _dense_mlp(lp, normed), jnp.zeros((), jnp.float32)
@@ -947,7 +964,7 @@ def build_train_step(cfg: LlamaConfig, topo, optimizer=None, use_pp=None,
                     for path, s in flat_specs]
 
     def init_fn(rng):
-        with mesh:
+        with jax.set_mesh(mesh):
             params = jax.jit(
                 lambda k: init_params(cfg, k),
                 out_shardings=param_sh)(rng)
@@ -992,8 +1009,17 @@ def build_train_step(cfg: LlamaConfig, topo, optimizer=None, use_pp=None,
                        donate_argnums=(0, 1))
 
     def step_fn(params, opt_state, batch):
-        with mesh:
+        # set_mesh (not the legacy ``with mesh``): the trace reads the
+        # mesh from the context to place Pallas kernels
+        # (pallas_ops.kernel_axes)
+        with jax.set_mesh(mesh):
             return step_jit(params, opt_state, batch)
+
+    def lower(params, opt_state, batch):
+        """The step traced and lowered (not run) under the same mesh
+        context ``step_fn`` runs it in; avals do for arguments."""
+        with jax.set_mesh(mesh):
+            return step_jit.lower(params, opt_state, batch)
 
     def abstract_state():
         """ShapeDtypeStructs (with shardings) for (params, opt_state) —
@@ -1022,6 +1048,7 @@ def build_train_step(cfg: LlamaConfig, topo, optimizer=None, use_pp=None,
         return p_abs, o_abs
 
     step_fn.jitted = step_jit
+    step_fn.lower = lower
     step_fn.abstract_state = abstract_state
     step_fn.batch_shardings = batch_sh
     return step_fn, init_fn

@@ -87,12 +87,9 @@ def install():
     if _installed[0]:
         return
     _installed[0] = True
-    try:
-        from jax import monitoring
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        monitoring.register_event_listener(_on_event)
-    except Exception:  # pragma: no cover - jax without monitoring
-        _installed[0] = False
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
 
 
 def installed() -> bool:

@@ -207,30 +207,6 @@ def check_tree(tree, name: str, action: str = "warn") -> bool:
 # first-bad-op localization
 # ---------------------------------------------------------------------------
 
-def _eqn_where(eqn) -> str:
-    """file:line (fn) attribution of a jaxpr eqn, best effort."""
-    try:
-        from jax._src import source_info_util
-        return source_info_util.summarize(eqn.source_info)
-    except Exception:
-        return "<unknown>"
-
-
-def _eqn_frame(eqn) -> Tuple[Optional[str], Optional[int]]:
-    try:
-        from jax._src import source_info_util
-        fr = source_info_util.user_frame(eqn.source_info)
-        if fr is not None:
-            return fr.file_name, int(fr.start_line)
-    except (ImportError, AttributeError, TypeError, ValueError) as e:
-        # jax._src layout moves between versions; attribution is
-        # best-effort garnish on the finding, never a reason to fail it
-        import logging
-        logging.getLogger(__name__).debug(
-            "eqn frame attribution failed: %s", e)
-    return None, None
-
-
 def _is_float(x) -> bool:
     import numpy as np
     dt = getattr(x, "dtype", None)
@@ -270,7 +246,7 @@ def _interpret(jaxpr, consts, args, path: str):
     where report names the first primitive producing non-finite outputs
     from finite inputs. Evaluation continues after a finding so callers
     still get the function's outputs."""
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     env: Dict[Any, Any] = {}
 
@@ -316,10 +292,11 @@ def _interpret(jaxpr, consts, args, path: str):
         if inner is not None:
             report = inner
         else:
-            file_name, line = _eqn_frame(eqn)
+            from ..analysis.core import eqn_site
+            file_name, line, where = eqn_site(eqn)
             report = {
                 "primitive": eqn.primitive.name,
-                "where": _eqn_where(eqn),
+                "where": where,
                 "file": file_name,
                 "line": line,
                 "eqn_index": idx,

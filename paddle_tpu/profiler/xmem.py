@@ -152,10 +152,8 @@ def aot_compile(source: str, name: str, jit_fn, args, kwargs=None,
     points call this INSTEAD of letting the first traced call compile
     internally, then dispatch every same-signature call through the
     returned executable."""
-    # every framework compile funnels through here — activating the
-    # persistent XLA cache at this chokepoint gives tests/examples/
-    # tools warm starts when FLAGS_tpu_persistent_cache is on
-    # (ensure() is internally best-effort: off-or-failed is a no-op)
+    # every captured framework compile funnels through here: a compile
+    # chokepoint, so the persistent XLA cache is on (core.compile_cache)
     from paddle_tpu.core import compile_cache
     compile_cache.ensure()
     try:

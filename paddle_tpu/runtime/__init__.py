@@ -11,22 +11,21 @@ from typing import List
 
 from . import watchdog, health, rewind  # noqa: F401
 from .watchdog import (PhaseTimeout, Watchdog, run_with_deadline,  # noqa: F401
-                       init_with_retries, incidents, last_incident,
+                       incidents, last_incident,
                        record_incident, clear_incidents,
                        persist_incidents, incident_sidecar_path)
 from .health import (CollectiveTimeout, HealthMonitor,  # noqa: F401
-                     HeartbeatTracker, collective_beacon,
-                     record_fused_fallback)
+                     HeartbeatTracker, collective_beacon)
 from .rewind import (RewindBudgetExceeded, RewindResult,  # noqa: F401
                      RewindGuard)
 
 __all__ = ["watchdog", "health", "rewind", "PhaseTimeout", "Watchdog",
-           "run_with_deadline", "init_with_retries", "incidents",
+           "run_with_deadline", "incidents",
            "last_incident", "record_incident", "clear_incidents",
            "persist_incidents", "incident_sidecar_path",
            "CollectiveTimeout", "HealthMonitor", "HeartbeatTracker",
            "collective_beacon",
-           "record_fused_fallback", "RewindBudgetExceeded", "RewindResult",
+           "RewindBudgetExceeded", "RewindResult",
            "RewindGuard", "summary_lines"]
 
 

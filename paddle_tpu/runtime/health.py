@@ -48,7 +48,7 @@ from .watchdog import (PhaseTimeout, record_incident, persist_incidents,
 
 __all__ = ["CollectiveTimeout", "HealthMonitor", "HeartbeatTracker",
            "install", "uninstall", "get", "monitored", "current_step",
-           "set_step", "collective_beacon", "record_fused_fallback"]
+           "set_step", "collective_beacon"]
 
 RELAUNCH_EXIT_CODE = 101  # distributed.fault_tolerance contract (PR 5)
 
@@ -465,15 +465,3 @@ def collective_beacon(op_name: str):
         return
     with m.collective(op_name):
         yield
-
-
-def record_fused_fallback(kernel: str, err: Exception):
-    """A fused Pallas block failed at execution time and the jnp
-    reference path took over (graceful degradation, not a crash)."""
-    record_incident("fused_fallback", kernel=kernel,
-                    error=(str(err) or repr(err))[-500:])
-    from ..profiler import metrics
-    if metrics.enabled():
-        metrics.counter("fused_fallback_total",
-                        "Fused-kernel runtime fallbacks to the jnp "
-                        "reference path", kernel=kernel).inc()

@@ -26,14 +26,9 @@ subsystem:
     :class:`PhaseTimeout` if it does not land. Generalizes bench.py's
     measure-thread watchdog.
 
-``init_with_retries``
-    Device/backend init with exponential backoff inside a window and
-    fail-fast on a hung attempt (bench.py's ``_init_device_with_retries``
-    now delegates here).
-
 Incident records accumulate in a bounded module buffer (``incidents()``)
 so bench.py and the Profiler "Health" section can report *what* hung
-and *when* instead of silently carrying stale numbers forward.
+and *when*.
 """
 from __future__ import annotations
 
@@ -48,7 +43,7 @@ from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
 __all__ = ["PhaseTimeout", "Watchdog", "run_with_deadline",
-           "init_with_retries", "record_incident", "incidents",
+           "record_incident", "incidents",
            "clear_incidents", "last_incident", "persist_incidents",
            "incident_sidecar_path", "INCIDENT_SCHEMA", "PHASES", "phase",
            "global_watchdog"]
@@ -404,65 +399,3 @@ def run_with_deadline(fn: Callable[[], Any], window_s: float, *,
     if "exc" in box:
         raise box["exc"]
     return box["value"]
-
-
-def init_with_retries(probe_fn, window_s: float = 240.0,
-                      base_delay: float = 5.0, factor: float = 2.0,
-                      max_delay: float = 60.0, log=None,
-                      sleep=time.sleep, clock=time.monotonic,
-                      phase: str = "device_init"):
-    """Retry transient init failures with exponential backoff until the
-    ``window_s`` budget expires.
-
-    A dead backend fails two ways: ``probe_fn`` raises (claim refused —
-    often transient while another job releases the chip, so retry), or
-    it never returns (make_c_api_client hang). Each attempt runs on its
-    own daemon thread so a hang is bounded by the remaining window
-    instead of blocking forever; a hung attempt is NOT retried, because
-    the runtime's init lock would block every later attempt behind it.
-
-    Returns ``(ok, attempts, last_error)``. Injectable sleep/clock keep
-    the backoff schedule unit-testable without real waiting.
-    """
-    deadline = clock() + window_s
-    delay = base_delay
-    attempts = 0
-    last_err = "no attempt made"
-    while clock() < deadline:
-        attempts += 1
-        box: Dict[str, Any] = {}
-        done = threading.Event()
-
-        def _attempt():
-            try:
-                probe_fn()
-                box["ok"] = True
-            except Exception as e:  # noqa: BLE001 — classified below
-                box["err"] = str(e) or repr(e)
-            finally:
-                done.set()
-
-        th = threading.Thread(target=_attempt, daemon=True)
-        th.start()
-        finished = done.wait(max(0.0, deadline - clock()))
-        if box.get("ok"):
-            return True, attempts, None
-        if not finished:
-            _expired_metric(phase)
-            record_incident("watchdog_expired", phase=phase,
-                            elapsed_s=window_s, deadline_s=window_s,
-                            detail=f"init attempt {attempts} hung")
-            return False, attempts, (
-                f"attempt {attempts} hung past the {window_s:.0f}s window")
-        last_err = box.get("err", "unknown init failure")
-        pause = min(delay, max(0.0, deadline - clock()))
-        if pause <= 0:
-            break
-        if log:
-            log(f"device init attempt {attempts} failed ({last_err}); "
-                f"retrying in {pause:.1f}s")
-        sleep(pause)
-        delay = min(delay * factor, max_delay)
-    record_incident("init_failed", phase=phase, attempts=attempts,
-                    window_s=window_s, error=str(last_err)[-500:])
-    return False, attempts, last_err

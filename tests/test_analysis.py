@@ -95,7 +95,7 @@ def test_host_callback_in_while_fires():
 
 
 def test_f64_promotion_fires():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         found = jaxpr_checks.lint_callable(
             lambda x: x + np.float64(1.0), np.ones(2, np.float32))
     hits = [f for f in found if f.rule == "f64-promotion"]

@@ -1,71 +1,60 @@
-"""FLAGS_tpu_persistent_cache / core.compile_cache: the framework-wide
-persistent XLA compilation cache promoted out of bench.py."""
+"""core.compile_cache: the persistent XLA cache is placed from outside.
+
+``JAX_COMPILATION_CACHE_DIR`` set -> the code sets no directory (JAX reads
+the variable itself); unset -> ``<repo>/.jax_cache``. Thresholds are set
+either way, and the two entry points turn the cache on by themselves.
+"""
 import os
 
 import jax
 import pytest
 
-from paddle_tpu.core import compile_cache, flags
+from paddle_tpu.core import compile_cache
+
+_KEYS = ("jax_compilation_cache_dir",
+         "jax_persistent_cache_min_compile_time_secs",
+         "jax_persistent_cache_min_entry_size_bytes")
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
-def _fresh_state():
-    saved_flag = flags.flag("FLAGS_tpu_persistent_cache")
-    saved_dir = jax.config.jax_compilation_cache_dir
-    saved_env = os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR")
-    compile_cache._reset_for_tests()
+def _fresh_state(monkeypatch):
+    saved = {k: getattr(jax.config, k) for k in _KEYS}
+    monkeypatch.setattr(compile_cache, "_done", False)
     yield
-    compile_cache._reset_for_tests()
-    flags.set_flags({"FLAGS_tpu_persistent_cache": saved_flag})
-    jax.config.update("jax_compilation_cache_dir", saved_dir)
-    if saved_env is None:
-        os.environ.pop("PADDLE_TPU_COMPILE_CACHE_DIR", None)
-    else:
-        os.environ["PADDLE_TPU_COMPILE_CACHE_DIR"] = saved_env
+    for k, v in saved.items():
+        jax.config.update(k, v)
 
 
-def test_flag_off_is_noop():
-    flags.set_flags({"FLAGS_tpu_persistent_cache": False})
-    assert compile_cache.ensure() is None
-    assert not compile_cache.enabled()
+def test_env_places_the_cache_and_code_sets_no_directory(monkeypatch,
+                                                         tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    jax.config.update("jax_compilation_cache_dir", "left-to-jax")
+    assert compile_cache.ensure() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == "left-to-jax"
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 2
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
 
 
-def test_flag_on_activates_and_is_idempotent(tmp_path):
-    os.environ["PADDLE_TPU_COMPILE_CACHE_DIR"] = str(tmp_path / "cc")
-    flags.set_flags({"FLAGS_tpu_persistent_cache": True})
-    path = compile_cache.ensure()
-    assert path == str(tmp_path / "cc") and os.path.isdir(path)
-    assert compile_cache.enabled()
-    assert jax.config.jax_compilation_cache_dir == path
-    assert compile_cache.ensure() == path  # repeat call: cached answer
+def test_without_env_the_cache_is_repo_jax_cache(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(_REPO, ".jax_cache")
+    assert compile_cache.ensure() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    jax.config.update("jax_compilation_cache_dir", "not-again")
+    assert compile_cache.ensure() == want        # idempotent: one placement
+    assert jax.config.jax_compilation_cache_dir == "not-again"
 
 
-def test_force_overrides_flag(tmp_path):
-    os.environ["PADDLE_TPU_COMPILE_CACHE_DIR"] = str(tmp_path / "cc")
-    flags.set_flags({"FLAGS_tpu_persistent_cache": False})
-    assert compile_cache.ensure() is None          # flag says no
-    assert compile_cache.ensure(force=True) is not None  # bench says yes
-    assert compile_cache.enabled()
+def test_entry_points_turn_the_cache_on(monkeypatch):
+    from paddle_tpu.distributed.plan import Plan
+    from paddle_tpu.models import llama
+    from paddle_tpu import serving
 
-
-def test_default_dir_is_bench_compatible():
-    # the framework default must be the .jax_cache dir bench.py has
-    # always written, so existing warm caches keep hitting
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    os.environ.pop("PADDLE_TPU_COMPILE_CACHE_DIR", None)
-    assert compile_cache.cache_dir() == os.path.join(repo, ".jax_cache")
-
-
-def test_aot_compile_path_respects_flag(tmp_path):
-    """xmem.aot_compile (the jit/api.py AOT chokepoint) activates the
-    cache when the flag is on."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.profiler import xmem
-
-    os.environ["PADDLE_TPU_COMPILE_CACHE_DIR"] = str(tmp_path / "cc")
-    flags.set_flags({"FLAGS_tpu_persistent_cache": True})
-    fn = jax.jit(lambda x: x * 2)
-    compiled = xmem.aot_compile("test", "double", fn, (jnp.ones((4,)),))
-    assert compiled is not None
-    assert compile_cache.enabled()
+    cfg = llama.preset("llama-debug")
+    Plan().train_step(cfg, jax.devices()[:1], verify=False)
+    assert compile_cache._done
+    monkeypatch.setattr(compile_cache, "_done", False)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    serving.LLMEngine(cfg, params, max_running=2)._step_fn(1)
+    assert compile_cache._done

@@ -66,7 +66,7 @@ class TestShardTensor:
 class TestCollectivesUnderShardMap:
     def test_all_reduce_psum(self):
         topo = dist.init_mesh(dp=8)
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def f(x):
             t = paddle.Tensor(x, stop_gradient=True)
@@ -81,7 +81,7 @@ class TestCollectivesUnderShardMap:
 
     def test_all_gather(self):
         topo = dist.init_mesh(dp=8)
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def f(x):
             t = paddle.Tensor(x, stop_gradient=True)
@@ -96,7 +96,7 @@ class TestCollectivesUnderShardMap:
 
     def test_all_to_all(self):
         topo = dist.init_mesh(dp=8)
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def f(x):
             t = paddle.Tensor(x, stop_gradient=True)
@@ -111,7 +111,7 @@ class TestCollectivesUnderShardMap:
 
     def test_reduce_scatter(self):
         topo = dist.init_mesh(dp=8)
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def f(x):
             t = paddle.Tensor(x, stop_gradient=True)
@@ -132,7 +132,7 @@ class TestCollectivesUnderShardMap:
         paddle.set_flags({"FLAGS_tpu_metrics": True})
         try:
             topo = dist.init_mesh(dp=8)
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
 
             def f(x):
                 t = paddle.Tensor(x, stop_gradient=True)

@@ -1,13 +1,12 @@
 """Perf ledger (ISSUE 17): schema round-trip, direction-aware regression
-gate, staleness verdict, artifact ingestion, and the stdlib-only CLI.
+gate, staleness verdict, normalizers, and the stdlib-only CLI.
 
-The acceptance bar: the committed ``PERF_LEDGER.jsonl`` passes ``check``
-and its ``report`` reproduces the known trajectory (62.41%% MFU at r5,
-multichip 144.84 ms/step with vs_baseline 0.789 at r6) with no jax
-import; a seeded tokens/s regression and a stale-measurement ledger both
-exit 1; schema garbage exits 2; the chip-free proxy gate
-(``check --proxies-only``) is a tier-1 ratchet that can never silently
-regress.
+Everything runs on rows built here or under ``tmp_path``: the repo tracks
+no ledger of its own (``PERF_LEDGER.jsonl`` at the root is the driver's
+file, which the repo's tools never write), so no test reads a record
+file. A seeded tokens/s regression and a stale-measurement ledger both
+exit 1; schema garbage exits 2; ``append``/``report``/``check`` run with
+no jax import.
 """
 import importlib.util
 import json
@@ -19,12 +18,14 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CLI = os.path.join(REPO, "tools", "perf_ledger.py")
-COMMITTED = os.path.join(REPO, "PERF_LEDGER.jsonl")
 
-_ARTIFACTS = ([os.path.join(REPO, f"BENCH_r0{i}.json") for i in range(1, 7)]
-              + [os.path.join(REPO, f"MULTICHIP_r0{i}.json")
-                 for i in range(1, 6)]
-              + [os.path.join(REPO, "FLEET_r01.json")])
+# a bench_serve.py result line, as the script prints it on a CPU smoke
+_SERVE_LINE = {
+    "metric": "serve_tokens_per_sec_chip", "value": 263.35,
+    "unit": "tokens/s/chip", "ttft_p95_ms": 10.0, "latency_p95_ms": 80.0,
+    "requests": 6, "workload": "uniform", "tokens": 96, "steps": 40,
+    "reuse": {"prefix_hit_rate": 0.0}, "kv": {"dtype": "bf16"},
+    "preset": "llama-debug", "platform": "cpu", "device": "cpu", "chips": 1}
 
 
 @pytest.fixture(scope="module")
@@ -194,48 +195,8 @@ def test_proxies_only_gates_proxies_and_skips_staleness(L):
 # ---------------------------------------------------------------------------
 
 
-def test_ingest_reproduces_known_trajectory(L):
-    rows = L.ingest_artifacts(_ARTIFACTS)
-    text = L.report(rows, fmt="json")
-    doc = json.loads(text)
-    by_metric = {(s["metric"], s["source"]): s for s in doc["series"]}
-    mfu = by_metric[("mfu_percent", "bench.py")]
-    assert mfu["trajectory"] == [{"round": 3, "value": 62.27},
-                                 {"round": 5, "value": 62.41}]
-    step = by_metric[("multichip_step_ms", "bench.py --multichip")]
-    assert step["latest"] == 144.84
-    vs = by_metric[("multichip_vs_lockstep", "bench.py --multichip")]
-    assert vs["latest"] == 0.789
-    fleet = by_metric[("fleet_min_replicas", "fleet_sim")]
-    assert fleet["latest"] == 2.0
-    # the six BENCH rounds: r01/r02 parse failures and r03/r04/r05
-    # timeouts are error rows, not silent gaps
-    errors = [r for r in rows if r["kind"] == "error"]
-    assert len(errors) == 5
-    # ingestion is deterministic: byte-identical on re-run
-    again = L.ingest_artifacts(_ARTIFACTS)
-    assert [L.dumps(r) for r in rows] == [L.dumps(r) for r in again]
-
-
-def test_committed_ledger_matches_artifact_ingest(L):
-    committed = L.load(COMMITTED)
-    rows = L.ingest_artifacts(_ARTIFACTS)
-    # driver-artifact rows are the committed prefix (the tail carries
-    # rows appended by later bench runs, e.g. the ingested serve line)
-    assert len(committed) >= len(rows)
-    assert ([L.dumps(r) for r in committed[:len(rows)]]
-            == [L.dumps(r) for r in rows])
-
-
-def test_committed_ledger_passes_gate(L):
-    verdict = L.check(L.load(COMMITTED))
-    assert verdict["ok"], verdict
-
-
 def test_from_bench_serve_result_labels_series(L):
-    with open(os.path.join(REPO, ".bench_serve_last.json")) as f:
-        payload = json.load(f)
-    row = L.from_bench_serve_result(payload, round=None)
+    row = L.from_bench_serve_result(_SERVE_LINE, round=None)
     assert row["label"] == "llama-debug:uniform:kv=bf16"
     assert row["metrics"]["serve_tokens_per_sec_chip"] == 263.35
     assert row["metrics"]["serve_ttft_p95_ms"] == 10.0
@@ -257,11 +218,6 @@ def test_from_pod_report_serving_shape(L):
 # ---------------------------------------------------------------------------
 # CLI: exit-code matrix, no-jax guard, tier-1 proxy ratchet
 # ---------------------------------------------------------------------------
-
-
-def test_cli_check_ok_on_committed_history():
-    p = _run_cli("check")
-    assert p.returncode == 0, p.stdout + p.stderr
 
 
 def test_cli_exit_1_on_seeded_regression(L, tmp_path):
@@ -294,55 +250,56 @@ def test_cli_exit_2_on_schema_garbage(tmp_path):
     assert p.returncode == 2
 
 
-def test_cli_ingest_append_report_runs_without_jax(tmp_path):
+def test_cli_append_report_check_run_without_jax(tmp_path):
     poison = tmp_path / "poison"
     poison.mkdir()
     (poison / "jax.py").write_text(
         "raise ImportError('perf_ledger must not import jax')\n")
     env = {"PYTHONPATH": str(poison)}
     path = str(tmp_path / "ledger.jsonl")
-    p = _run_cli("--ledger", path, "ingest", *_ARTIFACTS, env_extra=env)
-    assert p.returncode == 0, p.stderr
-    p = _run_cli("--ledger", path, "append",
-                 os.path.join(REPO, ".bench_serve_last.json"),
-                 env_extra=env)
-    assert p.returncode == 0, p.stderr
+    serve = tmp_path / "serve_line.json"
+    serve.write_text(json.dumps(_SERVE_LINE))
+    for artifact in (str(serve), os.path.join(REPO, "FLEET_r01.json")):
+        p = _run_cli("--ledger", path, "append", artifact, env_extra=env)
+        assert p.returncode == 0, p.stderr
     p = _run_cli("--ledger", path, "report", env_extra=env)
     assert p.returncode == 0, p.stderr
-    assert "144.84" in p.stdout and "62.41" in p.stdout
+    assert "263.35" in p.stdout
     p = _run_cli("--ledger", path, "report", "--format", "json",
                  env_extra=env)
-    assert json.loads(p.stdout)["rows"] == 15
-    p = _run_cli("--ledger", path, "check", env_extra=env)
+    assert json.loads(p.stdout)["rows"] == 2
+    p = _run_cli("--ledger", path, "check", "--proxies-only",
+                 env_extra=env)
     assert p.returncode == 0, p.stdout + p.stderr
-
-
-def test_proxy_ratchet_on_committed_ledger():
-    """Tier-1 ratchet: chip-free proxy metrics (plan_capacity,
-    overlap_fraction, predicted step ms, ...) in the committed ledger
-    must never regress — the CI analogue of the tpu_lint zero-findings
-    guard."""
-    p = _run_cli("check", "--proxies-only")
-    assert p.returncode == 0, \
-        f"proxy metric regression in PERF_LEDGER.jsonl:\n{p.stdout}"
     verdict = json.loads(p.stdout)
     assert verdict["proxies_only"] and verdict["ok"]
 
 
+def test_default_ledger_is_not_the_drivers_file():
+    """Nothing in the repo writes <repo>/PERF_LEDGER.jsonl: the tool and
+    both benches default to runs/perf_ledger.jsonl."""
+    p = _run_cli("--help")
+    assert "runs/perf_ledger.jsonl" in p.stdout
+    for script in ("bench.py", "bench_serve.py",
+                   os.path.join("tools", "perf_ledger.py"),
+                   os.path.join("tools", "pod_report.py")):
+        with open(os.path.join(REPO, script)) as f:
+            src = f.read()
+        assert '"runs", "perf_ledger.jsonl")' in src
+        assert '"PERF_LEDGER.jsonl"' not in src
+
+
 def test_bench_ledger_out_appends_error_row(tmp_path):
     """bench.py --ledger-out writes a ledger row even when the bench
-    dies (chaos hook kills device init) — error rounds are history
-    too."""
+    fails (no chip here) — error rounds are history too — and the exit
+    code says it failed."""
     path = str(tmp_path / "ledger.jsonl")
-    env = dict(os.environ)
-    env.update({"PTQ_CHAOS": "raise@device.init",
-                "PADDLE_TPU_BENCH_DEVICE_TIMEOUT": "1",
-                "PADDLE_TPU_BENCH_DEVICE_RETRY_DELAY": "0.1",
-                "JAX_PLATFORMS": "cpu"})
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"),
          "--ledger-out", path],
         capture_output=True, text=True, env=env, cwd=REPO, timeout=300)
+    assert p.returncode == 1
     line = [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
     assert line, p.stdout + p.stderr
     assert json.loads(line[-1])["error"]

@@ -458,6 +458,9 @@ def test_bench_serve_shared_prefix_smoke():
         "JAX_PLATFORMS": "cpu",
         "PADDLE_TPU_BENCH_SERVE_REQUESTS": "8",
         "PADDLE_TPU_BENCH_SERVE_NEW": "6",
+        # prefix reuse is page-granular: toy prompts (<= 24 tokens) need
+        # toy pages, not the engine's 128-token default
+        "PADDLE_TPU_BENCH_SERVE_PAGE": "16",
         "PADDLE_TPU_BENCH_TIMEOUT": "300",
     })
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

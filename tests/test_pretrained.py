@@ -168,6 +168,12 @@ def test_no_constructor_drops_the_flag(monkeypatch, tmp_path):
     """Every zoo constructor must route pretrained= to load_pretrained:
     with no artifact anywhere, pretrained=True always raises."""
     _isolate_sources(monkeypatch, tmp_path)
+    # the 38 models are built only to be refused: zero-filled parameters
+    # instead of the random initializers (~140 s of tier-1 at full size)
+    from paddle_tpu.nn.layer import layers
+    monkeypatch.setattr(
+        layers, "_resolve_initializer",
+        lambda *a: lambda shape, dtype: np.zeros(shape, dtype))
     ctors = ["resnet18", "resnet34", "resnet50", "resnet101", "resnet152",
              "resnext50_32x4d", "resnext50_64x4d", "resnext101_32x4d",
              "resnext101_64x4d", "resnext152_32x4d", "resnext152_64x4d",

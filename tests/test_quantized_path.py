@@ -292,13 +292,17 @@ def test_quantize_params_forward_parity(model):
     assert int(jnp.argmax(out[0, -1])) == int(jnp.argmax(ref[0, -1]))
 
 
-def test_quantized_mode_gating(model):
-    cfg, _ = model
-    assert not llama._quantized_mode(cfg)          # auto, off-TPU
-    assert llama._quantized_mode(_tiny_cfg(quantized="on"))
-    assert not llama._quantized_mode(_tiny_cfg(quantized="off"))
-    with pytest.raises(AssertionError):
-        _tiny_cfg(quantized="sometimes")
+def test_quantized_is_asked_for_never_inferred(model):
+    """int8 weights are a different model: the engine serves them only
+    when the config says "on" — never because of the platform (there is
+    no "auto"), so CPU tests and chip serving share their numerics."""
+    cfg, params = model
+    assert cfg.quantized == "off"
+    eng = serving.LLMEngine(cfg, params, max_running=2)
+    assert not isinstance(eng.params["layers"]["wq"], dict)
+    for bad in ("auto", "sometimes", None):
+        with pytest.raises(AssertionError):
+            _tiny_cfg(quantized=bad)
 
 
 def test_engine_quantized_weights_streams(model):

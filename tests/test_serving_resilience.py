@@ -288,6 +288,28 @@ def test_failure_classification():
     assert classify(ValueError("?")) == "unknown"
 
 
+def test_step_that_cannot_be_lowered_raises_with_nothing_quarantined(model):
+    """Recovery is for run-time faults. A step function that fails while
+    being traced/lowered/compiled is a broken program: it raises out of
+    ``step()`` — no recovery, no bisection, no quarantined request."""
+    cfg, params = model
+    eng = _engine(cfg, params)
+
+    def refused(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    eng._forward_paged = refused
+    rid = eng.add_request([5, 6, 7], 4)
+    before = serving.serving_stats()
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        eng.step()
+    after = serving.serving_stats()
+    assert after["recoveries"] == before["recoveries"]
+    assert after["quarantined"] == before["quarantined"]
+    assert eng.state_of(rid).value == "running"
+    assert eng.error_of(rid) is None
+
+
 def test_transient_step_failure_recovers_bit_identical(model, workload):
     """Injected fail@serve.step (once): pools rebuild, every stream
     replays through the unified fed/known path and finishes identical

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""perf_ledger — append, gate and report the repo's perf ledger.
+"""perf_ledger — append, gate and report the repo's own perf ledger.
 
 Stdlib-only CLI over ``paddle_tpu/profiler/ledger.py``; loads that module
 as a standalone file so it works on machines with no jax installed (same
@@ -9,19 +9,17 @@ Subcommands:
 
   append  ARTIFACT.json [--ledger PATH] [--round N]
       Sniff an artifact (bench.py line, bench_serve.py line, pod_report
-      verdict, fleet_sim report, driver BENCH/MULTICHIP wrapper) and
-      append its normalized row(s).
-
-  ingest  ARTIFACT.json... [--ledger PATH] [--reset]
-      Deterministically normalize driver artifacts (BENCH_r0*.json,
-      MULTICHIP_r0*.json, FLEET_r01.json) into the ledger.  --reset
-      truncates first, so re-ingest is reproducible byte-for-byte.
+      verdict, fleet_sim report) and append its normalized row.
 
   check   [--ledger PATH] [--tol F] [--stale-after N] [--proxies-only]
       Regression + staleness gate over the ledger trajectory.
 
   report  [--ledger PATH] [--format markdown|json]
       Per-series trajectory table with deltas.
+
+The default ledger is ``runs/perf_ledger.jsonl`` (untracked).
+``PERF_LEDGER.jsonl`` at the repo root belongs to the driver; this tool
+never writes it.
 
 Exit codes: 0 ok · 1 regression or stale ledger · 2 schema/usage error.
 """
@@ -35,7 +33,7 @@ import os
 import sys
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_DEFAULT_LEDGER = os.path.join(_REPO, "PERF_LEDGER.jsonl")
+_DEFAULT_LEDGER = os.path.join(_REPO, "runs", "perf_ledger.jsonl")
 
 
 def _load_ledger_mod():
@@ -50,8 +48,6 @@ def _load_ledger_mod():
 def _sniff_rows(L, payload, path, rnd):
     """Route one artifact JSON to the right normalizer."""
     if isinstance(payload, dict):
-        if "n_devices" in payload or ("rc" in payload and "cmd" in payload):
-            return L.ingest_artifacts([path])
         if "recommended" in payload:
             return [L.from_fleet_report(payload, round=rnd)]
         if "predicted" in payload or payload.get("mode") == "serving":
@@ -59,7 +55,7 @@ def _sniff_rows(L, payload, path, rnd):
         metric = payload.get("metric", "")
         if metric.startswith("serve_"):
             return [L.from_bench_serve_result(payload, round=rnd)]
-        if metric.startswith("llama_train") or "last_measured" in payload:
+        if metric.startswith("llama_train"):
             return [L.from_bench_result(payload, round=rnd)]
     raise L.LedgerSchemaError(f"cannot determine artifact type of {path}")
 
@@ -71,17 +67,6 @@ def cmd_append(L, args) -> int:
     for row in rows:
         L.append(args.ledger, row)
     print(f"perf_ledger: appended {len(rows)} row(s) to {args.ledger}")
-    return 0
-
-
-def cmd_ingest(L, args) -> int:
-    rows = L.ingest_artifacts(args.artifacts)
-    if args.reset and os.path.exists(args.ledger):
-        os.remove(args.ledger)
-    for row in rows:
-        L.append(args.ledger, row)
-    print(f"perf_ledger: ingested {len(rows)} row(s) from "
-          f"{len(args.artifacts)} artifact(s) into {args.ledger}")
     return 0
 
 
@@ -103,19 +88,14 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="perf_ledger",
                                  description=__doc__.splitlines()[0])
     ap.add_argument("--ledger", default=_DEFAULT_LEDGER,
-                    help="ledger JSONL path (default: PERF_LEDGER.jsonl)")
+                    help="ledger JSONL path (default: "
+                         "runs/perf_ledger.jsonl)")
     sub = ap.add_subparsers(dest="command", required=True)
 
     p = sub.add_parser("append", help="normalize + append one artifact")
     p.add_argument("artifact")
     p.add_argument("--round", type=int, default=None)
     p.set_defaults(fn=cmd_append)
-
-    p = sub.add_parser("ingest", help="normalize driver artifacts")
-    p.add_argument("artifacts", nargs="+")
-    p.add_argument("--reset", action="store_true",
-                   help="truncate the ledger first (reproducible rebuild)")
-    p.set_defaults(fn=cmd_ingest)
 
     p = sub.add_parser("check", help="regression + staleness gate")
     p.add_argument("--tol", type=float, default=0.05)
