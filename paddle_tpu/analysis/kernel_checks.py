@@ -229,6 +229,10 @@ def capture_sites(sites: List[KernelSite]):
 
     def shim(kernel, *args, **kwargs):
         fr = sys._getframe(1)
+        if fr.f_code.co_name == "_pallas_call":
+            # ops/pallas_ops.py makes its calls through one helper; the
+            # site is the helper's caller
+            fr = fr.f_back
         site = _normalize_call(kernel, args, kwargs, blockspec_cls,
                                fr.f_code.co_filename, fr.f_lineno)
         wrapped = real(kernel, *args, **kwargs)

@@ -144,25 +144,26 @@ def _in_trace(x):
 # ---------------------------------------------------------------------------
 
 def _apply_collective(f, tensor, op_name):
-    """apply_op with telemetry and health instrumentation: a host span
-    when a profiler is live; when FLAGS_tpu_metrics is on, bytes-moved
+    """apply_op with telemetry and health instrumentation: a
+    ``collective/<op>`` span (``profiler.trace.span``: a profiler
+    annotation, and the flight recorder's event under
+    FLAGS_tpu_trace); when FLAGS_tpu_metrics is on, bytes-moved
     counters + a latency histogram per collective op; when a runtime
     HealthMonitor is installed, an entry/exit beacon (so a rank that
     enters and never exits is detected within the collective deadline)
     plus a ``collective.<op>`` chaos point for hang injection. The
-    un-instrumented path costs one list truthiness check, one
-    dict-lookup+bool (metrics.enabled), and two module-global None
+    un-instrumented path costs the inert annotation, two
+    dict-lookup+bools (trace, metrics), and two module-global None
     checks (health hook, chaos hook)."""
-    from ..profiler import _record_span, metrics as _metrics, \
-        trace as _trace
+    from ..profiler import metrics as _metrics, trace as _trace
     from ..runtime import health as _health
     rec = _metrics.enabled()
     t0 = time.perf_counter() if rec else None
     span_name = f"collective/{op_name}"
-    # the health beacon promoted to a first-class trace span: when
-    # FLAGS_tpu_trace is on, every collective entry/exit lands in the
-    # flight recorder with its duration (disabled: one dict lookup)
-    with _record_span(span_name), _trace.span(span_name, op=op_name):
+    # the health beacon promoted to a first-class span: a profiler
+    # annotation always and, when FLAGS_tpu_trace is on, the flight
+    # recorder's event with its duration
+    with _trace.span(span_name):
         # beacon outermost: the chaos hang below must count as "inside
         # the collective" so self-detection sees the overdue beacon
         with _health.collective_beacon(op_name):

@@ -125,30 +125,29 @@ def _put_global(arr, sharding):
 
 
 def _wrap_step_tracing(plan: "Plan", step_fn: Callable) -> Callable:
-    """Per-rank train-step spans for the flight recorder.
+    """The trainer's ``train/step`` span, around the step's dispatch.
 
-    Each invocation emits a shared-name barrier event (the anchor
-    ``trace.merge_ranks`` aligns rank clocks on) and wraps the step in a
-    ``train/step`` span; the first traced step of a pipelined plan also
-    records the static 1F1B schedule via
+    Always a profiler annotation (``profiler.trace.span``). With
+    ``FLAGS_tpu_trace`` on, each invocation also emits a shared-name
+    barrier event (the anchor ``trace.merge_ranks`` aligns rank clocks
+    on) and the span's ring event; the first traced step of a pipelined
+    plan also records the static 1F1B schedule via
     ``trace.record_pipeline_schedule`` so ``tools/trace_report.py`` can
     compute measured overlap with the simulator's exact event schema.
-    Tracing off → one dict lookup per step, step_fn runs untouched.
     """
     counter = {"n": 0}
 
     def traced(params, opt_state, batch):
-        if not _trace.enabled():
-            return step_fn(params, opt_state, batch)
         n = counter["n"]
         counter["n"] += 1
-        if n == 0 and plan.pp > 1 and plan.schedule != "none":
-            _trace.record_pipeline_schedule(
-                plan.pp, plan.n_microbatches or plan.pp,
-                overlap=plan.overlap, step=n)
-        _trace.barrier(f"train/step{n}")
-        with _trace.span("train/step", step=n, dp=plan.dp, pp=plan.pp,
-                         schedule=plan.schedule, overlap=plan.overlap):
+        if _trace.enabled():
+            if n == 0 and plan.pp > 1 and plan.schedule != "none":
+                _trace.record_pipeline_schedule(
+                    plan.pp, plan.n_microbatches or plan.pp,
+                    overlap=plan.overlap, step=n)
+            _trace.barrier(f"train/step{n}")
+        with _trace.span("train/step", step=n, pp=plan.pp,
+                         schedule=plan.schedule):
             return step_fn(params, opt_state, batch)
 
     for attr in ("jitted", "lower", "abstract_state", "batch_shardings",

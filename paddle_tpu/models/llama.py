@@ -455,31 +455,35 @@ def decoder_layer(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
         # weight refs, so quantized layers always use the unfused
         # composition (whose matmuls dispatch through _qmm)
         fused_attn = fused_mlp = False
-    if fused_attn:
-        # norm + qkv + rope + flash + wo + residual in two Pallas kernels
-        h = _per_device(
-            functools.partial(pallas_ops.fused_attention_block,
-                              head_dim=cfg.head_dim, eps=cfg.rms_norm_eps),
-            (x,), (lp["ln1"], lp["wq"], lp["wk"], lp["wv"], lp["wo"],
-                   sin, cos))
-    else:
-        h = x + _attention(cfg, lp,
-                           _rms_norm(x, lp["ln1"], cfg.rms_norm_eps),
-                           sin, cos, cp_mesh=cp_mesh, cp_axis=cp_axis,
-                           cp_axis_level=cp_axis_level)
-    if cfg.moe_num_experts > 0:
-        mlp_out, aux = _moe_mlp(cfg, lp,
-                                _rms_norm(h, lp["ln2"], cfg.rms_norm_eps))
-        return h + mlp_out, aux
-    if fused_mlp:
-        # norm + gate/up + silu + down + residual in one Pallas kernel
-        out = _per_device(
-            functools.partial(pallas_ops.fused_mlp_block,
-                              eps=cfg.rms_norm_eps),
-            (h,), (lp["ln2"], lp["w_gate"], lp["w_up"], lp["w_down"]))
-        return out, jnp.zeros((), jnp.float32)
-    normed = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
-    return h + _dense_mlp(lp, normed), jnp.zeros((), jnp.float32)
+    with jax.named_scope("attn"):
+        if fused_attn:
+            # norm + qkv + rope + flash + wo + residual in two Pallas
+            # kernels
+            h = _per_device(
+                functools.partial(pallas_ops.fused_attention_block,
+                                  head_dim=cfg.head_dim,
+                                  eps=cfg.rms_norm_eps),
+                (x,), (lp["ln1"], lp["wq"], lp["wk"], lp["wv"], lp["wo"],
+                       sin, cos))
+        else:
+            h = x + _attention(cfg, lp,
+                               _rms_norm(x, lp["ln1"], cfg.rms_norm_eps),
+                               sin, cos, cp_mesh=cp_mesh, cp_axis=cp_axis,
+                               cp_axis_level=cp_axis_level)
+    with jax.named_scope("mlp"):
+        if cfg.moe_num_experts > 0:
+            mlp_out, aux = _moe_mlp(
+                cfg, lp, _rms_norm(h, lp["ln2"], cfg.rms_norm_eps))
+            return h + mlp_out, aux
+        if fused_mlp:
+            # norm + gate/up + silu + down + residual in one Pallas kernel
+            out = _per_device(
+                functools.partial(pallas_ops.fused_mlp_block,
+                                  eps=cfg.rms_norm_eps),
+                (h,), (lp["ln2"], lp["w_gate"], lp["w_up"], lp["w_down"]))
+            return out, jnp.zeros((), jnp.float32)
+        normed = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
+        return h + _dense_mlp(lp, normed), jnp.zeros((), jnp.float32)
 
 
 def run_layer_stack(cfg: LlamaConfig, stacked, x, sin, cos,
@@ -511,7 +515,9 @@ def run_layer_stack(cfg: LlamaConfig, stacked, x, sin, cos,
                                 policy=policy)
         h, a = fn(cfg, lp, h, sin, cos)
         return (h, aux + a), None
-    (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)), stacked)
+    with jax.named_scope("layers"):
+        (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                               stacked)
     return x, aux
 
 
@@ -524,7 +530,8 @@ def forward_pure(cfg: LlamaConfig, params, input_ids, sp_axis=None,
     sharded end to end, exact causal attention at O(S/sp) memory."""
     B, S = input_ids.shape
     sin, cos = _rope_tables(cfg, S)
-    x = jnp.take(params["embed"], input_ids, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], input_ids, axis=0)
     if cp_mesh is not None:
         # pin ONLY the sequence dim: UNCONSTRAINED (not None — None means
         # replicated) leaves batch/hidden placement to GSPMD, so dp batch
@@ -536,8 +543,9 @@ def forward_pure(cfg: LlamaConfig, params, input_ids, sp_axis=None,
     x, aux = run_layer_stack(cfg, params["layers"], x, sin, cos,
                              cp_mesh=cp_mesh, cp_axis=cp_axis,
                              grad_sync_axis=grad_sync_axis)
-    x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-    logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+        logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
     return logits, aux
 
 
@@ -732,31 +740,32 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
                 new_sc.reshape(nkv, R * W), mode="drop")
             return pool, scales
 
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
 
-    def body(h, inp):
-        if quant_kv:
-            lp, kp, vp, ks, vs = inp
-        else:
-            lp, kp, vp = inp
-            ks = vs = None
-        xn = _rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
-        q = rope(_qmm(xn, lp["wq"]).reshape(R, Tc, nh, d))
-        k = rope(_qmm(xn, lp["wk"]).reshape(R, Tc, nkv, d))
-        v = _qmm(xn, lp["wv"]).reshape(R, Tc, nkv, d)
+    def kv_write(kp, vp, ks, vs, k, v):
         if quant_kv:
             kp, ks = quant_write(
                 kp, ks, k.transpose(2, 0, 1, 3).astype(jnp.float32))
             vp, vs = quant_write(
                 vp, vs, v.transpose(2, 0, 1, 3).astype(jnp.float32))
-        else:
-            # scatter new k/v: [R, Tc, nkv, d] -> [nkv, R*Tc, d] at dest
-            k_t = k.transpose(2, 0, 1, 3).reshape(nkv, R * Tc, d)
-            v_t = v.transpose(2, 0, 1, 3).reshape(nkv, R * Tc, d)
-            kp = kp.reshape(nkv, num_pages * page, d).at[:, dest].set(
-                k_t.astype(kp.dtype)).reshape(nkv, num_pages, page, d)
-            vp = vp.reshape(nkv, num_pages * page, d).at[:, dest].set(
-                v_t.astype(vp.dtype)).reshape(nkv, num_pages, page, d)
+            return kp, vp, ks, vs
+        # scatter new k/v: [R, Tc, nkv, d] -> [nkv, R*Tc, d] at dest
+        k_t = k.transpose(2, 0, 1, 3).reshape(nkv, R * Tc, d)
+        v_t = v.transpose(2, 0, 1, 3).reshape(nkv, R * Tc, d)
+        kp = kp.reshape(nkv, num_pages * page, d).at[:, dest].set(
+            k_t.astype(kp.dtype)).reshape(nkv, num_pages, page, d)
+        vp = vp.reshape(nkv, num_pages * page, d).at[:, dest].set(
+            v_t.astype(vp.dtype)).reshape(nkv, num_pages, page, d)
+        return kp, vp, ks, vs
+
+    def attn(h, lp, kp, vp, ks, vs):
+        xn = _rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
+        q = rope(_qmm(xn, lp["wq"]).reshape(R, Tc, nh, d))
+        k = rope(_qmm(xn, lp["wk"]).reshape(R, Tc, nkv, d))
+        v = _qmm(xn, lp["wv"]).reshape(R, Tc, nkv, d)
+        with jax.named_scope("kv_write"):
+            kp, vp, ks, vs = kv_write(kp, vp, ks, vs, k, v)
         # kernel layout [R, nkv, Tc*rep, d]: row t*rep + j = q head
         # k*rep + j of token t (the h // rep GQA mapping)
         qk = q.reshape(R, Tc, nkv, rep, d).transpose(
@@ -766,26 +775,38 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
                                      k_scales=ks, v_scales=vs)
         out = out.reshape(R, nkv, Tc, rep, d).transpose(
             0, 2, 1, 3, 4).reshape(R, Tc, H)
-        h = h + _qmm(out.astype(h.dtype), lp["wo"])
-        hn = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
-        if cfg.moe_num_experts > 0:
-            mlp_out, _aux = _moe_mlp(cfg, lp, hn)
-            h = h + mlp_out
+        return h + _qmm(out.astype(h.dtype), lp["wo"]), kp, vp, ks, vs
+
+    def body(h, inp):
+        if quant_kv:
+            lp, kp, vp, ks, vs = inp
         else:
-            h = h + _dense_mlp(lp, hn)
+            lp, kp, vp = inp
+            ks = vs = None
+        with jax.named_scope("attn"):
+            h, kp, vp, ks, vs = attn(h, lp, kp, vp, ks, vs)
+        with jax.named_scope("mlp"):
+            hn = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
+            if cfg.moe_num_experts > 0:
+                mlp_out, _aux = _moe_mlp(cfg, lp, hn)
+                h = h + mlp_out
+            else:
+                h = h + _dense_mlp(lp, hn)
         if quant_kv:
             return h, (kp, vp, ks, vs)
         return h, (kp, vp)
 
-    if quant_kv:
-        x, (new_k, new_v, new_ks, new_vs) = lax.scan(
-            body, x, (params["layers"], k_pages, v_pages,
-                      k_scales, v_scales))
-    else:
-        x, (new_k, new_v) = lax.scan(body, x,
-                                     (params["layers"], k_pages, v_pages))
-    x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-    logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("layers"):
+        if quant_kv:
+            x, (new_k, new_v, new_ks, new_vs) = lax.scan(
+                body, x, (params["layers"], k_pages, v_pages,
+                          k_scales, v_scales))
+        else:
+            x, (new_k, new_v) = lax.scan(
+                body, x, (params["layers"], k_pages, v_pages))
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+        logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
     if quant_kv:
         return logits, (new_k, new_v, new_ks, new_vs)
     return logits, (new_k, new_v)
@@ -991,20 +1012,22 @@ def build_train_step(cfg: LlamaConfig, topo, optimizer=None, use_pp=None,
             opt_state = replicate_scalars(mesh, opt_state)
         return params, opt_state
 
-    def step(params, opt_state, batch):
-        if grad_fn is not None:
-            (total, ce), grads = grad_fn(params, batch)
-        else:
-            (total, ce), grads = jax.value_and_grad(
-                lambda p: loss(p, batch), has_aux=True)(params)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+    def train_step(params, opt_state, batch):
+        with jax.named_scope("fwd_bwd"):
+            if grad_fn is not None:
+                (total, ce), grads = grad_fn(params, batch)
+            else:
+                (total, ce), grads = jax.value_and_grad(
+                    lambda p: loss(p, batch), has_aux=True)(params)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, {"loss": total, "ce": ce}
 
     batch_axes = getattr(topo, "batch_axes", "dp")
     batch_sh = {"input_ids": NamedSharding(mesh, P(batch_axes, None)),
                 "labels": NamedSharding(mesh, P(batch_axes, None))}
-    step_jit = jax.jit(step, in_shardings=(param_sh, None, batch_sh),
+    step_jit = jax.jit(train_step, in_shardings=(param_sh, None, batch_sh),
                        out_shardings=(param_sh, None, None),
                        donate_argnums=(0, 1))
 

@@ -313,6 +313,22 @@ def _compiler_params(*dimension_semantics):
         dimension_semantics=tuple(dimension_semantics))
 
 
+def _pallas_call(kernel, **kwargs):
+    """The one door to ``pl.pallas_call``: the call is made under
+    ``jax.named_scope("pallas/<kernel function name>")``, so every Mosaic
+    custom call carries its kernel's name in its ``op_name`` on the device
+    trace — the name the lowered text's ``kernel_name`` already has.
+    ``interpret`` follows ``_INTERPRET``."""
+    from jax.experimental import pallas as pl
+    scope = f"pallas/{getattr(kernel, 'func', kernel).__name__}"
+    call = pl.pallas_call(kernel, interpret=_INTERPRET, **kwargs)
+
+    def scoped(*operands):
+        with jax.named_scope(scope):
+            return call(*operands)
+    return scoped
+
+
 # ---------------------------------------------------------------------------
 # Pallas flash forward (emits LSE for the backward)
 # ---------------------------------------------------------------------------
@@ -375,7 +391,7 @@ def _flash_fwd_streamed(q, k, v, bq=None, bk=None):
     grid = (BH, S // bq, S // bk)
     by_q = lambda b, i, j: (b, i, 0)  # noqa: E731
     by_k = lambda b, i, j: (b, j, 0)  # noqa: E731
-    out, lse = pl.pallas_call(
+    out, lse = _pallas_call(
         functools.partial(_flash_fwd_kernel_streamed, bq=bq, bk=bk, scale=scale),
         out_shape=(jax.ShapeDtypeStruct((BH, S, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, S, _LANES), jnp.float32)),
@@ -393,7 +409,6 @@ def _flash_fwd_streamed(q, k, v, bq=None, bk=None):
             pltpu.VMEM((bq, D), jnp.float32),        # output accumulator
         ],
         compiler_params=_compiler_params(),
-        interpret=_INTERPRET,
     )(q, k, v)
     return out, lse
 
@@ -501,7 +516,7 @@ def _flash_bwd_streamed(q, k, v, g, o, lse, bq=None, bk=None):
     by_k = lambda b, i, j: (b, j, 0)    # noqa: E731
 
     dq_specs = specs["bwd_dq"]
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         functools.partial(_flash_bwd_dq_kernel_streamed, bq=bq, bk=bk, scale=scale),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         grid=(BH, S // bq, S // bk),
@@ -516,14 +531,13 @@ def _flash_bwd_streamed(q, k, v, g, o, lse, bq=None, bk=None):
         out_specs=pl.BlockSpec(dq_specs["out"][0][0], by_q),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_compiler_params(),
-        interpret=_INTERPRET,
     )(q, k, v, g, o, lse)
 
     # dkv grid: k blocks ride dim 1 (the by_q map), q blocks stream on
     # dim 2 (the by_k map) — same two index maps, roles swapped
     by_kv, by_qs = by_q, by_k
     dkv_specs = specs["bwd_dkv"]
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         functools.partial(_flash_bwd_dkv_kernel_streamed, bq=bq, bk=bk,
                           scale=scale),
         out_shape=(jax.ShapeDtypeStruct((BH, S, D), k.dtype),
@@ -542,7 +556,6 @@ def _flash_bwd_streamed(q, k, v, g, o, lse, bq=None, bk=None):
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=_compiler_params(),
-        interpret=_INTERPRET,
     )(q, k, v, g, o, lse)
     return dq, dk, dv
 
@@ -592,7 +605,7 @@ def _flash_fwd_resident(q, k, v, bq=None, bk=None):
     grid = (BH, S // bq)
     blocked = lambda b, i: (b, i, 0)  # noqa: E731
     whole = lambda b, i: (b, 0, 0)    # noqa: E731
-    out, lse = pl.pallas_call(
+    out, lse = _pallas_call(
         functools.partial(_flash_fwd_kernel_resident, bq=bq, bk=bk, scale=scale),
         out_shape=(jax.ShapeDtypeStruct((BH, S, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, S, _LANES), jnp.float32)),
@@ -604,7 +617,6 @@ def _flash_fwd_resident(q, k, v, bq=None, bk=None):
         ],
         out_specs=(pl.BlockSpec(specs["out"][0][0], blocked),
                    pl.BlockSpec(specs["out"][1][0], blocked)),
-        interpret=_INTERPRET,
     )(q, k, v)
     return out, lse
 
@@ -701,7 +713,7 @@ def _flash_bwd_resident(q, k, v, g, o, lse, bq=None, bk=None):
     whole = lambda b, i: (b, 0, 0)    # noqa: E731
 
     dq_specs = specs["bwd_dq"]
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         functools.partial(_flash_bwd_dq_kernel_resident, bq=bq, bk=bk, scale=scale),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         grid=(BH, S // bq),
@@ -714,11 +726,10 @@ def _flash_bwd_resident(q, k, v, g, o, lse, bq=None, bk=None):
             pl.BlockSpec(dq_specs["in"][5][0], blocked),   # lse
         ],
         out_specs=pl.BlockSpec(dq_specs["out"][0][0], blocked),
-        interpret=_INTERPRET,
     )(q, k, v, g, o, lse)
 
     dkv_specs = specs["bwd_dkv"]
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         functools.partial(_flash_bwd_dkv_kernel_resident, bq=bq, bk=bk, scale=scale,
                           n_qblocks=S // bq),
         out_shape=(jax.ShapeDtypeStruct((BH, S, D), k.dtype),
@@ -734,7 +745,6 @@ def _flash_bwd_resident(q, k, v, g, o, lse, bq=None, bk=None):
         ],
         out_specs=(pl.BlockSpec(dkv_specs["out"][0][0], blocked),
                    pl.BlockSpec(dkv_specs["out"][1][0], blocked)),
-        interpret=_INTERPRET,
     )(q, k, v, g, o, lse)
     return dq, dk, dv
 
@@ -1174,7 +1184,7 @@ def _fused_qkv_proj(x, ln2d, wq, wk, wv, sin, cos, D, bq, eps):
     by_rope = lambda b, i, h: (i, 0)      # noqa: E731
     by_head = lambda b, i, h: (b, i, h)   # noqa: E731
     out_sds = jax.ShapeDtypeStruct((B, S, H), x.dtype)
-    return pl.pallas_call(
+    return _pallas_call(
         functools.partial(_qkv_fused_kernel, eps=eps),
         out_shape=(out_sds, out_sds, out_sds),
         grid=(B, S // bq, nh),
@@ -1191,7 +1201,6 @@ def _fused_qkv_proj(x, ln2d, wq, wk, wv, sin, cos, D, bq, eps):
         scratch_shapes=[pltpu.VMEM((bq, H), jnp.float32)],
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
-        interpret=_INTERPRET,
     )(x, ln2d, wq, wk, wv, sin, cos)
 
 
@@ -1263,7 +1272,7 @@ def _fused_attn_epilogue(qb, kb, vb, x, wo, D, bq, bk):
     by_x = lambda b, i, h: (b, i, 0)      # noqa: E731
     by_wo = lambda b, i, h: (h, 0)        # noqa: E731
     by_lse = lambda b, i, h: (b, h, i, 0)  # noqa: E731
-    return pl.pallas_call(
+    return _pallas_call(
         functools.partial(_attn_epi_kernel, bq=bq, bk=bk, scale=scale),
         out_shape=(jax.ShapeDtypeStruct((B, S, H), x.dtype),
                    jax.ShapeDtypeStruct((B, S, H), x.dtype),
@@ -1282,7 +1291,6 @@ def _fused_attn_epilogue(qb, kb, vb, x, wo, D, bq, bk):
         scratch_shapes=[pltpu.VMEM((bq, H), jnp.float32)],
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
-        interpret=_INTERPRET,
     )(qb, kb, vb, x, wo)
 
 
@@ -1303,7 +1311,7 @@ def _fused_flash_bwd_heads(qb, kb, vb, gb, ob, lse, D, bq, bk):
     lse_full = lambda bh, i: (bh, 0, 0)              # noqa: E731
 
     dq_specs = specs["bwd_dq"]
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         functools.partial(_flash_bwd_dq_kernel_resident, bq=bq, bk=bk,
                           scale=scale),
         out_shape=jax.ShapeDtypeStruct((B, S, H), qb.dtype),
@@ -1317,11 +1325,10 @@ def _fused_flash_bwd_heads(qb, kb, vb, gb, ob, lse, D, bq, bk):
             pl.BlockSpec(dq_specs["in"][5][0], lse_blk),   # lse
         ],
         out_specs=pl.BlockSpec(dq_specs["out"][0][0], blocked),
-        interpret=_INTERPRET,
     )(qb, kb, vb, gb, ob, lse_bh)
 
     dkv_specs = specs["bwd_dkv"]
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         functools.partial(_flash_bwd_dkv_kernel_resident, bq=bq, bk=bk,
                           scale=scale, n_qblocks=S // bq),
         out_shape=(jax.ShapeDtypeStruct((B, S, H), kb.dtype),
@@ -1337,7 +1344,6 @@ def _fused_flash_bwd_heads(qb, kb, vb, gb, ob, lse, D, bq, bk):
         ],
         out_specs=(pl.BlockSpec(dkv_specs["out"][0][0], blocked),
                    pl.BlockSpec(dkv_specs["out"][1][0], blocked)),
-        interpret=_INTERPRET,
     )(qb, kb, vb, gb, ob, lse_bh)
     return dq, dk, dv
 
@@ -1500,7 +1506,7 @@ def _fused_mlp_pallas(kernel, inputs, out_dtype, S, H, I, bs, bi,
     by_d = lambda b, i, ii: (ii, 0)      # noqa: E731
     maps = [by_x, by_ln, by_gu, by_gu, by_d] + \
         ([by_x] if which == "bwd_dx" else [])
-    return pl.pallas_call(
+    return _pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, S, H), out_dtype),
         grid=(B, S // bs, I // bi),
@@ -1511,7 +1517,6 @@ def _fused_mlp_pallas(kernel, inputs, out_dtype, S, H, I, bs, bi,
                         pltpu.VMEM((bs, H), jnp.float32)],
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
-        interpret=_INTERPRET,
     )(*inputs)
 
 
@@ -1996,13 +2001,12 @@ def _rpa_call(q, k_pages, v_pages, block_tables, seq_lens, q_lens, *,
     kern = functools.partial(
         _rpa_kernel_quant if quantized else _rpa_kernel,
         page=page, rep=rep, bq_rows=bq_rows, scale=scale)
-    call = pl.pallas_call(
+    call = _pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, nkv, Tr, d), q.dtype),
         compiler_params=_compiler_params(
             "parallel", "parallel", "parallel", "arbitrary"),
-        interpret=_INTERPRET,
     )
     if quantized:
         return call(block_tables, seq_lens, q_lens, k_scales, v_scales,
@@ -2294,7 +2298,7 @@ def _int8_matmul_call(x, w_q, w_scale, *, bm, bn):
     def o_map(i, j):
         return (i, j)
 
-    return pl.pallas_call(
+    return _pallas_call(
         _int8_matmul_kernel,
         grid=(M // bm, N // bn),
         in_specs=[pl.BlockSpec(specs["in"][0][0], x_map),
@@ -2303,7 +2307,6 @@ def _int8_matmul_call(x, w_q, w_scale, *, bm, bn):
         out_specs=pl.BlockSpec(specs["out"][0][0], o_map),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         compiler_params=_compiler_params("parallel", "parallel"),
-        interpret=_INTERPRET,
     )(x, w_q, w_scale)
 
 

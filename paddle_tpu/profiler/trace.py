@@ -4,12 +4,20 @@ Counters (PR 1) say *that* time was spent; this module records *where*:
 ring-buffered structured events with monotonic timestamps, rank /
 replica / request tags, span nesting, and an injectable clock, gated by
 ``FLAGS_tpu_trace`` with the same dict-lookup-only disabled path as
-``FLAGS_tpu_metrics`` — a call site pays one dict lookup + bool when
-tracing is off.
+``FLAGS_tpu_metrics`` — an event call site pays one dict lookup + bool
+when tracing is off.
+
+:func:`span` is the program's one span primitive and has two sinks. It
+always enters a ``jax.profiler.TraceAnnotation`` of the same name and
+fields, so whenever a ``jax.profiler`` trace runs around a live engine
+or trainer the program's spans lie on the profiler's clock beside the
+device's operations (their fields arrive as the event's stats); with no
+profiler session the annotation is inert, well under a microsecond.
+With ``FLAGS_tpu_trace`` on it also records the ring event below.
 
 Three event families share the buffer:
 
-* **spans** — ``with span("engine/step"): ...`` records one event with
+* **spans** — ``with span("serve/step"): ...`` records one event with
   ``t``/``dur``/``depth``/``parent`` (thread-local nesting stack);
 * **request lifecycle** — ``request_event(phase, rid, ...)`` marks the
   serving transitions (queued → admitted → prefill/decode → terminal),
@@ -78,40 +86,41 @@ def _env_rank() -> int:
         return 0
 
 
-class _NullSpan:
-    """Returned by :func:`span` when tracing is disabled — one shared
-    instance, so the disabled path allocates nothing."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    __slots__ = ("_rec", "_name", "_fields", "_t0", "_depth", "_parent")
+    """One ring event on exit; ``annotation``, when given, is entered and
+    left with it (the profiler's sink of the module-level :func:`span`)."""
 
-    def __init__(self, rec: "TraceRecorder", name: str, fields: dict):
+    __slots__ = ("_rec", "_name", "_fields", "_ann", "_t0", "_depth",
+                 "_parent")
+
+    def __init__(self, rec: "TraceRecorder", name: str, fields: dict,
+                 annotation=None):
         self._rec = rec
         self._name = name
         self._fields = fields
+        self._ann = annotation
+
+    def set_metadata(self, **fields) -> None:
+        """Fields known only inside the span (``TraceAnnotation``'s own
+        method, so a call site needs no branch on the flag)."""
+        self._fields.update(fields)
+        if self._ann is not None:
+            self._ann.set_metadata(**fields)
 
     def __enter__(self):
         stack = self._rec._stack()
         self._depth = len(stack)
         self._parent = stack[-1] if stack else None
         stack.append(self._name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = self._rec._clock()
         return self
 
     def __exit__(self, *exc):
         dur = self._rec._clock() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stack = self._rec._stack()
         if stack and stack[-1] == self._name:
             stack.pop()
@@ -230,10 +239,23 @@ def event(name: str, kind: str = "instant", t: Optional[float] = None,
     return _RECORDER.event(name, kind=kind, t=t, **fields)
 
 
+# jax.profiler.TraceAnnotation, bound by the first span: this module is
+# also loaded without jax (tools/fleet_sim.py), which opens no span
+_annotation = None
+
+
 def span(name: str, **fields):
+    """The program's span: a profiler annotation always, and the ring
+    event too when ``FLAGS_tpu_trace`` is on. Tracing off, the object
+    returned is the annotation itself."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    ann = _annotation(name, **fields)
     if not _FLAG_DICT.get(_FLAG_NAME, False):
-        return _NULL_SPAN
-    return _RECORDER.span(name, **fields)
+        return ann
+    return _Span(_RECORDER, name, fields, ann)
 
 
 def barrier(name: str, **fields) -> Optional[dict]:

@@ -427,18 +427,22 @@ class LLMEngine:
             logits, pools = fwd(cfg, params, tokens, kp, vp, tbl, lens,
                                 qlens, **dict(zip(
                                     ("k_scales", "v_scales"), scales)))
-            last = jnp.clip(qlens - 1, 0, tokens.shape[1] - 1)
-            rows = jnp.take_along_axis(
-                logits, last[:, None, None], axis=1)[:, 0]   # [R, V]
-            # argmax at EVERY fed position [R, Tc]: position q_len-1 is
-            # the sampled token; the earlier positions are what
-            # spec-decode verification reads — multi-token verify needs
-            # the target's choice after each draft token.
-            # chk: one float per row (max logit) — a cheap [R] transfer
-            # the numerics watchdog scans for NaN/Inf poisoning
-            return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                    jnp.max(rows, axis=-1), pools)
+            with jax.named_scope("sample"):
+                last = jnp.clip(qlens - 1, 0, tokens.shape[1] - 1)
+                rows = jnp.take_along_axis(
+                    logits, last[:, None, None], axis=1)[:, 0]   # [R, V]
+                # argmax at EVERY fed position [R, Tc]: position q_len-1
+                # is the sampled token; the earlier positions are what
+                # spec-decode verification reads — multi-token verify
+                # needs the target's choice after each draft token.
+                # chk: one float per row (max logit) — a cheap [R]
+                # transfer the numerics watchdog scans for NaN/Inf
+                # poisoning
+                return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                        jnp.max(rows, axis=-1), pools)
 
+        # the program's name in a profile: jit_serve_step_tc<Tc>
+        step.__name__ = f"serve_step_tc{Tc}"
         R = self.max_running
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
         return jax.jit(
@@ -540,7 +544,21 @@ class LLMEngine:
     def step(self) -> List[int]:
         """One continuous-batching iteration.  Returns the request ids
         that finished at this step boundary (empty list when idle,
-        still mid-flight, or after a recovered step failure)."""
+        still mid-flight, or after a recovered step failure).
+
+        Spans (``profiler.trace.span``: on the profiler's clock whenever
+        a ``jax.profiler`` trace runs, in the flight recorder under
+        ``FLAGS_tpu_trace``): ``serve/engine_step`` around the whole
+        call, and inside it, in order, ``serve/schedule``,
+        ``serve/batch``, ``serve/step`` (the guarded forward, itself
+        ``serve/dispatch`` then ``serve/fetch``) and ``serve/commit``."""
+        whole = _trace.span("serve/engine_step", step=self._steps)
+        with whole:
+            return self._step(whole)
+
+    def _schedule(self) -> StepPlan:
+        """Deadlines, the scheduler's plan, admission stamps and the
+        plan's COW page copies (the ``serve/schedule`` span)."""
         now = self._clock()
         self._expire_deadlines(now)
         plan = self.scheduler.schedule()
@@ -575,31 +593,52 @@ class LLMEngine:
         pairs = self.kv.drain_copies()
         if pairs:
             self._apply_copies(pairs)
+        return plan
+
+    def _step(self, whole) -> List[int]:
+        """``step()`` inside its ``serve/engine_step`` span ``whole``,
+        which is told what the step fed once the batch is built."""
+        with _trace.span("serve/schedule"):
+            plan = self._schedule()
         if not plan.seqs:
             return []
         R, Tc = self.max_running, plan.bucket
-        drafts: Optional[Dict[int, List[int]]] = None
-        if self._draft is not None:
-            spec_rows = [
-                (s.slot, s.request.known[s.request.fed], s.request.fed,
-                 self.kv.block_row(s.request.rid))
-                for s in plan.seqs if s.spec]
-            if spec_rows:
-                drafts = self._draft.propose(
-                    spec_rows, self._spec_k, R, self.max_blocks)
-        tokens, tbl, lens, qlens = self._batch_arrays(
-            plan.seqs, R, Tc, self.max_blocks, self.kv, drafts)
-
-        # build (or fetch) the bucket's executable before the guarded
-        # call: a step that cannot be compiled is a broken program, not
-        # a run-time fault, and must not be "recovered" into quarantines
-        step_fn = self._step_fn(Tc)
-        t_fwd = self._clock()
+        with _trace.span("serve/batch"):
+            drafts: Optional[Dict[int, List[int]]] = None
+            if self._draft is not None:
+                spec_rows = [
+                    (s.slot, s.request.known[s.request.fed],
+                     s.request.fed, self.kv.block_row(s.request.rid))
+                    for s in plan.seqs if s.spec]
+                if spec_rows:
+                    drafts = self._draft.propose(
+                        spec_rows, self._spec_k, R, self.max_blocks)
+            tokens, tbl, lens, qlens = self._batch_arrays(
+                plan.seqs, R, Tc, self.max_blocks, self.kv, drafts)
+            # what this step feeds, from the arrays just built (the
+            # readers of benchmark/ take their fill and kernel-cost counts
+            # from here)
+            decode_rows = int((qlens == 1).sum())
+            whole.set_metadata(
+                bucket=Tc, rows=len(plan.seqs),
+                prefill_rows=len(plan.seqs) - decode_rows,
+                decode_rows=decode_rows, fed_tokens=int(qlens.sum()),
+                slot_tokens=R * Tc, kv_tokens=int(lens.sum()),
+                qk_pairs=int(np.dot(qlens.astype(np.int64), lens)))
+            # build (or fetch) the bucket's executable before the guarded
+            # call: a step that cannot be compiled is a broken program,
+            # not a run-time fault, and must not be "recovered" into
+            # quarantines
+            step_fn = self._step_fn(Tc)
+            # _step_wall_s (the service model's step cost) runs from the
+            # uploads to the fetch, as it always has
+            t_fwd = self._clock()
+            uploaded = (jnp.asarray(tokens), jnp.asarray(tbl),
+                        jnp.asarray(lens), jnp.asarray(qlens))
         try:
             with _trace.span("serve/step", step=self._steps,
                              batch=len(plan.seqs), bucket=Tc):
-                nxt = self._guarded_forward(plan, step_fn, tokens, tbl,
-                                            lens, qlens)
+                nxt = self._guarded_forward(plan, step_fn, *uploaded)
         except ReplicaKilled:
             # whole-replica death is the router's failure domain, not a
             # step-recoverable fault — propagate
@@ -612,9 +651,15 @@ class LLMEngine:
             # tracks the target's fed counter in lockstep (donated
             # pages then carry valid draft kv for future borrowers)
             self._draft.forward(tokens, tbl, lens, qlens)
-
         now = self._clock()
         self._step_wall_s.setdefault(Tc, []).append(now - t_fwd)
+        with _trace.span("serve/commit"):
+            return self._commit(plan, nxt, drafts, now)
+
+    def _commit(self, plan: StepPlan, nxt, drafts, now: float) -> List[int]:
+        """Acceptance, ``scheduler.apply``, the ``on_token`` callbacks
+        and the step's stats (the ``serve/commit`` span)."""
+        tracing = _trace.enabled()
         out: Dict[int, object] = {}
         prefill = decode = 0
         spec_proposed = spec_accepted = 0
@@ -725,7 +770,8 @@ class LLMEngine:
     def _guarded_forward(self, plan: StepPlan, step_fn, tokens, tbl, lens,
                          qlens) -> np.ndarray:
         """The device call under the serve.step watchdog phase, chaos
-        point, and numerics check.  Returns the sampled tokens [R]."""
+        point, and numerics check, on inputs already on the device.
+        Returns the sampled tokens [R]."""
         wd = self._wd()
         if wd is not None:
             wd.begin("serve.step")
@@ -733,10 +779,12 @@ class LLMEngine:
             chaos_point("serve.step", step=self._steps,
                         rids=[s.request.rid for s in plan.seqs],
                         pool=self.kv.allocator, engine=self)
-            nxt, chk, self._pools = step_fn(
-                self.params, jnp.asarray(tokens), self._pools,
-                jnp.asarray(tbl), jnp.asarray(lens), jnp.asarray(qlens))
-            nxt = np.asarray(nxt)
+            with _trace.span("serve/dispatch"):
+                nxt, chk, self._pools = step_fn(
+                    self.params, tokens, self._pools, tbl, lens, qlens)
+            with _trace.span("serve/fetch"):
+                # the wait for the device, and the copy back
+                nxt = np.asarray(nxt)
             if _numerics.enabled():
                 rows = np.asarray(chk)[[s.slot for s in plan.seqs]]
                 _numerics.check_array(rows, "serve.step.logits",

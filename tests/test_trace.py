@@ -105,7 +105,8 @@ def _engine(cfg, params, **kw):
 
 
 # ---------------------------------------------------------------------------
-# disabled path: one dict lookup, nothing recorded, nothing allocated
+# disabled path: one dict lookup, nothing recorded; a span is the
+# profiler's annotation alone
 # ---------------------------------------------------------------------------
 
 class TestDisabledPath:
@@ -120,12 +121,17 @@ class TestDisabledPath:
             pass
         assert trace.events() == []
 
-    def test_disabled_span_is_one_shared_instance(self):
-        # the off path must not allocate per call: span() hands back
-        # the module-level null span regardless of name/fields
+    def test_disabled_span_is_the_profiler_annotation(self):
+        # the off path keeps nothing in the ring: span() hands back the
+        # jax.profiler annotation itself (inert without a profiler
+        # session), which takes the fields a call site learns later
+        import jax
+        trace.clear()
         s = trace.span("a", k=1)
-        assert s is trace.span("b")
-        assert s is trace._NULL_SPAN
+        assert type(s) is jax.profiler.TraceAnnotation
+        with s:
+            s.set_metadata(rows=3)
+        assert trace.events() == []
 
 
 # ---------------------------------------------------------------------------
