@@ -65,7 +65,7 @@ TRAIN_KERNELS = {"_qkv_fused_kernel", "_attn_epi_kernel", "_mlp_fused_kernel",
 TRAIN_KERNELS_MP = {"_flash_fwd_kernel_resident",
                     "_flash_bwd_dq_kernel_resident",
                     "_flash_bwd_dkv_kernel_resident"}
-SERVE_KERNELS = {"_rpa_kernel"}
+SERVE_KERNELS = {"_rpa_kernel", "_kv_write_kernel"}
 SERVE_KERNELS_INT8 = {"_rpa_kernel_quant", "_int8_matmul_kernel"}
 
 # Served logits vs the float32 forward_pure on the same dense weights, as

@@ -621,10 +621,10 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
 
     tokens        [R, Tc] int32   current-chunk token slots; request r
                                   uses tokens[r, :q_lens[r]]
-    k/v_pages     [L, nkv, P, page, d] per-layer pools
+    k/v_pages     [L, nkv, P, page, d] the stacked pools of all layers
     block_tables  [R, Bmax] i32   pool page of each logical kv block
-                                  (page 0 = allocator's reserved null
-                                  page, absorbs padding-token scatters)
+                                  (page 0 = the allocator's reserved
+                                  null page: never written, never read)
     seq_lens      [R] i32         total kv length incl. this chunk
     q_lens        [R] i32         chunk lengths (0 = inactive slot)
     k/v_scales    [L, nkv, P] f32 per-page dequant scales — presence
@@ -635,12 +635,27 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
 
     Fixed shapes throughout — one compilation per (R, Tc, pool)
     signature.  Rope runs at each token's absolute position
-    (seq_lens - q_lens + t), new k/v are scattered through the block
-    table, and attention is ``ops.pallas_ops.ragged_paged_attention``
-    (jnp reference off-TPU).  Returns (logits [R, Tc, V] fp32,
-    (k_pages, v_pages)) — with scales, (k_pages, v_pages, k_scales,
-    v_scales); logits in padding rows are garbage by contract —
-    callers read row q_lens[r] - 1.
+    (seq_lens - q_lens + t), new k/v are written through the block
+    table (``ops.pallas_ops.paged_kv_write``), and attention is
+    ``ops.pallas_ops.ragged_paged_attention`` (jnp reference off-TPU).
+    Returns (logits [R, Tc, V] fp32, (k_pages, v_pages)) — with
+    scales, (k_pages, v_pages, k_scales, v_scales); logits in padding
+    rows are garbage by contract — callers read row q_lens[r] - 1.
+
+    The pools stay one buffer.  They go round the ONE ``lax.scan`` over
+    the layers whole, in its carry beside the hidden state (the scanned
+    inputs are the layer weights and the layer counter), and come back
+    as the same arrays: a caller that donates them (the engine does)
+    gets them updated in place, argument to result.  No operation
+    slices a layer's pool out of the stack or writes one back — the
+    write touches only the new tokens' rows at (layer, :, page, row),
+    the attention kernel takes the stack and the layer index, and the
+    int8 path gathers and scatters its window pages at
+    (layer, :, page) with this layer's [nkv, P] scales sliced out for
+    the kernel.  (Scanning the pools instead costs a slice, two
+    relayouts and a write-back of every layer's pool on every layer
+    and a second copy of both stacks: half the serve step, PERF.md
+    section 6, PR 26.)
 
     Quantized-KV write path: a per-request window of W logical blocks
     starting at the chunk's first page is gathered, dequantized,
@@ -658,7 +673,7 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
     Window slots whose block-table entry is 0 (unallocated → the
     reserved null page) are dropped from the scatter, keeping the
     null page zero."""
-    from ..ops.pallas_ops import ragged_paged_attention
+    from ..ops.pallas_ops import paged_kv_write, ragged_paged_attention
 
     R, Tc = tokens.shape
     nh, nkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
@@ -685,12 +700,6 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
         rot = jnp.concatenate([-x2, x1], axis=-1)
         return (x * cos[:, :, None, :].astype(x.dtype)
                 + rot * sin[:, :, None, :].astype(x.dtype))
-
-    # flat pool destination of each new token, through the block table;
-    # padding tokens land on the null page (never mapped, never read)
-    blk = jnp.clip(qpos_c // page, 0, block_tables.shape[1] - 1)
-    phys = jnp.take_along_axis(block_tables, blk, axis=1)  # [R, Tc]
-    dest = jnp.where(valid, phys * page + qpos_c % page, 0).reshape(-1)
 
     quant_kv = k_scales is not None
     if quant_kv:
@@ -719,72 +728,66 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
             + jnp.arange(W * page, dtype=jnp.int32)[None, :]   # [R,W*page]
         live = wpos < seq_lens[:, None]
 
-        def quant_write(pool, scales, new_t):
-            # pool [nkv, P, page, d] int8 · scales [nkv, P] f32 ·
-            # new_t [nkv, R, Tc, d] f32 — gather window, dequant,
-            # insert new tokens, per-page absmax rescale, requantize
-            win = jnp.take(pool, flat_w, axis=1).astype(jnp.float32)
-            sc = jnp.take(scales, flat_w, axis=1)              # [nkv,R*W]
-            deq = (win * sc[:, :, None, None]).reshape(
-                nkv, R, W * page, d)
-            deq = deq.at[:, rows, rel_c].set(new_t, mode="drop")
-            deq = jnp.where(live[None, :, :, None], deq, 0.0)
-            wp = deq.reshape(nkv, R, W, page, d)
-            amax = jnp.max(jnp.abs(wp), axis=(3, 4))           # [nkv,R,W]
+        def quant_write(pool, scales, l, new_t):
+            # pool [L, nkv, P, page, d] int8 · scales [L, nkv, P] f32 ·
+            # new_t [R, Tc, nkv, d] f32 — gather layer l's window, dequant,
+            # insert new tokens, per-page absmax rescale, requantize.
+            # (l, :, pages) indexes the stack itself: the layer and the
+            # page indices are apart, so the gathered axis leads
+            win = pool[l, :, flat_w].astype(jnp.float32)  # [R*W,nkv,page,d]
+            sc = scales[l, :, flat_w]                      # [R*W, nkv]
+            deq = (win * sc[:, :, None, None]).reshape(R, W, nkv, page, d)
+            deq = deq.at[rows, rel_c // page, :, rel_c % page].set(
+                new_t, mode="drop")
+            deq = jnp.where(live.reshape(R, W, 1, page, 1), deq, 0.0)
+            amax = jnp.max(jnp.abs(deq), axis=(3, 4))          # [R,W,nkv]
             new_sc = jnp.maximum(amax, 1e-8) / 127.0
-            qp = jnp.clip(jnp.round(wp / new_sc[..., None, None]),
+            qp = jnp.clip(jnp.round(deq / new_sc[..., None, None]),
                           -127, 127).astype(pool.dtype)
-            pool = pool.at[:, scatter_pg].set(
-                qp.reshape(nkv, R * W, page, d), mode="drop")
-            scales = scales.at[:, scatter_pg].set(
-                new_sc.reshape(nkv, R * W), mode="drop")
+            pool = pool.at[l, :, scatter_pg].set(
+                qp.reshape(R * W, nkv, page, d), mode="drop")
+            scales = scales.at[l, :, scatter_pg].set(
+                new_sc.reshape(R * W, nkv), mode="drop")
             return pool, scales
 
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
 
-    def kv_write(kp, vp, ks, vs, k, v):
+    def kv_write(pools, l, k, v):
+        # k, v [R, Tc, nkv, d]; only the new tokens are written, into
+        # the stack: nothing here yields a layer's pool
         if quant_kv:
-            kp, ks = quant_write(
-                kp, ks, k.transpose(2, 0, 1, 3).astype(jnp.float32))
-            vp, vs = quant_write(
-                vp, vs, v.transpose(2, 0, 1, 3).astype(jnp.float32))
+            kp, vp, ks, vs = pools
+            kp, ks = quant_write(kp, ks, l, k.astype(jnp.float32))
+            vp, vs = quant_write(vp, vs, l, v.astype(jnp.float32))
             return kp, vp, ks, vs
-        # scatter new k/v: [R, Tc, nkv, d] -> [nkv, R*Tc, d] at dest
-        k_t = k.transpose(2, 0, 1, 3).reshape(nkv, R * Tc, d)
-        v_t = v.transpose(2, 0, 1, 3).reshape(nkv, R * Tc, d)
-        kp = kp.reshape(nkv, num_pages * page, d).at[:, dest].set(
-            k_t.astype(kp.dtype)).reshape(nkv, num_pages, page, d)
-        vp = vp.reshape(nkv, num_pages * page, d).at[:, dest].set(
-            v_t.astype(vp.dtype)).reshape(nkv, num_pages, page, d)
-        return kp, vp, ks, vs
+        return paged_kv_write(*pools, k, v, block_tables, seq_lens,
+                              q_lens, layer=l)
 
-    def attn(h, lp, kp, vp, ks, vs):
+    def attn(h, lp, pools, l):
         xn = _rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
         q = rope(_qmm(xn, lp["wq"]).reshape(R, Tc, nh, d))
         k = rope(_qmm(xn, lp["wk"]).reshape(R, Tc, nkv, d))
         v = _qmm(xn, lp["wv"]).reshape(R, Tc, nkv, d)
         with jax.named_scope("kv_write"):
-            kp, vp, ks, vs = kv_write(kp, vp, ks, vs, k, v)
+            pools = kv_write(pools, l, k, v)
         # kernel layout [R, nkv, Tc*rep, d]: row t*rep + j = q head
         # k*rep + j of token t (the h // rep GQA mapping)
         qk = q.reshape(R, Tc, nkv, rep, d).transpose(
             0, 2, 1, 3, 4).reshape(R, nkv, Tc * rep, d)
-        out = ragged_paged_attention(qk, kp, vp, block_tables,
-                                     seq_lens, q_lens, rep=rep,
-                                     k_scales=ks, v_scales=vs)
+        out = ragged_paged_attention(
+            qk, pools[0], pools[1], block_tables, seq_lens, q_lens,
+            rep=rep, layer=l,
+            **dict(zip(("k_scales", "v_scales"), pools[2:])))
         out = out.reshape(R, nkv, Tc, rep, d).transpose(
             0, 2, 1, 3, 4).reshape(R, Tc, H)
-        return h + _qmm(out.astype(h.dtype), lp["wo"]), kp, vp, ks, vs
+        return h + _qmm(out.astype(h.dtype), lp["wo"]), pools
 
-    def body(h, inp):
-        if quant_kv:
-            lp, kp, vp, ks, vs = inp
-        else:
-            lp, kp, vp = inp
-            ks = vs = None
+    def body(carry, inp):
+        h, pools = carry
+        lp, l = inp
         with jax.named_scope("attn"):
-            h, kp, vp, ks, vs = attn(h, lp, kp, vp, ks, vs)
+            h, pools = attn(h, lp, pools, l)
         with jax.named_scope("mlp"):
             hn = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
             if cfg.moe_num_experts > 0:
@@ -792,24 +795,21 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
                 h = h + mlp_out
             else:
                 h = h + _dense_mlp(lp, hn)
-        if quant_kv:
-            return h, (kp, vp, ks, vs)
-        return h, (kp, vp)
+        return (h, pools), None
 
+    # the pools go round the layer loop whole, in the carry: a scanned
+    # input or a stacked output would be a slice and a write-back of a
+    # layer's whole pool on every layer, and a second copy of the stacks
+    pools = (k_pages, v_pages) + ((k_scales, v_scales) if quant_kv else ())
+    n_layers = k_pages.shape[0]
     with jax.named_scope("layers"):
-        if quant_kv:
-            x, (new_k, new_v, new_ks, new_vs) = lax.scan(
-                body, x, (params["layers"], k_pages, v_pages,
-                          k_scales, v_scales))
-        else:
-            x, (new_k, new_v) = lax.scan(
-                body, x, (params["layers"], k_pages, v_pages))
+        (x, pools), _ = lax.scan(
+            body, (x, pools),
+            (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
         logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
-    if quant_kv:
-        return logits, (new_k, new_v, new_ks, new_vs)
-    return logits, (new_k, new_v)
+    return logits, pools
 
 
 def _cfg_key(cfg):

@@ -60,6 +60,7 @@ __all__ = ["causal_attention", "flash_attention_available",
            "tune_fused_blocks", "fused_parity_cases",
            "ragged_paged_attention", "ragged_attention_available",
            "rpa_block_specs", "rpa_candidates", "tune_ragged_attention",
+           "paged_kv_write", "kv_write_available",
            "int8_matmul", "int8_matmul_available",
            "int8_matmul_block_specs", "int8_matmul_candidates",
            "tune_int8_matmul", "quantize_int8"]
@@ -1743,8 +1744,12 @@ def fused_parity_cases():
 #                                  slots; request r contributes
 #                                  q_lens[r] real tokens (rep q-head
 #                                  slots each), the rest is padding
-#   k/v pools    [nkv, P, page, d] head-major so a (head, page) pair is
-#                                  one contiguous VMEM block
+#   k/v pools    [L, nkv, P, page, d] every layer's pool in one stacked
+#                                  buffer, head-major so a (layer, head,
+#                                  page) triple is one contiguous VMEM
+#                                  block; the kernel picks the layer by
+#                                  index and never sees a slice of it
+#   layer        [1] i32           which layer of the stack to attend over
 #   block_tables [R, Bmax] i32     logical kv-block j of request r lives
 #                                  in pool page block_tables[r, j];
 #                                  unused slots hold 0 (page 0 is the
@@ -1754,9 +1759,9 @@ def fused_parity_cases():
 #                                  inactive slot, 1 = decode, >1 =
 #                                  chunked prefill)
 #
-# Grid (R, nkv, Tr//bq_rows, Bmax); the three scalar operands ride in
+# Grid (R, nkv, Tr//bq_rows, Bmax); the four scalar operands ride in
 # via ``pltpu.PrefetchScalarGridSpec`` so the k/v index maps can read
-# ``tbl[r, j]`` before the block is fetched.  Inner axis j streams kv
+# ``layer[0]`` and ``tbl[r, j]`` before the block is fetched.  Inner axis j streams kv
 # pages with the online-softmax flash recurrence; pages past the
 # request's causal horizon or its kv length are skipped entirely
 # (``@pl.when``), which is what makes the ragged batch cheap.  Padding
@@ -1774,11 +1779,14 @@ def _rep_cols(col, n):
     return jnp.broadcast_to(col, (col.shape[0], n))
 
 
-def _rpa_kernel(tbl_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref, o_ref,
-                m_s, l_s, acc_s, *, page, rep, bq_rows, scale):
+def _rpa_kernel(tbl_ref, lens_ref, qlens_ref, layer_ref, q_ref, k_ref,
+                v_ref, o_ref, m_s, l_s, acc_s, *, page, rep, bq_rows, scale):
     """Grid point (r, h, qt, j): q rows [qt*bq_rows, +bq_rows) of
-    request r, q-head group h, against kv page j of r's block table."""
+    request r, q-head group h, against kv page j of r's block table in
+    layer ``layer_ref[0]`` of the stacked pools (the index maps read the
+    layer; the body sees one (page, d) block)."""
     from jax.experimental import pallas as pl
+    del layer_ref
     r = pl.program_id(0)
     qt = pl.program_id(2)
     j = pl.program_id(3)
@@ -1800,8 +1808,8 @@ def _rpa_kernel(tbl_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when((j * page < kvlen) & (j * page <= horizon))
     def _update():
         q = q_ref[0, 0].astype(jnp.float32)          # [bq_rows, d]
-        k = k_ref[0, 0].astype(jnp.float32)          # [page, d]
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)       # [page, d]
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         row = qt * bq_rows + lax.broadcasted_iota(
@@ -1834,17 +1842,18 @@ def _rpa_kernel(tbl_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref, o_ref,
             o_ref.dtype)
 
 
-def _rpa_kernel_quant(tbl_ref, lens_ref, qlens_ref, ksc_ref, vsc_ref,
-                      q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
-                      page, rep, bq_rows, scale):
+def _rpa_kernel_quant(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref,
+                      vsc_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
+                      *, page, rep, bq_rows, scale):
     """Quantized-KV variant of ``_rpa_kernel``: the k/v pools hold int8
-    pages and two extra scalar-prefetch operands carry the per-page
-    dequant scales ([nkv, P] f32, same block-table indirection — the
-    'second prefetched operand' of the quantized paged KV design).
+    pages and two extra scalar-prefetch operands carry this layer's
+    per-page dequant scales ([nkv, P] f32, same block-table indirection
+    — the 'second prefetched operand' of the quantized paged KV design).
     Dequant happens at page load inside the skip-predicated update, so
     skipped pages pay nothing.  Online-softmax body kept in lockstep
     with ``_rpa_kernel`` — any change there lands here too."""
     from jax.experimental import pallas as pl
+    del layer_ref
     r = pl.program_id(0)
     h = pl.program_id(1)
     qt = pl.program_id(2)
@@ -1866,8 +1875,8 @@ def _rpa_kernel_quant(tbl_ref, lens_ref, qlens_ref, ksc_ref, vsc_ref,
     @pl.when((j * page < kvlen) & (j * page <= horizon))
     def _update():
         q = q_ref[0, 0].astype(jnp.float32)          # [bq_rows, d]
-        k = k_ref[0, 0].astype(jnp.float32) * ksc_ref[h, pg]
-        v = v_ref[0, 0].astype(jnp.float32) * vsc_ref[h, pg]
+        k = k_ref[0, 0, 0].astype(jnp.float32) * ksc_ref[h, pg]
+        v = v_ref[0, 0, 0].astype(jnp.float32) * vsc_ref[h, pg]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         row = qt * bq_rows + lax.broadcasted_iota(
@@ -1899,39 +1908,76 @@ def _rpa_kernel_quant(tbl_ref, lens_ref, qlens_ref, ksc_ref, vsc_ref,
             o_ref.dtype)
 
 
-def rpa_block_specs(R, nkv, Tr, d, num_pages, page, Bmax, bq_rows=None):
+def rpa_block_specs(R, nkv, Tr, d, num_pages, page, Bmax, bq_rows=None,
+                    layers=1):
     """(block, array) shape pairs for the ragged-paged-attention call —
     the single source of truth shared by the call site, the candidate
-    generator, and the Level-3 verifier."""
+    generator, and the Level-3 verifier.  The k/v array is the stacked
+    pool ``[layers, nkv, num_pages, page, d]`` and its block one
+    (layer, head, page) triple: the kernel indexes the layer, so no
+    caller slices a layer's pool out of the stack."""
     if bq_rows is None:
         bq_rows = Tr
     qblk = ((1, 1, bq_rows, d), (R, nkv, Tr, d))
-    kvblk = ((1, 1, page, d), (nkv, num_pages, page, d))
+    kvblk = ((1, 1, 1, page, d), (layers, nkv, num_pages, page, d))
     return {"in": [qblk, kvblk, kvblk], "out": [qblk]}
 
 
+def _layer_operand(layer):
+    """The layer as the kernels' ``[1]`` int32 scalar-prefetch operand.  A
+    Python int stays concrete (numpy), so the Level-3 verifier can prove
+    the index maps that read it; the engine's is its scan's counter."""
+    import numpy as np
+    if isinstance(layer, int):
+        return np.full((1,), layer, np.int32)
+    return jnp.reshape(layer, (1,)).astype(jnp.int32)
+
+
+def _rpa_operands(k_pages, v_pages, k_scales, v_scales, layer):
+    """What the kernel and its jnp reference index: the stacked pools
+    ``[L, nkv, P, page, d]`` and this layer's scales ``[nkv, P]`` (or
+    None).  One layer's 4-D pool is the stack of that one layer
+    (``pool[None]``, a bitcast; its scales are already per-layer).  The
+    scales of a stack are sliced per layer here — ``[nkv, P]`` is what
+    fits SMEM, the whole ``[L, nkv, P]`` does not belong there."""
+    if k_pages.ndim == 4:
+        return k_pages[None], v_pages[None], k_scales, v_scales
+    if k_scales is not None:
+        k_scales, v_scales = (
+            s[layer] if isinstance(layer, int)
+            else lax.dynamic_index_in_dim(s, layer, 0, keepdims=False)
+            for s in (k_scales, v_scales))
+    return k_pages, v_pages, k_scales, v_scales
+
+
 def _ragged_attention_jnp(q, k_pages, v_pages, block_tables, seq_lens,
-                          q_lens, rep, k_scales=None, v_scales=None):
+                          q_lens, rep, k_scales=None, v_scales=None,
+                          layer=0):
     """Reference implementation and CPU fallback: gather every
-    request's pages into a dense [R, Bmax*page] kv span, mask, softmax.
-    Bit-for-bit semantics of the kernel (same ``_NEG_BIG`` masking, f32
-    accumulation, exact-zero padding rows).  With per-page scales
-    ([nkv, P] f32, quantized int8 pools), pages dequant at the gather —
-    the same scale-then-dot order as ``_rpa_kernel_quant``."""
+    request's pages of layer ``layer`` straight out of the stacked pools
+    into a dense [R, Bmax*page] kv span, mask, softmax.  Bit-for-bit
+    semantics of the kernel (same ``_NEG_BIG`` masking, f32 accumulation,
+    exact-zero padding rows).  With per-page scales (quantized int8
+    pools), pages dequant at the gather — the same scale-then-dot order
+    as ``_rpa_kernel_quant``.  Takes 4-D pools like ``_rpa_call``."""
+    k_pages, v_pages, k_scales, v_scales = _rpa_operands(
+        k_pages, v_pages, k_scales, v_scales, layer)
     R, nkv, Tr, d = q.shape
-    page = k_pages.shape[2]
+    page = k_pages.shape[3]
     Bmax = block_tables.shape[1]
     flat = block_tables.reshape(-1)                  # [R*Bmax]
-    k_seq = jnp.take(k_pages, flat, axis=1)          # [nkv, R*Bmax, page, d]
-    v_seq = jnp.take(v_pages, flat, axis=1)
-    if k_scales is not None:
-        k_seq = k_seq.astype(jnp.float32) \
-            * jnp.take(k_scales, flat, axis=1)[:, :, None, None]
-    if v_scales is not None:
-        v_seq = v_seq.astype(jnp.float32) \
-            * jnp.take(v_scales, flat, axis=1)[:, :, None, None]
-    k_seq = k_seq.reshape(nkv, R, Bmax * page, d)
-    v_seq = v_seq.reshape(nkv, R, Bmax * page, d)
+
+    def span(pages, scales):
+        # (layer, :, flat): the two indices are apart, so the gathered
+        # axis leads — [R*Bmax, nkv, page, d] -> [nkv, R, Bmax*page, d]
+        seq = pages[layer, :, flat]
+        if scales is not None:
+            seq = seq.astype(jnp.float32) \
+                * jnp.take(scales, flat, axis=1).T[:, :, None, None]
+        return seq.reshape(R, Bmax, nkv, page, d).transpose(
+            2, 0, 1, 3, 4).reshape(nkv, R, Bmax * page, d)
+
+    k_seq, v_seq = span(k_pages, k_scales), span(v_pages, v_scales)
     scale = 1.0 / math.sqrt(float(d))
     s = jnp.einsum("rhtd,hrsd->rhts", q.astype(jnp.float32),
                    k_seq.astype(jnp.float32)) * scale
@@ -1949,42 +1995,44 @@ def _ragged_attention_jnp(q, k_pages, v_pages, block_tables, seq_lens,
 
 
 def _rpa_call(q, k_pages, v_pages, block_tables, seq_lens, q_lens, *,
-              rep, bq_rows, k_scales=None, v_scales=None):
-    """Raw pallas_call for the ragged-paged-attention kernel.  With
-    ``k_scales``/``v_scales`` ([nkv, P] f32 per-page dequant scales) the
-    quantized-KV kernel variant runs instead: the scale pools ride in as
-    two more scalar-prefetch operands (SMEM, no VMEM block), indexed by
-    the same block table."""
+              rep, bq_rows, k_scales=None, v_scales=None, layer=0):
+    """Raw pallas_call for the ragged-paged-attention kernel over the
+    stacked pools ``[L, nkv, P, page, d]``: ``layer`` rides in as a
+    fourth scalar-prefetch operand (``[1]`` int32) and the k/v index map
+    is ``(layer[0], h, tbl[r, j], 0, 0)``, so the stack is the kernel's
+    operand as it stands and nothing slices or copies a layer's pool.
+    One layer's 4-D pool goes the same way as the stack of that one
+    layer (decided from the rank).  With ``k_scales``/``v_scales``
+    ([L, nkv, P] f32 per-page dequant scales; [nkv, P] beside a 4-D
+    pool) the quantized-KV kernel variant runs instead: this layer's
+    [nkv, P] scales ride in as two more scalar-prefetch operands (SMEM,
+    no VMEM block), indexed by the same block table."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    k_pages, v_pages, k_scales, v_scales = _rpa_operands(
+        k_pages, v_pages, k_scales, v_scales, layer)
     R, nkv, Tr, d = q.shape
-    num_pages, page = k_pages.shape[1], k_pages.shape[2]
+    layers, _, num_pages, page, _ = k_pages.shape
     Bmax = block_tables.shape[1]
     n_qt = Tr // bq_rows
     scale = 1.0 / math.sqrt(float(d))
     specs = rpa_block_specs(R, nkv, Tr, d, num_pages, page, Bmax,
-                            bq_rows)
+                            bq_rows, layers)
     quantized = k_scales is not None
-
+    scalars = (block_tables, seq_lens, q_lens, _layer_operand(layer))
     if quantized:
-        def q_map(r, h, qt, j, tbl, lens, qlens, ksc, vsc):
-            del j, tbl, lens, qlens, ksc, vsc
-            return (r, h, qt, 0)
+        scalars += (k_scales, v_scales)
 
-        def kv_map(r, h, qt, j, tbl, lens, qlens, ksc, vsc):
-            del qt, lens, qlens, ksc, vsc
-            return (h, tbl[r, j], 0, 0)
-    else:
-        def q_map(r, h, qt, j, tbl, lens, qlens):
-            del j, tbl, lens, qlens
-            return (r, h, qt, 0)
+    def q_map(r, h, qt, j, *scalars):
+        del j, scalars
+        return (r, h, qt, 0)
 
-        def kv_map(r, h, qt, j, tbl, lens, qlens):
-            del qt, lens, qlens
-            return (h, tbl[r, j], 0, 0)
+    def kv_map(r, h, qt, j, tbl, lens, qlens, layer, *scales):
+        del qt, lens, qlens, scales
+        return (layer[0], h, tbl[r, j], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 if quantized else 3,
+        num_scalar_prefetch=len(scalars),
         grid=(R, nkv, n_qt, Bmax),
         in_specs=[
             pl.BlockSpec(specs["in"][0][0], q_map),
@@ -2008,10 +2056,7 @@ def _rpa_call(q, k_pages, v_pages, block_tables, seq_lens, q_lens, *,
         compiler_params=_compiler_params(
             "parallel", "parallel", "parallel", "arbitrary"),
     )
-    if quantized:
-        return call(block_tables, seq_lens, q_lens, k_scales, v_scales,
-                    q, k_pages, v_pages)
-    return call(block_tables, seq_lens, q_lens, q, k_pages, v_pages)
+    return call(*scalars, q, k_pages, v_pages)
 
 
 def ragged_attention_available(q_shape, kv_shape, dtype=None,
@@ -2022,7 +2067,7 @@ def ragged_attention_available(q_shape, kv_shape, dtype=None,
     mode."""
     del dtype
     R, nkv, Tr, d = q_shape
-    page = kv_shape[2]
+    page = kv_shape[-2]
     if page % _LANES != 0:
         return False
     if bq_rows is not None:
@@ -2050,7 +2095,7 @@ def _rpa_config(q_shape, kv_shape, dtype=None):
     shape, else the whole q-slot (one tile per request)."""
     from paddle_tpu.ops import autotune
     R, nkv, Tr, d = q_shape
-    page = kv_shape[2]
+    page = kv_shape[-2]
     cfg = autotune.lookup_chain("ragged_paged_attention",
                                 _rpa_keys(Tr, d, page, dtype))
     if cfg is not None:
@@ -2062,17 +2107,25 @@ def _rpa_config(q_shape, kv_shape, dtype=None):
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                            q_lens, *, rep=1, bq_rows=None,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None, layer=0):
     """Mixed prefill+decode attention over a paged KV cache.
 
     q            [R, nkv, Tc*rep, d] per-request q slots (GQA: the rep
                  q heads of kv head h sit at rows tok*rep..tok*rep+rep-1)
-    k/v pages    [nkv, P, page, d] pools
+    k/v pages    [L, nkv, P, page, d] the stacked pools of every layer,
+                 as the engine holds them: passed whole and never
+                 sliced — the kernel's index map picks ``layer``
+    layer        which layer of the stack to attend over (an int or a
+                 traced int32 scalar, e.g. the counter of a layer scan)
     block_tables [R, Bmax] i32, seq_lens/q_lens [R] i32 (see module
                  section comment for the ragged-batch contract)
-    k/v_scales   optional [nkv, P] f32 per-page dequant scales for
-                 quantized (int8) pools; pages dequant on read inside
-                 the kernel via two extra scalar-prefetch operands
+    k/v_scales   optional [L, nkv, P] f32 per-page dequant scales for
+                 quantized (int8) pools; this layer's [nkv, P] are
+                 sliced out (12 KB) and ride into the kernel as two
+                 extra scalar-prefetch operands, pages dequant on read
+
+    One layer's pool ``[nkv, P, page, d]`` (with ``[nkv, P]`` scales)
+    takes the same path as the stack of that one layer, layer 0.
 
     Decode is the Tc == 1 specialization of the same kernel.  The jnp
     reference serves off-TPU and lane-unaligned pages (a choice made
@@ -2082,12 +2135,12 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                                       bq_rows):
         return _ragged_attention_jnp(q, k_pages, v_pages, block_tables,
                                      seq_lens, q_lens, rep,
-                                     k_scales, v_scales)
+                                     k_scales, v_scales, layer)
     b = bq_rows if bq_rows is not None else _rpa_config(
         q.shape, k_pages.shape, q.dtype)
     return _rpa_call(q, k_pages, v_pages, block_tables, seq_lens,
                      q_lens, rep=rep, bq_rows=b,
-                     k_scales=k_scales, v_scales=v_scales)
+                     k_scales=k_scales, v_scales=v_scales, layer=layer)
 
 
 def rpa_candidates(R, nkv, Tr, d, num_pages, page, Bmax,
@@ -2206,6 +2259,174 @@ def tune_ragged_attention(R=8, nkv=2, Tc=8, rep=2, d=128, num_pages=64,
         time_candidate, budget_s=budget_s, verbose=verbose,
         verify_candidate=_verify_rpa_candidate(
             R, nkv, Tr, d, num_pages, page, Bmax, rep, dtype))
+
+
+# ---------------------------------------------------------------------------
+# Paged KV write: a step's new tokens into the stacked pools, in place
+# ---------------------------------------------------------------------------
+#
+# The other half of keeping the pools one buffer: ``forward_paged`` hands
+# the stacked pools [L, nkv, P, page, d] and each request's chunk of new
+# k/v to ``paged_kv_write``, which writes token t < q_lens[r] of request r
+# at kv position seq_lens[r] - q_lens[r] + t of layer ``layer`` through the
+# block table, and returns the same buffers (``input_output_aliases``).
+# An XLA scatter cannot do this on the TPU without harm: with a (nkv, d)
+# window it wants the head axis beside d and relayouts the whole stack
+# around itself, and as single rows (6,144 of 256 B a layer and pool) it
+# costs 10 ms a step and pool (PERF.md section 6, PR 26).  So the write is a
+# Mosaic kernel, and the Mosaic layout is the only layout the pools have.
+#
+# A row of a packed dtype cannot be stored alone, so the unit is the
+# ``rows``-row tile that holds it (8 rows of 4 bytes: 16 of bf16): grid
+# (R, n_t) where n_t tiles cover any chunk of Tc tokens; grid point
+# (r, i) reads the tile of all nkv heads, replaces the rows this chunk
+# owns, and writes it back.  Tiles a chunk does not reach (and inactive
+# rows) map to tile 0 of the allocator's null page and write it back as
+# read.  Two requests never write one tile: a page under write has one
+# owner (shared prefix pages are full, copy-on-write forks before a write).
+
+def _kv_tile_rows(dtype):
+    """Rows of the sublane tile of ``dtype``: 8 of four bytes, packed."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _kv_write_kernel(layer_ref, pg_ref, sub_ref, shift_ref, qlens_ref,
+                     knew_ref, vnew_ref, kin_ref, vin_ref, kout_ref,
+                     vout_ref, *, rows, Tc):
+    """Grid point (r, i): the i-th ``rows``-row tile that request r's
+    chunk touches, all kv heads, of layer ``layer_ref[0]``.  Row j of the
+    tile takes chunk token ``j - shift`` where that is a real token."""
+    from jax.experimental import pallas as pl
+    del layer_ref, pg_ref, sub_ref
+    r = pl.program_id(0)
+    i = pl.program_id(1)
+    qlen = qlens_ref[r]
+    shape = kin_ref.shape[1:2] + kin_ref.shape[3:]       # (nkv, rows, d)
+    tok = lax.broadcasted_iota(jnp.int32, shape, 1) - shift_ref[r, i]
+    for new_ref, in_ref, out_ref in ((knew_ref, kin_ref, kout_ref),
+                                     (vnew_ref, vin_ref, vout_ref)):
+        tile = in_ref[0, :, 0].astype(jnp.float32)       # [nkv, rows, d]
+        new = new_ref[0].astype(jnp.float32)             # [nkv, Tc, d]
+        for t in range(Tc):
+            tile = jnp.where((tok == t) & (t < qlen),
+                             new[:, t:t + 1, :], tile)
+        out_ref[0, :, 0] = tile.astype(out_ref.dtype)
+
+
+def _kv_write_tiles(block_tables, seq_lens, q_lens, Tc, page, rows):
+    """Which tile each grid point (r, i) of the write kernel holds:
+    (pool page, tile within the page, start - first position of the tile),
+    each [R, n_t] int32.  Tiles past the chunk's end and inactive rows
+    hold tile 0 of the null page."""
+    n_t = (Tc + rows - 2) // rows + 1
+    Bmax = block_tables.shape[1]
+    start = (seq_lens - q_lens).astype(jnp.int32)
+    pos0 = (start[:, None] // rows
+            + jnp.arange(n_t, dtype=jnp.int32)[None, :]) * rows
+    reached = (q_lens > 0)[:, None] & (pos0 < seq_lens[:, None])
+    pg = jnp.take_along_axis(
+        block_tables, jnp.clip(pos0 // page, 0, Bmax - 1), axis=1)
+    return (jnp.where(reached, pg, 0).astype(jnp.int32),
+            jnp.where(reached, pos0 % page // rows, 0).astype(jnp.int32),
+            start[:, None] - pos0)
+
+
+def _kv_write_call(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
+                   q_lens, layer):
+    """Raw pallas_call of the paged KV write: pools aliased in to out."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    R, Tc, nkv, d = k_new.shape
+    page = k_pages.shape[3]
+    rows = _kv_tile_rows(k_pages.dtype)
+    pg, sub, shift = _kv_write_tiles(block_tables, seq_lens, q_lens, Tc,
+                                     page, rows)
+    scalars = (_layer_operand(layer), pg, sub, shift, q_lens)
+
+    def new_map(r, i, *scalars):
+        del i, scalars
+        return (r, 0, 0, 0)
+
+    def pool_map(r, i, layer, pg, sub, *rest):
+        del rest
+        return (layer[0], 0, pg[r, i], sub[r, i], 0)
+
+    new_spec = pl.BlockSpec((1, nkv, Tc, d), new_map)
+    pool_spec = pl.BlockSpec((1, nkv, 1, rows, d), pool_map)
+    call = _pallas_call(
+        functools.partial(_kv_write_kernel, rows=rows, Tc=Tc),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(R, pg.shape[1]),
+            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec]),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype)
+                   for p in (k_pages, v_pages)],
+        # operand numbers count the scalars: the pools are 7 and 8
+        input_output_aliases={len(scalars) + 2: 0, len(scalars) + 3: 1},
+        compiler_params=_compiler_params("arbitrary", "arbitrary"),
+    )
+    # [R, Tc, nkv, d] -> [R, nkv, Tc, d]: a head's chunk rows together
+    return tuple(call(*scalars,
+                      k_new.transpose(0, 2, 1, 3).astype(k_pages.dtype),
+                      v_new.transpose(0, 2, 1, 3).astype(v_pages.dtype),
+                      k_pages, v_pages))
+
+
+def _kv_write_jnp(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
+                  q_lens, layer):
+    """Reference and off-TPU body of ``paged_kv_write``: an XLA scatter of
+    single rows at (layer, head, page, row).  Rows, not (nkv, d) windows:
+    every index leads, so the scatter works on the stack's own layout."""
+    R, Tc, nkv, d = k_new.shape
+    num_pages, page = k_pages.shape[2], k_pages.shape[3]
+    t_off = jnp.arange(Tc, dtype=jnp.int32)[None, :]
+    qpos = (seq_lens - q_lens).astype(jnp.int32)[:, None] + t_off
+    blk = jnp.clip(qpos // page, 0, block_tables.shape[1] - 1)
+    # padding tokens (t >= q_len) get a page past the pool and drop
+    pg = jnp.where(t_off < q_lens[:, None],
+                   jnp.take_along_axis(block_tables, blk, axis=1),
+                   num_pages).reshape(-1, 1)
+    off = (qpos % page).reshape(-1, 1)
+    heads = jnp.arange(nkv, dtype=jnp.int32)[None, :]
+    return tuple(
+        p.at[layer, heads, pg, off].set(
+            new.reshape(R * Tc, nkv, d).astype(p.dtype), mode="drop")
+        for p, new in ((k_pages, k_new), (v_pages, v_new)))
+
+
+def kv_write_available(kv_shape, dtype):
+    """True when the Pallas write kernel can serve these pools: whole
+    sublane tiles in a page, lane-aligned heads, a TPU backend or
+    interpret mode (the same choice as ``ragged_attention_available``)."""
+    page, d = kv_shape[-2], kv_shape[-1]
+    if page % _kv_tile_rows(dtype) or d % _LANES:
+        return False
+    return _kernels_enabled("paged_kv_write")
+
+
+def paged_kv_write(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
+                   q_lens, *, layer=0):
+    """Write a step's new k/v into the stacked page pools, in place.
+
+    k/v pages    [L, nkv, P, page, d] the stacked pools, as the engine
+                 holds them; returned as the same buffers, updated
+    k/v new      [R, Tc, nkv, d] each request's chunk; tokens
+                 t < q_lens[r] go to kv positions seq_lens[r] -
+                 q_lens[r] + t, the rest is padding and is not written
+    layer        which layer of the stack (an int or a traced scalar)
+    block_tables [R, Bmax] i32, seq_lens/q_lens [R] i32 as for
+                 ``ragged_paged_attention``
+
+    Only the new tokens' tiles move: no operation yields a pool or a
+    layer's pool.  On the TPU a Mosaic kernel (``_kv_write_kernel``);
+    off-TPU and for pages or heads that are not tile-aligned an XLA
+    scatter of rows."""
+    write = (_kv_write_call
+             if kv_write_available(k_pages.shape, k_pages.dtype)
+             else _kv_write_jnp)
+    return write(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
+                 q_lens, layer)
 
 
 # ---------------------------------------------------------------------------
@@ -2583,8 +2804,10 @@ def kernel_verify_cases():
     # them — an out-of-range table entry here would fire index-oob.
     import numpy as np
     Rr, nkv, rep, page = 4, 2, 2, _LANES
-    P, Bmax = 16, 4
-    kv_aval = SDS((nkv, P, page, D), f32)
+    P, Bmax, Ls, layer = 16, 4, 3, 2
+    # the engine's form: the stacked pools of Ls layers and a layer other
+    # than 0, so the index maps are proved with the layer in them
+    kv_aval = SDS((Ls, nkv, P, page, D), f32)
     tbl = (1 + np.arange(Rr * Bmax, dtype=np.int32)
            % (P - 1)).reshape(Rr, Bmax)
     lens = np.full((Rr,), Bmax * page, dtype=np.int32)
@@ -2595,7 +2818,7 @@ def kernel_verify_cases():
 
         def fwd(q, kp, vp):
             return _rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
-                             bq_rows=Tr)
+                             bq_rows=Tr, layer=layer)
         return fwd, (SDS((Rr, nkv, Tr, D), f32), kv_aval, kv_aval)
 
     mixed_fn, mixed_avals = rpa_case(8)
@@ -2614,21 +2837,43 @@ def kernel_verify_cases():
 
     # quantized-KV ragged paged attention: int8 pools, with the
     # per-page scale pools riding as CONCRETE scalar-prefetch operands
-    # — concrete so the verifier proves the (tbl[r, j]) index maps at
-    # the extended 5-scalar signature, and so the VMEM estimate's
-    # scalar-operand accounting sees the real scale-pool shapes.
-    ksc = np.ones((nkv, P), dtype=np.float32)
-    vsc = np.ones((nkv, P), dtype=np.float32)
+    # — concrete so the verifier proves the (layer[0], h, tbl[r, j])
+    # index maps at the extended 6-scalar signature, and so the VMEM
+    # estimate's scalar-operand accounting sees the real per-layer
+    # scale shapes ([nkv, P], sliced out of the stack's [Ls, nkv, P]).
+    ksc = np.ones((Ls, nkv, P), dtype=np.float32)
+    vsc = np.ones((Ls, nkv, P), dtype=np.float32)
     Tc_q = 8
     qlens_q = np.full((Rr,), Tc_q, dtype=np.int32)
-    kv_i8 = SDS((nkv, P, page, D), jnp.int8)
+    kv_i8 = SDS((Ls, nkv, P, page, D), jnp.int8)
 
     def rpa_quant_fwd(q, kp, vp):
         return _rpa_call(q, kp, vp, tbl, lens, qlens_q, rep=rep,
-                         bq_rows=Tc_q * rep, k_scales=ksc, v_scales=vsc)
+                         bq_rows=Tc_q * rep, k_scales=ksc, v_scales=vsc,
+                         layer=layer)
 
     cases.append(("ragged_paged_attention_quant_kv", rpa_quant_fwd,
                   (SDS((Rr, nkv, Tc_q * rep, D), f32), kv_i8, kv_i8)))
+
+    # the paged KV write into the same stacked pools: a chunk that
+    # starts mid-tile (two tiles a request) and the one-token decode
+    # write.  Block legality and the VMEM estimate are checked; its
+    # (layer, page, tile) index maps read tables that jnp derives from
+    # the block table, so they stay traced and are not evaluated (nor is
+    # output coverage, which an aliased in-place output does not owe)
+    def kv_write_case(Tc):
+        qlens = np.full((Rr,), Tc, dtype=np.int32)
+        lens_w = np.full((Rr,), page + 5 + Tc, dtype=np.int32)
+
+        def fwd(kn, vn, kp, vp):
+            return _kv_write_call(kp, vp, kn, vn, tbl, lens_w, qlens,
+                                  layer)
+        new = SDS((Rr, Tc, nkv, D), f32)
+        return fwd, (new, new, kv_aval, kv_aval)
+
+    for name, Tc in (("paged_kv_write", 8), ("paged_kv_write_decode", 1)):
+        fn, avals = kv_write_case(Tc)
+        cases.append((name, fn, avals))
 
     # int8 weight-path matmul at a representative lane-aligned shape
     Mq, Kq, Nq = 256, 256, 256

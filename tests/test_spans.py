@@ -335,11 +335,11 @@ def test_the_rpa_kernel_is_under_attn_and_the_new_tokens_under_kv_write(
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     found = scopes_of(functools.partial(llama.forward_paged, cfg), params,
                       i32(R, Tc), pool, pool, i32(R, 2), i32(R), i32(R))
-    (rpa,) = [s for p, s in found if p == "pallas_call"]
+    write, rpa = [s for p, s in found if p == "pallas_call"]
     assert rpa.endswith("layers/attn/pallas/_rpa_kernel")
-    scatters = [s for p, s in found if p == "scatter"]
-    assert scatters and all(s.endswith("layers/attn/kv_write")
-                            for s in scatters)
+    # the new tokens go in through the write kernel: no XLA scatter
+    assert write.endswith("layers/attn/kv_write/pallas/_kv_write_kernel")
+    assert not [s for p, s in found if p == "scatter"]
     stacks = {s for _, s in found}
     for scope in ("embed", "layers/mlp", "lm_head"):
         assert any(s == scope or s.endswith("/" + scope) or

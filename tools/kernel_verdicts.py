@@ -29,6 +29,7 @@ H, NH, D, I, V = 2048, 16, 128, 5504, 32000
 # engine defaults (serving/engine.py): batch width, prefill chunk, page
 R, CHUNK, PAGE = 8, 16, 128
 NUM_PAGES, BMAX = 257, 32      # max_model_len 4096 / page 128, +1 null page
+LAYERS, LAYER = 2, 1           # the pools are stacked; the kernels index one
 
 
 def _cases():
@@ -108,15 +109,18 @@ def _cases():
         grads_of(functools.partial(po._mlp_block_jnp, eps=1e-6), 5),
         mlp_block_args, 3e-2))
 
-    # ragged paged attention at the engine's two buckets, MHA (rep=1):
-    # pages shuffled as an allocator leaves them, ragged kv lengths
+    # ragged paged attention at the engine's two buckets, MHA (rep=1), in
+    # the form the engine runs: the stacked pools of LAYERS layers and a
+    # layer other than 0, against the jnp body on that layer's own 4-D
+    # pool; pages shuffled as an allocator leaves them, ragged kv lengths
     def rpa_args(Tc, quant):
         def make(key):
             ks = jax.random.split(key, 3)
             rng = np.random.RandomState(0)
             q = jax.random.normal(ks[0], (R, NH, Tc, D), bf16) * 0.5
-            kp = jax.random.normal(ks[1], (NH, NUM_PAGES, PAGE, D)) * 0.5
-            vp = jax.random.normal(ks[2], (NH, NUM_PAGES, PAGE, D)) * 0.5
+            stack = (LAYERS, NH, NUM_PAGES, PAGE, D)
+            kp = jax.random.normal(ks[1], stack) * 0.5
+            vp = jax.random.normal(ks[2], stack) * 0.5
             tbl = (1 + rng.permutation(NUM_PAGES - 1)[:R * BMAX]).reshape(
                 R, BMAX).astype(np.int32)
             lens = rng.randint(Tc, BMAX * PAGE, size=(R,)).astype(np.int32)
@@ -127,12 +131,12 @@ def _cases():
                 qlens[1] = 1                  # a decode row in the mixed bucket
             out = (q,)
             if quant:
-                amax = jnp.max(jnp.abs(kp), axis=(2, 3))
+                amax = jnp.max(jnp.abs(kp), axis=(3, 4))
                 ksc = jnp.maximum(amax, 1e-8) / 127.0
-                vsc = jnp.maximum(jnp.max(jnp.abs(vp), axis=(2, 3)),
+                vsc = jnp.maximum(jnp.max(jnp.abs(vp), axis=(3, 4)),
                                   1e-8) / 127.0
-                kq = jnp.round(kp / ksc[:, :, None, None]).astype(jnp.int8)
-                vq = jnp.round(vp / vsc[:, :, None, None]).astype(jnp.int8)
+                kq = jnp.round(kp / ksc[..., None, None]).astype(jnp.int8)
+                vq = jnp.round(vp / vsc[..., None, None]).astype(jnp.int8)
                 out += (kq, vq, jnp.asarray(tbl), jnp.asarray(lens),
                         jnp.asarray(qlens), ksc, vsc)
             else:
@@ -143,11 +147,13 @@ def _cases():
 
     def rpa(q, kp, vp, tbl, lens, qlens, ksc=None, vsc=None):
         return po.ragged_paged_attention(q, kp, vp, tbl, lens, qlens, rep=1,
-                                         k_scales=ksc, v_scales=vsc)
+                                         k_scales=ksc, v_scales=vsc,
+                                         layer=LAYER)
 
     def rpa_ref(q, kp, vp, tbl, lens, qlens, ksc=None, vsc=None):
-        return po._ragged_attention_jnp(q, kp, vp, tbl, lens, qlens, 1,
-                                        ksc, vsc)
+        one = [None if a is None else a[LAYER] for a in (kp, vp, ksc, vsc)]
+        return po._ragged_attention_jnp(q, one[0], one[1], tbl, lens, qlens,
+                                        1, one[2], one[3])
 
     cases += [
         (f"rpa_mixed[Tc={CHUNK}]", rpa, rpa_ref, rpa_args(CHUNK, False), 3e-2),
@@ -155,6 +161,30 @@ def _cases():
         (f"rpa_quant_mixed[Tc={CHUNK}]", rpa, rpa_ref,
          rpa_args(CHUNK, True), 3e-2),
         ("rpa_quant_decode[Tc=1]", rpa, rpa_ref, rpa_args(1, True), 3e-2),
+    ]
+
+    # the write of a step's new tokens into the same stacked pools, in
+    # place, against the XLA row scatter: exact, whole pools compared
+    def kv_write_args(Tc):
+        def make(key):
+            q, kp, vp, tbl, lens, qlens = rpa_args(Tc, False)(key)
+            ks = jax.random.split(jax.random.fold_in(key, 1), 2)
+            new = tuple(jax.random.normal(k, (R, Tc, NH, D), bf16)
+                        for k in ks)
+            return (kp, vp) + new + (tbl, lens, qlens)
+        return make
+
+    def kv_write(*a):
+        return po.paged_kv_write(*a, layer=LAYER)
+
+    def kv_write_ref(*a):
+        return po._kv_write_jnp(*a, LAYER)
+
+    cases += [
+        (f"paged_kv_write[Tc={CHUNK}]", kv_write, kv_write_ref,
+         kv_write_args(CHUNK), 0.0),
+        ("paged_kv_write[Tc=1]", kv_write, kv_write_ref,
+         kv_write_args(1), 0.0),
     ]
 
     # int8 weight matmul at every (M, K, N) forward_paged issues:
