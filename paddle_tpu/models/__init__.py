@@ -2,14 +2,17 @@
 
 - llama: RoPE/GQA/SwiGLU decoder with 4-D parallel train step (the
   Llama-2 pretrain north star), optional MoE layers, ring-attention CP.
+- jamba: Mamba-1 layers with an attention layer every few (forward and the
+  serving engine's ragged step with per-slot recurrent state; no train step).
 - gpt: GPT-2-style decoder (learned positions, fused QKV, GELU, tied head).
 - ernie: encoder pretraining family (MLM+NSP).
 - decoding: shared KV-cache autoregressive generation.
 """
 from . import llama  # noqa: F401
+from . import jamba  # noqa: F401
 from . import gpt  # noqa: F401
 from . import ernie  # noqa: F401
 from . import decoding  # noqa: F401
 from . import convert  # noqa: F401
 
-__all__ = ["llama", "gpt", "ernie", "decoding", "convert"]
+__all__ = ["llama", "jamba", "gpt", "ernie", "decoding", "convert"]

@@ -1,0 +1,445 @@
+"""Jamba: a decoder whose layers are Mamba-1 mixers with an attention layer
+every ``attn_layer_period`` (the public ``JambaForCausalLM``; dense SwiGLU in
+every layer, no positional encoding, tied head).
+
+Layer ``i`` is attention where ``i % attn_layer_period == attn_layer_offset``
+and a Mamba mixer otherwise.  With D the hidden size, E = expand x D, N the
+state size, K the convolution width and r the dt rank, a Mamba layer on
+``u [T, D]`` is::
+
+    [x, z] = RMSNorm(u) W_in                                (D -> 2E)
+    x_t   <- silu(b_conv + sum_k w_conv[k] * x_{t-K+1+k})   causal, depthwise
+    [dt, B, C] = x W_x                                      (E -> r + 2N)
+    Delta = softplus(RMSNorm(dt) W_dt + b_dt)               [T, E]
+    S_t   = exp(Delta_t (x) A) * S_{t-1} + (Delta_t * x_t) (x) RMSNorm(B_t)
+    y_t   = S_t RMSNorm(C_t) + D_skip * x_t                 A = -exp(A_log)
+    u    <- u + (y * silu(z)) W_out                         (E -> D)
+
+with ``S`` in float32, then ``u <- u + SwiGLU(RMSNorm(u))`` as in every
+layer.  An attention layer is causal softmax attention of
+``num_attention_heads`` query heads over ``num_key_value_heads`` K/V heads
+without any positional term.
+
+Parameters: ``embed [V, D]``, ``norm_f [D]``, ``mamba`` (every leaf stacked
+over the Mamba layers) and ``attn`` (stacked over the attention layers),
+both holding their layers' ``ln2 / w_gate / w_up / w_down`` too.  What has a
+state axis keeps it second to last (``A_log [M, N, E]``, ``conv_w [M, K,
+E]``): E fills the 128 lanes of a TPU tile, N or K the sublanes.
+
+Serving (``SERVING``, the protocol ``serving.LLMEngine`` asks a
+configuration for): the cache is a dict of the two attention layers' page
+pools ``k_pages / v_pages [A, nkv, P, page, d]``, written and read exactly
+as Llama's, and the recurrent state of every engine slot, ``conv [M, K-1, R,
+E]`` (the last K-1 inputs of the convolution) and ``ssm [M, N, R, E]``
+float32.  The state belongs to a slot, not to pages: see ``forward_paged``.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+import types
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .llama import _dense_mlp, _rms_norm
+
+__all__ = ["JambaConfig", "PRESETS", "preset", "config_from_fields",
+           "init_params", "param_count", "forward_pure", "forward_paged",
+           "init_cache", "cache_bytes", "SERVING"]
+
+
+@dataclasses.dataclass
+class JambaConfig:
+    """Field names are those of the public ``config.json``; the defaults
+    are AI21-Jamba2-3B."""
+    vocab_size: int = 65536
+    hidden_size: int = 2560
+    intermediate_size: int = 8192
+    num_hidden_layers: int = 28
+    num_attention_heads: int = 20
+    num_key_value_heads: int = 1
+    attn_layer_period: int = 14
+    attn_layer_offset: int = 7
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 160
+    max_position_embeddings: int = 262144
+    rms_norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def attn_layers(self) -> tuple:
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if i % self.attn_layer_period == self.attn_layer_offset)
+
+    @property
+    def num_mamba_layers(self) -> int:
+        return self.num_hidden_layers - len(self.attn_layers)
+
+    def layer_runs(self) -> list:
+        """The layers in order as ``("mamba", lo, hi)`` (Mamba layers
+        ``lo..hi`` of the Mamba stack, consecutive in the model) and
+        ``("attn", a)`` (attention layer ``a`` of the attention stack)."""
+        runs, lo, hi = [], 0, 0
+        for a, i in enumerate(self.attn_layers):
+            hi = i - a
+            runs += [("mamba", lo, hi)] * (hi > lo) + [("attn", a)]
+            lo = hi
+        last = self.num_mamba_layers
+        return runs + [("mamba", lo, last)] * (last > lo)
+
+    @property
+    def serving(self):
+        return SERVING
+
+
+PRESETS: Dict[str, Dict[str, Any]] = {
+    "jamba2-3b": {},
+    # six layers, the third one attention; for the CPU tests
+    "jamba-debug": dict(vocab_size=256, hidden_size=64, intermediate_size=128,
+                        num_hidden_layers=6, num_attention_heads=4,
+                        num_key_value_heads=1, attn_layer_period=6,
+                        attn_layer_offset=2, mamba_dt_rank=4,
+                        max_position_embeddings=512),
+}
+
+
+def preset(name: str, **overrides) -> JambaConfig:
+    if name not in PRESETS:
+        raise KeyError(f"unknown jamba preset {name!r}; available: "
+                       f"{sorted(PRESETS)}")
+    return JambaConfig(**dict(PRESETS[name], **overrides))
+
+
+def config_from_fields(fields: dict) -> JambaConfig:
+    """A ``JambaConfig`` from a ``config.json``-shaped dict: every key that
+    is a field, ``dtype`` by name; other keys are not this model's."""
+    names = {f.name for f in dataclasses.fields(JambaConfig)}
+    kw = {k: v for k, v in fields.items() if k in names}
+    kw["dtype"] = jnp.dtype(kw.get("dtype", "bfloat16")).type
+    return JambaConfig(**kw)
+
+
+def param_count(cfg: JambaConfig) -> int:
+    D, I, E = cfg.hidden_size, cfg.intermediate_size, cfg.mamba_inner
+    N, K, r = cfg.mamba_d_state, cfg.mamba_d_conv, cfg.mamba_dt_rank
+    mlp_and_norms = 3 * D * I + 2 * D
+    mixer = (2 * D * E + K * E + E + E * (r + 2 * N) + r * E + E
+             + N * E + E + E * D + r + 2 * N)
+    qkv = D * cfg.head_dim * (cfg.num_attention_heads
+                              + 2 * cfg.num_key_value_heads)
+    attn = qkv + cfg.num_attention_heads * cfg.head_dim * D
+    return (cfg.num_mamba_layers * (mixer + mlp_and_norms)
+            + len(cfg.attn_layers) * (attn + mlp_and_norms)
+            + cfg.vocab_size * D + D)
+
+
+def init_params(cfg: JambaConfig, key) -> Dict[str, Any]:
+    """Seeded weights: normal(0, 0.02) matrices, and Mamba's standard
+    start for the recurrence — ``A_log = log(1..N)``, ``D_skip = 1``, a dt
+    bias that is softplus^-1 of step sizes log-uniform in [1e-3, 1e-1] and
+    a dt projection uniform in +-r^-0.5 — so that the state neither dies
+    nor saturates within a request."""
+    D, I, E, V = (cfg.hidden_size, cfg.intermediate_size, cfg.mamba_inner,
+                  cfg.vocab_size)
+    N, K, r = cfg.mamba_d_state, cfg.mamba_d_conv, cfg.mamba_dt_rank
+    M, A = cfg.num_mamba_layers, len(cfg.attn_layers)
+    Q, KV = (cfg.num_attention_heads * cfg.head_dim,
+             cfg.num_key_value_heads * cfg.head_dim)
+    k = iter(jax.random.split(key, 20))
+
+    def normal(shape):
+        return (jax.random.normal(next(k), shape, jnp.float32)
+                * 0.02).astype(cfg.dtype)
+
+    def ones(*shape):
+        return jnp.ones(shape, cfg.dtype)
+
+    def mlp(n):
+        return {"ln2": ones(n, D), "w_gate": normal((n, D, I)),
+                "w_up": normal((n, D, I)), "w_down": normal((n, I, D))}
+
+    dt = jnp.exp(jax.random.uniform(next(k), (M, E), jnp.float32)
+                 * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+    mamba = {
+        "ln1": ones(M, D), "w_in": normal((M, D, 2 * E)),
+        "conv_w": (jax.random.uniform(next(k), (M, K, E), jnp.float32, -1, 1)
+                   / math.sqrt(K)).astype(cfg.dtype),
+        "conv_b": jnp.zeros((M, E), cfg.dtype),
+        "w_x": normal((M, E, r + 2 * N)),
+        "dt_norm": ones(M, r), "b_norm": ones(M, N), "c_norm": ones(M, N),
+        "w_dt": (jax.random.uniform(next(k), (M, r, E), jnp.float32, -1, 1)
+                 / math.sqrt(r)).astype(cfg.dtype),
+        "b_dt": (dt + jnp.log(-jnp.expm1(-dt))).astype(cfg.dtype),
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[None, :, None],
+            (M, N, E)).astype(cfg.dtype),
+        "D_skip": ones(M, E), "w_out": normal((M, E, D)), **mlp(M)}
+    attn = {"ln1": ones(A, D), "wq": normal((A, D, Q)),
+            "wk": normal((A, D, KV)), "wv": normal((A, D, KV)),
+            "wo": normal((A, Q, D)), **mlp(A)}
+    return {"embed": normal((V, D)), "mamba": mamba, "attn": attn,
+            "norm_f": ones(D)}
+
+
+# ---------------------------------------------------------------------------
+# the Mamba mixer on a ragged batch of chunks, with carried state
+# ---------------------------------------------------------------------------
+
+def _ssm_conv(lp, x, conv, q_lens):
+    """The causal depthwise convolution of ``x [R, Tc, E]`` continued from
+    ``conv [K-1, R, E]``, the K-1 inputs before the chunk.  Returns the
+    activations ``[R, Tc, E]`` float32 and the last K-1 REAL inputs of each
+    row (those before position ``q_lens[r]`` of the window), so padding
+    never enters the state and a row with ``q_lens == 0`` keeps its own."""
+    K = conv.shape[0] + 1
+    Tc = x.shape[1]
+    win = [conv[j] for j in range(K - 1)] + [x[:, t] for t in range(Tc)]
+    w = lp["conv_w"].astype(jnp.float32)
+    out = [lp["conv_b"].astype(jnp.float32)
+           + sum(w[j] * win[t + j].astype(jnp.float32) for j in range(K))
+           for t in range(Tc)]
+    new = []
+    for j in range(K - 1):
+        kept = win[j]
+        for q in range(1, Tc + 1):
+            kept = jnp.where((q_lens == q)[:, None], win[q + j], kept)
+        new.append(kept)
+    return jax.nn.silu(jnp.stack(out, 1)), jnp.stack(new, 0)
+
+
+def _ssm_scan(ssm, dt, dx, Bm, Cm, A):
+    """``S_t = exp(dt_t A) S_{t-1} + dx_t B_t``, ``y_t = S_t C_t`` over the
+    ``Tc`` positions of a chunk: ``ssm [N, R, E]`` float32, ``dt``, ``dx [R,
+    Tc, E]``, ``Bm``, ``Cm [R, Tc, N]``, ``A [N, E]``.  Unrolled over the
+    positions as plain array expressions, no loop carry, so that XLA may
+    fuse several positions into one pass over the state instead of reading
+    and writing it once a position (on a v5e a chunk of 16 costs 1.9 ms a
+    layer alone against 2.3 ms as a ``lax.scan`` over positions: PERF.md
+    section 6, PR 27).  A position
+    with ``dt == 0`` (and so ``dx == 0``) leaves the state as it was."""
+    ys = []
+    for t in range(dt.shape[1]):
+        ssm = (jnp.exp(dt[None, :, t] * A[:, None, :]) * ssm
+               + dx[None, :, t] * Bm[:, t].T[:, :, None])
+        ys.append(jnp.sum(ssm * Cm[:, t].T[:, :, None], axis=0))
+    return jnp.stack(ys, 1), ssm
+
+
+def _layer_at(stack, l):
+    """Layer ``l`` (traced) of a stack of per-layer leaves: the slice a
+    ``lax.scan`` over the stack would take, from the whole stack, so that a
+    run of layers scans the one stack by index and copies none of it."""
+    return jax.tree_util.tree_map(
+        lambda w: lax.dynamic_index_in_dim(w, l, 0, keepdims=False), stack)
+
+
+def _mamba_mixer(cfg, lp, h, conv, ssm, l, q_lens, fresh):
+    """Mixer ``l`` on ``h [R, Tc, D]`` with the state of each row, read from
+    and written back into layer ``l`` of the stacks ``conv [M, K-1, R, E]``
+    and ``ssm [M, N, R, E]`` float32.  A row whose chunk is ``fresh`` starts
+    from zero state; positions ``t >= q_lens[r]`` advance neither state.
+    Returns the mixer's output ``[R, Tc, D]`` and both stacks.
+
+    The scopes ``ssm_conv`` and ``ssm_scan`` hold everything that touches
+    their state: its slice out of the stack, the reset, the update and the
+    write-back, so a reader of the scope's time times every byte that
+    ``benchmark/kernel_costs_ssm.py`` counts."""
+    N, r, eps = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.rms_norm_eps
+    Tc, f32 = h.shape[1], jnp.float32
+    x, z = jnp.split(_rms_norm(h, lp["ln1"], eps) @ lp["w_in"], 2, axis=-1)
+    with jax.named_scope("ssm_conv"):
+        c = jnp.where(fresh[None, :, None], 0, _layer_at(conv, l))
+        x, c = _ssm_conv(lp, x, c, q_lens)                  # x float32
+        conv = lax.dynamic_update_index_in_dim(conv, c, l, 0)
+    dt, Bm, Cm = jnp.split(x.astype(h.dtype) @ lp["w_x"], [r, r + N], axis=-1)
+    dt = _rms_norm(dt, lp["dt_norm"], eps) @ lp["w_dt"]
+    dt = jax.nn.softplus(dt.astype(f32) + lp["b_dt"].astype(f32))
+    real = jnp.arange(Tc)[None, :] < q_lens[:, None]         # [R, Tc]
+    dt = jnp.where(real[:, :, None], dt, 0.0)
+    Bm = _rms_norm(Bm, lp["b_norm"], eps).astype(f32)
+    Cm = _rms_norm(Cm, lp["c_norm"], eps).astype(f32)
+    with jax.named_scope("ssm_scan"):
+        s = jnp.where(fresh[None, :, None], 0, _layer_at(ssm, l))
+        A = -jnp.exp(lp["A_log"].astype(f32))
+        y, s = _ssm_scan(s, dt, dt * x, Bm, Cm, A)
+        ssm = lax.dynamic_update_index_in_dim(ssm, s.astype(ssm.dtype), l, 0)
+    y = (y + lp["D_skip"].astype(f32) * x) * jax.nn.silu(z.astype(f32))
+    return y.astype(h.dtype) @ lp["w_out"], conv, ssm
+
+
+def _mamba_run(cfg, stack, lo, hi, h, conv, ssm, q_lens, fresh):
+    """Mamba layers ``lo..hi`` of the stack as one scan.  ``conv [M, K-1, R,
+    E]`` and ``ssm [M, N, R, E]`` go round in the carry and are updated in
+    place at the layer's index."""
+    def body(carry, l):
+        h, conv, ssm = carry
+        lp = _layer_at(stack, l)
+        with jax.named_scope("mamba"):
+            out, conv, ssm = _mamba_mixer(cfg, lp, h, conv, ssm, l, q_lens,
+                                          fresh)
+            h = h + out
+        with jax.named_scope("mlp"):
+            h = h + _dense_mlp(lp, _rms_norm(h, lp["ln2"], cfg.rms_norm_eps))
+        return (h, conv, ssm), None
+
+    (h, conv, ssm), _ = lax.scan(body, (h, conv, ssm),
+                                 jnp.arange(lo, hi, dtype=jnp.int32))
+    return h, conv, ssm
+
+
+def _forward(cfg, params, tokens, conv, ssm, q_lens, fresh, attend):
+    """Embedding, the layers in order, final norm, tied head.  ``attend(a,
+    q, k, v)`` is attention layer ``a``'s mixing of ``q [R, Tc, nh, d]``
+    with ``k, v [R, Tc, nkv, d]`` and whatever came before them."""
+    R, Tc = tokens.shape
+    nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                  cfg.head_dim)
+    with jax.named_scope("embed"):
+        h = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("layers"):
+        for run in cfg.layer_runs():
+            if run[0] == "mamba":
+                h, conv, ssm = _mamba_run(cfg, params["mamba"], run[1],
+                                          run[2], h, conv, ssm, q_lens, fresh)
+                continue
+            lp = jax.tree_util.tree_map(lambda w: w[run[1]], params["attn"])
+            with jax.named_scope("attn"):
+                xn = _rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
+                out = attend(run[1], (xn @ lp["wq"]).reshape(R, Tc, nh, d),
+                             (xn @ lp["wk"]).reshape(R, Tc, nkv, d),
+                             (xn @ lp["wv"]).reshape(R, Tc, nkv, d))
+                h = h + out.reshape(R, Tc, nh * d).astype(h.dtype) @ lp["wo"]
+            with jax.named_scope("mlp"):
+                h = h + _dense_mlp(
+                    lp, _rms_norm(h, lp["ln2"], cfg.rms_norm_eps))
+    with jax.named_scope("lm_head"):
+        h = _rms_norm(h, params["norm_f"], cfg.rms_norm_eps)
+        logits = jnp.einsum("rtd,vd->rtv", h, params["embed"],
+                            preferred_element_type=jnp.float32)
+    return logits, conv, ssm
+
+
+def _fresh_state(cfg, rows: int):
+    E, f32 = cfg.mamba_inner, jnp.float32
+    M = cfg.num_mamba_layers
+    return (jnp.zeros((M, cfg.mamba_d_conv - 1, rows, E), cfg.dtype),
+            jnp.zeros((M, cfg.mamba_d_state, rows, E), f32))
+
+
+def forward_pure(cfg: JambaConfig, params, input_ids):
+    """Logits ``[B, S, V]`` float32 of whole sequences ``[B, S]``: no
+    cache, plain causal attention.  The recurrence is unrolled over S
+    (``_ssm_scan``), so this is for sequences of test length."""
+    B, S = input_ids.shape
+    rep = cfg.num_attention_heads // cfg.num_key_value_heads
+    causal = jnp.tril(jnp.ones((S, S), bool))
+
+    def attend(a, q, k, v):
+        k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                       preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(
+            jnp.where(causal, s / math.sqrt(cfg.head_dim), -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+
+    conv, ssm = _fresh_state(cfg, B)
+    return _forward(cfg, params, input_ids, conv, ssm,
+                    jnp.full((B,), S, jnp.int32), jnp.ones((B,), bool),
+                    attend)[0]
+
+
+# ---------------------------------------------------------------------------
+# serving: the engine's protocol
+# ---------------------------------------------------------------------------
+
+def init_cache(cfg: JambaConfig, slots: int, num_pages: int, page_size: int,
+               kv_dtype):
+    """The cache of an engine with ``slots`` rows: zeroed page pools of the
+    attention layers and zero recurrent state of every slot."""
+    if jnp.dtype(kv_dtype).itemsize < 2:
+        raise ValueError(
+            f"kv_dtype {jnp.dtype(kv_dtype)} pages need the per-page scale "
+            "pools that only models/llama.py's step writes")
+    pool = (len(cfg.attn_layers), cfg.num_key_value_heads, num_pages,
+            page_size, cfg.head_dim)
+    conv, ssm = _fresh_state(cfg, slots)
+    return {"k_pages": jnp.zeros(pool, kv_dtype),
+            "v_pages": jnp.zeros(pool, kv_dtype), "conv": conv, "ssm": ssm}
+
+
+def cache_bytes(cfg: JambaConfig, kv_dtype_bytes: int = 2) -> dict:
+    """What the cache costs: K/V bytes a token (attention layers only),
+    scale bytes a page (none: no quantized pages) and recurrent-state bytes
+    a slot, whatever the length of the request in it."""
+    E = cfg.mamba_inner
+    return {
+        "per_token": (2 * len(cfg.attn_layers) * cfg.num_key_value_heads
+                      * cfg.head_dim * kv_dtype_bytes),
+        "scales_per_page": 0,
+        "per_slot": cfg.num_mamba_layers * E * (
+            cfg.mamba_d_state * 4
+            + (cfg.mamba_d_conv - 1) * jnp.dtype(cfg.dtype).itemsize)}
+
+
+def forward_paged(cfg: JambaConfig, params, tokens, cache, block_tables,
+                  seq_lens, q_lens):
+    """The engine's step: ragged mixed prefill and decode rows ``tokens [R,
+    Tc]`` (row r feeds ``tokens[r, :q_lens[r]]`` and then holds ``seq_lens[r]``
+    tokens) over ``cache`` (``init_cache``).  Returns ``(logits [R, Tc, V]
+    float32, cache)``; logits of padding positions are garbage.
+
+    Attention layers write and read their page pools through the block
+    table with the kernels Llama's step uses (``paged_kv_write``,
+    ``ragged_paged_attention``).  The recurrent state is per ROW (engine
+    slot).  Row r's state is zeroed inside this step where its chunk starts
+    at position 0 (``seq_lens[r] == q_lens[r] > 0``): that is all admission
+    into a used slot, preemption-replay and a rebuilt engine need, since
+    each feeds a request from its first token.  Positions ``t >= q_lens[r]``
+    advance neither state, and a row with ``q_lens[r] == 0`` comes back as it
+    was.  A step that skipped tokens (a prefix-cache hit) or fed tokens to
+    take back (a rejected draft) would leave the state wrong, which is why
+    ``recurrent_state`` makes the engine refuse both."""
+    from ..ops.pallas_ops import paged_kv_write, ragged_paged_attention
+    R, Tc = tokens.shape
+    nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                  cfg.head_dim)
+    rep = nh // nkv
+    pools = [cache["k_pages"], cache["v_pages"]]
+
+    def attend(a, q, k, v):
+        with jax.named_scope("kv_write"):
+            pools[:] = paged_kv_write(*pools, k, v, block_tables, seq_lens,
+                                      q_lens, layer=a)
+        # the kernel's layout [R, nkv, Tc*rep, d], as in llama.forward_paged
+        qk = q.reshape(R, Tc, nkv, rep, d).transpose(
+            0, 2, 1, 3, 4).reshape(R, nkv, Tc * rep, d)
+        out = ragged_paged_attention(qk, *pools, block_tables, seq_lens,
+                                     q_lens, rep=rep, layer=a)
+        return out.reshape(R, nkv, Tc, rep, d).transpose(0, 2, 1, 3, 4)
+
+    fresh = (q_lens > 0) & (seq_lens == q_lens)
+    logits, conv, ssm = _forward(cfg, params, tokens, cache["conv"],
+                                 cache["ssm"], q_lens, fresh, attend)
+    return logits, {"k_pages": pools[0], "v_pages": pools[1], "conv": conv,
+                    "ssm": ssm}
+
+
+# what serving.LLMEngine asks a configuration for (``cfg.serving``)
+SERVING = types.SimpleNamespace(
+    forward_paged=forward_paged, init_cache=init_cache,
+    cache_bytes=cache_bytes, param_count=param_count,
+    prepare_params=lambda cfg, params: params,   # no weight is converted
+    recurrent_state=True)

@@ -30,6 +30,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import types
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
@@ -42,7 +43,7 @@ from .decoding import GenerationMixin
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "init_params", "forward_pure",
            "forward_with_cache", "forward_paged", "build_train_step",
-           "param_specs", "PRESETS", "preset", "quantize_params"]
+           "param_specs", "PRESETS", "preset", "quantize_params", "SERVING"]
 
 
 @dataclasses.dataclass
@@ -93,6 +94,11 @@ class LlamaConfig:
     @property
     def head_dim(self):
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def serving(self):
+        """What ``serving.LLMEngine`` asks a configuration for."""
+        return SERVING
 
 
 # Named shapes for tools (bench presets, tools/pod_report.py). The
@@ -810,6 +816,67 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
         x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
         logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
     return logits, pools
+
+
+# ---------------------------------------------------------------------------
+# the engine's protocol (``cfg.serving``): the cache is the bare page pools
+# ---------------------------------------------------------------------------
+
+def init_cache(cfg: LlamaConfig, slots: int, num_pages: int, page_size: int,
+               kv_dtype):
+    """The cache ``forward_paged`` runs on, as one pytree: zeroed page pools
+    ``(k_pages, v_pages)``; with int8 pages also the per-page scale pools at
+    1.0, so untouched (all-zero) pages dequant to exact zeros.  Nothing is
+    held per slot."""
+    del slots
+    L, nkv = cfg.num_hidden_layers, cfg.num_key_value_heads
+    shape = (L, nkv, num_pages, page_size, cfg.head_dim)
+    cache = (jnp.zeros(shape, kv_dtype), jnp.zeros(shape, kv_dtype))
+    if jnp.dtype(kv_dtype) == jnp.dtype(jnp.int8):
+        cache += (jnp.ones((L, nkv, num_pages), jnp.float32),
+                  jnp.ones((L, nkv, num_pages), jnp.float32))
+    return cache
+
+
+def cache_bytes(cfg: LlamaConfig, kv_dtype_bytes: int = 2) -> dict:
+    """What the cache costs: K/V bytes a token over all layers, scale bytes
+    a page (two float32 a layer and K/V head beside sub-2-byte pages) and
+    bytes a slot (none: no state but the pages)."""
+    heads = cfg.num_hidden_layers * cfg.num_key_value_heads
+    return {"per_token": 2 * heads * cfg.head_dim * kv_dtype_bytes,
+            "scales_per_page": 2 * heads * 4 if kv_dtype_bytes < 2 else 0,
+            "per_slot": 0}
+
+
+def param_count(cfg: LlamaConfig) -> int:
+    """Dense parameter count from the config (embed + L blocks + final
+    norm + lm_head), the number that dominates serving HBM."""
+    H, I = cfg.hidden_size, cfg.intermediate_size
+    nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                  cfg.head_dim)
+    per_layer = (H * nh * d + 2 * H * nkv * d + nh * d * H  # attn
+                 + 3 * H * I                                 # gated mlp
+                 + 2 * H)                                    # norms
+    return (cfg.vocab_size * H * 2                           # embed+head
+            + cfg.num_hidden_layers * per_layer + H)
+
+
+def _serve_step(cfg, params, tokens, cache, block_tables, seq_lens, q_lens):
+    """``forward_paged`` on the cache as one pytree."""
+    k_pages, v_pages, *scales = cache
+    return forward_paged(cfg, params, tokens, k_pages, v_pages, block_tables,
+                         seq_lens, q_lens,
+                         **dict(zip(("k_scales", "v_scales"), scales)))
+
+
+SERVING = types.SimpleNamespace(
+    forward_paged=_serve_step, init_cache=init_cache,
+    cache_bytes=cache_bytes, param_count=param_count,
+    # int8 weight path: PTQ the serving weights once at engine build; asked
+    # for by the config, never by the platform
+    prepare_params=lambda cfg, params: (
+        quantize_params(cfg, params) if cfg.quantized == "on" else params),
+    recurrent_state=False)
 
 
 def _cfg_key(cfg):

@@ -11,7 +11,9 @@ The three layers, bottom-up:
                     shapes;
   * ``engine``    — ``LLMEngine``: ``add_request()`` / ``step()`` /
                     streaming ``on_token`` callbacks, one jitted
-                    ``models.llama.forward_paged`` call per step, plus
+                    step of the model the configuration names
+                    (``cfg.serving``: ``models.llama``, ``models.jamba``)
+                    on one cache pytree, plus
                     the resilience layer: bounded admission with typed
                     retriable shedding, per-request deadlines/SLOs,
                     cooperative cancellation, and step-failure

@@ -3,8 +3,20 @@
 ``add_request()`` enqueues, ``step()`` runs one continuous-batching
 iteration (schedule -> one jitted forward_paged call -> commit), and
 streaming happens through per-request ``on_token`` callbacks.  The
-engine owns the device-side page pools and threads them through the
-compiled step; the scheduler and PagedKVCache own all host-side state.
+engine owns the device-side cache and threads it through the compiled
+step as one donated pytree; the scheduler and PagedKVCache own all
+host-side state.
+
+The model is whatever the configuration names (``cfg.serving``), through
+a protocol of a few members: ``init_cache(cfg, slots, num_pages,
+page_size, kv_dtype)`` builds the cache pytree (page pools, and for a
+model with recurrent layers also fixed-size state per engine slot),
+``forward_paged(cfg, params, tokens, cache, block_tables, seq_lens,
+q_lens) -> (logits [R, Tc, V] f32, cache)`` is the step,
+``cache_bytes(cfg, kv_dtype_bytes)`` and ``param_count(cfg)`` size it,
+``prepare_params(cfg, params)`` converts weights once at build, and
+``recurrent_state`` says whether the cache holds state that depends on
+every token fed, in order (docs/serving.md, "Recurrent state").
 
 Compilation discipline: the batch is always [max_running, Tc] with
 Tc in {1, chunk}, so a serving process compiles at most two step
@@ -65,7 +77,7 @@ from ..runtime.watchdog import (PhaseTimeout, Watchdog, global_watchdog,
 from ..testing.chaos import ChaosError, ReplicaKilled, chaos_point
 from .errors import (AdmissionRejected, DeadlineExceeded,
                      RequestQuarantined)
-from .kv_cache import PagedKVCache, _cdiv, kv_bytes_per_token
+from .kv_cache import PagedKVCache, _cdiv
 from .scheduler import (AdmissionGate, Request, RequestState, Scheduler,
                         StepPlan)
 from .spec_decode import DraftModel, SpecDecodeConfig, greedy_accept
@@ -104,6 +116,10 @@ def summary_lines() -> List[str]:
     lines.append(
         f"  kv pools: {s['pool_bytes'] / 2**20:.1f} MiB  "
         f"compiled buckets: {int(s['compiled_buckets'])}")
+    if s["state_bytes"]:
+        lines.append(
+            f"  recurrent state: {s['state_bytes'] / 2**20:.1f} MiB  "
+            f"{int(s['state_resets'])} slot resets")
     if s["prefix_hit_tokens"] or s["spec_proposed"]:
         lines.append(
             f"  reuse: {int(s['prefix_hit_tokens'])} prefix-hit tokens "
@@ -166,7 +182,8 @@ class _SafeCallback:
 
 
 class LLMEngine:
-    """Continuous-batching serving engine over ``models/llama.py``.
+    """Continuous-batching serving engine over the model ``cfg.serving``
+    names (``models/llama.py``, ``models/jamba.py``).
 
     Parameters mirror the capacity plan: ``page_size`` tokens per pool
     page (default 128, the lane width — the Pallas ragged-paged-attention
@@ -191,7 +208,9 @@ class LLMEngine:
     (``serving/prefix_cache.py``); ``spec=SpecDecodeConfig(...)``
     attaches a draft model for speculative decoding — every decode row
     widens to a 1+k verify chunk through the prefill bucket
-    (``serving/spec_decode.py``).
+    (``serving/spec_decode.py``).  Both raise ``ValueError`` for a model
+    with recurrent state: a page hit would skip tokens the state never
+    saw, and a rejected draft would have advanced it.
     """
 
     def __init__(self, cfg, params, *, max_running: int = 8,
@@ -205,16 +224,16 @@ class LLMEngine:
                  watchdog: Optional[Watchdog] = None,
                  prefix_cache: bool = False,
                  spec: Optional["SpecDecodeConfig"] = None):
-        from ..models import llama as _llama
-
         self.cfg = cfg
-        if cfg.quantized == "on":
-            # int8 weight path: PTQ the serving weights once at engine
-            # build; forward bodies dispatch through the int8 matmul
-            # kernels. Asked for by the config, never by the platform.
-            params = _llama.quantize_params(cfg, params)
-        self.params = params
-        self._forward_paged = _llama.forward_paged
+        self._model = model = cfg.serving
+        if model.recurrent_state and (prefix_cache or spec is not None):
+            raise ValueError(
+                f"{type(cfg).__name__} keeps recurrent state beside its "
+                "K/V pages, which only advances token by token: "
+                "prefix_cache=True would skip the tokens of a page hit and "
+                "spec= would feed draft tokens that cannot be taken back. "
+                "Neither is built for such a model")
+        self.params = model.prepare_params(cfg, params)
         self.max_running = int(max_running)
         self.chunk = int(chunk)
         self.page_size = int(page_size)
@@ -253,31 +272,32 @@ class LLMEngine:
         if isinstance(kv_dtype, str):
             kv_dtype = {"bf16": jnp.bfloat16,
                         "int8": jnp.int8}.get(kv_dtype, kv_dtype)
-        L, nkv, d = (cfg.num_hidden_layers, cfg.num_key_value_heads,
-                     cfg.head_dim)
         self._kv_dtype = kv_dtype
         # int8 pages select the quantized-KV path: a parallel per-page
-        # scale pool (f32 [L, nkv, P], indexed by the same block
-        # tables) rides every step — quantize-on-write in
+        # scale pool rides every step — quantize-on-write in
         # forward_paged, dequant-on-read inside ragged_paged_attention
         self._quant_kv = jnp.dtype(kv_dtype) == jnp.dtype(jnp.int8)
-        self._pool_shape = (L, nkv, self.num_pages, self.page_size, d)
-        self._scale_shape = (L, nkv, self.num_pages)
-        # (k_pages, v_pages) or, quantized, (k_pages, v_pages, k_scales,
-        # v_scales): threaded through every compiled step as one pytree
+        # the cache the model describes, threaded through every compiled
+        # step as one pytree: Llama's is (k_pages, v_pages) or, quantized,
+        # (k_pages, v_pages, k_scales, v_scales)
         self._pools = self._fresh_pools()
-        scale_bytes = (2 * int(np.prod(self._scale_shape)) * 4
-                       if self._quant_kv else 0)
-        pool_bytes = (2 * int(np.prod(self._pool_shape))
-                      * jnp.dtype(kv_dtype).itemsize) + scale_bytes
+        layout = model.cache_bytes(cfg, jnp.dtype(kv_dtype).itemsize)
+        scale_bytes = layout["scales_per_page"] * self.num_pages
+        pool_bytes = (layout["per_token"] * self.page_size * self.num_pages
+                      + scale_bytes)
         _xmem.record_reservation(
             "serving.kv_pages", pool_bytes, pages=self.num_pages,
             page_size=self.page_size, kv_dtype=str(jnp.dtype(kv_dtype)),
             scale_pool_bytes=scale_bytes,
-            bytes_per_token=kv_bytes_per_token(
-                cfg, jnp.dtype(kv_dtype).itemsize))
+            bytes_per_token=layout["per_token"])
         self._pool_bytes = pool_bytes
         self._scale_bytes = scale_bytes
+        # recurrent state: a fixed size per slot, whatever is in it
+        self._state_bytes = layout["per_slot"] * self.max_running
+        if self._state_bytes:
+            _xmem.record_reservation(
+                "serving.state", self._state_bytes, slots=self.max_running,
+                bytes_per_slot=layout["per_slot"])
 
         on_tpu = jax.default_backend() == "tpu"
         if on_tpu and self.page_size % 128:
@@ -321,6 +341,7 @@ class LLMEngine:
 
         _STATS["engines"] += 1
         _STATS["pool_bytes"] += pool_bytes
+        _STATS["state_bytes"] += self._state_bytes
 
         _exporter.maybe_serve("engine", self)
 
@@ -408,25 +429,28 @@ class LLMEngine:
 
     # -- the compiled step ----------------------------------------------
     def _fresh_pools(self):
-        """Zeroed page pools; with int8 pages also the per-page scale
-        pools at 1.0, so untouched (all-zero) pages dequant to exact
-        zeros, matching the dense pools' init state."""
-        pools = (jnp.zeros(self._pool_shape, self._kv_dtype),
-                 jnp.zeros(self._pool_shape, self._kv_dtype))
-        if self._quant_kv:
-            pools += (jnp.ones(self._scale_shape, jnp.float32),
-                      jnp.ones(self._scale_shape, jnp.float32))
-        return pools
+        """The model's cache as at engine build: zeroed page pools (and
+        what rides with them), zero state in every slot."""
+        return self._model.init_cache(self.cfg, self.max_running,
+                                      self.num_pages, self.page_size,
+                                      self._kv_dtype)
+
+    def _forward_paged(self, cfg, params, tokens, k_pages, v_pages, tbl,
+                       lens, qlens, **scales):
+        """The model's step in Llama's pool signature, for callers that
+        hold the pools of a cache apart: ``benchmark/reference.py`` and
+        ``chip_smoke.py`` replay a request's logits through it."""
+        cache = (k_pages, v_pages) + tuple(
+            scales[k] for k in ("k_scales", "v_scales") if k in scales)
+        return self._model.forward_paged(cfg, params, tokens, cache, tbl,
+                                         lens, qlens)
 
     def _lower(self, Tc: int):
         """The step function of bucket ``Tc``, traced and lowered."""
-        cfg, fwd = self.cfg, self._forward_paged
+        cfg, fwd = self.cfg, self._model.forward_paged
 
         def step(params, tokens, pools, tbl, lens, qlens):
-            kp, vp, *scales = pools
-            logits, pools = fwd(cfg, params, tokens, kp, vp, tbl, lens,
-                                qlens, **dict(zip(
-                                    ("k_scales", "v_scales"), scales)))
+            logits, pools = fwd(cfg, params, tokens, pools, tbl, lens, qlens)
             with jax.named_scope("sample"):
                 last = jnp.clip(qlens - 1, 0, tokens.shape[1] - 1)
                 rows = jnp.take_along_axis(
@@ -490,7 +514,9 @@ class LLMEngine:
         """Execute COW page forks on device, target pools and (when
         speculative decoding is on) draft pools — the same page pair,
         so a donated page always carries both models' kv.  One compile:
-        src/dst are traced scalars, not baked constants."""
+        src/dst are traced scalars, not baked constants.  Only the
+        prefix cache forks pages, and a model with recurrent state is
+        refused one, so every leaf of the cache here is a page pool."""
         if self._copy_fn is None:
             def cp(pools, s, d):
                 # pages and (quantized) their dequant scales: the page
@@ -619,12 +645,20 @@ class LLMEngine:
             # readers of benchmark/ take their fill and kernel-cost counts
             # from here)
             decode_rows = int((qlens == 1).sum())
-            whole.set_metadata(
+            counts = dict(
                 bucket=Tc, rows=len(plan.seqs),
                 prefill_rows=len(plan.seqs) - decode_rows,
                 decode_rows=decode_rows, fed_tokens=int(qlens.sum()),
                 slot_tokens=R * Tc, kv_tokens=int(lens.sum()),
                 qk_pairs=int(np.dot(qlens.astype(np.int64), lens)))
+            if self._model.recurrent_state:
+                # rows whose state the step advances, and those among
+                # them that it first zeroes (a chunk that starts at 0)
+                resets = int(((qlens > 0) & (lens == qlens)).sum())
+                counts.update(state_rows=int((qlens > 0).sum()),
+                              state_resets=resets)
+                _STATS["state_resets"] += resets
+            whole.set_metadata(**counts)
             # build (or fetch) the bucket's executable before the guarded
             # call: a step that cannot be compiled is a broken program,
             # not a run-time fault, and must not be "recovered" into
@@ -1025,9 +1059,12 @@ class LLMEngine:
         return self.kv.prefix.peek([int(t) for t in prompt])
 
     def shutdown(self) -> None:
-        """Drop the pools and their xmem reservation."""
+        """Drop the cache and its xmem reservations."""
         _STATS["pool_bytes"] -= self._pool_bytes
+        _STATS["state_bytes"] -= self._state_bytes
         _xmem.record_reservation("serving.kv_pages", 0)
+        if self._state_bytes:
+            _xmem.record_reservation("serving.state", 0)
         self._pools = None
         self._step_fns.clear()
         self._copy_fn = None

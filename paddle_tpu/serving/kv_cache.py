@@ -329,22 +329,9 @@ class PagedKVCache:
 # ---------------------------------------------------------------------------
 
 def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:
-    """Paged-KV bytes one token costs across all layers (k and v)."""
-    return (2 * cfg.num_hidden_layers * cfg.num_key_value_heads
-            * cfg.head_dim * dtype_bytes)
-
-
-def _param_count(cfg) -> int:
-    """Dense llama parameter count from the config (embed + L blocks +
-    final norm + lm_head), the number that dominates serving HBM."""
-    H, I = cfg.hidden_size, cfg.intermediate_size
-    nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                  cfg.head_dim)
-    per_layer = (H * nh * d + 2 * H * nkv * d + nh * d * H  # attn
-                 + 3 * H * I                                 # gated mlp
-                 + 2 * H)                                    # norms
-    return (cfg.vocab_size * H * 2                           # embed+head
-            + cfg.num_hidden_layers * per_layer + H)
+    """Paged-KV bytes one token costs (k and v), over the layers that
+    hold K/V: the model's own layout (``cfg.serving.cache_bytes``)."""
+    return cfg.serving.cache_bytes(cfg, dtype_bytes)["per_token"]
 
 
 #: --kv-dtype axis of the capacity plan: page itemsize in bytes
@@ -360,7 +347,11 @@ def plan_capacity(cfg, *, hbm_bytes: int, page_size: int = 128,
     """HBM budget for one chip: how many pool pages fit after weights,
     and how many concurrent max-length requests that sustains.  Pure
     arithmetic — safe on a CPU-only host, used by pod_report's
-    ``serving`` section and by the engine's default pool sizing.
+    ``serving`` section and by the engine's default pool sizing.  The
+    layout is the model's (``cfg.serving.cache_bytes`` and
+    ``param_count``): K/V bytes a token over the layers that hold K/V,
+    and for a model with recurrent state the bytes a slot costs before
+    its first token.
 
     ``kv_dtype`` ("bf16"/"int8"/...) overrides ``kv_dtype_bytes`` and,
     for sub-2-byte pages, adds the quantized-KV path's per-page scale
@@ -372,20 +363,21 @@ def plan_capacity(cfg, *, hbm_bytes: int, page_size: int = 128,
             raise ValueError(f"unknown kv_dtype {kv_dtype!r}; "
                              f"choose from {sorted(KV_DTYPE_BYTES)}")
         kv_dtype_bytes = KV_DTYPE_BYTES[kv_dtype]
-    weights = _param_count(cfg) * weights_dtype_bytes
+    model = cfg.serving
+    layout = model.cache_bytes(cfg, kv_dtype_bytes)
+    weights = model.param_count(cfg) * weights_dtype_bytes
     usable = int(hbm_bytes * (1.0 - headroom_fraction)) - weights \
         - int(runtime_bytes)
-    page_bytes = kv_bytes_per_token(cfg, kv_dtype_bytes) * page_size
-    scale_bytes_per_page = 0
-    if kv_dtype_bytes < 2:
-        # k + v scale-pool entries across layers, f32 each
-        scale_bytes_per_page = 2 * cfg.num_hidden_layers \
-            * cfg.num_key_value_heads * 4
-        page_bytes += scale_bytes_per_page
-    num_pages = max(usable // page_bytes, 0)
+    scale_bytes_per_page = layout["scales_per_page"]
+    page_bytes = layout["per_token"] * page_size + scale_bytes_per_page
     blocks_per_req = _cdiv(max_len, page_size)
-    max_concurrent = (num_pages - 1) // blocks_per_req \
-        if num_pages > 1 else 0
+    # a max-length request holds its blocks and one slot's state; one page
+    # more is the allocator's null page
+    max_concurrent = max(
+        (usable - page_bytes)
+        // (blocks_per_req * page_bytes + layout["per_slot"]), 0)
+    num_pages = max(
+        (usable - max_concurrent * layout["per_slot"]) // page_bytes, 0)
     return {
         "hbm_bytes": int(hbm_bytes),
         "weights_bytes": int(weights),
@@ -395,7 +387,8 @@ def plan_capacity(cfg, *, hbm_bytes: int, page_size: int = 128,
         "kv_dtype": kv_dtype or f"{kv_dtype_bytes}B",
         "scale_bytes_per_page": int(scale_bytes_per_page),
         "num_pages": int(num_pages),
-        "kv_bytes_per_token": kv_bytes_per_token(cfg, kv_dtype_bytes),
+        "kv_bytes_per_token": layout["per_token"],
+        "state_bytes_per_slot": layout["per_slot"],
         "max_model_len": max_len,
         "blocks_per_request": int(blocks_per_req),
         "max_concurrent_requests": int(max_concurrent),

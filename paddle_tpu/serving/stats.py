@@ -21,6 +21,9 @@ def stats_zero() -> Dict[str, float]:
         "requests_preempted": 0, "steps": 0, "prefill_tokens": 0,
         "decode_tokens": 0, "peak_running": 0, "pool_bytes": 0,
         "compiled_buckets": 0,
+        # recurrent state beside the pages: bytes held by live engines, and
+        # rows whose state a step zeroed (a request's first chunk in a slot)
+        "state_bytes": 0, "state_resets": 0,
         # work reuse (prefix cache + speculative decoding)
         "prefix_hit_tokens": 0, "prefix_evicted_pages": 0,
         "spec_proposed": 0, "spec_accepted": 0,

@@ -12,6 +12,7 @@ path; and the router fails a killed replica's in-flight streams over
 to the survivor with bit-identical, idempotent continuations.
 """
 import signal
+import types
 
 import jax
 import jax.numpy as jnp
@@ -298,7 +299,8 @@ def test_step_that_cannot_be_lowered_raises_with_nothing_quarantined(model):
     def refused(*a, **k):
         raise RuntimeError("Mosaic failed to compile TPU kernel")
 
-    eng._forward_paged = refused
+    eng._model = types.SimpleNamespace(**dict(vars(eng._model),
+                                              forward_paged=refused))
     rid = eng.add_request([5, 6, 7], 4)
     before = serving.serving_stats()
     with pytest.raises(RuntimeError, match="Mosaic"):
