@@ -21,7 +21,12 @@ kernel-grid-divisibility      error      grid x block_shape does not tile an
 kernel-index-oob              error      an index_map emits a block index
                                          outside the operand (the classic
                                          off-by-one ``i + 1``) — Mosaic
-                                         reads/writes out of bounds
+                                         reads/writes out of bounds; or a
+                                         scalar-prefetch table the kernel
+                                         indexes an HBM operand with by its
+                                         own DMA (declared in the call's
+                                         ``metadata["dma_indexes"]``) holds
+                                         an entry outside that axis
 kernel-output-coverage        error      some output block is never emitted
                                          by any grid point — silent garbage
                                          in the uncovered region
@@ -56,6 +61,7 @@ import contextlib
 import functools
 import inspect
 import itertools
+import json
 import math
 import sys
 import textwrap
@@ -131,6 +137,10 @@ class KernelSite:
         self.num_scalar_prefetch = int(num_scalar_prefetch)
         self.operands: list = []          # avals, filled at the inner call
         self.scalar_operands: list = []   # leading scalar-prefetch args
+        # (scalar operand, input operand, axis): the kernel reads that
+        # input in HBM by its own DMA at indexes it takes from that scalar
+        # operand — no index map carries them
+        self.dma_indexes: List[Tuple[int, int, int]] = []
 
     @property
     def kernel_name(self) -> str:
@@ -199,7 +209,7 @@ def _normalize_call(kernel, args, kwargs, blockspec_cls, file, line
         return None  # dynamic grid — out of scope
     is_spec = lambda s: isinstance(s, blockspec_cls)
     is_shape = lambda s: hasattr(s, "shape") and hasattr(s, "dtype")
-    return KernelSite(
+    site = KernelSite(
         kernel=kernel,
         grid=grid,
         in_specs=[s if is_spec(s) else None
@@ -209,6 +219,11 @@ def _normalize_call(kernel, args, kwargs, blockspec_cls, file, line
         out_shapes=_tree_leaves(out_shape, is_leaf=is_shape),
         scratch_shapes=_tree_leaves(_as_tuple(scratch), is_leaf=is_shape),
         file=file, line=line, num_scalar_prefetch=nsp)
+    declared = (kwargs.get("metadata") or {}).get("dma_indexes")
+    if declared:
+        site.dma_indexes = [tuple(int(i) for i in triple)
+                            for triple in json.loads(declared)]
+    return site
 
 
 @contextlib.contextmanager
@@ -345,6 +360,13 @@ def _is_blocked(spec) -> bool:
     return type(mode).__name__ in ("Blocked", "blocked")
 
 
+def _in_hbm(spec) -> bool:
+    """The operand stays where it is (``memory_space=pl.ANY`` / HBM): no
+    block of it is brought to VMEM, the kernel reads it by DMA."""
+    space = getattr(spec, "memory_space", None)
+    return getattr(space, "name", str(space)).upper() in ("ANY", "HBM")
+
+
 class _Operand:
     """One (array, spec) pair the grid iterates over."""
 
@@ -414,6 +436,7 @@ class _SiteChecker:
         self._check_divisibility(blocked)
         self._check_mosaic(blocked)
         self._check_index_maps(blocked)
+        self._check_dma_indexes()
         self._check_vmem(ops)
         self._check_kernel_body()
         return self.findings
@@ -556,6 +579,34 @@ class _SiteChecker:
                         operand=op.label, missing=len(missing),
                         required=len(required))
 
+    def _check_dma_indexes(self):
+        """The indexes a kernel hands its own DMA: every entry of a
+        declared scalar-prefetch table must be a legal index of the axis
+        it addresses (all of them: an unused block-table slot holds the
+        null page, which is in range).  Traced tables are not provable."""
+        if not self.site.dma_indexes or not self._want("kernel-index-oob"):
+            return
+        import numpy as np
+        scalars = self._concrete_scalars()
+        if scalars is None:
+            return
+        for sc, operand, axis in self.site.dma_indexes:
+            if sc >= len(scalars) or operand >= len(self.site.operands):
+                continue
+            extent = int(self.site.operands[operand].shape[axis])
+            table = scalars[sc]
+            bad = np.argwhere((table < 0) | (table >= extent))
+            if len(bad):
+                at = tuple(int(i) for i in bad[0])
+                self._emit(
+                    "kernel-index-oob",
+                    f"{self.site.kernel_name}: scalar operand {sc}{list(at)}"
+                    f" = {int(table[at])} indexes axis {axis} of in["
+                    f"{operand}] (extent {extent}) in the kernel's own DMA "
+                    f"— out-of-bounds HBM read ({len(bad)} such entries)",
+                    operand=f"in[{operand}]", scalar_operand=sc,
+                    entry=list(at), value=int(table[at]), extent=extent)
+
     # --- rule: kernel-vmem-budget -----------------------------------------
     def _vmem_budget(self) -> Tuple[int, str]:
         override = self.cfg.get("vmem_budget_bytes")
@@ -576,6 +627,8 @@ class _SiteChecker:
     def _check_vmem(self, ops: List[_Operand]):
         block_bytes = 0
         for op in ops:
+            if _in_hbm(op.spec):
+                continue  # the kernel copies out of it; its scratch counts
             dims = op.blocks if op.blocks is not None else op.shape
             block_bytes += math.prod(dims) * _dtype_itemsize(op.dtype)
         scratch_bytes = 0

@@ -43,6 +43,7 @@ interpreter on CPU for numerical validation without TPU hardware.
 from __future__ import annotations
 
 import functools
+import json
 import logging
 import math
 
@@ -59,7 +60,6 @@ __all__ = ["causal_attention", "flash_attention_available",
            "fused_attn_candidates", "fused_mlp_candidates",
            "tune_fused_blocks", "fused_parity_cases",
            "ragged_paged_attention", "ragged_attention_available",
-           "rpa_block_specs", "rpa_candidates", "tune_ragged_attention",
            "paged_kv_write", "kv_write_available",
            "int8_matmul", "int8_matmul_available",
            "int8_matmul_block_specs", "int8_matmul_candidates",
@@ -314,15 +314,23 @@ def _compiler_params(*dimension_semantics):
         dimension_semantics=tuple(dimension_semantics))
 
 
-def _pallas_call(kernel, **kwargs):
+def _pallas_call(kernel, own_dma=False, **kwargs):
     """The one door to ``pl.pallas_call``: the call is made under
     ``jax.named_scope("pallas/<kernel function name>")``, so every Mosaic
     custom call carries its kernel's name in its ``op_name`` on the device
     trace — the name the lowered text's ``kernel_name`` already has.
-    ``interpret`` follows ``_INTERPRET``."""
+    ``interpret`` follows ``_INTERPRET``.  A kernel that issues its own
+    copies and waits on their semaphores (``own_dma``) is interpreted by
+    the TPU interpreter, which models both: a copy lands when it is waited
+    for and scratch starts as NaN, where the plain interpreter copies at
+    once, waits for nothing and starts from zeros."""
     from jax.experimental import pallas as pl
     scope = f"pallas/{getattr(kernel, 'func', kernel).__name__}"
-    call = pl.pallas_call(kernel, interpret=_INTERPRET, **kwargs)
+    interpret = _INTERPRET
+    if interpret and own_dma:
+        from jax.experimental.pallas import tpu as pltpu
+        interpret = pltpu.InterpretParams()
+    call = pl.pallas_call(kernel, interpret=interpret, **kwargs)
 
     def scoped(*operands):
         with jax.named_scope(scope):
@@ -1745,10 +1753,9 @@ def fused_parity_cases():
 #                                  q_lens[r] real tokens (rep q-head
 #                                  slots each), the rest is padding
 #   k/v pools    [L, nkv, P, page, d] every layer's pool in one stacked
-#                                  buffer, head-major so a (layer, head,
-#                                  page) triple is one contiguous VMEM
-#                                  block; the kernel picks the layer by
-#                                  index and never sees a slice of it
+#                                  buffer, left in HBM: the kernel copies
+#                                  the pages it needs out of it itself and
+#                                  never sees a slice of it
 #   layer        [1] i32           which layer of the stack to attend over
 #   block_tables [R, Bmax] i32     logical kv-block j of request r lives
 #                                  in pool page block_tables[r, j];
@@ -1759,13 +1766,20 @@ def fused_parity_cases():
 #                                  inactive slot, 1 = decode, >1 =
 #                                  chunked prefill)
 #
-# Grid (R, nkv, Tr//bq_rows, Bmax); the four scalar operands ride in
-# via ``pltpu.PrefetchScalarGridSpec`` so the k/v index maps can read
-# ``layer[0]`` and ``tbl[r, j]`` before the block is fetched.  Inner axis j streams kv
-# pages with the online-softmax flash recurrence; pages past the
-# request's causal horizon or its kv length are skipped entirely
-# (``@pl.when``), which is what makes the ragged batch cheap.  Padding
-# rows (tok >= q_lens[r]) are fully masked and flushed as exact zeros.
+# Grid (R,): one grid step is one request row, all its kv heads.  The four
+# scalar operands ride in via ``pltpu.PrefetchScalarGridSpec`` (SMEM); q
+# and the output are (1, nkv, Tr, d) blocks; the pools have no block.  A
+# row walks only its LIVE pages, ``ceil(seq_len / page)`` of the table's
+# Bmax entries (none when q_len == 0), ``G`` pages a turn of an in-kernel
+# loop: page ``tbl[r, j]`` of all nkv heads is one strided copy out of
+# ``pool[layer, :, page]`` into one of two VMEM slots, and group i + 1 is
+# in flight while group i is computed.  The row's last turn starts the
+# first group of the next row, so only the call's first fetch is exposed.
+# Per group and head: QK^T over the G x page keys at once, the
+# online-softmax flash recurrence in float32, PV.  Table entries past a
+# row's length and the tables of idle rows cost nothing: no grid step, no
+# copy, no compute.  Padding rows (tok >= q_lens[r]) are flushed as exact
+# zeros.  ``G`` comes from the shapes (``_rpa_group_pages``).
 
 _NEG_BIG = -1e30  # finite mask value: -inf would NaN fully-masked rows
 
@@ -1779,154 +1793,197 @@ def _rep_cols(col, n):
     return jnp.broadcast_to(col, (col.shape[0], n))
 
 
-def _rpa_kernel(tbl_ref, lens_ref, qlens_ref, layer_ref, q_ref, k_ref,
-                v_ref, o_ref, m_s, l_s, acc_s, *, page, rep, bq_rows, scale):
-    """Grid point (r, h, qt, j): q rows [qt*bq_rows, +bq_rows) of
-    request r, q-head group h, against kv page j of r's block table in
-    layer ``layer_ref[0]`` of the stacked pools (the index maps read the
-    layer; the body sees one (page, d) block)."""
+def _rpa_group_pages(nkv, Tr, d, page, itemsize, Bmax):
+    """``G``, the pages of one DMA group: the largest power of two, at
+    most the table's width, whose working set fits ``_VMEM_BUDGET``
+    beside what a row needs whatever G is.  A page of the group costs K
+    and V of all heads in both slots, and eight 4-byte ``[Tr, page]``
+    tiles of the walk (token, column and horizon, which live through the
+    loop; a head's mask, scores, exponent, probabilities and their
+    scaled copy); a row its q and o blocks (the pipeline keeps two of
+    each) and the float32 statistics and accumulator of every head.
+    Fewer, fatter turns win while the copies bound the row (8 kv heads,
+    Tr 32: 7.1 ms a step of the chat cell's shapes at G 8, 7.3 at 4, 10
+    at 2); where the scores bound it, a wide group's dead key columns
+    cost more than its turns save (1 kv head, Tr 320: 1.45 ms at G 8,
+    1.81 at 16), which the tiles' share of the budget stands for
+    (PERF.md section 6, PR 28)."""
+    fixed = 4 * nkv * Tr * d * itemsize + nkv * Tr * (2 * _LANES + d) * 4
+    per_page = 4 * nkv * page * d * itemsize + 8 * Tr * page * 4
+    fit = max(1, min((_VMEM_BUDGET - fixed) // per_page, Bmax))
+    return 1 << (int(fit).bit_length() - 1)
+
+
+def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
+              q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, m_s,
+              l_s, acc_s, *, page, rep, scale):
+    """Grid step r: request r's q rows, all kv heads, against its live kv
+    pages in layer ``layer_ref[0]`` of the stacked pools, ``G`` pages a
+    loop turn (``kbuf``/``vbuf`` are ``[2, nkv, G * page, d]``: two slots
+    for the double buffer).  With ``ksc_ref``/``vsc_ref`` (int8 pools)
+    a page's dequant scale multiplies its score columns, and its
+    probability columns before PV: the same products as a dequantized
+    page gives, taken after the copy landed and off the [page, d] tile."""
     from jax.experimental import pallas as pl
-    del layer_ref
+    from jax.experimental.pallas import tpu as pltpu
     r = pl.program_id(0)
-    qt = pl.program_id(2)
-    j = pl.program_id(3)
-    n_j = pl.num_programs(3)
+    R = pl.num_programs(0)
+    Bmax = tbl_ref.shape[1]
+    nkv, Tr, d = q_ref.shape[1:]
+    span = kbuf.shape[2]
+    G = span // page
+    layer = layer_ref[0]
+
+    def live_pages(row):
+        return jnp.where(
+            qlens_ref[row] > 0,
+            jnp.minimum(pl.cdiv(lens_ref[row], page), Bmax), 0)
+
+    def copies(page_of, g, slot):
+        """K's and V's copy of one page into place g of ``slot``."""
+        for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            yield pltpu.make_async_copy(
+                hbm.at[layer, :, page_of],
+                buf.at[slot, :, pl.ds(pl.multiple_of(g * page, page), page)],
+                sem.at[slot, which])
+
+    def in_group(i, live):
+        return jnp.clip(live - i * G, 0, G)      # live pages of group i
+
+    def start(row, i, slot, live):
+        def one(g, carry):
+            for copy in copies(tbl_ref[row, i * G + g], g, slot):
+                copy.start()
+            return carry
+        lax.fori_loop(0, in_group(i, live), one, 0)
+
+    def wait(i, slot, live):
+        def one(g, carry):
+            for copy in copies(0, g, slot):      # a wait counts the bytes
+                copy.wait()
+            return carry
+        lax.fori_loop(0, in_group(i, live), one, 0)
+
+    live = live_pages(r)
+    n = pl.cdiv(live, G)
+    nxt = jnp.minimum(r + 1, R - 1)
+    live_nxt = jnp.where(r + 1 < R, live_pages(nxt), 0)
+
+    @pl.when(r == 0)
+    def _first_row():
+        # a masked key's probability is an exact 0, and 0 * NaN is not:
+        # what no copy has overwritten yet must be finite
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+        start(r, 0, 0, live)
+
+    slot0 = slot_ref[0]      # where this row's first group lands
+    m_s[...] = jnp.full_like(m_s, _NEG_BIG)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
     kvlen = lens_ref[r]
     qlen = qlens_ref[r]
 
-    @pl.when(j == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, _NEG_BIG)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    @pl.when(n == 0)
+    def _idle_row():
+        start(nxt, 0, slot0, live_nxt)
 
-    # causal horizon of the last row in this q tile: pages strictly past
-    # it contribute nothing to any row and are skipped wholesale
-    last_tok = ((qt + 1) * bq_rows - 1) // rep
-    horizon = kvlen - qlen + last_tok
+    tok = lax.broadcasted_iota(jnp.int32, (Tr, span), 0) // rep
+    col = lax.broadcasted_iota(jnp.int32, (Tr, span), 1)
+    # a q row sees the keys up to its own position; a padding row none
+    horizon = jnp.where(tok < qlen, kvlen - qlen + tok, -1)
 
-    @pl.when((j * page < kvlen) & (j * page <= horizon))
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32)          # [bq_rows, d]
-        k = k_ref[0, 0, 0].astype(jnp.float32)       # [page, d]
-        v = v_ref[0, 0, 0].astype(jnp.float32)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        row = qt * bq_rows + lax.broadcasted_iota(
-            jnp.int32, (bq_rows, page), 0)
-        tok = row // rep                             # q token index
-        qpos = kvlen - qlen + tok                    # absolute position
-        kpos = j * page + lax.broadcasted_iota(
-            jnp.int32, (bq_rows, page), 1)
-        mask = (kpos <= qpos) & (kpos < kvlen) & (tok < qlen)
-        s = jnp.where(mask, s, _NEG_BIG)
-        m = m_s[...]
-        l = l_s[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
-        # explicit zeroing: on a fully-masked row exp(s - m) == 1, not 0
-        p = jnp.where(mask,
-                      jnp.exp(s - _rep_cols(m_new[:, :1], page)), 0.0)
-        corr = jnp.exp(m - m_new)
-        l_s[...] = l * corr + jnp.sum(p, axis=-1)[:, None]
-        m_s[...] = m_new
-        d = acc_s.shape[-1]
-        acc_s[...] = (acc_s[...] * _rep_cols(corr[:, :1], d)
-                      + lax.dot(p, v, preferred_element_type=jnp.float32))
+    def group(i, carry):
+        slot = (slot0 + i) % 2
+        # the next group of this row, or the first of the next row
+        more = i + 1 < n
+        start(jnp.where(more, r, nxt), jnp.where(more, i + 1, 0), 1 - slot,
+              jnp.where(more, live, live_nxt))
+        wait(i, slot, live)
+        mask = i * span + col <= horizon
 
-    @pl.when(j == n_j - 1)
-    def _flush():
-        d = acc_s.shape[-1]
-        l = l_s[...]
-        denom = jnp.where(l == 0.0, 1.0, l)  # padding rows -> exact 0
-        o_ref[0, 0] = (acc_s[...] / _rep_cols(denom[:, :1], d)).astype(
-            o_ref.dtype)
+        def page_scales(sc_ref, h):
+            """[1, span]: each key column's page's dequant scale."""
+            out = jnp.zeros((1, span), jnp.float32)
+            for g in range(G):
+                pg = tbl_ref[r, jnp.minimum(i * G + g, Bmax - 1)]
+                out = jnp.where(col[:1] // page == g, sc_ref[h, pg], out)
+            return out
+
+        def head(h):
+            q = q_ref[0, h]                              # [Tr, d]
+            k = kbuf[slot, h]                            # [span, d]
+            v = vbuf[slot, h].astype(jnp.float32)
+            if ksc_ref is not None:
+                k = k.astype(q.dtype)    # int8: exact in bf16 and float32
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if ksc_ref is not None:
+                s = s * page_scales(ksc_ref, h)
+            s = jnp.where(mask, s, _NEG_BIG)
+            m = m_s[h]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
+            # a masked score is _NEG_BIG under a real row's running max
+            # and its exp an exact 0; padding rows, whose every score is
+            # masked, gather ones here and are zeroed at the flush
+            p = jnp.exp(s - _rep_cols(m_new[:, :1], span))
+            corr = jnp.exp(m - m_new)
+            l_s[h] = l_s[h] * corr + jnp.sum(p, axis=-1)[:, None]
+            m_s[h] = m_new
+            if vsc_ref is not None:
+                p = p * page_scales(vsc_ref, h)
+            acc_s[h] = (acc_s[h] * _rep_cols(corr[:, :1], d)
+                        + lax.dot(p, v, preferred_element_type=jnp.float32))
+
+        # unrolled: the scheduler overlaps one head's softmax with the
+        # next one's matmuls (7.3 against 8.6 ms a step as a rolled loop,
+        # the chat cell's shapes; PERF.md section 6, PR 28)
+        for h in range(nkv):
+            head(h)
+        return carry
+
+    lax.fori_loop(0, n, group, 0)
+    slot_ref[0] = (slot0 + n) % 2
+    real = lax.broadcasted_iota(jnp.int32, (Tr, d), 0) // rep < qlen
+
+    def flush(h, carry):
+        l = l_s[h]
+        denom = jnp.where(l == 0.0, 1.0, l)      # an idle row: 0 / 1
+        o_ref[0, h] = jnp.where(
+            real, acc_s[h] / _rep_cols(denom[:, :1], d), 0.0).astype(
+                o_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, nkv, flush, 0)
+
+
+def _rpa_kernel(tbl_ref, lens_ref, qlens_ref, layer_ref, q_ref, k_hbm,
+                v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, m_s, l_s, acc_s,
+                **tiles):
+    """The ragged-paged-attention kernel (``_rpa_walk``) on dense pools."""
+    _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, None, None, q_ref,
+              k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, m_s, l_s,
+              acc_s, **tiles)
 
 
 def _rpa_kernel_quant(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref,
-                      vsc_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
-                      *, page, rep, bq_rows, scale):
-    """Quantized-KV variant of ``_rpa_kernel``: the k/v pools hold int8
-    pages and two extra scalar-prefetch operands carry this layer's
-    per-page dequant scales ([nkv, P] f32, same block-table indirection
-    — the 'second prefetched operand' of the quantized paged KV design).
-    Dequant happens at page load inside the skip-predicated update, so
-    skipped pages pay nothing.  Online-softmax body kept in lockstep
-    with ``_rpa_kernel`` — any change there lands here too."""
-    from jax.experimental import pallas as pl
-    del layer_ref
-    r = pl.program_id(0)
-    h = pl.program_id(1)
-    qt = pl.program_id(2)
-    j = pl.program_id(3)
-    n_j = pl.num_programs(3)
-    kvlen = lens_ref[r]
-    qlen = qlens_ref[r]
-    pg = tbl_ref[r, j]
-
-    @pl.when(j == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, _NEG_BIG)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    last_tok = ((qt + 1) * bq_rows - 1) // rep
-    horizon = kvlen - qlen + last_tok
-
-    @pl.when((j * page < kvlen) & (j * page <= horizon))
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32)          # [bq_rows, d]
-        k = k_ref[0, 0, 0].astype(jnp.float32) * ksc_ref[h, pg]
-        v = v_ref[0, 0, 0].astype(jnp.float32) * vsc_ref[h, pg]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        row = qt * bq_rows + lax.broadcasted_iota(
-            jnp.int32, (bq_rows, page), 0)
-        tok = row // rep
-        qpos = kvlen - qlen + tok
-        kpos = j * page + lax.broadcasted_iota(
-            jnp.int32, (bq_rows, page), 1)
-        mask = (kpos <= qpos) & (kpos < kvlen) & (tok < qlen)
-        s = jnp.where(mask, s, _NEG_BIG)
-        m = m_s[...]
-        l = l_s[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
-        p = jnp.where(mask,
-                      jnp.exp(s - _rep_cols(m_new[:, :1], page)), 0.0)
-        corr = jnp.exp(m - m_new)
-        l_s[...] = l * corr + jnp.sum(p, axis=-1)[:, None]
-        m_s[...] = m_new
-        d = acc_s.shape[-1]
-        acc_s[...] = (acc_s[...] * _rep_cols(corr[:, :1], d)
-                      + lax.dot(p, v, preferred_element_type=jnp.float32))
-
-    @pl.when(j == n_j - 1)
-    def _flush():
-        d = acc_s.shape[-1]
-        l = l_s[...]
-        denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_s[...] / _rep_cols(denom[:, :1], d)).astype(
-            o_ref.dtype)
-
-
-def rpa_block_specs(R, nkv, Tr, d, num_pages, page, Bmax, bq_rows=None,
-                    layers=1):
-    """(block, array) shape pairs for the ragged-paged-attention call —
-    the single source of truth shared by the call site, the candidate
-    generator, and the Level-3 verifier.  The k/v array is the stacked
-    pool ``[layers, nkv, num_pages, page, d]`` and its block one
-    (layer, head, page) triple: the kernel indexes the layer, so no
-    caller slices a layer's pool out of the stack."""
-    if bq_rows is None:
-        bq_rows = Tr
-    qblk = ((1, 1, bq_rows, d), (R, nkv, Tr, d))
-    kvblk = ((1, 1, 1, page, d), (layers, nkv, num_pages, page, d))
-    return {"in": [qblk, kvblk, kvblk], "out": [qblk]}
+                      vsc_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
+                      slot_ref, m_s, l_s, acc_s, **tiles):
+    """The same walk over int8 pools: two more scalar-prefetch operands
+    carry this layer's per-page dequant scales ([nkv, P] f32, indexed
+    through the same block table), applied to the pages a row walks and
+    to no other.  One body (``_rpa_walk``); the second name is what a
+    profile and the lowered text tell the int8 step by."""
+    _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
+              q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, m_s,
+              l_s, acc_s, **tiles)
 
 
 def _layer_operand(layer):
     """The layer as the kernels' ``[1]`` int32 scalar-prefetch operand.  A
     Python int stays concrete (numpy), so the Level-3 verifier can prove
-    the index maps that read it; the engine's is its scan's counter."""
+    what reads it; the engine's is its scan's counter."""
     import numpy as np
     if isinstance(layer, int):
         return np.full((1,), layer, np.int32)
@@ -1955,11 +2012,10 @@ def _ragged_attention_jnp(q, k_pages, v_pages, block_tables, seq_lens,
                           layer=0):
     """Reference implementation and CPU fallback: gather every
     request's pages of layer ``layer`` straight out of the stacked pools
-    into a dense [R, Bmax*page] kv span, mask, softmax.  Bit-for-bit
-    semantics of the kernel (same ``_NEG_BIG`` masking, f32 accumulation,
-    exact-zero padding rows).  With per-page scales (quantized int8
-    pools), pages dequant at the gather — the same scale-then-dot order
-    as ``_rpa_kernel_quant``.  Takes 4-D pools like ``_rpa_call``."""
+    into a dense [R, Bmax*page] kv span, mask, softmax.  The kernel's
+    semantics (same ``_NEG_BIG`` masking, f32 accumulation, exact-zero
+    padding rows).  With per-page scales (quantized int8 pools), pages
+    dequant at the gather.  Takes 4-D pools like ``_rpa_call``."""
     k_pages, v_pages, k_scales, v_scales = _rpa_operands(
         k_pages, v_pages, k_scales, v_scales, layer)
     R, nkv, Tr, d = q.shape
@@ -1995,126 +2051,94 @@ def _ragged_attention_jnp(q, k_pages, v_pages, block_tables, seq_lens,
 
 
 def _rpa_call(q, k_pages, v_pages, block_tables, seq_lens, q_lens, *,
-              rep, bq_rows, k_scales=None, v_scales=None, layer=0):
+              rep, k_scales=None, v_scales=None, layer=0):
     """Raw pallas_call for the ragged-paged-attention kernel over the
-    stacked pools ``[L, nkv, P, page, d]``: ``layer`` rides in as a
-    fourth scalar-prefetch operand (``[1]`` int32) and the k/v index map
-    is ``(layer[0], h, tbl[r, j], 0, 0)``, so the stack is the kernel's
-    operand as it stands and nothing slices or copies a layer's pool.
-    One layer's 4-D pool goes the same way as the stack of that one
-    layer (decided from the rank).  With ``k_scales``/``v_scales``
-    ([L, nkv, P] f32 per-page dequant scales; [nkv, P] beside a 4-D
-    pool) the quantized-KV kernel variant runs instead: this layer's
-    [nkv, P] scales ride in as two more scalar-prefetch operands (SMEM,
-    no VMEM block), indexed by the same block table."""
+    stacked pools ``[L, nkv, P, page, d]``, which stay in HBM
+    (``memory_space=pl.ANY``, no block): the stack is the kernel's
+    operand as it stands, ``layer`` rides in as a fourth scalar-prefetch
+    operand (``[1]`` int32) and the kernel's own copies read
+    ``pool[layer[0], :, tbl[r, j]]``, so nothing slices or copies a
+    layer's pool.  One layer's 4-D pool goes the same way as the stack
+    of that one layer (decided from the rank).  With
+    ``k_scales``/``v_scales`` ([L, nkv, P] f32 per-page dequant scales;
+    [nkv, P] beside a 4-D pool) the same walk runs under its int8 name:
+    this layer's [nkv, P] scales ride in as two more scalar-prefetch
+    operands (SMEM), indexed by the same block table."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     k_pages, v_pages, k_scales, v_scales = _rpa_operands(
         k_pages, v_pages, k_scales, v_scales, layer)
     R, nkv, Tr, d = q.shape
-    layers, _, num_pages, page, _ = k_pages.shape
-    Bmax = block_tables.shape[1]
-    n_qt = Tr // bq_rows
-    scale = 1.0 / math.sqrt(float(d))
-    specs = rpa_block_specs(R, nkv, Tr, d, num_pages, page, Bmax,
-                            bq_rows, layers)
+    page = k_pages.shape[3]
+    G = _rpa_group_pages(nkv, Tr, d, page, k_pages.dtype.itemsize,
+                         block_tables.shape[1])
     quantized = k_scales is not None
     scalars = (block_tables, seq_lens, q_lens, _layer_operand(layer))
     if quantized:
         scalars += (k_scales, v_scales)
 
-    def q_map(r, h, qt, j, *scalars):
-        del j, scalars
-        return (r, h, qt, 0)
+    def row_map(r, *scalars):
+        del scalars
+        return (r, 0, 0, 0)
 
-    def kv_map(r, h, qt, j, tbl, lens, qlens, layer, *scales):
-        del qt, lens, qlens, scales
-        return (layer[0], h, tbl[r, j], 0, 0)
-
+    row = pl.BlockSpec((1, nkv, Tr, d), row_map)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    slots = (2, nkv, G * page, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(R, nkv, n_qt, Bmax),
-        in_specs=[
-            pl.BlockSpec(specs["in"][0][0], q_map),
-            pl.BlockSpec(specs["in"][1][0], kv_map),
-            pl.BlockSpec(specs["in"][2][0], kv_map),
-        ],
-        out_specs=pl.BlockSpec(specs["out"][0][0], q_map),
+        grid=(R,),
+        in_specs=[row, in_hbm, in_hbm],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((bq_rows, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((bq_rows, _LANES), jnp.float32),   # running sum
-            pltpu.VMEM((bq_rows, d), jnp.float32),        # accumulator
+            pltpu.VMEM(slots, k_pages.dtype),           # K page groups
+            pltpu.VMEM(slots, v_pages.dtype),           # V page groups
+            pltpu.SemaphoreType.DMA((2, 2)),            # [slot, K or V]
+            pltpu.SMEM((1,), jnp.int32),                # next row's slot
+            pltpu.VMEM((nkv, Tr, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((nkv, Tr, _LANES), jnp.float32),  # running sum
+            pltpu.VMEM((nkv, Tr, d), jnp.float32),      # accumulator
         ],
     )
     kern = functools.partial(
         _rpa_kernel_quant if quantized else _rpa_kernel,
-        page=page, rep=rep, bq_rows=bq_rows, scale=scale)
+        page=page, rep=rep, scale=1.0 / math.sqrt(float(d)))
     call = _pallas_call(
-        kern,
+        kern, own_dma=True,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, nkv, Tr, d), q.dtype),
-        compiler_params=_compiler_params(
-            "parallel", "parallel", "parallel", "arbitrary"),
+        # a row starts the next row's first copy: rows run in order
+        compiler_params=_compiler_params("arbitrary"),
+        # no index map carries the page ids any more: the block table
+        # (scalar 0) indexes axis 2 of both pools (inputs 1, 2) and the
+        # layer (scalar 3) their axis 0 — what the Level-3 verifier bounds
+        metadata={"dma_indexes": json.dumps(
+            [[0, 1, 2], [0, 2, 2], [3, 1, 0], [3, 2, 0]])},
     )
     return call(*scalars, q, k_pages, v_pages)
 
 
-def ragged_attention_available(q_shape, kv_shape, dtype=None,
-                               bq_rows=None):
+def ragged_attention_available(q_shape, kv_shape, dtype=None):
     """True when the Pallas path can serve this problem.  The kernel
     needs lane-aligned pages (page % 128 == 0) — smaller pages are
     served by the jnp reference — plus a TPU backend or interpret
     mode."""
-    del dtype
-    R, nkv, Tr, d = q_shape
-    page = kv_shape[-2]
-    if page % _LANES != 0:
+    del q_shape, dtype
+    if kv_shape[-2] % _LANES != 0:
         return False
-    if bq_rows is not None:
-        if Tr % bq_rows != 0:
-            return False
-        if bq_rows % 8 != 0 and bq_rows != Tr:
-            return False
     return _kernels_enabled("ragged_paged_attention")
 
 
-def _rpa_keys(Tr, d, page, dtype=None):
-    """Lookup-key chain for the tuned bq_rows: context-qualified first,
-    shape-only fallback."""
-    from paddle_tpu.ops import autotune
-    keys = []
-    if dtype is not None:
-        keys.append(["bq_rows", int(Tr), int(d), int(page)]
-                    + autotune.context_key(str(jnp.dtype(dtype))))
-    keys.append(["bq_rows", int(Tr), int(d), int(page)])
-    return keys
-
-
-def _rpa_config(q_shape, kv_shape, dtype=None):
-    """Resolve bq_rows: tuned value if cached and still legal for this
-    shape, else the whole q-slot (one tile per request)."""
-    from paddle_tpu.ops import autotune
-    R, nkv, Tr, d = q_shape
-    page = kv_shape[-2]
-    cfg = autotune.lookup_chain("ragged_paged_attention",
-                                _rpa_keys(Tr, d, page, dtype))
-    if cfg is not None:
-        b = int(cfg[0] if isinstance(cfg, (list, tuple)) else cfg)
-        if Tr % b == 0 and (b % 8 == 0 or b == Tr):
-            return b
-    return Tr
-
-
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
-                           q_lens, *, rep=1, bq_rows=None,
-                           k_scales=None, v_scales=None, layer=0):
+                           q_lens, *, rep=1, k_scales=None, v_scales=None,
+                           layer=0):
     """Mixed prefill+decode attention over a paged KV cache.
 
     q            [R, nkv, Tc*rep, d] per-request q slots (GQA: the rep
                  q heads of kv head h sit at rows tok*rep..tok*rep+rep-1)
     k/v pages    [L, nkv, P, page, d] the stacked pools of every layer,
                  as the engine holds them: passed whole and never
-                 sliced — the kernel's index map picks ``layer``
+                 sliced — the kernel copies a row's live pages of
+                 ``layer`` out of them
     layer        which layer of the stack to attend over (an int or a
                  traced int32 scalar, e.g. the counter of a layer scan)
     block_tables [R, Bmax] i32, seq_lens/q_lens [R] i32 (see module
@@ -2127,138 +2151,17 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     One layer's pool ``[nkv, P, page, d]`` (with ``[nkv, P]`` scales)
     takes the same path as the stack of that one layer, layer 0.
 
-    Decode is the Tc == 1 specialization of the same kernel.  The jnp
-    reference serves off-TPU and lane-unaligned pages (a choice made
-    from platform and shape); a kernel that fails to lower or compile
-    raises."""
-    if not ragged_attention_available(q.shape, k_pages.shape, q.dtype,
-                                      bq_rows):
+    Decode is the Tc == 1 specialization of the same kernel; its tile
+    parameters follow from the shapes.  The jnp reference serves off-TPU
+    and lane-unaligned pages (a choice made from platform and shape); a
+    kernel that fails to lower or compile raises."""
+    if not ragged_attention_available(q.shape, k_pages.shape, q.dtype):
         return _ragged_attention_jnp(q, k_pages, v_pages, block_tables,
                                      seq_lens, q_lens, rep,
                                      k_scales, v_scales, layer)
-    b = bq_rows if bq_rows is not None else _rpa_config(
-        q.shape, k_pages.shape, q.dtype)
     return _rpa_call(q, k_pages, v_pages, block_tables, seq_lens,
-                     q_lens, rep=rep, bq_rows=b,
-                     k_scales=k_scales, v_scales=v_scales, layer=layer)
-
-
-def rpa_candidates(R, nkv, Tr, d, num_pages, page, Bmax,
-                   dtype=jnp.float32):
-    """Legal (bq_rows,) candidates: divisors of Tr that Mosaic can tile
-    (via ``autotune.legal_candidates`` over the real block specs), so
-    illegal shapes are unrepresentable rather than filtered late."""
-    from paddle_tpu.ops import autotune
-    pool = sorted({Tr} | {b for b in (8, 16, 32, 64, 128, 256, 512)
-                          if Tr % b == 0 and b <= Tr})
-    pool = [(b,) for b in pool]
-
-    def spec_fn(cand):
-        (b,) = cand
-        if Tr % b != 0:
-            return None
-        specs = rpa_block_specs(R, nkv, Tr, d, num_pages, page, Bmax, b)
-        return list(specs["in"]) + list(specs["out"])
-
-    bits = 8 * jnp.dtype(dtype).itemsize
-    return autotune.legal_candidates(pool, spec_fn, dtype_bits=bits)
-
-
-def _verify_rpa_candidate(R, nkv, Tr, d, num_pages, page, Bmax, rep,
-                          dtype):
-    """autotune verify hook: refute a (bq_rows,) candidate with the
-    Level-3 verifier before any compile.  Closes over a concrete
-    in-range block table so the scalar-prefetch index maps are
-    provable."""
-    import numpy as np
-    tbl = (np.arange(R * Bmax, dtype=np.int32) % num_pages).reshape(
-        R, Bmax)
-    lens = np.full((R,), min(Bmax * page, page), dtype=np.int32)
-    qlens = np.ones((R,), dtype=np.int32)
-
-    def verify(cand):
-        from paddle_tpu.analysis import kernel_checks as _kc
-        (b,) = cand
-        avals = (
-            jax.ShapeDtypeStruct((R, nkv, Tr, d), dtype),
-            jax.ShapeDtypeStruct((nkv, num_pages, page, d), dtype),
-            jax.ShapeDtypeStruct((nkv, num_pages, page, d), dtype),
-        )
-
-        def fwd(q, kp, vp):
-            return _rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
-                             bq_rows=b)
-
-        found = _kc.verify_kernel(
-            fwd, *avals, name=f"ragged_paged_attention[{b}]")
-        return [f"{f.rule}: {f.message}" for f in found
-                if f.severity == "error"]
-    return verify
-
-
-def tune_ragged_attention(R=8, nkv=2, Tc=8, rep=2, d=128, num_pages=64,
-                          page=128, Bmax=8, dtype=jnp.bfloat16,
-                          budget_s=None, verbose=False):
-    """Autotune bq_rows for a serving bucket signature.  Cached result
-    short-circuits; off-TPU (and not interpret) returns None without
-    touching the tuner."""
-    import numpy as np
-    import time
-
-    from paddle_tpu.ops import autotune
-    Tr = Tc * rep
-    cached = autotune.lookup_chain("ragged_paged_attention",
-                                   _rpa_keys(Tr, d, page, dtype))
-    if cached is not None:
-        return tuple(cached) if isinstance(cached, (list, tuple)) \
-            else (int(cached),)
-    if not (_on_tpu() or _INTERPRET):
-        return None
-
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.standard_normal((R, nkv, Tr, d)), dtype)
-    kp = jnp.asarray(rng.standard_normal((nkv, num_pages, page, d)),
-                     dtype)
-    vp = jnp.asarray(rng.standard_normal((nkv, num_pages, page, d)),
-                     dtype)
-    # page 0 reserved (null page); shuffled assignment like a real
-    # allocator would produce after churn
-    if num_pages - 1 >= R * Bmax:
-        pages = 1 + rng.permutation(num_pages - 1)[:R * Bmax]
-    else:
-        pages = 1 + np.arange(R * Bmax) % (num_pages - 1)
-    tbl = jnp.asarray(pages.reshape(R, Bmax), jnp.int32)
-    lens = jnp.full((R,), Bmax * page, jnp.int32)
-    qlens = jnp.full((R,), Tc, jnp.int32)
-    n_chain = 8
-
-    def time_candidate(cand):
-        (b,) = cand
-
-        @jax.jit
-        def chained(qc):
-            def body(qq, _):
-                o = _rpa_call(qq, kp, vp, tbl, lens, qlens, rep=rep,
-                              bq_rows=b)
-                return qq + o * jnp.asarray(1e-6, qq.dtype), None
-            qf, _ = lax.scan(body, qc, None, length=n_chain)
-            return jnp.sum(qf[0, 0])
-
-        chained(q).block_until_ready()       # compile
-        best = float("inf")
-        for _ in range(5):
-            t0 = time.perf_counter()
-            chained(q).block_until_ready()
-            best = min(best, (time.perf_counter() - t0) / n_chain)
-        return best
-
-    key = _rpa_keys(Tr, d, page, dtype)[0]
-    return autotune.tune(
-        "ragged_paged_attention", key,
-        rpa_candidates(R, nkv, Tr, d, num_pages, page, Bmax, dtype),
-        time_candidate, budget_s=budget_s, verbose=verbose,
-        verify_candidate=_verify_rpa_candidate(
-            R, nkv, Tr, d, num_pages, page, Bmax, rep, dtype))
+                     q_lens, rep=rep, k_scales=k_scales, v_scales=v_scales,
+                     layer=layer)
 
 
 # ---------------------------------------------------------------------------
@@ -2799,14 +2702,15 @@ def kernel_verify_cases():
 
     # ragged paged attention: mixed prefill+decode and the decode-only
     # (Tc == 1) specialization.  The cases close over CONCRETE numpy
-    # block tables / lengths, which is what lets the verifier evaluate
-    # the scalar-prefetch index maps (tbl[r, j]) instead of skipping
-    # them — an out-of-range table entry here would fire index-oob.
+    # block tables / lengths, which is what lets the verifier bound the
+    # page ids the kernel's own copies read (the call declares them,
+    # ``dma_indexes``) instead of skipping them — an out-of-range table
+    # entry here would fire index-oob.
     import numpy as np
     Rr, nkv, rep, page = 4, 2, 2, _LANES
     P, Bmax, Ls, layer = 16, 4, 3, 2
     # the engine's form: the stacked pools of Ls layers and a layer other
-    # than 0, so the index maps are proved with the layer in them
+    # than 0, so the layer is bounded with the table
     kv_aval = SDS((Ls, nkv, P, page, D), f32)
     tbl = (1 + np.arange(Rr * Bmax, dtype=np.int32)
            % (P - 1)).reshape(Rr, Bmax)
@@ -2818,7 +2722,7 @@ def kernel_verify_cases():
 
         def fwd(q, kp, vp):
             return _rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
-                             bq_rows=Tr, layer=layer)
+                             layer=layer)
         return fwd, (SDS((Rr, nkv, Tr, D), f32), kv_aval, kv_aval)
 
     mixed_fn, mixed_avals = rpa_case(8)
@@ -2830,17 +2734,17 @@ def kernel_verify_cases():
     # tokens in one step as a short ragged prefill (Tc = 1 + k; k = 3
     # matches SpecDecodeConfig's default).  Same kernel, distinct
     # compiled shape — registering it keeps the Level-3 sweep proving
-    # the block-table index maps at the shape serving actually runs.
+    # the block table at the shape serving actually runs.
     spec_fn, spec_avals = rpa_case(4)
     cases.append(("ragged_paged_attention_spec_verify", spec_fn,
                   spec_avals))
 
     # quantized-KV ragged paged attention: int8 pools, with the
     # per-page scale pools riding as CONCRETE scalar-prefetch operands
-    # — concrete so the verifier proves the (layer[0], h, tbl[r, j])
-    # index maps at the extended 6-scalar signature, and so the VMEM
-    # estimate's scalar-operand accounting sees the real per-layer
-    # scale shapes ([nkv, P], sliced out of the stack's [Ls, nkv, P]).
+    # — concrete so the verifier bounds table and layer at the extended
+    # 6-scalar signature, and so the VMEM estimate's scalar-operand
+    # accounting sees the real per-layer scale shapes ([nkv, P], sliced
+    # out of the stack's [Ls, nkv, P]).
     ksc = np.ones((Ls, nkv, P), dtype=np.float32)
     vsc = np.ones((Ls, nkv, P), dtype=np.float32)
     Tc_q = 8
@@ -2849,8 +2753,7 @@ def kernel_verify_cases():
 
     def rpa_quant_fwd(q, kp, vp):
         return _rpa_call(q, kp, vp, tbl, lens, qlens_q, rep=rep,
-                         bq_rows=Tc_q * rep, k_scales=ksc, v_scales=vsc,
-                         layer=layer)
+                         k_scales=ksc, v_scales=vsc, layer=layer)
 
     cases.append(("ragged_paged_attention_quant_kv", rpa_quant_fwd,
                   (SDS((Rr, nkv, Tc_q * rep, D), f32), kv_i8, kv_i8)))

@@ -645,12 +645,19 @@ class LLMEngine:
             # readers of benchmark/ take their fill and kernel-cost counts
             # from here)
             decode_rows = int((qlens == 1).sum())
+            # the pages the attention kernel walks (a live row's, to its
+            # length) of those the block table has room for
+            kv_pages = int(_cdiv(lens, self.page_size)[qlens > 0].sum())
+            table_pages = R * self.max_blocks
             counts = dict(
                 bucket=Tc, rows=len(plan.seqs),
                 prefill_rows=len(plan.seqs) - decode_rows,
                 decode_rows=decode_rows, fed_tokens=int(qlens.sum()),
                 slot_tokens=R * Tc, kv_tokens=int(lens.sum()),
-                qk_pairs=int(np.dot(qlens.astype(np.int64), lens)))
+                qk_pairs=int(np.dot(qlens.astype(np.int64), lens)),
+                kv_pages=kv_pages, table_pages=table_pages)
+            _STATS["kv_pages"] += kv_pages
+            _STATS["table_pages"] += table_pages
             if self._model.recurrent_state:
                 # rows whose state the step advances, and those among
                 # them that it first zeroes (a chunk that starts at 0)

@@ -21,6 +21,9 @@ def stats_zero() -> Dict[str, float]:
         "requests_preempted": 0, "steps": 0, "prefill_tokens": 0,
         "decode_tokens": 0, "peak_running": 0, "pool_bytes": 0,
         "compiled_buckets": 0,
+        # block-table entries the attention kernel walked (the live pages of
+        # rows with tokens to feed) of those the steps' tables held
+        "kv_pages": 0, "table_pages": 0,
         # recurrent state beside the pages: bytes held by live engines, and
         # rows whose state a step zeroed (a request's first chunk in a slot)
         "state_bytes": 0, "state_resets": 0,

@@ -966,6 +966,57 @@ def test_kernel_scalar_prefetch_output_gap_fires():
     assert _k_rules(found, "kernel-index-oob") == []
 
 
+def _dma_gather_kernel(tbl_ref, x_hbm, o_ref, buf, sem):
+    i = pl.program_id(0)
+    copy = pltpu.make_async_copy(x_hbm.at[tbl_ref[i]], buf, sem)
+    copy.start()
+    copy.wait()
+    o_ref[...] = buf[...]
+
+
+def _dma_gather(table):
+    """A kernel that reads an HBM operand by its own DMA at rows it takes
+    from a scalar-prefetch table, and says so in its metadata: no index
+    map carries the table, so only the declaration lets it be bounded."""
+    def run(x):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(3,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((8, 128), lambda i, tbl: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())])
+        return pl.pallas_call(  # LINT-MARK-K-DMA-OOB
+            _dma_gather_kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((24, 128), jnp.float32),
+            metadata={"dma_indexes": "[[0, 0, 0]]"})(table, x)
+    return run
+
+
+@pytest.mark.parametrize("table,oob", [(_SP_TBL_OK, False),
+                                       (_SP_TBL_OOB, True),
+                                       (np.asarray([0, -1, 2], np.int32),
+                                        True)],
+                         ids=["in-range", "past-the-end", "negative"])
+def test_kernel_dma_index_table_is_bounded_by_its_declaration(table, oob):
+    # a 4-row pool in HBM; 1 GiB of it must not count as VMEM either
+    pool = jax.ShapeDtypeStruct((4, 8, 128), jnp.float32)
+    run = _dma_gather(table)
+    found = kernel_checks.verify_kernel(run, pool)
+    hits = _k_rules(found, "kernel-index-oob")
+    assert bool(hits) == oob, [f.to_dict() for f in found]
+    if oob:
+        assert hits[0].severity == "error"
+        assert hits[0].extra["extent"] == 4
+        assert hits[0].line == _marker_line(run, "LINT-MARK-K-DMA-OOB")
+    assert [f for f in found if f not in hits] == []
+
+
+def test_kernel_hbm_operand_is_not_counted_as_vmem():
+    big = jax.ShapeDtypeStruct((1 << 16, 8, 128), jnp.float32)   # 256 MiB
+    found = kernel_checks.verify_kernel(_dma_gather(_SP_TBL_OK), big)
+    assert _k_rules(found, "kernel-vmem-budget") == []
+
+
 def test_shipped_pallas_kernels_verify_clean():
     """ISSUE acceptance: every kernel in ops/pallas_ops.py verifies
     clean on CPU — flash fwd/bwd (streamed + resident, f32 + bf16), the

@@ -1,8 +1,9 @@
 """The stacked K/V page pools stay one buffer through the serve step.
 
 ``forward_paged`` carries the pools ``[L, nkv, P, page, d]`` whole through
-its scan over the layers; the ragged-paged-attention kernel indexes the
-layer inside its index map; ``paged_kv_write`` writes only the new tokens.
+its scan over the layers; the ragged-paged-attention kernel copies a row's
+pages of the layer it is given out of the stack; ``paged_kv_write`` writes
+only the new tokens.
 These tests hold the mechanism itself: the scan's signature, the kernels
 against their references on stacks whose layers differ, the 4-D form as
 the stack of one layer, and the outputs of ``forward_paged`` against the
@@ -157,8 +158,7 @@ def test_the_kernel_reads_the_layer_it_is_given(rep, Tc, quant, interpret):
     want = pallas_ops._ragged_attention_jnp(
         q, kp[layer], vp[layer], tbl, lens, qlens, rep,
         *kw_l.values())
-    got = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
-                               bq_rows=Tc * rep, layer=layer, **kw)
+    got = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep, layer=layer, **kw)
     assert _maxerr(got, want) < 2e-5
     # the same through the public entry with the layer traced, as the
     # layer scan hands it over
@@ -173,7 +173,7 @@ def test_the_kernel_reads_the_layer_it_is_given(rep, Tc, quant, interpret):
     # and a wrong layer cannot pass: every other layer answers otherwise
     for other in (0, 1):
         wrong = pallas_ops._rpa_call(
-            q, kp, vp, tbl, lens, qlens, rep=rep, bq_rows=Tc * rep,
+            q, kp, vp, tbl, lens, qlens, rep=rep,
             layer=other, **kw)
         assert _maxerr(wrong, want) > 1e-2
 
@@ -182,18 +182,15 @@ def test_the_kernel_reads_the_layer_it_is_given(rep, Tc, quant, interpret):
 def test_a_4d_pool_is_the_stack_of_one_layer(quant, interpret):
     q, kp, vp, tbl, lens, qlens, scales = _stack_case(2, 16, quant, seed=1)
     kw = dict(zip(("k_scales", "v_scales"), scales))
-    stack0 = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=2,
-                                  bq_rows=32, layer=0, **kw)
+    stack0 = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=2, layer=0, **kw)
     flat = pallas_ops._rpa_call(
-        q, kp[0], vp[0], tbl, lens, qlens, rep=2, bq_rows=32,
+        q, kp[0], vp[0], tbl, lens, qlens, rep=2,
         **{k: v[0] for k, v in kw.items()})
     assert _maxerr(flat, stack0) == 0.0
     ref = pallas_ops._ragged_attention_jnp(
         q, kp[0], vp[0], tbl, lens, qlens, 2,
         *(v[0] for v in kw.values()))
     assert _maxerr(flat, ref) < 2e-5
-    assert pallas_ops.rpa_block_specs(4, 2, 32, 128, 12, 128, 2, 32, 3)[
-        "in"][1] == ((1, 1, 1, 128, 128), (3, 2, 12, 128, 128))
 
 
 # ---------------------------------------------------------------------------
@@ -289,14 +286,20 @@ def test_both_kernels_lower_for_the_tpu_on_the_stacked_pools():
                 kp, vp = pallas_ops._kv_write_call(
                     kp, vp, kn, vn, tbl, lens, qlens, layer)
                 return pallas_ops._rpa_call(
-                    q, kp, vp, tbl, lens, qlens, rep=rep,
-                    bq_rows=Tc * rep, layer=layer), kp, vp
+                    q, kp, vp, tbl, lens, qlens, rep=rep, layer=layer), kp, vp
 
             new = sds((R, Tc, nkv, D), jnp.bfloat16)
             text = jax.export.export(jax.jit(step), platforms=["tpu"])(
                 sds((R, nkv, Tc * rep, D), jnp.bfloat16), new, new, pool,
                 pool, sds((), jnp.int32)).mlir_module()
             assert "_rpa_kernel" in text and "_kv_write_kernel" in text
+            # both custom calls take the 5-D stacks as they stand
+            calls = [ln for ln in text.splitlines()
+                     if "tpu_custom_call" in ln]
+            assert len(calls) == 2
+            for ln in calls:
+                assert ln.count(f"tensor<{L}x{nkv}x{P}x{page}x{D}xbf16>") \
+                    >= 2, ln[:300]
     finally:
         pallas_ops._INTERPRET = old
 

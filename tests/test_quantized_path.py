@@ -249,8 +249,7 @@ def test_rpa_quantized_pools_match_jnp_reference():
                       .reshape(R, Bmax), jnp.int32)
     lens = jnp.asarray([40, 17, 64, 0], jnp.int32)
     qlens = jnp.asarray([8, 1, 3, 0], jnp.int32)
-    out = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
-                               bq_rows=Tr, k_scales=ksc, v_scales=vsc)
+    out = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep, k_scales=ksc, v_scales=vsc)
     ref = pallas_ops._ragged_attention_jnp(q, kp, vp, tbl, lens, qlens,
                                            rep, ksc, vsc)
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-5

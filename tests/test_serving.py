@@ -106,8 +106,7 @@ def _rpa_case(R, nkv, rep, Tc, d, P, page, Bmax, seq_lens, q_lens,
     lens = jnp.asarray(seq_lens, jnp.int32)
     qlens = jnp.asarray(q_lens, jnp.int32)
     ref = pallas_ops._ragged_attention_jnp(q, kp, vp, tbl, lens, qlens, rep)
-    out = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
-                               bq_rows=Tr)
+    out = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep)
     return q, out, ref, qlens
 
 
@@ -147,22 +146,113 @@ def test_rpa_gqa_bf16_lane_aligned_page():
     assert _maxerr(out, ref) < 2e-2  # bf16 has ~8 mantissa bits
 
 
-def test_rpa_row_blocking_matches_unblocked():
-    rng = np.random.RandomState(3)
-    R, nkv, rep, Tc, d, P, page, Bmax = 2, 2, 2, 8, 32, 16, 16, 4
-    Tr = Tc * rep
-    q = jnp.asarray(rng.standard_normal((R, nkv, Tr, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((nkv, P, page, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((nkv, P, page, d)), jnp.float32)
-    tbl = jnp.asarray((1 + rng.permutation(P - 1)[:R * Bmax])
-                      .reshape(R, Bmax), jnp.int32)
-    lens = jnp.asarray([50, 30], jnp.int32)
-    qlens = jnp.asarray([8, 5], jnp.int32)
-    full = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
-                                bq_rows=Tr)
-    blocked = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
-                                   bq_rows=8)
-    assert _maxerr(full, blocked) < 2e-5
+def _forced_group(monkeypatch, G, nkv, Tr, d, page, itemsize, Bmax):
+    """Shrink the kernel's VMEM budget until the shapes give groups of G
+    pages: the group size has no argument, it follows from what fits."""
+    monkeypatch.setattr(pallas_ops, "_VMEM_BUDGET", 0)   # restored after
+    for kib in range(16, 64 << 10, 16):
+        pallas_ops._VMEM_BUDGET = kib << 10
+        if pallas_ops._rpa_group_pages(nkv, Tr, d, page, itemsize,
+                                       Bmax) == G:
+            return
+    raise AssertionError(f"no budget gives groups of {G} pages")
+
+
+# the rows of one batch, by what the page walk has to get right; G is the
+# group size the case forces, page 128, Bmax 4
+_WALK_ROWS = (
+    # name,                         seq_len,            q_len
+    ("idle: no live page",          lambda G, Tc: 300,  lambda Tc: 0),
+    ("exactly one page",            lambda G, Tc: 128,  lambda Tc: Tc),
+    ("a whole number of groups",    lambda G, Tc: min(2 * G, 4) * 128,
+                                                        lambda Tc: 1),
+    ("one token past a group",      lambda G, Tc: min(G * 128 + 1, 512),
+                                                        lambda Tc: 1),
+    ("the whole table",             lambda G, Tc: 512,  lambda Tc: Tc),
+    ("chunk across a page edge",    lambda G, Tc: 256 + Tc // 2,
+                                                        lambda Tc: Tc),
+    ("shares pages with the whole", lambda G, Tc: 200,
+                                    lambda Tc: max(Tc - 1, 1)),
+    ("first token",                 lambda G, Tc: 1,    lambda Tc: 1),
+)
+
+
+def _walk_case(rep, nkv, Tc, kind, G, layer=1, L=3, d=128, page=128,
+               Bmax=4, seed=0):
+    rng = np.random.RandomState(seed)
+    R, P = len(_WALK_ROWS), 40
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    q = jnp.asarray(rng.standard_normal((R, nkv, Tc * rep, d)), dtype)
+    kp = rng.standard_normal((L, nkv, P, page, d)).astype(np.float32)
+    vp = rng.standard_normal((L, nkv, P, page, d)).astype(np.float32)
+    scales = {}
+    if kind == "int8":
+        def quantize(p):
+            sc = np.maximum(np.abs(p).max(axis=(3, 4)), 1e-8) / 127.0
+            return (np.round(p / sc[..., None, None]).astype(np.int8),
+                    sc.astype(np.float32))
+        (kp, ksc), (vp, vsc) = quantize(kp), quantize(vp)
+        scales = dict(k_scales=jnp.asarray(ksc), v_scales=jnp.asarray(vsc))
+        kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+    else:
+        kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
+    lens = np.array([f(G, Tc) for _, f, _ in _WALK_ROWS], np.int32)
+    qlens = np.array([f(Tc) for _, _, f in _WALK_ROWS], np.int32)
+    # a shuffled table; slots past a row's length hold the null page, as
+    # the engine's do; row 6 names row 4's first two pages (a shared prefix)
+    tbl = (1 + rng.permutation(P - 1)[:R * Bmax]).reshape(R, Bmax)
+    tbl[np.arange(Bmax)[None, :] * page >= lens[:, None]] = 0
+    tbl[6, :2] = tbl[4, :2]
+    return (q, kp, vp, jnp.asarray(tbl, jnp.int32), jnp.asarray(lens),
+            jnp.asarray(qlens), scales)
+
+
+@pytest.mark.parametrize("G", [1, 2, 4])
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("Tc", [1, 16])
+@pytest.mark.parametrize("heads", [(1, 2), (2, 2), (20, 1)],
+                         ids=["mha", "gqa2", "mqa20"])
+def test_rpa_walks_a_rows_live_pages_in_groups(heads, Tc, kind, G,
+                                               monkeypatch):
+    """The kernel against the reference on one batch that holds every
+    kind of row the walk meets, on layer 1 of a stack of 3, with the
+    pages fetched 1, 2 and 4 (the whole table) at a time."""
+    rep, nkv = heads
+    q, kp, vp, tbl, lens, qlens, scales = _walk_case(rep, nkv, Tc, kind, G)
+    _forced_group(monkeypatch, G, nkv, Tc * rep, 128, 128,
+                  kp.dtype.itemsize, tbl.shape[1])
+    out = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
+                               layer=1, **scales)
+    ref = pallas_ops._ragged_attention_jnp(
+        q, kp, vp, tbl, lens, qlens, rep, *scales.values(), layer=1)
+    assert not bool(jnp.any(jnp.isnan(out.astype(jnp.float32))))
+    assert _maxerr(out, ref) < (2e-2 if kind == "bf16" else 2e-5)
+    # padding rows and the idle request are exact zeros
+    pad = (np.arange(Tc * rep) // rep)[None, :] >= np.asarray(qlens)[:, None]
+    assert float(jnp.max(jnp.abs(jnp.where(
+        jnp.asarray(pad)[:, None, :, None], out, 0)))) == 0.0
+    assert float(jnp.max(jnp.abs(out[0].astype(jnp.float32)))) == 0.0
+
+
+def test_rpa_group_size_follows_the_shapes_and_the_budget():
+    group = pallas_ops._rpa_group_pages
+    # the two cells' buckets: 8 kv heads of bf16 pages, Tr 32 and 2,
+    # Bmax 20; one kv head, Tr 320 and 20, Bmax 24
+    assert group(8, 32, 128, 128, 2, 20) == group(8, 2, 128, 128, 2, 20) == 8
+    assert group(1, 320, 128, 128, 2, 24) == 8
+    assert group(1, 20, 128, 128, 2, 24) == 16
+    for nkv, Tr, itemsize, Bmax in ((8, 32, 2, 20), (16, 16, 2, 8),
+                                    (1, 320, 2, 24), (2, 8, 4, 4),
+                                    (32, 512, 4, 64), (8, 32, 1, 3)):
+        G = group(nkv, Tr, 128, 128, itemsize, Bmax)
+        assert 1 <= G <= Bmax and G & (G - 1) == 0
+        # both slots of K and V for every head, and a head's scores
+        used = 4 * nkv * G * 128 * 128 * itemsize + 8 * Tr * G * 128 * 4
+        assert used <= pallas_ops._VMEM_BUDGET or G == 1
+        # wider pages, more heads or a longer chunk never widen the group
+        assert group(2 * nkv, Tr, 128, 128, itemsize, Bmax) <= G
+        assert group(nkv, 2 * Tr, 128, 128, itemsize, Bmax) <= G
+        assert group(nkv, Tr, 128, 256, itemsize, Bmax) <= G
 
 
 def test_rpa_public_entry_falls_back_off_tpu():
@@ -200,26 +290,20 @@ def test_rpa_tpu_lowering_hardware_free():
     def mixed(q, kp, vp):
         return pallas_ops._rpa_call(
             q, kp, vp, tbl, lens, jnp.full((Rr,), 8, jnp.int32),
-            rep=rep, bq_rows=Tr)
+            rep=rep)
 
     def decode(q, kp, vp):
         return pallas_ops._rpa_call(
             q, kp, vp, tbl, lens, jnp.ones((Rr,), jnp.int32),
-            rep=rep, bq_rows=rep)
+            rep=rep)
 
-    jax.export.export(jax.jit(mixed), platforms=["tpu"])(
-        SDS((Rr, nkv, Tr, D), jnp.float32), kv_aval, kv_aval)
-    jax.export.export(jax.jit(decode), platforms=["tpu"])(
-        SDS((Rr, nkv, rep, D), jnp.float32), kv_aval, kv_aval)
-
-
-def test_rpa_candidates_are_legal_divisors():
-    cands = pallas_ops.rpa_candidates(R=4, nkv=2, Tr=16, d=128,
-                                      num_pages=16, page=128, Bmax=4,
-                                      dtype=jnp.bfloat16)
-    assert cands, "no legal candidates for the canonical geometry"
-    for (b,) in cands:
-        assert 16 % b == 0 and (b % 8 == 0 or b == 16)
+    for fn, rows in ((mixed, Tr), (decode, rep)):
+        text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+            SDS((Rr, nkv, rows, D), jnp.float32), kv_aval,
+            kv_aval).mlir_module()
+        assert "tpu_custom_call" in text and "_rpa_kernel" in text
+        # the pool is the kernel's operand whole, as the stack of one layer
+        assert f"tensor<1x{nkv}x{P}x{page}x{D}xf32>" in text
 
 
 # ---------------------------------------------------------------------------

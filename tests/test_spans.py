@@ -204,8 +204,36 @@ def test_the_engine_step_spans_arguments_are_what_the_scheduler_planned(
         assert got["qk_pairs"] == sum(a * b for a, b in zip(q, kv))
         assert got["decode_rows"] == sum(n == 1 for n in q)
         assert got["prefill_rows"] + got["decode_rows"] == got["rows"]
+        # the pages the attention kernel walks, of the table's entries
+        assert got["kv_pages"] == sum(-(-n // eng.page_size) for n in kv)
+        assert got["table_pages"] == eng.max_running * eng.max_blocks
+        assert 0 < got["kv_pages"] < got["table_pages"]
     assert [a["step"] for a in args] == \
         list(range(args[0]["step"], args[0]["step"] + len(args)))
+
+
+def test_serving_stats_sum_the_walked_pages_and_the_tables_entries():
+    serving.reset_stats()
+    eng = tiny_engine()
+    eng.add_request(list(range(1, 12)), 3)      # 11 tokens: 2 pages of 8
+    eng.add_request([5, 6, 7], 2)
+    walked = steps = 0
+    schedule = eng.scheduler.schedule
+
+    def recording():
+        nonlocal walked, steps
+        plan = schedule()
+        if plan.seqs:
+            steps += 1
+            walked += sum(-(-s.seq_len // eng.page_size) for s in plan.seqs)
+        return plan
+    eng.scheduler.schedule = recording
+    while eng.has_work():
+        eng.step()
+    eng.shutdown()
+    stats = serving.serving_stats()
+    assert stats["kv_pages"] == walked > steps
+    assert stats["table_pages"] == steps * eng.max_running * eng.max_blocks
 
 
 def test_serve_step_keeps_its_fields_and_the_ring_gets_every_phase(trace_on):
@@ -301,7 +329,10 @@ def test_every_pallas_call_of_the_module_goes_through_the_one_helper():
     src = open(pallas_ops.__file__).read()
     assert len(re.findall(r"\bpl\.pallas_call\(", src)) == 1
     assert len(re.findall(r"\b_pallas_call\(", src)) >= 14   # 13 sites + def
-    assert "interpret=_INTERPRET" in src
+    # interpret follows _INTERPRET; a kernel with its own copies takes
+    # the TPU interpreter through the same door
+    assert "interpret = _INTERPRET" in src
+    assert "pl.pallas_call(kernel, interpret=interpret, **kwargs)" in src
 
 
 KERNEL_CALLS = {

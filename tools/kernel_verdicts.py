@@ -109,16 +109,18 @@ def _cases():
         grads_of(functools.partial(po._mlp_block_jnp, eps=1e-6), 5),
         mlp_block_args, 3e-2))
 
-    # ragged paged attention at the engine's two buckets, MHA (rep=1), in
-    # the form the engine runs: the stacked pools of LAYERS layers and a
-    # layer other than 0, against the jnp body on that layer's own 4-D
-    # pool; pages shuffled as an allocator leaves them, ragged kv lengths
-    def rpa_args(Tc, quant):
+    # ragged paged attention at the engine's two buckets, in the form the
+    # engine runs: the stacked pools of LAYERS layers and a layer other
+    # than 0, against the jnp body on that layer's own 4-D pool; pages
+    # shuffled as an allocator leaves them, ragged kv lengths.  MHA
+    # (rep=1, the widths above) dense and int8; grouped heads as the
+    # benchmark's models have them: rep 2 on 8 kv heads, rep 20 on one
+    def rpa_args(Tc, quant, nkv=NH, rep=1):
         def make(key):
             ks = jax.random.split(key, 3)
             rng = np.random.RandomState(0)
-            q = jax.random.normal(ks[0], (R, NH, Tc, D), bf16) * 0.5
-            stack = (LAYERS, NH, NUM_PAGES, PAGE, D)
+            q = jax.random.normal(ks[0], (R, nkv, Tc * rep, D), bf16) * 0.5
+            stack = (LAYERS, nkv, NUM_PAGES, PAGE, D)
             kp = jax.random.normal(ks[1], stack) * 0.5
             vp = jax.random.normal(ks[2], stack) * 0.5
             tbl = (1 + rng.permutation(NUM_PAGES - 1)[:R * BMAX]).reshape(
@@ -145,15 +147,15 @@ def _cases():
             return out
         return make
 
-    def rpa(q, kp, vp, tbl, lens, qlens, ksc=None, vsc=None):
-        return po.ragged_paged_attention(q, kp, vp, tbl, lens, qlens, rep=1,
-                                         k_scales=ksc, v_scales=vsc,
+    def rpa(q, kp, vp, tbl, lens, qlens, ksc=None, vsc=None, rep=1):
+        return po.ragged_paged_attention(q, kp, vp, tbl, lens, qlens,
+                                         rep=rep, k_scales=ksc, v_scales=vsc,
                                          layer=LAYER)
 
-    def rpa_ref(q, kp, vp, tbl, lens, qlens, ksc=None, vsc=None):
+    def rpa_ref(q, kp, vp, tbl, lens, qlens, ksc=None, vsc=None, rep=1):
         one = [None if a is None else a[LAYER] for a in (kp, vp, ksc, vsc)]
         return po._ragged_attention_jnp(q, one[0], one[1], tbl, lens, qlens,
-                                        1, one[2], one[3])
+                                        rep, one[2], one[3])
 
     cases += [
         (f"rpa_mixed[Tc={CHUNK}]", rpa, rpa_ref, rpa_args(CHUNK, False), 3e-2),
@@ -162,6 +164,14 @@ def _cases():
          rpa_args(CHUNK, True), 3e-2),
         ("rpa_quant_decode[Tc=1]", rpa, rpa_ref, rpa_args(1, True), 3e-2),
     ]
+    for nkv, rep in ((8, 2), (1, 20)):
+        fns = [functools.partial(f, rep=rep) for f in (rpa, rpa_ref)]
+        cases += [
+            (f"rpa_mixed[Tc={CHUNK},nkv={nkv},rep={rep}]", *fns,
+             rpa_args(CHUNK, False, nkv, rep), 3e-2),
+            (f"rpa_decode[Tc=1,nkv={nkv},rep={rep}]", *fns,
+             rpa_args(1, False, nkv, rep), 3e-2),
+        ]
 
     # the write of a step's new tokens into the same stacked pools, in
     # place, against the XLA row scatter: exact, whole pools compared
