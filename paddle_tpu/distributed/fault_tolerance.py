@@ -28,8 +28,7 @@ Preemption
 
 Retries
     :func:`retry_with_backoff` — bounded attempts, exponential backoff,
-    seeded jitter, injectable sleep/clock (the ``bench.py``
-    ``_init_device_with_retries`` idiom) — shared by the TCPStore client
+    seeded jitter, injectable sleep/clock — shared by the TCPStore client
     and ``utils.download``.
 
 Telemetry lands in the profiler metrics registry (``ckpt_save_seconds``,

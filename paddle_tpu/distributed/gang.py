@@ -39,9 +39,8 @@ the static ``overlap.schedule_events`` model, or any rank is missing
 its sidecar / terminal barrier.
 
 ``python -m paddle_tpu.distributed.gang`` is the runnable preset: the
-bench multichip llama config driven through ``Plan.run_train_loop``
-under a real gang, printing one ``GANG_RESULT {json}`` line per rank
-(``bench.py --multichip --gang N`` parses these into the perf ledger).
+multichip llama config driven through ``Plan.run_train_loop``
+under a real gang, printing one ``GANG_RESULT {json}`` line per rank.
 """
 from __future__ import annotations
 
@@ -429,8 +428,8 @@ def _preset_result(ctx: GangContext, plan, history,
 def main(argv=None) -> int:
     """``python -m paddle_tpu.distributed.gang``: run the multichip
     llama preset through ``Plan.run_train_loop`` under a real gang and
-    print one ``GANG_RESULT {json}`` line (parsed by ``bench.py
-    --multichip --gang N`` and the gang E2E tests). The pipeline spans
+    print one ``GANG_RESULT {json}`` line (parsed by the gang E2E
+    tests). The pipeline spans
     the processes: with N ranks of one device each, ``pp=N`` 1F1B p2p
     crosses real process boundaries."""
     p = argparse.ArgumentParser(

@@ -9,7 +9,7 @@ Two faces:
 2. The functional core (`init_params` / `forward_pure` /
    `build_train_step`) — pure jnp functions over a stacked-parameter
    pytree, which is what the 4-D+ parallel trainer, the pipeline schedule,
-   `__graft_entry__.dryrun_multichip` and `bench.py` drive. This is the
+   `__graft_entry__.dryrun_multichip` and `benchmark/run.py` drive. This is the
    TPU-native replacement for fleet's PipelineLayer/LayerDesc partitioning
    (reference: fleet/meta_parallel/parallel_layers/pp_layers.py:209) —
    layers are stacked along a leading axis and sharded/scanned rather than
@@ -101,7 +101,7 @@ class LlamaConfig:
         return SERVING
 
 
-# Named shapes for tools (bench presets, tools/pod_report.py). The
+# Named shapes for tools (tools/pod_report.py, chip_smoke.py, tests). The
 # LlamaConfig defaults ARE the 7B shape, so llama7b overrides nothing.
 PRESETS: Dict[str, Dict[str, Any]] = {
     "llama7b": {},
@@ -1113,7 +1113,7 @@ def build_train_step(cfg: LlamaConfig, topo, optimizer=None, use_pp=None,
 
     def abstract_state():
         """ShapeDtypeStructs (with shardings) for (params, opt_state) —
-        lets tools (pod_report, bench) lower/compile the step and read
+        lets tools (pod_report) lower/compile the step and read
         its memory_analysis() without ever materializing the weights."""
         p_abs = jax.eval_shape(functools.partial(init_params, cfg),
                                jax.ShapeDtypeStruct((2,), jnp.uint32))

@@ -19,9 +19,6 @@ Telemetry siblings in this package:
   exporter.py         — live HTTP observability endpoint: /metrics,
                         /healthz, /slo, /incidents, /trace/tail
                         (FLAGS_tpu_metrics_port)
-  ledger.py           — provenance-stamped perf ledger: schema, direction-
-                        aware metric registry, regression/staleness gate
-                        (stdlib-only; CLI at tools/perf_ledger.py)
 """
 from __future__ import annotations
 
@@ -41,12 +38,10 @@ from . import xmem
 from . import numerics
 from . import trace
 from . import exporter
-from . import ledger
 
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "make_scheduler",
            "RecordEvent", "export_chrome_tracing", "benchmark", "metrics",
-           "compile_tracker", "xmem", "numerics", "trace", "exporter",
-           "ledger"]
+           "compile_tracker", "xmem", "numerics", "trace", "exporter"]
 
 # host-span aggregation for the summary stats table (reference:
 # profiler/profiler_statistic.py — EventSummary/statistic_data tables).

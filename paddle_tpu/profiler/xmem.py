@@ -2,7 +2,8 @@
 
 Every place the framework lowers a function to an XLA executable — the
 `to_static` jit cache (jit/api.py), the static-graph Executor
-(static/program.py), the inference Predictor, and bench.py — reports the
+(static/program.py), the inference Predictor, `Plan.compile` and
+`serving.LLMEngine` — reports the
 compiled executable's `memory_analysis()` (argument / output / temp /
 generated-code bytes, and the derived per-device peak) and
 `cost_analysis()` (flops, bytes accessed) into one process-global store.

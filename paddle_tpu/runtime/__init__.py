@@ -1,7 +1,6 @@
 """Runtime health layer: phase watchdogs, heartbeats, hang recovery.
 
-Promotes bench.py's ad-hoc hang defenses into a shared subsystem
-(ROADMAP items 3/4): `watchdog` holds the phase-deadline machinery and
+Hang defenses as one shared subsystem: `watchdog` holds the phase-deadline machinery and
 deadline executors, `health` the cross-rank heartbeat/beacon failure
 detector that converts hangs into exit-101 elastic relaunches.
 """

@@ -8,8 +8,7 @@ treat *hangs* — a device claim that never returns, a compile that never
 finishes, a collective a peer never enters — as routine failures that
 must convert to a bounded-time, restartable error.
 
-This module promotes bench.py's ad-hoc staged deadlines into a shared
-subsystem:
+Staged deadlines as one shared subsystem:
 
 ``Watchdog``
     Named phases (``device_init``, ``compile``, ``first_step``,
@@ -23,12 +22,11 @@ subsystem:
 
 ``run_with_deadline``
     Daemon-thread executor: run ``fn`` with a wall-clock budget, raise
-    :class:`PhaseTimeout` if it does not land. Generalizes bench.py's
-    measure-thread watchdog.
+    :class:`PhaseTimeout` if it does not land.
 
 Incident records accumulate in a bounded module buffer (``incidents()``)
-so bench.py and the Profiler "Health" section can report *what* hung
-and *when*.
+so the Profiler "Health" section and the exporter's ``/incidents`` can
+report *what* hung and *when*.
 """
 from __future__ import annotations
 
@@ -80,8 +78,8 @@ class PhaseTimeout(TimeoutError):
 
 # -- incident records --------------------------------------------------------
 #
-# Structured, bounded, in-process. The consumers: bench.py attaches the
-# last incident to its JSON line, HealthMonitor/Profiler summarize them.
+# Structured, bounded, in-process. The consumers: HealthMonitor, the
+# Profiler summary and the exporter's /incidents endpoint.
 
 _INCIDENTS: List[Dict[str, Any]] = []
 _INCIDENTS_MAX = 64

@@ -27,7 +27,7 @@ The three layers, bottom-up:
                     (``retriable`` or terminal).
 
 Fleet planning rides on top: ``workloads`` (seeded synthetic arrival
-processes shared by bench_serve, pod_report and tools/fleet_sim.py)
+processes shared by pod_report and tools/fleet_sim.py)
 and ``autoscale`` (the per-replica ServiceModel, multi-window SLO
 burn-rate gauges, and the recommend-only AutoscalePolicy the Router
 surfaces).  Both are stdlib-only, like ``stats`` — the jax-free slice

@@ -2,9 +2,8 @@
 
 One place defines what "diurnal" or "flash-crowd" traffic means, so a
 capacity recommendation computed offline (``tools/fleet_sim.py``,
-``tools/pod_report.py serving``) and a benchmark replayed live
-(``bench_serve.py --workload``) describe byte-for-byte the same
-request stream: same arrival offsets, same prompts, same token
+``tools/pod_report.py serving``) and a stream replayed through a live
+``LLMEngine`` describe byte-for-byte the same request stream: same arrival offsets, same prompts, same token
 budgets, for the same ``(preset, n_requests, seed, ...)`` tuple.
 
 Arrival processes are inhomogeneous-Poisson shaped: exactly
@@ -61,7 +60,7 @@ class Arrival:
 
 def validate(preset: str) -> str:
     """Return ``preset`` or raise ValueError enumerating every valid
-    preset (the bench_serve/fleet_sim unknown-workload diagnostic)."""
+    preset (the fleet_sim unknown-workload diagnostic)."""
     if preset not in PRESETS:
         raise ValueError(
             f"unknown workload preset {preset!r} "
@@ -171,7 +170,7 @@ def step_schedule(arrivals: Sequence[Arrival],
                   total_steps: int) -> Dict[int, List[Arrival]]:
     """Map arrival offsets onto ``total_steps`` engine-step slots
     (step index -> arrivals submitted before that step).  This is how
-    a step-driven harness (bench_serve) replays a time-based workload
+    a step-driven harness replays a time-based workload
     without knowing wall step duration in advance: relative pacing is
     preserved, absolute time is measured, not assumed."""
     if not arrivals:

@@ -263,8 +263,8 @@ def test_fused_candidates_always_legal_property(shape, dtype):
 
 
 def test_committed_bench_cache_short_circuits_tuning():
-    """bench.py seeds tuning from .flash_autotune.json; a cache hit must
-    return the winner without measuring (no device work)."""
+    """A hit in the committed .flash_autotune.json must return the
+    winner without measuring (no device work)."""
     import os
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -456,6 +456,43 @@ def test_cli_rejects_corrupt_sidecar(tmp_path, payload):
     assert "fleet_sim: error:" in p.stderr
 
 
+def test_cli_replays_a_trace_the_engine_recorded(tmp_path):
+    """An engine under FLAGS_tpu_trace leaves the request arrivals and
+    the step costs in the ring; written as a sidecar they are a workload
+    and a calibration that ``--trace-dir`` replays."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models import llama
+    from paddle_tpu.profiler import trace
+
+    cfg = llama.preset("llama-debug")
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    paddle.set_flags({"FLAGS_tpu_trace": True})
+    trace.clear()
+    try:
+        eng = serving.LLMEngine(cfg, params, max_running=4, chunk=4,
+                                page_size=16, max_model_len=32)
+        for a in workloads.generate("uniform", 6, prompt_len=8,
+                                    max_new_tokens=4, vocab=cfg.vocab_size):
+            eng.add_request(list(a.prompt), a.max_new_tokens)
+        while eng.has_work():
+            eng.step()
+        eng.shutdown()
+        side = trace.write_sidecar(trace.sidecar_path(str(tmp_path)))
+    finally:
+        paddle.set_flags({"FLAGS_tpu_trace": False})
+        trace.clear()
+    assert os.path.basename(side) == "trace_rank0.jsonl"
+    p = _run_tool(FLEET_SIM, "--trace-dir", str(tmp_path),
+                  "--replicas", "1-2", "--slo-ttft-s", "60")
+    assert p.returncode == 0, p.stderr
+    doc = json.loads(p.stdout)
+    assert doc["sweep"]
+    assert all(run["admitted"] == 6 for run in doc["sweep"])
+
+
 def test_cli_runs_without_jax(tmp_path):
     poison = tmp_path / "poison"
     poison.mkdir()

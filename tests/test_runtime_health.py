@@ -8,11 +8,7 @@ conversion goes through a recorded ``exit_fn`` instead of ``os._exit``.
 The real cross-process hang → detect → relaunch proof lives in
 tests/test_hang_recovery.py (slow tier).
 """
-import json
-import os
 import pickle
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -27,8 +23,6 @@ from paddle_tpu.runtime.health import CollectiveTimeout, HealthMonitor
 from paddle_tpu.runtime.watchdog import (PhaseTimeout, Watchdog,
                                          run_with_deadline)
 from paddle_tpu.testing import chaos
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -418,23 +412,3 @@ class TestHealthReporting:
         table = p.summary_table()
         assert "Health" in table
         assert "monitor: not installed" in table
-
-
-# ---------------------------------------------------------------------------
-# bench.py: without a chip there is no number — an error line, exit code 1
-# ---------------------------------------------------------------------------
-
-def test_bench_without_a_chip_fails_loudly():
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=180)
-    assert proc.returncode == 1
-    rec = json.loads(next(ln for ln in proc.stdout.splitlines()
-                          if ln.startswith("{")))
-    assert "value" not in rec and "last_measured" not in rec
-    assert "measures on a TPU" in rec["error"]
-    assert rec["device"]["platform"] == "cpu"
-    assert rec["device"]["device_kind"] == "cpu"
-    assert rec["device"]["device_count"] >= 1

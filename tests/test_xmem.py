@@ -5,8 +5,7 @@ static Executor, inference Predictor), the "Memory" section of
 Profiler.summary_table(), the metrics-registry export, the
 device.memory_stats() merge of live allocator counters with
 analysis-derived static peaks, the pod-fit reporter
-(tools/pod_report.py, hardware-free on a virtual v5p-64 mesh), and the
-bench device-init retry ladder.
+(tools/pod_report.py, hardware-free on a virtual v5p-64 mesh).
 """
 import importlib.util
 import json
@@ -231,25 +230,6 @@ class TestPodReport:
             mod.parse_mesh("h100-8")
         with pytest.raises(SystemExit):
             mod.parse_mesh("v5p")
-
-
-# ---------------------------------------------------------------------------
-# bench peaks: an unknown device is an error, not a default
-# ---------------------------------------------------------------------------
-
-def test_bench_refuses_unknown_device_kind():
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_test", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    class Dev:
-        device_kind = "TPU v5 lite"
-
-    assert bench._peak_flops(Dev()) == 197e12
-    Dev.device_kind = "cpu"
-    with pytest.raises(RuntimeError, match="unknown device_kind"):
-        bench._peak_flops(Dev())
 
 
 # ---------------------------------------------------------------------------

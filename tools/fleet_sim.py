@@ -307,7 +307,8 @@ def load_trace(pt: _Paddle, trace_dir: str):
     if not queued:
         die(2, f"{trace_dir}: trace holds no serve/queued request "
                f"events — record with FLAGS_tpu_trace=1 while "
-               f"serving (bench_serve --trace-out writes one)")
+               f"serving, then profiler.trace.write_sidecar("
+               f"trace.sidecar_path(DIR))")
     t0 = min(float(e["t"]) for e in queued)
     arrivals = []
     for i, e in enumerate(sorted(queued, key=lambda e: float(e["t"]))):

@@ -99,23 +99,14 @@ def _parse_args(argv=None):
                          "service model")
     ap.add_argument("--prefix-hit-rate", type=float, default=None,
                     help="measured shared-prefix hit rate in [0, 1) "
-                         "(e.g. the prefix_hit_rate from bench_serve "
-                         "--workload shared-prefix) — the serving "
+                         "(e.g. LLMEngine.serving_stats()'s "
+                         "prefix_hit_rate) — the serving "
                          "section then also reports effective "
                          "blocks-per-request and concurrency with "
                          "that fraction of each request's pages "
                          "shared from the radix cache")
     ap.add_argument("--topology", default=None,
                     help="override the planner: dp,pp,sharding,mp")
-    ap.add_argument("--ledger", nargs="?", metavar="PATH",
-                    const=os.path.join("runs", "perf_ledger.jsonl"),
-                    default=None,
-                    help="append the report's chip-free proxy verdict "
-                         "(predicted step ms/MFU, plan capacity, KV "
-                         "capacity ratio, fleet min replicas) as a "
-                         "provenance-stamped row to the perf ledger at "
-                         "PATH (default runs/perf_ledger.jsonl, relative "
-                         "to the repo root)")
     ap.add_argument("--out", default="-",
                     help="output path for the JSON report (- = stdout)")
     ap.add_argument("--plan-out", default=None,
@@ -499,7 +490,7 @@ def _fleet_block(plan, args):
     rec["horizon_s"] = float(args.fleet_horizon_s)
     rec["service_model"] = model.to_dict()
     rec["note"] = ("uncalibrated step costs (shared defaults); feed a "
-                   "measured trace or bench_serve fleet block through "
+                   "measured trace through "
                    "tools/fleet_sim.py to validate under simulation")
     return rec
 
@@ -572,27 +563,6 @@ def write_plan_spec(report, preset, path):
     print(f"wrote plan spec {path}", file=sys.stderr)
 
 
-def _ledger_append(repo_root, ledger_path, report):
-    """Append the chip-free proxy verdict to the perf ledger.
-
-    Loads profiler/ledger.py standalone (stdlib-only, no package import)
-    so the fast hardware-free 'serving' mode stays fast."""
-    import importlib.util
-    src = os.path.join(repo_root, "paddle_tpu", "profiler", "ledger.py")
-    spec = importlib.util.spec_from_file_location("perf_ledger_core", src)
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules.setdefault(spec.name, mod)
-    spec.loader.exec_module(mod)
-    if not os.path.isabs(ledger_path):
-        ledger_path = os.path.join(repo_root, ledger_path)
-    cmd = "python " + " ".join(
-        [os.path.basename(sys.argv[0] or "pod_report.py")] + sys.argv[1:])
-    row = mod.from_pod_report(report, ts=time.time(), cmd=cmd)
-    mod.append(ledger_path, row)
-    print(f"pod_report: ledger row appended to {ledger_path}",
-          file=sys.stderr)
-
-
 def main(argv=None):
     args = _parse_args(argv)
     _, n_dev = parse_mesh(args.mesh)
@@ -618,15 +588,11 @@ def main(argv=None):
             with open(args.out, "w") as f:
                 f.write(payload + "\n")
             print(f"wrote {args.out}", file=sys.stderr)
-        if args.ledger:
-            _ledger_append(repo_root, args.ledger, report)
         return 0
 
     report = build_report(args)
     if args.plan_out:
         write_plan_spec(report, args.preset, args.plan_out)
-    if args.ledger:
-        _ledger_append(repo_root, args.ledger, report)
     payload = json.dumps(report, indent=2, sort_keys=False)
     if args.out == "-":
         print(payload)
