@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .llama import _dense_mlp, _rms_norm
+from .step_layout import StepLayout
 
 __all__ = ["JambaConfig", "PRESETS", "preset", "config_from_fields",
            "init_params", "param_count", "forward_pure", "forward_paged",
@@ -246,41 +247,50 @@ def _layer_at(stack, l):
         lambda w: lax.dynamic_index_in_dim(w, l, 0, keepdims=False), stack)
 
 
-def _mamba_mixer(cfg, lp, h, conv, ssm, l, q_lens, fresh):
-    """Mixer ``l`` on ``h [R, Tc, D]`` with the state of each row, read from
-    and written back into layer ``l`` of the stacks ``conv [M, K-1, R, E]``
-    and ``ssm [M, N, R, E]`` float32.  A row whose chunk is ``fresh`` starts
-    from zero state; positions ``t >= q_lens[r]`` advance neither state.
-    Returns the mixer's output ``[R, Tc, D]`` and both stacks.
+def _mamba_mixer(cfg, lp, h, conv, ssm, l, q_lens, fresh, lay):
+    """Mixer ``l`` on the flat tokens ``h [T, D]`` of the step ``lay`` with
+    the state of each row, read from and written back into layer ``l`` of
+    the stacks ``conv [M, K-1, R, E]`` and ``ssm [M, N, R, E]`` float32.  A
+    row whose chunk is ``fresh`` starts from zero state; positions ``t >=
+    q_lens[r]`` advance neither state.  Returns the mixer's output ``[T, D]``
+    and both stacks.
 
-    The scopes ``ssm_conv`` and ``ssm_scan`` hold everything that touches
-    their state: its slice out of the stack, the reset, the update and the
-    write-back, so a reader of the scope's time times every byte that
-    ``benchmark/kernel_costs_ssm.py`` counts."""
+    The four matmuls, the norms and the gate are per token and stay flat;
+    the convolution and the scan need a row's positions in order, so their
+    inputs go to the padded ``[R, Tc, ...]`` rows and their results come
+    back flat.  The scopes ``ssm_conv`` and ``ssm_scan`` hold everything
+    that touches their state and nothing else: its slice out of the stack,
+    the reset, the update and the write-back, so a reader of the scope's
+    time times every byte that ``benchmark/kernel_costs_ssm.py`` counts,
+    and the moves between the layouts stay outside them."""
     N, r, eps = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.rms_norm_eps
-    Tc, f32 = h.shape[1], jnp.float32
+    f32 = jnp.float32
     x, z = jnp.split(_rms_norm(h, lp["ln1"], eps) @ lp["w_in"], 2, axis=-1)
+    x = lay.rows(x)
     with jax.named_scope("ssm_conv"):
         c = jnp.where(fresh[None, :, None], 0, _layer_at(conv, l))
         x, c = _ssm_conv(lp, x, c, q_lens)                  # x float32
         conv = lax.dynamic_update_index_in_dim(conv, c, l, 0)
-    dt, Bm, Cm = jnp.split(x.astype(h.dtype) @ lp["w_x"], [r, r + N], axis=-1)
+    xf = lay.flat(x)                                         # [T, E]
+    dt, Bm, Cm = jnp.split(xf.astype(h.dtype) @ lp["w_x"], [r, r + N],
+                           axis=-1)
     dt = _rms_norm(dt, lp["dt_norm"], eps) @ lp["w_dt"]
     dt = jax.nn.softplus(dt.astype(f32) + lp["b_dt"].astype(f32))
-    real = jnp.arange(Tc)[None, :] < q_lens[:, None]         # [R, Tc]
-    dt = jnp.where(real[:, :, None], dt, 0.0)
-    Bm = _rms_norm(Bm, lp["b_norm"], eps).astype(f32)
-    Cm = _rms_norm(Cm, lp["c_norm"], eps).astype(f32)
+    real = jnp.arange(lay.Tc)[None, :] < q_lens[:, None]     # [R, Tc]
+    dt = jnp.where(real[:, :, None], lay.rows(dt), 0.0)
+    Bm = lay.rows(_rms_norm(Bm, lp["b_norm"], eps).astype(f32))
+    Cm = lay.rows(_rms_norm(Cm, lp["c_norm"], eps).astype(f32))
     with jax.named_scope("ssm_scan"):
         s = jnp.where(fresh[None, :, None], 0, _layer_at(ssm, l))
         A = -jnp.exp(lp["A_log"].astype(f32))
         y, s = _ssm_scan(s, dt, dt * x, Bm, Cm, A)
         ssm = lax.dynamic_update_index_in_dim(ssm, s.astype(ssm.dtype), l, 0)
-    y = (y + lp["D_skip"].astype(f32) * x) * jax.nn.silu(z.astype(f32))
+    y = ((lay.flat(y) + lp["D_skip"].astype(f32) * xf)
+         * jax.nn.silu(z.astype(f32)))
     return y.astype(h.dtype) @ lp["w_out"], conv, ssm
 
 
-def _mamba_run(cfg, stack, lo, hi, h, conv, ssm, q_lens, fresh):
+def _mamba_run(cfg, stack, lo, hi, h, conv, ssm, q_lens, fresh, lay):
     """Mamba layers ``lo..hi`` of the stack as one scan.  ``conv [M, K-1, R,
     E]`` and ``ssm [M, N, R, E]`` go round in the carry and are updated in
     place at the layer's index."""
@@ -289,7 +299,7 @@ def _mamba_run(cfg, stack, lo, hi, h, conv, ssm, q_lens, fresh):
         lp = _layer_at(stack, l)
         with jax.named_scope("mamba"):
             out, conv, ssm = _mamba_mixer(cfg, lp, h, conv, ssm, l, q_lens,
-                                          fresh)
+                                          fresh, lay)
             h = h + out
         with jax.named_scope("mlp"):
             h = h + _dense_mlp(lp, _rms_norm(h, lp["ln2"], cfg.rms_norm_eps))
@@ -300,34 +310,39 @@ def _mamba_run(cfg, stack, lo, hi, h, conv, ssm, q_lens, fresh):
     return h, conv, ssm
 
 
-def _forward(cfg, params, tokens, conv, ssm, q_lens, fresh, attend):
-    """Embedding, the layers in order, final norm, tied head.  ``attend(a,
-    q, k, v)`` is attention layer ``a``'s mixing of ``q [R, Tc, nh, d]``
-    with ``k, v [R, Tc, nkv, d]`` and whatever came before them."""
-    R, Tc = tokens.shape
+def _forward(cfg, params, tokens, conv, ssm, q_lens, fresh, attend, lay):
+    """Embedding, the layers in order, final norm, tied head, on the flat
+    tokens ``[T, ...]`` of the step ``lay`` (``step_layout.StepLayout``);
+    returns the logits ``[T, V]``.  ``attend(a, q, k, v)`` is attention
+    layer ``a``'s mixing of ``q [R, Tc, nh, d]`` with ``k, v [R, Tc, nkv,
+    d]`` and whatever came before them."""
+    R, Tc, T = lay.R, lay.Tc, lay.T
     nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                   cfg.head_dim)
     with jax.named_scope("embed"):
-        h = jnp.take(params["embed"], tokens, axis=0)
+        h = jnp.take(params["embed"], lay.flat(tokens), axis=0)
     with jax.named_scope("layers"):
         for run in cfg.layer_runs():
             if run[0] == "mamba":
                 h, conv, ssm = _mamba_run(cfg, params["mamba"], run[1],
-                                          run[2], h, conv, ssm, q_lens, fresh)
+                                          run[2], h, conv, ssm, q_lens, fresh,
+                                          lay)
                 continue
             lp = jax.tree_util.tree_map(lambda w: w[run[1]], params["attn"])
             with jax.named_scope("attn"):
                 xn = _rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
-                out = attend(run[1], (xn @ lp["wq"]).reshape(R, Tc, nh, d),
-                             (xn @ lp["wk"]).reshape(R, Tc, nkv, d),
-                             (xn @ lp["wv"]).reshape(R, Tc, nkv, d))
-                h = h + out.reshape(R, Tc, nh * d).astype(h.dtype) @ lp["wo"]
+                out = attend(
+                    run[1], lay.rows((xn @ lp["wq"]).reshape(T, nh, d)),
+                    lay.rows((xn @ lp["wk"]).reshape(T, nkv, d)),
+                    lay.rows((xn @ lp["wv"]).reshape(T, nkv, d)))
+                out = lay.flat(out.reshape(R, Tc, nh * d))
+                h = h + out.astype(h.dtype) @ lp["wo"]
             with jax.named_scope("mlp"):
                 h = h + _dense_mlp(
                     lp, _rms_norm(h, lp["ln2"], cfg.rms_norm_eps))
     with jax.named_scope("lm_head"):
         h = _rms_norm(h, params["norm_f"], cfg.rms_norm_eps)
-        logits = jnp.einsum("rtd,vd->rtv", h, params["embed"],
+        logits = jnp.einsum("td,vd->tv", h, params["embed"],
                             preferred_element_type=jnp.float32)
     return logits, conv, ssm
 
@@ -356,9 +371,10 @@ def forward_pure(cfg: JambaConfig, params, input_ids):
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
     conv, ssm = _fresh_state(cfg, B)
-    return _forward(cfg, params, input_ids, conv, ssm,
-                    jnp.full((B,), S, jnp.int32), jnp.ones((B,), bool),
-                    attend)[0]
+    q_lens = jnp.full((B,), S, jnp.int32)
+    lay = StepLayout(q_lens, S)
+    return lay.rows(_forward(cfg, params, input_ids, conv, ssm, q_lens,
+                             jnp.ones((B,), bool), attend, lay)[0])
 
 
 # ---------------------------------------------------------------------------
@@ -395,11 +411,14 @@ def cache_bytes(cfg: JambaConfig, kv_dtype_bytes: int = 2) -> dict:
 
 
 def forward_paged(cfg: JambaConfig, params, tokens, cache, block_tables,
-                  seq_lens, q_lens):
+                  seq_lens, q_lens, step_tokens=None):
     """The engine's step: ragged mixed prefill and decode rows ``tokens [R,
     Tc]`` (row r feeds ``tokens[r, :q_lens[r]]`` and then holds ``seq_lens[r]``
     tokens) over ``cache`` (``init_cache``).  Returns ``(logits [R, Tc, V]
-    float32, cache)``; logits of padding positions are garbage.
+    float32, cache)``; logits of padding positions are garbage.  With
+    ``step_tokens = T`` the program computes ``T`` positions in place of
+    ``R x Tc`` and returns the logits flat, ``[T, V]`` (``StepLayout``); the
+    caller guarantees ``sum(q_lens) <= T``.
 
     Attention layers write and read their page pools through the block
     table with the kernels Llama's step uses (``paged_kv_write``,
@@ -431,10 +450,11 @@ def forward_paged(cfg: JambaConfig, params, tokens, cache, block_tables,
         return out.reshape(R, nkv, Tc, rep, d).transpose(0, 2, 1, 3, 4)
 
     fresh = (q_lens > 0) & (seq_lens == q_lens)
+    lay = StepLayout(q_lens, Tc, step_tokens)
     logits, conv, ssm = _forward(cfg, params, tokens, cache["conv"],
-                                 cache["ssm"], q_lens, fresh, attend)
-    return logits, {"k_pages": pools[0], "v_pages": pools[1], "conv": conv,
-                    "ssm": ssm}
+                                 cache["ssm"], q_lens, fresh, attend, lay)
+    return (logits if lay.compact else lay.rows(logits)), {
+        "k_pages": pools[0], "v_pages": pools[1], "conv": conv, "ssm": ssm}
 
 
 # what serving.LLMEngine asks a configuration for (``cfg.serving``)

@@ -40,6 +40,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 from .decoding import GenerationMixin
+from .step_layout import StepLayout
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "init_params", "forward_pure",
            "forward_with_cache", "forward_paged", "build_train_step",
@@ -621,7 +622,7 @@ def forward_with_cache(cfg: LlamaConfig, params, tokens, cache, pos):
 
 def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
                   block_tables, seq_lens, q_lens, *,
-                  k_scales=None, v_scales=None):
+                  k_scales=None, v_scales=None, step_tokens=None):
     """Ragged mixed prefill+decode forward over a paged KV cache (the
     serving engine's step function).
 
@@ -638,6 +639,11 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
                                   hold int8 pages, new k/v are
                                   quantize-on-write requantized per
                                   page, and attention dequants on read
+    step_tokens   int or None     how many positions the program
+                                  computes: None is all ``R x Tc``; a
+                                  count ``T`` is the token-major step,
+                                  whose caller guarantees
+                                  ``sum(q_lens) <= T``
 
     Fixed shapes throughout — one compilation per (R, Tc, pool)
     signature.  Rope runs at each token's absolute position
@@ -647,6 +653,13 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
     Returns (logits [R, Tc, V] fp32, (k_pages, v_pages)) — with
     scales, (k_pages, v_pages, k_scales, v_scales); logits in padding
     rows are garbage by contract — callers read row q_lens[r] - 1.
+
+    What is per token (embedding, norms, projections and rope, the MLP,
+    the head) runs on the flat batch ``[T, ...]`` of ``StepLayout``; the
+    K/V write and the attention take the padded ``[R, Tc, ...]`` rows
+    and hand their result back flat.  With ``step_tokens`` the logits
+    come back flat too, ``[T, V]``: token ``start[r] + t`` is row r's
+    position t (``StepLayout.last`` is each row's last fed token).
 
     The pools stay one buffer.  They go round the ONE ``lax.scan`` over
     the layers whole, in its carry beside the hidden state (the scanned
@@ -688,6 +701,8 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
     rep = nh // nkv
     page = k_pages.shape[3]
     num_pages = k_pages.shape[2]
+    lay = StepLayout(q_lens, Tc, step_tokens)
+    T = lay.T
 
     # absolute position of each token slot, clipped for the rope gather
     start = (seq_lens - q_lens).astype(jnp.int32)        # [R]
@@ -696,16 +711,17 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
     valid = t_off[None, :] < q_lens[:, None]             # [R, Tc]
     qpos_c = jnp.clip(qpos, 0, cfg.max_position_embeddings - 1)
     sin_full, cos_full = _rope_tables(cfg, cfg.max_position_embeddings)
-    sin = jnp.take(sin_full, qpos_c, axis=0)             # [R, Tc, D]
-    cos = jnp.take(cos_full, qpos_c, axis=0)
+    pos = lay.flat(qpos_c)                               # [T]
+    sin = jnp.take(sin_full, pos, axis=0)                # [T, D]
+    cos = jnp.take(cos_full, pos, axis=0)
 
     def rope(x):
         # per-token tables (ragged positions), else same as _apply_rope
         half = x.shape[-1] // 2
         x1, x2 = x[..., :half], x[..., half:]
         rot = jnp.concatenate([-x2, x1], axis=-1)
-        return (x * cos[:, :, None, :].astype(x.dtype)
-                + rot * sin[:, :, None, :].astype(x.dtype))
+        return (x * cos[:, None, :].astype(x.dtype)
+                + rot * sin[:, None, :].astype(x.dtype))
 
     quant_kv = k_scales is not None
     if quant_kv:
@@ -757,7 +773,7 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
             return pool, scales
 
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = jnp.take(params["embed"], lay.flat(tokens), axis=0)   # [T, H]
 
     def kv_write(pools, l, k, v):
         # k, v [R, Tc, nkv, d]; only the new tokens are written, into
@@ -772,9 +788,9 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
 
     def attn(h, lp, pools, l):
         xn = _rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
-        q = rope(_qmm(xn, lp["wq"]).reshape(R, Tc, nh, d))
-        k = rope(_qmm(xn, lp["wk"]).reshape(R, Tc, nkv, d))
-        v = _qmm(xn, lp["wv"]).reshape(R, Tc, nkv, d)
+        q = lay.rows(rope(_qmm(xn, lp["wq"]).reshape(T, nh, d)))
+        k = lay.rows(rope(_qmm(xn, lp["wk"]).reshape(T, nkv, d)))
+        v = lay.rows(_qmm(xn, lp["wv"]).reshape(T, nkv, d))
         with jax.named_scope("kv_write"):
             pools = kv_write(pools, l, k, v)
         # kernel layout [R, nkv, Tc*rep, d]: row t*rep + j = q head
@@ -785,8 +801,8 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
             qk, pools[0], pools[1], block_tables, seq_lens, q_lens,
             rep=rep, layer=l,
             **dict(zip(("k_scales", "v_scales"), pools[2:])))
-        out = out.reshape(R, nkv, Tc, rep, d).transpose(
-            0, 2, 1, 3, 4).reshape(R, Tc, H)
+        out = lay.flat(out.reshape(R, nkv, Tc, rep, d).transpose(
+            0, 2, 1, 3, 4).reshape(R, Tc, H))
         return h + _qmm(out.astype(h.dtype), lp["wo"]), pools
 
     def body(carry, inp):
@@ -797,8 +813,8 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
         with jax.named_scope("mlp"):
             hn = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
             if cfg.moe_num_experts > 0:
-                mlp_out, _aux = _moe_mlp(cfg, lp, hn)
-                h = h + mlp_out
+                mlp_out, _aux = _moe_mlp(cfg, lp, hn[None])
+                h = h + mlp_out[0]
             else:
                 h = h + _dense_mlp(lp, hn)
         return (h, pools), None
@@ -815,7 +831,7 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
         logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
-    return logits, pools
+    return (logits if lay.compact else lay.rows(logits)), pools
 
 
 # ---------------------------------------------------------------------------
@@ -861,11 +877,12 @@ def param_count(cfg: LlamaConfig) -> int:
             + cfg.num_hidden_layers * per_layer + H)
 
 
-def _serve_step(cfg, params, tokens, cache, block_tables, seq_lens, q_lens):
+def _serve_step(cfg, params, tokens, cache, block_tables, seq_lens, q_lens,
+                step_tokens=None):
     """``forward_paged`` on the cache as one pytree."""
     k_pages, v_pages, *scales = cache
     return forward_paged(cfg, params, tokens, k_pages, v_pages, block_tables,
-                         seq_lens, q_lens,
+                         seq_lens, q_lens, step_tokens=step_tokens,
                          **dict(zip(("k_scales", "v_scales"), scales)))
 
 

@@ -12,15 +12,23 @@ a protocol of a few members: ``init_cache(cfg, slots, num_pages,
 page_size, kv_dtype)`` builds the cache pytree (page pools, and for a
 model with recurrent layers also fixed-size state per engine slot),
 ``forward_paged(cfg, params, tokens, cache, block_tables, seq_lens,
-q_lens) -> (logits [R, Tc, V] f32, cache)`` is the step,
-``cache_bytes(cfg, kv_dtype_bytes)`` and ``param_count(cfg)`` size it,
+q_lens, step_tokens=None) -> (logits [R, Tc, V] f32, cache)`` is the step
+(with ``step_tokens = T`` it computes ``T`` positions, the step's fed
+tokens row after row, and returns their logits ``[T, V]``:
+``models/step_layout.py``), ``cache_bytes(cfg, kv_dtype_bytes)`` and ``param_count(cfg)`` size it,
 ``prepare_params(cfg, params)`` converts weights once at build, and
 ``recurrent_state`` says whether the cache holds state that depends on
 every token fed, in order (docs/serving.md, "Recurrent state").
 
 Compilation discipline: the batch is always [max_running, Tc] with
 Tc in {1, chunk}, so a serving process compiles at most two step
-executables per pool signature regardless of traffic.  Greedy decode
+executables per pool signature regardless of traffic.  The mixed
+(Tc=chunk) program is token-major: what is per token — embedding,
+norms, projections, the MLP, the head, the argmax — it computes for a
+budget of ``scheduler.step_tokens`` fed tokens and not for
+``max_running x chunk`` padded positions; only the K/V write, the
+attention and a recurrent layer's convolution and scan keep the padded
+rows.  The scheduler keeps every step within that budget.  Greedy decode
 only — sampling lives in models/decoding.py for the offline path; the
 serving acceptance bar is stream-for-stream parity with
 ``forward_with_cache`` greedy decode.
@@ -80,6 +88,7 @@ from .errors import (AdmissionRejected, DeadlineExceeded,
 from .kv_cache import PagedKVCache, _cdiv
 from .scheduler import (AdmissionGate, Request, RequestState, Scheduler,
                         StepPlan)
+from ..models.step_layout import StepLayout
 from .spec_decode import DraftModel, SpecDecodeConfig, greedy_accept
 from . import stats as _stats
 
@@ -193,6 +202,22 @@ class LLMEngine:
     slot at ``max_model_len``, +1 for the reserved null page),
     ``chunk`` the prefill chunk length (also the prefill bucket Tc),
     ``max_running`` the fixed batch width.
+
+    The token budget.  A mixed step feeds at most
+    ``scheduler.step_tokens`` tokens, a number that follows from
+    ``max_running``, ``chunk`` and the speculation depth and that nothing
+    sets (256 unless the slots need more; ``max_running x chunk``, every
+    position, for an engine smaller than that).  Never deferred: a decode
+    row, or its 1+k verify chunk — each is fed in every step, so the
+    budget adds no gap between a request's tokens.  Deferred when the
+    budget is spent: a prefill row's next chunk, youngest admission first;
+    the row keeps its slot, its pages and (recurrent) state, is left out
+    of that step's plan, and is fed whole chunks as before once the rows
+    ahead of it have prefilled, so every request is fed the chunks it
+    would be fed alone and its greedy output does not change.  The budget
+    binds when many slots prefill at once (a cold start, a burst); what it
+    costs is time to first token there.  ``step()`` raises if a plan
+    feeds more than the program computes.
 
     Resilience knobs: ``clock`` is the engine's monotonic time source
     (injectable for tests; never wall time, so NTP steps cannot corrupt
@@ -449,30 +474,43 @@ class LLMEngine:
         """The step function of bucket ``Tc``, traced and lowered."""
         cfg, fwd = self.cfg, self._model.forward_paged
 
+        R = self.max_running
+        # the mixed step computes its budget of fed tokens; T = R x Tc is
+        # every padded position (the decode step, a small engine)
+        T = self._positions(Tc)
+        T = T if T < R * Tc else None
+
         def step(params, tokens, pools, tbl, lens, qlens):
-            logits, pools = fwd(cfg, params, tokens, pools, tbl, lens, qlens)
+            logits, pools = fwd(cfg, params, tokens, pools, tbl, lens, qlens,
+                                step_tokens=T)
             with jax.named_scope("sample"):
-                last = jnp.clip(qlens - 1, 0, tokens.shape[1] - 1)
-                rows = jnp.take_along_axis(
-                    logits, last[:, None, None], axis=1)[:, 0]   # [R, V]
-                # argmax at EVERY fed position [R, Tc]: position q_len-1
-                # is the sampled token; the earlier positions are what
-                # spec-decode verification reads — multi-token verify
-                # needs the target's choice after each draft token.
-                # chk: one float per row (max logit) — a cheap [R]
-                # transfer the numerics watchdog scans for NaN/Inf
-                # poisoning
-                return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                        jnp.max(rows, axis=-1), pools)
+                lay = StepLayout(qlens, Tc, T)
+                flat = logits.reshape(-1, logits.shape[-1])      # [T, V]
+                # argmax at EVERY fed position, back in its row [R, Tc]:
+                # position q_len-1 is the sampled token; the earlier
+                # positions are what spec-decode verification reads —
+                # multi-token verify needs the target's choice after each
+                # draft token.
+                # chk: one float per row (the max logit of its last fed
+                # token) — a cheap [R] transfer the numerics watchdog
+                # scans for NaN/Inf poisoning
+                return (lay.rows(jnp.argmax(flat, axis=-1).astype(jnp.int32)),
+                        jnp.max(flat[lay.last], axis=-1), pools)
 
         # the program's name in a profile: jit_serve_step_tc<Tc>
         step.__name__ = f"serve_step_tc{Tc}"
-        R = self.max_running
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
         return jax.jit(
             step, donate_argnums=(2,) if self._donate else ()).lower(
             self.params, i32(R, Tc), self._pools, i32(R, self.max_blocks),
             i32(R), i32(R))
+
+    def _positions(self, Tc: int) -> int:
+        """How many positions the program of bucket ``Tc`` computes: a row
+        each in a decode step, the scheduler's token budget in a mixed
+        one."""
+        R = self.max_running
+        return R if Tc == 1 else min(R * Tc, self.scheduler.step_tokens)
 
     def _step_fn(self, Tc: int):
         """The compiled executable of bucket ``Tc``, built on first use.
@@ -603,7 +641,10 @@ class LLMEngine:
                 # first admission only: preemption replay keeps the
                 # original stamp so queue time stays arrival->admission
                 req.admitted_s = now
-        self._sched_rids = {s.request.rid for s in plan.seqs}
+        # a row the budget deferred stays admitted: it is not announced
+        # again when it is next fed
+        self._sched_rids = {s.request.rid for s in plan.seqs} | (
+            {r.rid for r in plan.deferred} & self._sched_rids)
         if plan.admission_blocked:
             # the pool (not the slot array) is the bottleneck: the
             # head-of-line request stays queued, never dropped
@@ -649,15 +690,28 @@ class LLMEngine:
             # length) of those the block table has room for
             kv_pages = int(_cdiv(lens, self.page_size)[qlens > 0].sum())
             table_pages = R * self.max_blocks
+            # slot_tokens: the positions the program computes, of which
+            # fed_tokens hold a token
             counts = dict(
                 bucket=Tc, rows=len(plan.seqs),
                 prefill_rows=len(plan.seqs) - decode_rows,
                 decode_rows=decode_rows, fed_tokens=int(qlens.sum()),
-                slot_tokens=R * Tc, kv_tokens=int(lens.sum()),
+                slot_tokens=self._positions(Tc),
+                deferred_rows=len(plan.deferred),
+                kv_tokens=int(lens.sum()),
                 qk_pairs=int(np.dot(qlens.astype(np.int64), lens)),
                 kv_pages=kv_pages, table_pages=table_pages)
+            if counts["fed_tokens"] > counts["slot_tokens"]:
+                # the tokens past the program's positions would vanish
+                # without a trace: a broken scheduler, not a run-time fault
+                raise RuntimeError(
+                    f"the plan feeds {counts['fed_tokens']} tokens and the "
+                    f"Tc={Tc} step computes {counts['slot_tokens']} "
+                    "(scheduler.step_tokens)")
             _STATS["kv_pages"] += kv_pages
             _STATS["table_pages"] += table_pages
+            _STATS["slot_tokens"] += counts["slot_tokens"]
+            _STATS["deferred_rows"] += counts["deferred_rows"]
             if self._model.recurrent_state:
                 # rows whose state the step advances, and those among
                 # them that it first zeroes (a chunk that starts at 0)
@@ -893,8 +947,13 @@ class LLMEngine:
         kv = PagedKVCache(self.num_pages, self.page_size,
                           self.max_blocks)
         seqs = []
+        left = self.scheduler.step_tokens
         for slot, req in enumerate(group):
-            q = min(self.chunk, req.num_known)
+            # a first chunk each, within the step's token budget: a token
+            # is kept back for every request still to come
+            q = min(self.chunk, req.num_known,
+                    left - (len(group) - slot - 1))
+            left -= q
             kv.grow(req.rid, q)
             seqs.append(_ProbeSeq(req, slot, q))
         Tc = self.chunk if any(s.q_len > 1 for s in seqs) else 1

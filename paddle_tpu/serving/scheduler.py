@@ -21,7 +21,13 @@ Per step boundary:
     between "batches"), behind a free-page watermark of one decode
     page per running request so admission cannot starve decode;
   * if the pool cannot cover a running request's next chunk, the
-    youngest running request is preempted and requeued at the front.
+    youngest running request is preempted and requeued at the front;
+  * the mixed (Tc=chunk) step computes a budget of ``step_tokens`` fed
+    tokens, not ``max_running x chunk`` padded positions: every decode
+    (or verify) row is fed every step, prefill rows take what is left of
+    the budget, oldest admission first, and a prefill row that does not
+    fit is deferred — it keeps its slot and its pages, is not in the
+    plan, and is fed once the rows ahead of it have finished prefilling.
 """
 from __future__ import annotations
 
@@ -38,6 +44,14 @@ __all__ = ["AdmissionGate", "Request", "RequestState", "Scheduler",
            "StepPlan", "ScheduledSeq"]
 
 _IDS = itertools.count()
+
+# The least the mixed step computes, in fed tokens.  A bf16 matmul on a
+# v5e costs the read of its weights whatever it feeds up to the chip's
+# ridge of about 240 tokens (197 TFLOP/s over 819 GB/s), so a smaller
+# budget buys no time and defers prefill rows for nothing; past the ridge
+# every token costs compute.  The first multiple of 128 (the MXU's rows)
+# over the ridge.
+_STEP_TOKENS = 256
 
 
 class RequestState(enum.Enum):
@@ -107,6 +121,9 @@ class StepPlan:
     # prompt tokens served from the prefix cache by this step's
     # admissions (the engine folds these into serve_prefix_* metrics)
     prefix_hit_tokens: int = 0
+    # running prefill rows the token budget left out of this step: they
+    # keep slot and pages and are not in ``seqs``
+    deferred: List[Request] = dataclasses.field(default_factory=list)
 
 
 class AdmissionGate:
@@ -137,10 +154,12 @@ class AdmissionGate:
 
 class Scheduler:
     def __init__(self, kv: PagedKVCache, *, max_running: int = 8,
-                 chunk: int = 16, max_model_len: Optional[int] = None):
+                 chunk: int = 16, max_model_len: Optional[int] = None,
+                 step_tokens: Optional[int] = None):
         self.kv = kv
         self.max_running = int(max_running)
         self.chunk = int(chunk)
+        self._step_tokens = step_tokens
         self.max_model_len = int(max_model_len
                                  or kv.max_blocks * kv.page_size)
         self.waiting: Deque[Request] = deque()
@@ -151,6 +170,28 @@ class Scheduler:
         # widened to a verify chunk of 1 + spec_k tokens (the engine
         # sets this iff a draft model is attached)
         self.spec_k: int = 0
+
+    @property
+    def step_tokens(self) -> int:
+        """The token budget of a mixed step: how many fed tokens the
+        Tc=chunk program computes (``max_running x chunk`` means every
+        padded position, the program without a budget).  Never under
+        ``max_running x (1 + spec_k) + chunk``, so that every decode or
+        verify row and the oldest prefill row's chunk always fit: no token
+        gap grows by a skipped step and no prefill waits for ever.  Unless
+        the constructor fixed it (tests), the first multiple of 128 that
+        holds those rows, and at least ``_STEP_TOKENS``."""
+        least = self.max_running * (1 + self.spec_k) + self.chunk
+        if self._step_tokens is None:
+            budget = max(_STEP_TOKENS, -(-least // 128) * 128)
+        elif self._step_tokens < least:
+            raise ValueError(
+                f"step_tokens={self._step_tokens} cannot hold {least} "
+                f"tokens: a decode row of 1 + {self.spec_k} in each of "
+                f"{self.max_running} slots and one chunk of {self.chunk}")
+        else:
+            budget = int(self._step_tokens)
+        return min(budget, self.max_running * self.chunk)
 
     # -- queue ----------------------------------------------------------
     def add(self, req: Request) -> None:
@@ -350,22 +391,35 @@ class Scheduler:
             self._slot_of[req.rid] = slot
             req.state = RequestState.RUNNING
 
-        # 3) emit the plan
-        seqs: List[ScheduledSeq] = []
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
+        # 3) emit the plan, within the token budget: decode and verify
+        # rows first, all of them; then prefill rows while their whole
+        # chunk fits (a chunk is never cut: a request is fed the same
+        # chunks whatever its neighbours do), oldest admission first —
+        # ``_slot_of`` keeps its requests in the order they were seated
+        left = self.step_tokens
+        by_slot: Dict[int, ScheduledSeq] = {}
+        deferred: List[Request] = []
+        rows = [self.slots[slot] for slot in self._slot_of.values()]
+        rows.sort(key=lambda req: req.num_known - req.fed > 1)  # stable
+        for req in rows:
             q_len = self._q_len(req)
             gap = req.num_known - req.fed
-            seqs.append(ScheduledSeq(
+            if q_len > left:
+                deferred.append(req)
+                continue
+            left -= q_len
+            slot = self._slot_of[req.rid]
+            by_slot[slot] = ScheduledSeq(
                 request=req, slot=slot, q_len=q_len,
                 seq_len=req.fed + q_len,
                 produces=req.fed + q_len >= req.num_known,
-                spec=q_len - gap if gap == 1 and q_len > 1 else 0))
+                spec=q_len - gap if gap == 1 and q_len > 1 else 0)
+        seqs = [by_slot[slot] for slot in sorted(by_slot)]
         bucket = self.chunk if any(s.q_len > 1 for s in seqs) else 1
         return StepPlan(seqs=seqs, bucket=bucket, preempted=preempted,
                         admission_blocked=admission_blocked,
-                        prefix_hit_tokens=prefix_hit_tokens)
+                        prefix_hit_tokens=prefix_hit_tokens,
+                        deferred=deferred)
 
     def apply(self, plan: StepPlan, next_tokens: Dict[int, object],
               now_s: float = 0.0) -> List[Request]:

@@ -24,6 +24,10 @@ def stats_zero() -> Dict[str, float]:
         # block-table entries the attention kernel walked (the live pages of
         # rows with tokens to feed) of those the steps' tables held
         "kv_pages": 0, "table_pages": 0,
+        # positions the steps' programs computed (fed tokens over them is the
+        # steps' fill), and prefill rows the mixed step's token budget left
+        # out of a step
+        "slot_tokens": 0, "deferred_rows": 0,
         # recurrent state beside the pages: bytes held by live engines, and
         # rows whose state a step zeroed (a request's first chunk in a slot)
         "state_bytes": 0, "state_resets": 0,

@@ -123,8 +123,11 @@ def test_the_module_still_loads_without_jax_until_a_span_is_opened():
 @pytest.fixture(scope="module")
 def engine_trace(tmp_path_factory):
     """A debug-width engine drained under a CPU profiler trace: the slice
-    and, step by step, what the scheduler planned."""
-    eng = tiny_engine()
+    and, step by step, what the scheduler planned.  Its mixed step computes
+    a budget of 8 tokens, not its 4 x 4 positions, so that three prompts at
+    once make the scheduler defer a row."""
+    from test_token_major import with_budget
+    eng = with_budget(tiny_engine(), 8)
     for i in range(3):
         eng.add_request(list(range(1, 8 + 3 * i)), 4 + i)
     eng.step()                          # compile the prefill bucket
@@ -199,7 +202,13 @@ def test_the_engine_step_spans_arguments_are_what_the_scheduler_planned(
         kv = [s.seq_len for s in plan.seqs]
         assert got["bucket"] == plan.bucket and got["rows"] == len(q)
         assert got["fed_tokens"] == sum(q)
-        assert got["slot_tokens"] == eng.max_running * plan.bucket
+        # the positions the step's program computes: a row each in a decode
+        # step, the scheduler's token budget in a mixed one
+        assert got["slot_tokens"] == (eng.max_running if plan.bucket == 1
+                                      else eng.scheduler.step_tokens) == \
+            (4 if plan.bucket == 1 else 8) >= got["fed_tokens"]
+        assert got["deferred_rows"] == len(plan.deferred)
+        assert got["rows"] + got["deferred_rows"] <= eng.max_running
         assert got["kv_tokens"] == sum(kv)
         assert got["qk_pairs"] == sum(a * b for a, b in zip(q, kv))
         assert got["decode_rows"] == sum(n == 1 for n in q)
@@ -210,6 +219,8 @@ def test_the_engine_step_spans_arguments_are_what_the_scheduler_planned(
         assert 0 < got["kv_pages"] < got["table_pages"]
     assert [a["step"] for a in args] == \
         list(range(args[0]["step"], args[0]["step"] + len(args)))
+    assert sum(a["deferred_rows"] for a in args) > 0
+    assert any(a["deferred_rows"] == 0 and a["bucket"] > 1 for a in args)
 
 
 def test_serving_stats_sum_the_walked_pages_and_the_tables_entries():
