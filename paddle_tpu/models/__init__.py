@@ -4,15 +4,19 @@
   Llama-2 pretrain north star), optional MoE layers, ring-attention CP.
 - jamba: Mamba-1 layers with an attention layer every few (forward and the
   serving engine's ragged step with per-slot recurrent state; no train step).
+- phi4flash: Mamba-1 and window attention interleaved, then gated memory
+  units and cross layers on one full layer's K/V (forward and the serving
+  engine's step with window rings per slot; no train step).
 - gpt: GPT-2-style decoder (learned positions, fused QKV, GELU, tied head).
 - ernie: encoder pretraining family (MLM+NSP).
 - decoding: shared KV-cache autoregressive generation.
 """
 from . import llama  # noqa: F401
 from . import jamba  # noqa: F401
+from . import phi4flash  # noqa: F401
 from . import gpt  # noqa: F401
 from . import ernie  # noqa: F401
 from . import decoding  # noqa: F401
 from . import convert  # noqa: F401
 
-__all__ = ["llama", "jamba", "gpt", "ernie", "decoding", "convert"]
+__all__ = ["llama", "jamba", "phi4flash", "gpt", "ernie", "decoding", "convert"]

@@ -396,7 +396,8 @@ def init_cache(cfg: JambaConfig, slots: int, num_pages: int, page_size: int,
             "v_pages": jnp.zeros(pool, kv_dtype), "conv": conv, "ssm": ssm}
 
 
-def cache_bytes(cfg: JambaConfig, kv_dtype_bytes: int = 2) -> dict:
+def cache_bytes(cfg: JambaConfig, kv_dtype_bytes: int = 2,
+                page_size: int = 128) -> dict:
     """What the cache costs: K/V bytes a token (attention layers only),
     scale bytes a page (none: no quantized pages) and recurrent-state bytes
     a slot, whatever the length of the request in it."""

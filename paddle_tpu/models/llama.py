@@ -854,7 +854,8 @@ def init_cache(cfg: LlamaConfig, slots: int, num_pages: int, page_size: int,
     return cache
 
 
-def cache_bytes(cfg: LlamaConfig, kv_dtype_bytes: int = 2) -> dict:
+def cache_bytes(cfg: LlamaConfig, kv_dtype_bytes: int = 2,
+                page_size: int = 128) -> dict:
     """What the cache costs: K/V bytes a token over all layers, scale bytes
     a page (two float32 a layer and K/V head beside sub-2-byte pages) and
     bytes a slot (none: no state but the pages)."""

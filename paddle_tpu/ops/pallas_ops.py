@@ -1816,14 +1816,21 @@ def _rpa_group_pages(nkv, Tr, d, page, itemsize, Bmax):
 
 def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
               q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, m_s,
-              l_s, acc_s, *, page, rep, scale):
+              l_s, acc_s, *, page, rep, scale, window=None):
     """Grid step r: request r's q rows, all kv heads, against its live kv
     pages in layer ``layer_ref[0]`` of the stacked pools, ``G`` pages a
     loop turn (``kbuf``/``vbuf`` are ``[2, nkv, G * page, d]``: two slots
     for the double buffer).  With ``ksc_ref``/``vsc_ref`` (int8 pools)
     a page's dequant scale multiplies its score columns, and its
     probability columns before PV: the same products as a dequantized
-    page gives, taken after the copy landed and off the [page, d] tile."""
+    page gives, taken after the copy landed and off the [page, d] tile.
+
+    With ``window = W`` a q row at position p sees the keys ``p - W < j <=
+    p``: the row's walk starts at the page of the first key its first q row
+    sees, ``max(0, kvlen - qlen - W + 1) // page``, not at page 0, and the
+    mask gains the lower bound.  The pages before are never copied, so a
+    table may map them anywhere (a ring of pages: ``models/phi4flash.py``).
+    ``window=None`` traces exactly the walk from page 0."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     r = pl.program_id(0)
@@ -1839,6 +1846,23 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
             qlens_ref[row] > 0,
             jnp.minimum(pl.cdiv(lens_ref[row], page), Bmax), 0)
 
+    def first_page(row):
+        """The first page row ``row`` walks (None: page 0, no window)."""
+        if window is None:
+            return None
+        return jnp.maximum(
+            lens_ref[row] - qlens_ref[row] - window + 1, 0) // page
+
+    def walked(row):
+        """How many pages row ``row`` walks, from its first page on."""
+        if window is None:
+            return live_pages(row)
+        return jnp.maximum(live_pages(row) - first_page(row), 0)
+
+    def page_at(first, k):
+        """The table column of the k-th page of a walk."""
+        return k if first is None else first + k
+
     def copies(page_of, g, slot):
         """K's and V's copy of one page into place g of ``slot``."""
         for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
@@ -1850,9 +1874,10 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
     def in_group(i, live):
         return jnp.clip(live - i * G, 0, G)      # live pages of group i
 
-    def start(row, i, slot, live):
+    def start(row, first, i, slot, live):
         def one(g, carry):
-            for copy in copies(tbl_ref[row, i * G + g], g, slot):
+            for copy in copies(tbl_ref[row, page_at(first, i * G + g)], g,
+                               slot):
                 copy.start()
             return carry
         lax.fori_loop(0, in_group(i, live), one, 0)
@@ -1864,10 +1889,15 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
             return carry
         lax.fori_loop(0, in_group(i, live), one, 0)
 
-    live = live_pages(r)
+    live, first = walked(r), first_page(r)
     n = pl.cdiv(live, G)
     nxt = jnp.minimum(r + 1, R - 1)
-    live_nxt = jnp.where(r + 1 < R, live_pages(nxt), 0)
+    live_nxt, first_nxt = jnp.where(r + 1 < R, walked(nxt), 0), \
+        first_page(nxt)
+
+    def either(more, mine, next_rows):
+        """This row's or the next row's first page, by ``more``."""
+        return None if mine is None else jnp.where(more, mine, next_rows)
 
     @pl.when(r == 0)
     def _first_row():
@@ -1876,7 +1906,7 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
         kbuf[...] = jnp.zeros_like(kbuf)
         vbuf[...] = jnp.zeros_like(vbuf)
         slot_ref[0] = 0
-        start(r, 0, 0, live)
+        start(r, first, 0, 0, live)
 
     slot0 = slot_ref[0]      # where this row's first group lands
     m_s[...] = jnp.full_like(m_s, _NEG_BIG)
@@ -1887,27 +1917,34 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
 
     @pl.when(n == 0)
     def _idle_row():
-        start(nxt, 0, slot0, live_nxt)
+        start(nxt, first_nxt, 0, slot0, live_nxt)
 
     tok = lax.broadcasted_iota(jnp.int32, (Tr, span), 0) // rep
     col = lax.broadcasted_iota(jnp.int32, (Tr, span), 1)
     # a q row sees the keys up to its own position; a padding row none
     horizon = jnp.where(tok < qlen, kvlen - qlen + tok, -1)
+    # a key column's position, less the group's offset: the walk starts at
+    # page 0 unless a window moved it
+    pos = col if window is None else first * page + col
 
     def group(i, carry):
         slot = (slot0 + i) % 2
         # the next group of this row, or the first of the next row
         more = i + 1 < n
-        start(jnp.where(more, r, nxt), jnp.where(more, i + 1, 0), 1 - slot,
+        start(jnp.where(more, r, nxt), either(more, first, first_nxt),
+              jnp.where(more, i + 1, 0), 1 - slot,
               jnp.where(more, live, live_nxt))
         wait(i, slot, live)
-        mask = i * span + col <= horizon
+        mask = i * span + pos <= horizon
+        if window is not None:
+            mask &= i * span + pos > horizon - window
 
         def page_scales(sc_ref, h):
             """[1, span]: each key column's page's dequant scale."""
             out = jnp.zeros((1, span), jnp.float32)
             for g in range(G):
-                pg = tbl_ref[r, jnp.minimum(i * G + g, Bmax - 1)]
+                pg = tbl_ref[r, jnp.minimum(page_at(first, i * G + g),
+                                            Bmax - 1)]
                 out = jnp.where(col[:1] // page == g, sc_ref[h, pg], out)
             return out
 
@@ -2009,13 +2046,14 @@ def _rpa_operands(k_pages, v_pages, k_scales, v_scales, layer):
 
 def _ragged_attention_jnp(q, k_pages, v_pages, block_tables, seq_lens,
                           q_lens, rep, k_scales=None, v_scales=None,
-                          layer=0):
+                          layer=0, window=None):
     """Reference implementation and CPU fallback: gather every
     request's pages of layer ``layer`` straight out of the stacked pools
     into a dense [R, Bmax*page] kv span, mask, softmax.  The kernel's
     semantics (same ``_NEG_BIG`` masking, f32 accumulation, exact-zero
-    padding rows).  With per-page scales (quantized int8 pools), pages
-    dequant at the gather.  Takes 4-D pools like ``_rpa_call``."""
+    padding rows; with ``window`` the keys ``p - window < j <= p`` only).
+    With per-page scales (quantized int8 pools), pages dequant at the
+    gather.  Takes 4-D pools like ``_rpa_call``."""
     k_pages, v_pages, k_scales, v_scales = _rpa_operands(
         k_pages, v_pages, k_scales, v_scales, layer)
     R, nkv, Tr, d = q.shape
@@ -2043,6 +2081,8 @@ def _ragged_attention_jnp(q, k_pages, v_pages, block_tables, seq_lens,
     mask = ((kpos[None, None, :] <= qpos[:, :, None])
             & (kpos[None, None, :] < seq_lens[:, None, None])
             & (tok[None, :, None] < q_lens[:, None, None]))
+    if window is not None:
+        mask &= kpos[None, None, :] > qpos[:, :, None] - window
     s = jnp.where(mask[:, None], s, _NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("rhts,hrsd->rhtd", p, v_seq.astype(jnp.float32))
@@ -2051,7 +2091,7 @@ def _ragged_attention_jnp(q, k_pages, v_pages, block_tables, seq_lens,
 
 
 def _rpa_call(q, k_pages, v_pages, block_tables, seq_lens, q_lens, *,
-              rep, k_scales=None, v_scales=None, layer=0):
+              rep, k_scales=None, v_scales=None, layer=0, window=None):
     """Raw pallas_call for the ragged-paged-attention kernel over the
     stacked pools ``[L, nkv, P, page, d]``, which stay in HBM
     (``memory_space=pl.ANY``, no block): the stack is the kernel's
@@ -2063,7 +2103,8 @@ def _rpa_call(q, k_pages, v_pages, block_tables, seq_lens, q_lens, *,
     ``k_scales``/``v_scales`` ([L, nkv, P] f32 per-page dequant scales;
     [nkv, P] beside a 4-D pool) the same walk runs under its int8 name:
     this layer's [nkv, P] scales ride in as two more scalar-prefetch
-    operands (SMEM), indexed by the same block table."""
+    operands (SMEM), indexed by the same block table.  ``window`` (static)
+    is the walk's: see ``_rpa_walk``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     k_pages, v_pages, k_scales, v_scales = _rpa_operands(
@@ -2101,7 +2142,7 @@ def _rpa_call(q, k_pages, v_pages, block_tables, seq_lens, q_lens, *,
     )
     kern = functools.partial(
         _rpa_kernel_quant if quantized else _rpa_kernel,
-        page=page, rep=rep, scale=1.0 / math.sqrt(float(d)))
+        page=page, rep=rep, scale=1.0 / math.sqrt(float(d)), window=window)
     call = _pallas_call(
         kern, own_dma=True,
         grid_spec=grid_spec,
@@ -2130,7 +2171,7 @@ def ragged_attention_available(q_shape, kv_shape, dtype=None):
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                            q_lens, *, rep=1, k_scales=None, v_scales=None,
-                           layer=0):
+                           layer=0, window=None):
     """Mixed prefill+decode attention over a paged KV cache.
 
     q            [R, nkv, Tc*rep, d] per-request q slots (GQA: the rep
@@ -2147,6 +2188,11 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                  quantized (int8) pools; this layer's [nkv, P] are
                  sliced out (12 KB) and ride into the kernel as two
                  extra scalar-prefetch operands, pages dequant on read
+    window       static: None, or W for sliding-window attention, a q row
+                 at position p seeing the keys ``p - W < j <= p``.  A
+                 row's walk then starts at the page of the first key it
+                 sees, so the table's earlier columns are never read and
+                 may map a ring of pages (``models/phi4flash.py``)
 
     One layer's pool ``[nkv, P, page, d]`` (with ``[nkv, P]`` scales)
     takes the same path as the stack of that one layer, layer 0.
@@ -2158,10 +2204,10 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     if not ragged_attention_available(q.shape, k_pages.shape, q.dtype):
         return _ragged_attention_jnp(q, k_pages, v_pages, block_tables,
                                      seq_lens, q_lens, rep,
-                                     k_scales, v_scales, layer)
+                                     k_scales, v_scales, layer, window)
     return _rpa_call(q, k_pages, v_pages, block_tables, seq_lens,
                      q_lens, rep=rep, k_scales=k_scales, v_scales=v_scales,
-                     layer=layer)
+                     layer=layer, window=window)
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,8 @@ The three layers, bottom-up:
   * ``engine``    — ``LLMEngine``: ``add_request()`` / ``step()`` /
                     streaming ``on_token`` callbacks, one jitted
                     step of the model the configuration names
-                    (``cfg.serving``: ``models.llama``, ``models.jamba``)
+                    (``cfg.serving``: ``models.llama``, ``models.jamba``,
+                    ``models.phi4flash``)
                     on one cache pytree, plus
                     the resilience layer: bounded admission with typed
                     retriable shedding, per-request deadlines/SLOs,

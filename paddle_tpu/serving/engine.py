@@ -15,10 +15,19 @@ model with recurrent layers also fixed-size state per engine slot),
 q_lens, step_tokens=None) -> (logits [R, Tc, V] f32, cache)`` is the step
 (with ``step_tokens = T`` it computes ``T`` positions, the step's fed
 tokens row after row, and returns their logits ``[T, V]``:
-``models/step_layout.py``), ``cache_bytes(cfg, kv_dtype_bytes)`` and ``param_count(cfg)`` size it,
-``prepare_params(cfg, params)`` converts weights once at build, and
-``recurrent_state`` says whether the cache holds state that depends on
-every token fed, in order (docs/serving.md, "Recurrent state").
+``models/step_layout.py``), ``cache_bytes(cfg, kv_dtype_bytes, page_size)``
+(bytes ``per_token``, ``scales_per_page``, ``per_slot`` and, where the cache
+holds something that grows with none of them, ``fixed``) and
+``param_count(cfg)`` size it, ``prepare_params(cfg, params)`` converts weights
+once at build, and ``recurrent_state`` says whether the cache holds state
+that depends on every token fed, in order (docs/serving.md, "Recurrent
+state", "Window rings and shared K/V").  One member is optional:
+``step_counts(cfg, seq_lens, q_lens) -> dict`` of integers, called with the
+host arrays of every step that feeds the device; the engine puts its entries
+on the step's ``serve/engine_step`` span and sums them in
+``serving_stats()``.  It is how a model whose layers read the cache in ways
+the engine does not know (a window, a pool shared by several layers) counts
+what a step read: the engine learns no layer kinds.
 
 Compilation discipline: the batch is always [max_running, Tc] with
 Tc in {1, chunk}, so a serving process compiles at most two step
@@ -192,7 +201,8 @@ class _SafeCallback:
 
 class LLMEngine:
     """Continuous-batching serving engine over the model ``cfg.serving``
-    names (``models/llama.py``, ``models/jamba.py``).
+    names (``models/llama.py``, ``models/jamba.py``,
+    ``models/phi4flash.py``).
 
     Parameters mirror the capacity plan: ``page_size`` tokens per pool
     page (default 128, the lane width — the Pallas ragged-paged-attention
@@ -306,7 +316,8 @@ class LLMEngine:
         # step as one pytree: Llama's is (k_pages, v_pages) or, quantized,
         # (k_pages, v_pages, k_scales, v_scales)
         self._pools = self._fresh_pools()
-        layout = model.cache_bytes(cfg, jnp.dtype(kv_dtype).itemsize)
+        layout = model.cache_bytes(cfg, jnp.dtype(kv_dtype).itemsize,
+                                   self.page_size)
         scale_bytes = layout["scales_per_page"] * self.num_pages
         pool_bytes = (layout["per_token"] * self.page_size * self.num_pages
                       + scale_bytes)
@@ -317,8 +328,10 @@ class LLMEngine:
             bytes_per_token=layout["per_token"])
         self._pool_bytes = pool_bytes
         self._scale_bytes = scale_bytes
-        # recurrent state: a fixed size per slot, whatever is in it
-        self._state_bytes = layout["per_slot"] * self.max_running
+        # recurrent state (and window rings): a fixed size per slot,
+        # whatever is in it, and what the model holds whatever the slots
+        self._state_bytes = (layout["per_slot"] * self.max_running
+                             + layout.get("fixed", 0))
         if self._state_bytes:
             _xmem.record_reservation(
                 "serving.state", self._state_bytes, slots=self.max_running,
@@ -719,6 +732,12 @@ class LLMEngine:
                 counts.update(state_rows=int((qlens > 0).sum()),
                               state_resets=resets)
                 _STATS["state_resets"] += resets
+            model_counts = getattr(self._model, "step_counts", None)
+            if model_counts is not None:
+                # what the model's own layers read this step
+                for key, n in model_counts(self.cfg, lens, qlens).items():
+                    counts[key] = int(n)
+                    _STATS[key] = _STATS.get(key, 0) + int(n)
             whole.set_metadata(**counts)
             # build (or fetch) the bucket's executable before the guarded
             # call: a step that cannot be compiled is a broken program,

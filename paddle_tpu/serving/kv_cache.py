@@ -364,10 +364,10 @@ def plan_capacity(cfg, *, hbm_bytes: int, page_size: int = 128,
                              f"choose from {sorted(KV_DTYPE_BYTES)}")
         kv_dtype_bytes = KV_DTYPE_BYTES[kv_dtype]
     model = cfg.serving
-    layout = model.cache_bytes(cfg, kv_dtype_bytes)
+    layout = model.cache_bytes(cfg, kv_dtype_bytes, page_size)
     weights = model.param_count(cfg) * weights_dtype_bytes
     usable = int(hbm_bytes * (1.0 - headroom_fraction)) - weights \
-        - int(runtime_bytes)
+        - int(runtime_bytes) - layout.get("fixed", 0)
     scale_bytes_per_page = layout["scales_per_page"]
     page_bytes = layout["per_token"] * page_size + scale_bytes_per_page
     blocks_per_req = _cdiv(max_len, page_size)

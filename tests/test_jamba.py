@@ -68,20 +68,20 @@ def test_forward_pure_equals_the_reference_in_float32(model):
 # -- the ragged step, driven directly ----------------------------------------
 
 class Rows:
-    """``forward_paged`` on a cache of ``R`` slots, fed by hand: each call
-    of ``feed`` is one engine step over ``{slot: tokens}``; it returns the
-    logits of each slot's fed positions."""
+    """``forward_paged`` of ``module`` on a cache of ``R`` slots, fed by hand:
+    each call of ``feed`` is one engine step over ``{slot: tokens}``; it
+    returns the logits of each slot's fed positions."""
 
-    def __init__(self, model, R=3, blocks=8):
+    def __init__(self, model, R=3, blocks=8, module=jamba):
         self.cfg, self.params, _ = model
         self.R, self.blocks = R, blocks
-        self.cache = jamba.init_cache(self.cfg, R, 1 + R * blocks, PAGE,
-                                      jnp.float32)
+        self.cache = module.init_cache(self.cfg, R, 1 + R * blocks, PAGE,
+                                       jnp.float32)
         self.tbl = np.zeros((R, blocks), np.int32)
         for r in range(R):
             self.tbl[r] = 1 + r * blocks + np.arange(blocks)
         self.lens = np.zeros((R,), np.int32)
-        self.fwd = jax.jit(functools.partial(jamba.forward_paged, self.cfg))
+        self.fwd = jax.jit(functools.partial(module.forward_paged, self.cfg))
 
     def feed(self, Tc, rows):
         tokens = np.zeros((self.R, Tc), np.int32)

@@ -194,6 +194,107 @@ def test_a_4d_pool_is_the_stack_of_one_layer(quant, interpret):
 
 
 # ---------------------------------------------------------------------------
+# the window: a first live page a row, a lower bound in the mask
+# ---------------------------------------------------------------------------
+
+WINDOW, RING = 300, 5     # ceil(300 / 128) + 2 ring pages a slot
+
+
+def _window_case(rep, Tc, seed=0, L=2, R=5, nkv=2, d=128, page=128,
+                 Bmax=12):
+    """Pools behind a RING table (logical page b of slot r at pool page
+    ``1 + r x RING + b % RING``, as ``models/phi4flash.py`` builds it): rows
+    shorter than the window, exactly as long, idle, several pages longer
+    (past the ring's wrap) and a partial chunk."""
+    rng = np.random.RandomState(seed)
+    P = 1 + R * RING
+    q = jnp.asarray(rng.standard_normal((R, nkv, Tc * rep, d)), jnp.float32)
+    kp, vp = (jnp.asarray(rng.standard_normal((L, nkv, P, page, d)),
+                          jnp.float32) for _ in range(2))
+    tbl = jnp.asarray(1 + np.arange(R)[:, None] * RING
+                      + np.arange(Bmax)[None, :] % RING, jnp.int32)
+    qlens = np.array([Tc, 1, 0, Tc, max(Tc - 1, 1)], np.int32)
+    lens = np.array([100, WINDOW - 1, 0, 1100, 700], np.int32) + qlens
+    return q, kp, vp, tbl, jnp.asarray(lens), jnp.asarray(qlens)
+
+
+@pytest.mark.parametrize("Tc,rep", [(1, 4), (16, 4), (16, 1)],
+                         ids=["Tr4", "Tr64", "Tr16"])
+def test_the_windowed_kernel_walks_from_the_first_page_a_row_sees(
+        Tc, rep, interpret):
+    q, kp, vp, tbl, lens, qlens = _window_case(rep, Tc)
+    want = pallas_ops._ragged_attention_jnp(
+        q, kp, vp, tbl, lens, qlens, rep, layer=1, window=WINDOW)
+    got = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
+                               layer=1, window=WINDOW)
+    assert _maxerr(got, want) < 2e-5
+    assert not bool(jnp.any(got[2])) and bool(jnp.all(jnp.isfinite(got)))
+    traced = jax.jit(lambda l: pallas_ops.ragged_paged_attention(
+        q, kp, vp, tbl, lens, qlens, rep=rep, layer=l, window=WINDOW))(
+            jnp.int32(1))
+    assert _maxerr(traced, want) < 2e-5
+    # the window is no detail: without it the rows longer than it differ,
+    # the row shorter than it and the row exactly as long do not
+    full = pallas_ops._ragged_attention_jnp(q, kp, vp, tbl, lens, qlens, rep,
+                                            layer=1)
+    assert _maxerr(full[:2], want[:2]) == 0.0
+    for r in (3, 4):
+        assert _maxerr(full[r], want[r]) > 1e-2
+    # what lies before a row's first page is never read: the ring pages of
+    # row 3 that its walk does not reach may hold anything
+    first = (int(lens[3]) - int(qlens[3]) - WINDOW + 1) // 128
+    live = np.asarray(tbl)[3, first:-(-int(lens[3]) // 128)]
+    dead = jnp.asarray(sorted(set(np.asarray(tbl)[3]) - set(live)))
+    assert first == 6 and len(live) == 3 and len(dead) == RING - 3
+    spoiled = pallas_ops._rpa_call(
+        q, kp.at[:, :, dead].set(jnp.nan), vp.at[:, :, dead].set(jnp.nan),
+        tbl, lens, qlens, rep=rep, layer=1, window=WINDOW)
+    assert _maxerr(spoiled, got) == 0.0
+
+
+def test_the_reference_counts_the_token_itself_in_its_window():
+    """Position p sees the keys ``p - W < j <= p``: W keys with itself."""
+    q, kp, vp, tbl, lens, qlens = _window_case(1, 1, page=8, d=16, Bmax=6)
+    lens, qlens = jnp.asarray([20, 0, 0, 0, 0]), jnp.asarray([1, 0, 0, 0, 0])
+    tbl = jnp.asarray(np.arange(1, 31).reshape(5, 6), jnp.int32)
+    got = pallas_ops.ragged_paged_attention(q, kp, vp, tbl, lens, qlens,
+                                            window=4)[0, :, 0]
+    keys = kp[0, :, 1:4].reshape(2, 24, 16)[:, 16:20]     # positions 16..19
+    vals = vp[0, :, 1:4].reshape(2, 24, 16)[:, 16:20]
+    s = jnp.einsum("hd,hkd->hk", q[0, :, 0], keys) / 4.0
+    want = jnp.einsum("hk,hkd->hd", jax.nn.softmax(s, -1), vals)
+    assert _maxerr(got, want) < 1e-5
+
+
+# sha256[:16] of str(jax.make_jaxpr(_rpa_call at layer 1)) on the cases of
+# ``_stack_case``, written by the parent commit of the window (PR 31) under this suite's
+# conftest (matmul precision "highest"):
+# with ``window=None`` the kernel's program is what it was
+RPA_JAXPR_BEFORE_THE_WINDOW = {
+    (2, 16, False): "8599dc75f9e06d58", (1, 1, True): "5ddd59b9eb035616",
+    (2, 1, False): "7935b1cc0fcd1b3e"}
+
+
+@pytest.mark.parametrize("case", sorted(RPA_JAXPR_BEFORE_THE_WINDOW),
+                         ids=lambda c: f"rep{c[0]}-Tc{c[1]}-int8{c[2]}")
+def test_without_a_window_the_kernel_traces_to_what_it_was(case, interpret):
+    import hashlib
+    rep, Tc, quant = case
+    q, kp, vp, tbl, lens, qlens, scales = _stack_case(rep, Tc, quant)
+    names = ("k_scales", "v_scales")[:len(scales)]
+
+    def call(window):
+        return str(jax.make_jaxpr(lambda q, kp, vp, *sc: pallas_ops._rpa_call(
+            q, kp, vp, tbl, lens, qlens, rep=rep, layer=1, window=window,
+            **dict(zip(names, sc))))(q, kp, vp, *scales))
+
+    text = call(None)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == RPA_JAXPR_BEFORE_THE_WINDOW[case]
+    assert call(WINDOW) != text
+
+
+# ---------------------------------------------------------------------------
 # the write of the new tokens: kernel against the row scatter
 # ---------------------------------------------------------------------------
 

@@ -4,7 +4,8 @@ serves today. The test to run first for a new ``model_config``: add a row
 to ``MODELS``.
 
 What is checked: the six members are there; ``init_cache``'s leaves weigh
-exactly what ``cache_bytes`` says; ``forward_paged`` returns a cache of
+exactly what ``cache_bytes`` says (a token, a page's scales, a slot, and
+where a model says so a ``fixed`` amount, at the engine's page size); ``forward_paged`` returns a cache of
 the tree, shapes and dtypes it was given, which is what donating the
 cache into the step relies on; and ``kv_bytes_per_token``,
 ``plan_capacity`` and the engine's reservations in ``profiler.xmem``
@@ -15,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from paddle_tpu import serving
-from paddle_tpu.models import jamba, llama
+from paddle_tpu.models import jamba, llama, phi4flash
 from paddle_tpu.ops import pallas_ops
 from paddle_tpu.profiler import xmem
 
@@ -26,6 +27,7 @@ MODELS = {
     "llama-bf16": (llama, "llama-debug", None),
     "llama-int8": (llama, "llama-debug", "int8"),
     "jamba": (jamba, "jamba-debug", None),
+    "phi4flash": (phi4flash, "phi4flash-debug", None),
 }
 
 
@@ -54,7 +56,8 @@ def _nbytes(tree):
 
 def _says(layout, pages, page, slots):
     return (layout["per_token"] * page * pages
-            + layout["scales_per_page"] * pages + layout["per_slot"] * slots)
+            + layout["scales_per_page"] * pages + layout["per_slot"] * slots
+            + layout.get("fixed", 0))
 
 
 def test_the_protocol_is_whole_and_the_cache_weighs_what_it_says(served):
@@ -64,13 +67,15 @@ def test_the_protocol_is_whole_and_the_cache_weighs_what_it_says(served):
                    "param_count", "prepare_params"):
         assert callable(getattr(model, member)), member
     assert isinstance(model.recurrent_state, bool)
-    layout = model.cache_bytes(cfg, page_dtype.itemsize)
-    assert set(layout) == {"per_token", "scales_per_page", "per_slot"}
+    layout = model.cache_bytes(cfg, page_dtype.itemsize, PAGE)
+    assert set(layout) - {"fixed"} == {"per_token", "scales_per_page",
+                                       "per_slot"}
     assert (layout["per_slot"] > 0) == model.recurrent_state
     cache = model.init_cache(cfg, SLOTS, PAGES, PAGE, page_dtype)
     assert _nbytes(cache) == _says(layout, PAGES, PAGE, SLOTS)
     # and at another size: no term hides in a constant
     cache = model.init_cache(cfg, SLOTS + 2, PAGES + 4, 2 * PAGE, page_dtype)
+    layout = model.cache_bytes(cfg, page_dtype.itemsize, 2 * PAGE)
     assert _nbytes(cache) == _says(layout, PAGES + 4, 2 * PAGE, SLOTS + 2)
 
 
@@ -97,7 +102,7 @@ def test_forward_paged_hands_back_the_cache_it_was_given(served):
 
 def test_capacity_arithmetic_agrees_with_the_live_cache(served):
     cfg, params, page_dtype, kv_dtype = served
-    layout = cfg.serving.cache_bytes(cfg, page_dtype.itemsize)
+    layout = cfg.serving.cache_bytes(cfg, page_dtype.itemsize, PAGE)
     assert (serving.kv_bytes_per_token(cfg, page_dtype.itemsize)
             == layout["per_token"])
     plan = serving.plan_capacity(cfg, hbm_bytes=1 << 30, page_size=PAGE,
