@@ -20,6 +20,10 @@ depth of the ``llama1b`` preset (hidden 2048, 16 heads x 128, intermediate
   path      the Pallas kernels of the lowered train step and of both engine
             buckets are exactly the ones named below, none interpreted; no
             serve recovery, no quarantine.
+  scan      ``pallas_ops.selective_scan`` (the Mamba layers' kernel, which no
+            Llama engine runs) at the width and rows of both Mamba cells, E
+            5120, N 16, R 128 and 48, both programs, on ragged chunks
+            against its XLA body.
   4 chips   where the host has them: ``Plan(dp=2, mp=2)``, ``Plan(dp=4)`` and
             ``Plan(pp=2, mp=2)`` 1F1B, each against the one-device loss on the
             same weights and batch, with parameter shardings and per-device
@@ -67,6 +71,7 @@ TRAIN_KERNELS_MP = {"_flash_fwd_kernel_resident",
                     "_flash_bwd_dkv_kernel_resident"}
 SERVE_KERNELS = {"_rpa_kernel", "_kv_write_kernel"}
 SERVE_KERNELS_INT8 = {"_rpa_kernel_quant", "_int8_matmul_kernel"}
+SCAN_KERNEL = "_ssm_scan_kernel"
 
 # Served logits vs the float32 forward_pure on the same dense weights, as
 # ||served - ref|| / ||ref|| over the longest request's logits (prompt and
@@ -96,6 +101,11 @@ INT8_LOGITS_REL_TOL = 0.18
 # random one: about 4 sigma down at vocab 32000.
 DENSE_TOKEN_GAP = 0.24
 INT8_TOKEN_GAP = 1.08
+# The Mosaic selective scan against its XLA body on the same inputs, as max
+# |difference| over max |reference| of the state and of y's live positions:
+# float32 on both sides, the same equations in another order and with
+# another exponential, over at most 16 positions.
+SCAN_REL_TOL = 2e-5
 # dp=2 x mp=2 cross entropy against the one-chip loss on the same weights
 # and batch: the tolerance of __graft_entry__._run_variant.
 MESH_CE_TOL = 2e-4
@@ -357,6 +367,55 @@ def run_serving_passes(cfg, params, *, n_requests: int, n_new: int,
         eng.shutdown()
 
 
+def run_scan_parity(*, rows: tuple, inner: int, state: int, chunk: int,
+                    expect_kernel: bool) -> None:
+    """``selective_scan`` on layer 1 of a stack of three, ``R`` rows of
+    ragged chunks (idle rows, decode rows, a few positions, whole chunks,
+    fresh rows; NaN in the dead positions), both programs, against
+    ``_ssm_scan_jnp``; the other layers and the idle rows bit for bit."""
+    layer, M = 1, 3
+    for R in rows:
+        for Tc in (chunk, 1):
+            rng = np.random.default_rng(R * 100 + Tc)
+            q = rng.choice([0, 1, 1, 1, 1, 1, min(3, Tc), Tc], R).astype(
+                np.int32)
+            fresh = jnp.asarray((rng.random(R) < 0.25) & (q > 0))
+            dead = jnp.asarray(np.arange(Tc)[None, :] >= q[:, None])[..., None]
+
+            def normal(*shape):
+                return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+            ssm = normal(M, state, R, inner)
+            dt = jax.nn.softplus(normal(R, Tc, inner) - 2.0)
+            args = (dt, normal(R, Tc, inner), normal(R, Tc, state),
+                    normal(R, Tc, state), -jnp.exp(0.3 * normal(state, inner)))
+            tail = (jnp.asarray(q), fresh)
+            want_y, want_s = jax.jit(pallas_ops._ssm_scan_jnp)(
+                ssm, *args, *tail, layer)
+            scan = jax.jit(functools.partial(pallas_ops.selective_scan,
+                                             layer=layer))
+            poisoned = tuple(jnp.where(dead, jnp.nan, a)
+                             for a in args[:4]) + args[4:]
+            names = pallas_kernels(scan.lower(ssm, *poisoned, *tail).as_text())
+            check(names == ({SCAN_KERNEL} if expect_kernel else set()),
+                  f"selective_scan [{R}, {Tc}] runs Pallas kernels {names}")
+            got_y, got_s = (np.asarray(a) for a in scan(ssm, *poisoned, *tail))
+            err_s = float(np.abs(got_s - want_s).max() / np.abs(want_s).max())
+            live = ~np.asarray(dead)[..., 0]
+            err_y = float(np.abs(got_y - want_y)[live].max()
+                          / np.abs(np.asarray(want_y)[live]).max())
+            log(f"selective_scan [{R}, {Tc}]: state rel err {err_s:.2e}, "
+                f"y rel err {err_y:.2e}")
+            check(max(err_s, err_y) <= SCAN_REL_TOL,
+                  f"selective_scan [{R}, {Tc}] off its XLA body")
+            before = np.asarray(ssm)
+            check(all(np.array_equal(got_s[m], before[m])
+                      for m in range(M) if m != layer)
+                  and np.array_equal(got_s[layer][:, q == 0],
+                                     before[layer][:, q == 0]),
+                  f"selective_scan [{R}, {Tc}] touched state it does not own")
+
+
 def run_four_chip(cfg, *, batch: int, seq: int, steps: int) -> None:
     """Hybrid-parallel steps on four chips in this one process. What the
     compiled step does with each kernel is a rule of ``pallas_ops.kernel_axes``:
@@ -414,6 +473,11 @@ def main() -> int:
                        int8_kernels=SERVE_KERNELS_INT8)
     del params
     done("server", t0, c0)
+
+    t0, c0 = time.perf_counter(), cache_counts()
+    run_scan_parity(rows=(128, 48), inner=5120, state=16, chunk=16,
+                    expect_kernel=True)
+    done("scan", t0, c0)
 
     if device["count"] >= 4:
         t0, c0 = time.perf_counter(), cache_counts()

@@ -29,7 +29,9 @@ kernel-index-oob              error      an index_map emits a block index
                                          an entry outside that axis
 kernel-output-coverage        error      some output block is never emitted
                                          by any grid point — silent garbage
-                                         in the uncovered region
+                                         in the uncovered region (not for an
+                                         output aliased to an input: there
+                                         the input's values stay)
 kernel-mosaic-block           error      a derived block violates Mosaic
                                          tiling for the *actual* dtype
                                          (``autotune.mosaic_block_legal``)
@@ -141,6 +143,9 @@ class KernelSite:
         # input in HBM by its own DMA at indexes it takes from that scalar
         # operand — no index map carries them
         self.dma_indexes: List[Tuple[int, int, int]] = []
+        # outputs that are an input's buffer (``input_output_aliases``): a
+        # block no grid point writes holds what the input held
+        self.aliased_outputs: frozenset = frozenset()
 
     @property
     def kernel_name(self) -> str:
@@ -219,6 +224,8 @@ def _normalize_call(kernel, args, kwargs, blockspec_cls, file, line
         out_shapes=_tree_leaves(out_shape, is_leaf=is_shape),
         scratch_shapes=_tree_leaves(_as_tuple(scratch), is_leaf=is_shape),
         file=file, line=line, num_scalar_prefetch=nsp)
+    site.aliased_outputs = frozenset(
+        int(o) for o in (kwargs.get("input_output_aliases") or {}).values())
     declared = (kwargs.get("metadata") or {}).get("dma_indexes")
     if declared:
         site.dma_indexes = [tuple(int(i) for i in triple)
@@ -563,6 +570,7 @@ class _SiteChecker:
                     operand=op.label, grid_point=list(point),
                     block_index=list(idx))
             if (op.role == "out" and want_cov and exhaustive
+                    and op.index not in self.site.aliased_outputs
                     and emitted is not None and oob_hit is None):
                 required = set(itertools.product(
                     *(range(n) for n in grid_blocks)))

@@ -49,7 +49,7 @@ from .step_layout import StepLayout
 
 __all__ = ["JambaConfig", "PRESETS", "preset", "config_from_fields",
            "init_params", "param_count", "forward_pure", "forward_paged",
-           "init_cache", "cache_bytes", "SERVING"]
+           "init_cache", "cache_bytes", "step_counts", "SERVING"]
 
 
 @dataclasses.dataclass
@@ -221,24 +221,6 @@ def _ssm_conv(lp, x, conv, q_lens):
     return jax.nn.silu(jnp.stack(out, 1)), jnp.stack(new, 0)
 
 
-def _ssm_scan(ssm, dt, dx, Bm, Cm, A):
-    """``S_t = exp(dt_t A) S_{t-1} + dx_t B_t``, ``y_t = S_t C_t`` over the
-    ``Tc`` positions of a chunk: ``ssm [N, R, E]`` float32, ``dt``, ``dx [R,
-    Tc, E]``, ``Bm``, ``Cm [R, Tc, N]``, ``A [N, E]``.  Unrolled over the
-    positions as plain array expressions, no loop carry, so that XLA may
-    fuse several positions into one pass over the state instead of reading
-    and writing it once a position (on a v5e a chunk of 16 costs 1.9 ms a
-    layer alone against 2.3 ms as a ``lax.scan`` over positions: PERF.md
-    section 6, PR 27).  A position
-    with ``dt == 0`` (and so ``dx == 0``) leaves the state as it was."""
-    ys = []
-    for t in range(dt.shape[1]):
-        ssm = (jnp.exp(dt[None, :, t] * A[:, None, :]) * ssm
-               + dx[None, :, t] * Bm[:, t].T[:, :, None])
-        ys.append(jnp.sum(ssm * Cm[:, t].T[:, :, None], axis=0))
-    return jnp.stack(ys, 1), ssm
-
-
 def _layer_at(stack, l):
     """Layer ``l`` (traced) of a stack of per-layer leaves: the slice a
     ``lax.scan`` over the stack would take, from the whole stack, so that a
@@ -260,9 +242,13 @@ def _mamba_mixer(cfg, lp, h, conv, ssm, l, q_lens, fresh, lay):
     inputs go to the padded ``[R, Tc, ...]`` rows and their results come
     back flat.  The scopes ``ssm_conv`` and ``ssm_scan`` hold everything
     that touches their state and nothing else: its slice out of the stack,
-    the reset, the update and the write-back, so a reader of the scope's
-    time times every byte that ``benchmark/kernel_costs_ssm.py`` counts,
-    and the moves between the layouts stay outside them."""
+    the reset, the update and the write-back (for the scan all of it one
+    call, ``pallas_ops.selective_scan``: on the TPU a kernel that updates
+    the stack in place and walks only the live positions), so a reader of
+    the scope's time times every byte that
+    ``benchmark/kernel_costs_ssm.py`` counts, and the moves between the
+    layouts stay outside them."""
+    from ..ops.pallas_ops import selective_scan
     N, r, eps = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.rms_norm_eps
     f32 = jnp.float32
     x, z = jnp.split(_rms_norm(h, lp["ln1"], eps) @ lp["w_in"], 2, axis=-1)
@@ -276,15 +262,13 @@ def _mamba_mixer(cfg, lp, h, conv, ssm, l, q_lens, fresh, lay):
                            axis=-1)
     dt = _rms_norm(dt, lp["dt_norm"], eps) @ lp["w_dt"]
     dt = jax.nn.softplus(dt.astype(f32) + lp["b_dt"].astype(f32))
-    real = jnp.arange(lay.Tc)[None, :] < q_lens[:, None]     # [R, Tc]
-    dt = jnp.where(real[:, :, None], lay.rows(dt), 0.0)
+    dt = lay.rows(dt)
     Bm = lay.rows(_rms_norm(Bm, lp["b_norm"], eps).astype(f32))
     Cm = lay.rows(_rms_norm(Cm, lp["c_norm"], eps).astype(f32))
     with jax.named_scope("ssm_scan"):
-        s = jnp.where(fresh[None, :, None], 0, _layer_at(ssm, l))
         A = -jnp.exp(lp["A_log"].astype(f32))
-        y, s = _ssm_scan(s, dt, dt * x, Bm, Cm, A)
-        ssm = lax.dynamic_update_index_in_dim(ssm, s.astype(ssm.dtype), l, 0)
+        y, ssm = selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh,
+                                layer=l)
     y = ((lay.flat(y) + lp["D_skip"].astype(f32) * xf)
          * jax.nn.silu(z.astype(f32)))
     return y.astype(h.dtype) @ lp["w_out"], conv, ssm
@@ -357,7 +341,8 @@ def _fresh_state(cfg, rows: int):
 def forward_pure(cfg: JambaConfig, params, input_ids):
     """Logits ``[B, S, V]`` float32 of whole sequences ``[B, S]``: no
     cache, plain causal attention.  The recurrence is unrolled over S
-    (``_ssm_scan``), so this is for sequences of test length."""
+    (``pallas_ops._ssm_scan_jnp``: a chunk of S positions is no serve
+    step's), so this is for sequences of test length."""
     B, S = input_ids.shape
     rep = cfg.num_attention_heads // cfg.num_key_value_heads
     causal = jnp.tril(jnp.ones((S, S), bool))
@@ -409,6 +394,16 @@ def cache_bytes(cfg: JambaConfig, kv_dtype_bytes: int = 2,
         "per_slot": cfg.num_mamba_layers * E * (
             cfg.mamba_d_state * 4
             + (cfg.mamba_d_conv - 1) * jnp.dtype(cfg.dtype).itemsize)}
+
+
+def step_counts(cfg: JambaConfig, seq_lens, q_lens) -> dict:
+    """What one step's Mamba layers walk, a layer, from the host's arrays:
+    ``scan_positions``, the row-positions of the selective scan (8 x the
+    longest chunk of every group of 8 rows; ``R x Tc`` padded).  The engine
+    puts it on its ``serve/engine_step`` span and sums it."""
+    from ..ops.pallas_ops import scan_positions
+    del cfg, seq_lens
+    return {"scan_positions": scan_positions(q_lens)}
 
 
 def forward_paged(cfg: JambaConfig, params, tokens, cache, block_tables,
@@ -463,4 +458,4 @@ SERVING = types.SimpleNamespace(
     forward_paged=forward_paged, init_cache=init_cache,
     cache_bytes=cache_bytes, param_count=param_count,
     prepare_params=lambda cfg, params: params,   # no weight is converted
-    recurrent_state=True)
+    step_counts=step_counts, recurrent_state=True)

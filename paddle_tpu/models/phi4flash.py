@@ -103,7 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .jamba import _layer_at, _ssm_conv, _ssm_scan
+from .jamba import _layer_at, _ssm_conv
 from .step_layout import StepLayout
 
 __all__ = ["Phi4FlashConfig", "PRESETS", "preset", "config_from_fields",
@@ -327,6 +327,7 @@ def _mamba_layer(cfg, lp, h, state, l, q_lens, fresh, lay):
     and the scopes are ``jamba._mamba_mixer``'s: matmuls, norm and gate
     flat, the convolution and the scan on the padded rows, ``ssm_conv`` and
     ``ssm_scan`` around everything that touches their state."""
+    from ..ops.pallas_ops import selective_scan
     N, r, f32 = cfg.mamba_d_state, cfg.mamba_dt_rank, jnp.float32
     conv, ssm = state["conv"], state["ssm"]
     with jax.named_scope("mamba"):
@@ -342,15 +343,12 @@ def _mamba_layer(cfg, lp, h, state, l, q_lens, fresh, lay):
                                axis=-1)
         dt = jax.nn.softplus((dt @ lp["w_dt"]).astype(f32)
                              + lp["b_dt"].astype(f32))
-        real = jnp.arange(lay.Tc)[None, :] < q_lens[:, None]     # [R, Tc]
-        dt = jnp.where(real[:, :, None], lay.rows(dt), 0.0)
-        Bm, Cm = lay.rows(Bm.astype(f32)), lay.rows(Cm.astype(f32))
+        dt, Bm, Cm = (lay.rows(dt), lay.rows(Bm.astype(f32)),
+                      lay.rows(Cm.astype(f32)))
         with jax.named_scope("ssm_scan"):
-            s = jnp.where(fresh[None, :, None], 0, _layer_at(ssm, l))
             A = -jnp.exp(lp["A_log"].astype(f32))
-            y, s = _ssm_scan(s, dt, dt * x, Bm, Cm, A)
-            ssm = lax.dynamic_update_index_in_dim(ssm, s.astype(ssm.dtype),
-                                                  l, 0)
+            y, ssm = selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh,
+                                    layer=l)
         m = lay.flat(y) + lp["D_skip"].astype(f32) * xf
         out = (m * jax.nn.silu(z.astype(f32))).astype(h.dtype) @ lp["w_out"]
         h = h + out
@@ -479,7 +477,8 @@ def forward_pure(cfg: Phi4FlashConfig, params, input_ids):
     """Logits ``[B, S, V]`` float32 of whole sequences ``[B, S]``: no cache,
     plain masked attention on the same zero-padded query rows that the
     paged kernel is given.  The recurrence is unrolled over S
-    (``jamba._ssm_scan``), so this is for sequences of test length."""
+    (``pallas_ops._ssm_scan_jnp``), so this is for sequences of test
+    length."""
     B, S = input_ids.shape
     d, nkvp = cfg.head_dim, cfg.num_key_value_heads // 2
     rep = cfg.num_attention_heads // nkvp
@@ -551,18 +550,22 @@ def cache_bytes(cfg: Phi4FlashConfig, kv_dtype_bytes: int = 2,
 
 
 def step_counts(cfg: Phi4FlashConfig, seq_lens, q_lens) -> dict:
-    """What one step's window layers read, a layer, from the host's arrays
-    ``seq_lens, q_lens [R]``: ``window_kv_tokens``, over the fed rows the
-    keys some query of the row sees (``min(seq_len, W + q_len - 1)``), and
-    ``window_qk_pairs``, over the fed tokens the keys each sees (``min(p + 1,
-    W)`` at position p).  The engine puts them on its ``serve/engine_step``
-    span beside ``kv_tokens`` and ``qk_pairs``, which count the full layer."""
+    """What one step's window and Mamba layers read, a layer, from the
+    host's arrays ``seq_lens, q_lens [R]``: ``window_kv_tokens``, over the
+    fed rows the keys some query of the row sees (``min(seq_len, W + q_len -
+    1)``), ``window_qk_pairs``, over the fed tokens the keys each sees
+    (``min(p + 1, W)`` at position p), and ``scan_positions``, as
+    ``jamba.step_counts`` counts them.  The engine puts them on its
+    ``serve/engine_step`` span beside ``kv_tokens`` and ``qk_pairs``, which
+    count the full layer."""
+    from ..ops.pallas_ops import scan_positions
     W = cfg.sliding_window
     seq, q = np.asarray(seq_lens, np.int64), np.asarray(q_lens, np.int64)
     t = np.arange(int(q.max(initial=0)))[None, :]
     seen = np.minimum((seq - q)[:, None] + t + 1, W)
     return {"window_kv_tokens": int(np.minimum(seq, W + q - 1)[q > 0].sum()),
-            "window_qk_pairs": int(seen[t < q[:, None]].sum())}
+            "window_qk_pairs": int(seen[t < q[:, None]].sum()),
+            "scan_positions": scan_positions(q_lens)}
 
 
 def forward_paged(cfg: Phi4FlashConfig, params, tokens, cache, block_tables,

@@ -61,6 +61,7 @@ __all__ = ["causal_attention", "flash_attention_available",
            "tune_fused_blocks", "fused_parity_cases",
            "ragged_paged_attention", "ragged_attention_available",
            "paged_kv_write", "kv_write_available",
+           "selective_scan", "ssm_scan_available", "scan_positions",
            "int8_matmul", "int8_matmul_available",
            "int8_matmul_block_specs", "int8_matmul_candidates",
            "tune_int8_matmul", "quantize_int8"]
@@ -2379,6 +2380,311 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
 
 
 # ---------------------------------------------------------------------------
+# Selective scan of a Mamba layer in the serve step, its state in place
+# ---------------------------------------------------------------------------
+#
+# ``S_t = exp(dt_t A) S_{t-1} + (dt_t x_t) B_t``, ``y_t = S_t C_t`` over the
+# live positions of every row's chunk, on layer ``layer`` of the stacked
+# state ``ssm [M, N, R, E]`` float32, which goes in whole and comes back as
+# the same buffer (``input_output_aliases``; the layer rides in by scalar
+# prefetch, as for the K/V pools).  Grid ``(R / 8, E / Et)``: a grid point
+# holds the ``[N, 8, Et]`` tile of 8 rows' state in VMEM, read once and
+# written once whatever the chunk's length, and walks ``t`` from 0 to the
+# largest ``q_lens`` of its 8 rows: one position for a group of decode rows,
+# none for an idle group, whose tile comes back bit for bit.  A row with
+# fewer live positions than its group keeps its state past its own
+# ``q_len``: its ``dt``, ``dt x`` and ``B`` are zero there by select, so
+# whatever the dead positions of the inputs hold never reaches a state.
+#
+# The per-position inputs ``dt, x [Tc, R, E]`` are position-major, so the
+# ``[8, Et]`` slab of one position of a group is contiguous rows: position 0
+# comes with the grid's own pipeline (every live group has one), positions
+# from 1 on by the kernel's own double-buffered copies, and ``y`` goes out
+# the same way, a live position at a time, its wait left to the position
+# two later (``count_ref`` carries the slot across grid points).  So a step
+# reads and writes ``scan_positions x E`` of each, not ``R x Tc x E``, and
+# ``y`` at positions no group walks is not written at all.
+
+_SSM_ROWS = 8      # rows of a group: the float32 sublane tile of the R axis
+
+
+def scan_positions(q_lens, rows=_SSM_ROWS) -> int:
+    """The row-positions ``_ssm_scan_kernel`` walks in one layer for the
+    host's ``q_lens [R]``: every group of ``rows`` rows walks the largest
+    ``q_len`` among them.  ``R x Tc`` is what a padded scan walks."""
+    import numpy as np
+    q = np.asarray(q_lens, np.int64)
+    q = np.pad(q, (0, -len(q) % rows)).reshape(-1, rows)
+    return int(rows * q.max(axis=1).sum())
+
+
+def _ssm_scan_tile(N, E, Tc):
+    """``Et``: the widest tile of E, a multiple of the lanes that divides
+    E, whose working set fits ``_VMEM_BUDGET`` (None: no tile does).  A
+    lane of the tile costs the state's block in and out, two buffers each,
+    A's block, the two blocks of position 0 and the three scratch slabs in
+    two slots; the ``[Tc, 8, N]`` blocks of B and C pad N to the lanes, and
+    a position's columns of them take ``[N, 8, 128]`` each."""
+    per_lane = 4 * (4 * N * _SSM_ROWS + 2 * N + 10 * _SSM_ROWS)
+    fixed = 4 * (4 * Tc + 2 * N) * _SSM_ROWS * _LANES
+    for k in range(1, E // _LANES + 1):
+        Et = E // k
+        if E % k == 0 and Et % _LANES == 0 \
+                and fixed + per_lane * Et <= _VMEM_BUDGET:
+            return Et
+    return None
+
+
+def _ssm_scan_kernel(layer_ref, qlens_ref, fresh_ref, dt0_ref, x0_ref, b_ref,
+                     c_ref, a_ref, dt_hbm, x_hbm, s_in, s_out, y_hbm, dtbuf,
+                     xbuf, in_sem, ybuf, out_sem, count_ref, bp_ref, cp_ref):
+    """Grid point (g, e): rows ``8g .. 8g+7``, lanes ``e Et .. (e+1) Et`` of
+    the state of layer ``layer_ref[0]``.  ``dt0_ref``, ``x0_ref`` are the
+    group's blocks of position 0 of the arrays that ``dt_hbm``, ``x_hbm``
+    hold whole; the decode program (``Tc == 1``) reads nothing else."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    del layer_ref
+    Tc = b_ref.shape[0]
+    G8 = _SSM_ROWS
+    g, e = pl.program_id(0), pl.program_id(1)
+    N, Et = a_ref.shape
+    rows = pl.ds(pl.multiple_of(g * G8, G8), G8)
+    cols = pl.ds(pl.multiple_of(e * Et, _LANES), Et)
+
+    # the 8 rows' lengths down the sublanes of a vreg, from SMEM
+    sub = lax.broadcasted_iota(jnp.int32, (G8, _LANES), 0)
+    qv = jnp.zeros((G8, _LANES), jnp.int32)
+    fv = jnp.zeros((G8, _LANES), jnp.int32)
+    maxq = jnp.int32(0)
+    for j in range(G8):
+        q = jnp.minimum(qlens_ref[g * G8 + j], Tc)
+        maxq = jnp.maximum(maxq, q)
+        qv = jnp.where(sub == j, q, qv)
+        fv = jnp.where(sub == j, fresh_ref[g * G8 + j], fv)
+
+    @pl.when((g == 0) & (e == 0))
+    def _first_point():
+        count_ref[0] = 0
+
+    def y_copy(t, slot):
+        return pltpu.make_async_copy(ybuf.at[slot], y_hbm.at[t, rows, cols],
+                                     out_sem.at[slot])
+
+    def in_copies(t, slot):
+        for hbm, buf, which in ((dt_hbm, dtbuf, 0), (x_hbm, xbuf, 1)):
+            yield pltpu.make_async_copy(hbm.at[t, rows, cols], buf.at[slot],
+                                        in_sem.at[slot, which])
+
+    @pl.when(maxq == 0)
+    def _idle_group():
+        s_out[...] = s_in[...]
+
+    @pl.when(maxq > 0)
+    def _live_group():
+        if Tc > 1:
+            @pl.when(maxq > 1)
+            def _second_position():
+                for copy in in_copies(1, 1):
+                    copy.start()
+            dtbuf[0] = dt0_ref[0]
+            xbuf[0] = x0_ref[0]
+        keep = jnp.tile(fv, (1, Et // _LANES)) == 0
+        s_out[0] = jnp.where(keep[None], s_in[0], 0.0)
+        onehot = (lax.broadcasted_iota(jnp.int32, (N, G8, N), 0)
+                  == lax.broadcasted_iota(jnp.int32, (N, G8, N), 2))
+
+        def planes(m):
+            """``m [8, N]`` as ``[N, 8, 128]``: column n along the lanes of
+            plane n."""
+            col = jnp.sum(jnp.where(onehot, m[None], 0.0), axis=2,
+                          keepdims=True)
+            return jnp.broadcast_to(col, (N, G8, _LANES))
+
+        def position(t, carry):
+            if Tc == 1:
+                dt, x = dt0_ref[0], x0_ref[0]
+            else:
+                slot = t % 2
+
+                @pl.when(t > 0)
+                def _landed():
+                    for copy in in_copies(t, slot):
+                        copy.wait()
+
+                @pl.when((t > 0) & (t + 1 < maxq))
+                def _next_position():
+                    for copy in in_copies(t + 1, 1 - slot):
+                        copy.start()
+                dt, x = dtbuf[slot], xbuf[slot]
+            live = t < qv                                    # [8, 128]
+            livee = jnp.tile(live, (1, Et // _LANES))
+            count = count_ref[0]
+            yslot = count % 2
+
+            @pl.when(count >= 2)
+            def _slot_is_free():
+                y_copy(0, yslot).wait()
+
+            # a dead row's dt, dt x and B are zero by select
+            dt = jnp.where(livee, dt, 0.0)
+            dx = jnp.where(livee, dt * x, 0.0)
+            bp_ref[...] = planes(jnp.where(live[:, :N], b_ref[t], 0.0))
+            cp_ref[...] = planes(c_ref[t])
+
+            def plane(n, y):
+                s = (jnp.exp(dt * a_ref[pl.ds(n, 1)]) * s_out[0, n]
+                     + dx * jnp.tile(bp_ref[n], (1, Et // _LANES)))
+                s_out[0, n] = s
+                return y + s * jnp.tile(cp_ref[n], (1, Et // _LANES))
+
+            # traced once and unrolled in the lowering, where n is a constant
+            # again: a rolled loop's dynamically indexed stores order every
+            # load after them (twice the time), and the N planes written out
+            # in Python are four hundred equations that every engine start
+            # traces (PERF.md section 6, PR 32).  Whole [8, Et] rows an
+            # operation: Mosaic unrolls them over the lane tiles itself
+            ybuf[yslot] = lax.fori_loop(
+                0, N, plane, jnp.zeros((G8, Et), jnp.float32), unroll=True)
+            y_copy(t, yslot).start()
+            count_ref[0] = count + 1
+            return carry
+
+        lax.fori_loop(0, maxq, position, 0)
+
+    @pl.when((g == pl.num_programs(0) - 1) & (e == pl.num_programs(1) - 1))
+    def _last_point():
+        count = count_ref[0]
+        for back in (1, 2):
+            @pl.when(count >= back)
+            def _drain():
+                y_copy(0, (count - back) % 2).wait()
+
+
+def _ssm_scan_jnp(ssm, dt, x, Bm, Cm, A, q_lens, fresh, layer):
+    """Reference and off-TPU body of ``selective_scan``: the layer's state
+    out of its stack, the reset, the recurrence unrolled over the chunk's
+    positions as plain array expressions, no loop carry, so that XLA may
+    fuse several positions into one pass over the state (on a v5e a chunk of
+    16 costs 1.9 ms a layer alone against 2.3 ms as a ``lax.scan`` over
+    positions: PERF.md section 6, PR 27), and the write-back.  A position
+    ``t >= q_lens[r]`` has ``dt``, ``dt x`` and ``B`` zero by select and so
+    leaves the state as it was, whatever the inputs hold there."""
+    s = jnp.where(fresh[None, :, None], 0,
+                  lax.dynamic_index_in_dim(ssm, layer, 0, keepdims=False))
+    real = (jnp.arange(dt.shape[1])[None, :] < q_lens[:, None])[:, :, None]
+    dt = jnp.where(real, dt, 0.0)
+    dx = jnp.where(real, dt * x, 0.0)
+    Bm = jnp.where(real, Bm, 0.0)
+    ys = []
+    for t in range(dt.shape[1]):
+        s = (jnp.exp(dt[None, :, t] * A[:, None, :]) * s
+             + dx[None, :, t] * Bm[:, t].T[:, :, None])
+        ys.append(jnp.sum(s * Cm[:, t].T[:, :, None], axis=0))
+    return jnp.stack(ys, 1), lax.dynamic_update_index_in_dim(
+        ssm, s.astype(ssm.dtype), layer, 0)
+
+
+def _ssm_scan_call(ssm, dt, x, Bm, Cm, A, q_lens, fresh, layer, Et=None):
+    """Raw pallas_call of the selective scan: the state stack aliased in to
+    out, the rows' inputs position-major, tiles of ``Et`` lanes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    M, N, R, E = ssm.shape
+    Tc = dt.shape[1]
+    Et = Et or _ssm_scan_tile(N, E, Tc)
+    f32 = jnp.float32
+    # [Tc, R, ...]: what ``StepLayout.rows`` gathered, before its transpose
+    dt, x, Bm, Cm = (jnp.swapaxes(a.astype(f32), 0, 1)
+                     for a in (dt, x, Bm, Cm))
+    scalars = (_layer_operand(layer), q_lens.astype(jnp.int32),
+               fresh.astype(jnp.int32))
+
+    def state_map(g, e, layer, *rest):
+        del rest
+        return (layer[0], 0, g, e)
+
+    pos0 = pl.BlockSpec((1, _SSM_ROWS, Et), lambda g, e, *s: (0, g, e))
+    cols = pl.BlockSpec((Tc, _SSM_ROWS, N), lambda g, e, *s: (0, g, 0))
+    a_spec = pl.BlockSpec((N, Et), lambda g, e, *s: (0, e))
+    state = pl.BlockSpec((1, N, _SSM_ROWS, Et), state_map)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    slab = pltpu.VMEM((2, _SSM_ROWS, Et), f32)
+    planes = pltpu.VMEM((N, _SSM_ROWS, _LANES), f32)     # B_t's, C_t's columns
+    operands = (dt, x, Bm, Cm, A.astype(f32), dt, x, ssm)
+    call = _pallas_call(
+        _ssm_scan_kernel, own_dma=True,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(R // _SSM_ROWS, E // Et),
+            in_specs=[pos0, pos0, cols, cols, a_spec, in_hbm, in_hbm, state],
+            out_specs=[state, in_hbm],
+            scratch_shapes=[slab, slab, pltpu.SemaphoreType.DMA((2, 2)),
+                            slab, pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32), planes, planes]),
+        out_shape=[jax.ShapeDtypeStruct(ssm.shape, ssm.dtype),
+                   jax.ShapeDtypeStruct((Tc, R, E), f32)],
+        # operand numbers count the scalars: the state stack is the last
+        input_output_aliases={len(scalars) + len(operands) - 1: 0},
+        # a point leaves the wait for its last y to a later one: in order
+        compiler_params=_compiler_params("arbitrary", "arbitrary"),
+    )
+    ssm, y = call(*scalars, *operands)
+    return jnp.swapaxes(y, 0, 1), ssm
+
+
+# A model calls the scan from every run of Mamba layers it scans over (three
+# in Jamba's step): as one jitted function the kernel is traced and lowered
+# once a program, which every engine start pays for, compile cache or not
+# (PERF.md section 6, PR 32).  The tile is static: it follows
+# ``_VMEM_BUDGET``, which the trace cache does not see.
+_ssm_scan_jit = jax.jit(_ssm_scan_call, static_argnames="Et")
+
+
+def ssm_scan_available(ssm_shape, dtype, Tc):
+    """True when the Pallas scan can serve this state stack ``[M, N, R,
+    E]``: float32, whole groups of 8 rows, lane-aligned E, a tile within
+    ``_VMEM_BUDGET``, and a TPU backend or interpret mode."""
+    N, R, E = ssm_shape[1:]
+    if jnp.dtype(dtype) != jnp.float32 or R % _SSM_ROWS or E % _LANES \
+            or _ssm_scan_tile(N, E, Tc) is None:
+        return False
+    return _kernels_enabled("selective_scan")
+
+
+def selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh, *, layer=0):
+    """The selective scan of one Mamba layer over a step's ragged chunks,
+    its state updated in place in the stack.
+
+    ssm          [M, N, R, E] float32, the recurrent state of every Mamba
+                 layer and engine slot; returned as the same buffer with
+                 layer ``layer`` advanced
+    dt, x        [R, Tc, E] the step sizes (after softplus) and the
+                 convolution's activations of each row's chunk
+    Bm, Cm       [R, Tc, N]; A [N, E] (``-exp(A_log)``)
+    q_lens       [R] i32: row r has ``q_lens[r]`` live positions; the
+                 positions past them advance no state, whatever the inputs
+                 hold there (NaN included)
+    fresh        [R] bool: the row's chunk starts a request, its state
+                 starts from zero
+    layer        which layer of the stack (an int or a traced scalar)
+
+    Returns ``(y [R, Tc, E] float32, ssm)`` with ``S_t = exp(dt_t A)
+    S_{t-1} + (dt_t x_t) B_t`` and ``y_t = S_t C_t`` at the live positions,
+    all in float32; ``y`` at a position ``t >= q_lens[r]`` is unspecified
+    (the kernel writes only what some row of the group of 8 lives to).  On
+    the TPU a Mosaic kernel (``_ssm_scan_kernel``) for both programs,
+    ``Tc = chunk`` and ``Tc = 1``; off-TPU, for a row count that is no
+    multiple of 8 and for an E that is no multiple of 128, the XLA body."""
+    if not ssm_scan_available(ssm.shape, ssm.dtype, dt.shape[1]):
+        return _ssm_scan_jnp(ssm, dt, x, Bm, Cm, A, q_lens, fresh, layer)
+    # (the layer as an int32 array: a Python int would be another trace)
+    return _ssm_scan_jit(
+        ssm, dt, x, Bm, Cm, A, q_lens, fresh, jnp.asarray(layer, jnp.int32),
+        Et=_ssm_scan_tile(ssm.shape[1], ssm.shape[3], dt.shape[1]))
+
+
+# ---------------------------------------------------------------------------
 # int8 weight-path matmul (quantized serving)
 # ---------------------------------------------------------------------------
 #
@@ -2822,6 +3128,28 @@ def kernel_verify_cases():
 
     for name, Tc in (("paged_kv_write", 8), ("paged_kv_write_decode", 1)):
         fn, avals = kv_write_case(Tc)
+        cases.append((name, fn, avals))
+
+    # the selective scan of a Mamba layer, both programs: a layer other
+    # than 0 of a stack of Ls, two groups of 8 rows of which one holds a
+    # whole chunk beside decode rows and an idle row; concrete lengths, so
+    # the state's (layer, group, tile) index map is evaluated
+    Rs, Es, Ns = 16, 2 * _LANES, 16
+
+    def scan_case(Tc):
+        qlens = np.array([1] * 8 + [Tc, 1, 0, 1, 1, 1, 1, 1], np.int32)
+        fresh = qlens > 1
+
+        def fwd(ssm, dt, x, bm, cm, a):
+            return _ssm_scan_call(ssm, dt, x, bm, cm, a, qlens, fresh,
+                                  layer)
+        row = SDS((Rs, Tc, Es), f32)
+        col = SDS((Rs, Tc, Ns), f32)
+        return fwd, (SDS((Ls, Ns, Rs, Es), f32), row, row, col, col,
+                     SDS((Ns, Es), f32))
+
+    for name, Tc in (("selective_scan", 16), ("selective_scan_decode", 1)):
+        fn, avals = scan_case(Tc)
         cases.append((name, fn, avals))
 
     # int8 weight-path matmul at a representative lane-aligned shape

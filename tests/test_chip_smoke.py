@@ -43,6 +43,18 @@ def test_server_functions_at_debug_width():
         dense_kernels=set(), int8_kernels=set())
 
 
+@pytest.mark.parametrize("interpreted", [False, True])
+def test_scan_parity_at_debug_width(interpreted, monkeypatch):
+    """Off the chip both sides are the XLA body, or (interpreted) the kernel
+    against it: an interpreted kernel is no Mosaic call in the lowered text."""
+    monkeypatch.setattr(pallas_ops, "_INTERPRET", interpreted)
+    chip_smoke.run_scan_parity(rows=(16, 8), inner=128, state=16, chunk=4,
+                               expect_kernel=False)
+    with pytest.raises(AssertionError, match="runs Pallas kernels"):
+        chip_smoke.run_scan_parity(rows=(8,), inner=128, state=16, chunk=4,
+                                   expect_kernel=True)
+
+
 def test_server_check_reads_what_the_engine_served():
     cfg = llama.preset("llama-debug", max_position_embeddings=512)
     params = llama.init_params(cfg, jax.random.PRNGKey(1))

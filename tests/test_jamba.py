@@ -15,9 +15,10 @@ import pytest
 from benchmark import reference_jamba
 from paddle_tpu import serving
 from paddle_tpu.models import jamba, llama
+from paddle_tpu.ops import pallas_ops
 from paddle_tpu.profiler import xmem
 from paddle_tpu.testing import chaos
-from test_spans import scopes_of
+from test_spans import interpret, scopes_of  # noqa: F401 (a fixture)
 
 PAGE = 16
 
@@ -118,6 +119,31 @@ def test_prefill_in_chunks_then_decode_equals_one_full_forward(model, chunk):
     close(np.concatenate(got), want)
 
 
+def test_the_scan_kernel_prefills_in_chunks_then_decodes(model, interpret):
+    """The same through ``_ssm_scan_kernel`` under the interpreter (8 slots:
+    one group of rows): a prompt in chunks beside a shorter one, which
+    decodes while the first still prefills, then both a token a step."""
+    a, b = prompts_of(45, 20, seed=2)
+    want = ref_logits(model, [a, b])
+    rows, got = Rows(model, R=8, blocks=4), {0: [], 3: []}
+    assert pallas_ops.ssm_scan_available(rows.cache["ssm"].shape,
+                                         jnp.float32, 16)
+
+    def step(Tc, fed):
+        for r, out in rows.feed(Tc, fed).items():
+            got[r].append(out)
+
+    step(16, {0: a[:16], 3: b[:11]})
+    step(16, {0: a[16:32], 3: b[11:12]})          # a decode row in the group
+    step(16, {0: a[32:37], 3: b[12:13]})
+    for i in range(7):
+        step(1, {0: [a[37 + i]], 3: [b[13 + i]]})
+    step(1, {0: [a[44]]})                         # row 3 idle
+    close(np.concatenate(got[0]), want[0])
+    close(np.concatenate(got[3]), want[1])
+    assert not np.asarray(rows.cache["ssm"])[:, :, [1, 2, 4, 5, 6, 7]].any()
+
+
 def test_ragged_neighbours_and_a_decode_row_inside_a_chunk_bucket(model):
     a, b, c = prompts_of(30, 21, 9, seed=3)
     want = ref_logits(model, [a, b, c])
@@ -185,6 +211,19 @@ def test_the_mixers_scopes_are_in_the_step(model):
               and not s.endswith("mamba")]     # mamba itself: weight slices
         assert sorted(s.rsplit("/", 1)[1] for s in at) == [
             "ssm_conv", "ssm_conv", "ssm_scan", "ssm_scan"], (prim, at)
+    # where the kernel serves (8 rows, under the interpreter as on the chip)
+    # its call sits under the same scope, with its own name below
+    pallas_ops._INTERPRET = True
+    try:
+        cache = jax.eval_shape(lambda: jamba.init_cache(cfg, 8, 5, PAGE,
+                                                        jnp.float32))
+        found = scopes_of(functools.partial(jamba.forward_paged, cfg), params,
+                          i32(8, Tc), cache, i32(8, 2), i32(8), i32(8))
+    finally:
+        pallas_ops._INTERPRET = False
+    calls = [s for p, s in found if p == "pallas_call"]
+    assert len(calls) == 2 and all(
+        s.endswith("mamba/ssm_scan/pallas/_ssm_scan_kernel") for s in calls)
 
 
 # -- LLMEngine ---------------------------------------------------------------
@@ -229,6 +268,11 @@ def test_the_engine_serves_it_and_a_reused_slot_starts_from_zero(
     assert [eng.output_of(r) for r in rids] == expect
     stats = serving.serving_stats()
     assert stats["state_resets"] == len(prompts)
+    # three slots are one group of 8 for the scan: a step walks 8 x its
+    # longest chunk, so at least the padded slots and at most 8 a fed token
+    fed = stats["prefill_tokens"] + stats["decode_tokens"]
+    assert fed < stats["scan_positions"] <= 8 * fed
+    assert stats["scan_positions"] % 8 == 0
     assert stats["state_bytes"] == eng._state_bytes \
         == 3 * jamba.cache_bytes(eng.cfg)["per_slot"] > 0
     (held,) = [r for r in xmem.reservations() if r["name"] == "serving.state"]

@@ -159,6 +159,34 @@ def test_prefill_in_chunks_then_decode_equals_one_full_forward(model, chunk):
     close(np.concatenate(got), want)
 
 
+def test_the_scan_kernel_prefills_in_chunks_then_decodes(model):
+    """The same through ``_ssm_scan_kernel`` under the interpreter (8 slots:
+    one group of rows), a shorter request decoding beside the chunks."""
+    a, b = prompts_of(70, 20, seed=2)
+    want = ref_logits(model, [a, b])
+    pallas_ops._INTERPRET = True
+    try:
+        rows, got = test_jamba.Rows(model, R=8, module=phi4flash), \
+            {0: [], 5: []}
+        assert pallas_ops.ssm_scan_available(rows.cache["ssm"].shape,
+                                             jnp.float32, 16)
+
+        def step(Tc, fed):
+            for r, out in rows.feed(Tc, fed).items():
+                got[r].append(out)
+
+        step(16, {0: a[:16], 5: b[:11]})
+        step(16, {0: a[16:32], 5: b[11:12]})      # a decode row in the group
+        step(16, {0: a[32:48], 5: b[12:13]})
+        step(16, {0: a[48:63]})                   # row 5 idle
+        for i in range(7):
+            step(1, {0: [a[63 + i]], 5: [b[13 + i]]})
+    finally:
+        pallas_ops._INTERPRET = False
+    close(np.concatenate(got[0]), want[0])
+    close(np.concatenate(got[5]), want[1])
+
+
 def test_ragged_neighbours_and_a_decode_row_inside_a_chunk_bucket(model):
     a, b, c = prompts_of(78, 37, 9, seed=3)
     want = ref_logits(model, [a, b, c])
@@ -240,6 +268,20 @@ def test_the_layers_scopes_are_in_the_step(model):
     assert len(scans) == 2 * (1 + Tc)
     assert all(s.endswith("mamba/ssm_scan") for s in scans)
     assert not any("ssm" in s for _, s in found if "gmu" in s.split("/"))
+    # where the kernel serves (8 rows, under the interpreter as on the chip)
+    # its call sits under the same scope, with its own name below
+    pallas_ops._INTERPRET = True
+    try:
+        cache = jax.eval_shape(lambda: phi4flash.init_cache(
+            cfg, 8, 5, PAGE, jnp.float32))
+        found = scopes_of(functools.partial(phi4flash.forward_paged, cfg),
+                          params, i32(8, Tc), cache, i32(8, 2), i32(8),
+                          i32(8))
+    finally:
+        pallas_ops._INTERPRET = False
+    calls = [s for p, s in found if p == "pallas_call"]
+    assert len(calls) == 2 and all(
+        s.endswith("mamba/ssm_scan/pallas/_ssm_scan_kernel") for s in calls)
 
 
 def test_step_counts_are_what_the_window_layers_read(model):
@@ -301,6 +343,7 @@ def test_the_engine_serves_it_and_counts_what_the_model_counts(
     # at most a window of keys and at least itself
     fed = stats["prefill_tokens"] + stats["decode_tokens"]
     assert fed <= stats["window_qk_pairs"] <= 20 * fed
+    assert fed < stats["scan_positions"] <= 8 * fed
     assert 0 < stats["window_kv_tokens"] <= stats["window_qk_pairs"]
     served = [(p, eng.output_of(r)) for p, r in zip(prompts, rids)]
     verdict = reference.served_checks(model[2], eng, model[1], served)
@@ -331,6 +374,8 @@ def test_the_models_counts_are_on_the_engine_step_span(model):
     first = steps[0]
     assert first["window_kv_tokens"] == 16 and first["kv_tokens"] == 16
     assert first["window_qk_pairs"] == sum(range(1, 17))
+    assert first["scan_positions"] == 8 * 16 and steps[-1]["bucket"] == 1 \
+        and steps[-1]["scan_positions"] == 8
     second = steps[1]                                # positions 16..29
     assert second["window_kv_tokens"] == 30          # min(30, 20 + 14 - 1)
     assert second["window_qk_pairs"] == sum(min(p + 1, 20)

@@ -47,9 +47,11 @@ def test_attention_cost_by_hand():
             "qk_pairs": 16 * 1016 + 3001,
             "window_kv_tokens": (512 + 16 - 1) + 512,
             "window_qk_pairs": 17 * 512}
+    # (the two rows are one group of 8 for the scan, which walks 16)
     assert phi4flash.step_counts(
-        phi4flash.config_from_fields(config), [1016, 3001], [16, 1]) == {
-            k: step[k] for k in ("window_kv_tokens", "window_qk_pairs")}
+        phi4flash.config_from_fields(config), [1016, 3001], [16, 1]) == dict(
+            {k: step[k] for k in ("window_kv_tokens", "window_qk_pairs")},
+            scan_positions=8 * 16)
     cost = kernel_costs_phi4flash.rpa_step(config, traffic, step)
     # K and V of a token in one layer: 2 x 20 heads x 64 x 2 B = 5,120 B;
     # eight window layers read 1,039 tokens, eight layers the pool's 4,017;
