@@ -24,6 +24,15 @@ depth of the ``llama1b`` preset (hidden 2048, 16 heads x 128, intermediate
             Llama engine runs) at the width and rows of both Mamba cells, E
             5120, N 16, R 128 and 48, both programs, on ragged chunks
             against its XLA body.
+  latent    ``pallas_ops.latent_paged_attention`` and ``paged_latent_write``
+            (multi-head latent attention on paged latent vectors, which no
+            Llama engine runs) at the DeepSeek-V2-Lite cell's shapes: 32 rows,
+            16 heads on one latent of 640 lanes of which 512 are the value,
+            72 pages a row, both programs, against their XLA bodies.
+  experts   ``pallas_ops.grouped_experts`` (the routed experts' grouped
+            SwiGLU) at that cell's shapes: 64 experts of 2048 x 1408, the
+            rows of 256 and of 32 tokens' six experts each, against three
+            ``lax.ragged_dot``.
   4 chips   where the host has them: ``Plan(dp=2, mp=2)``, ``Plan(dp=4)`` and
             ``Plan(pp=2, mp=2)`` 1F1B, each against the one-device loss on the
             same weights and batch, with parameter shardings and per-device
@@ -72,6 +81,8 @@ TRAIN_KERNELS_MP = {"_flash_fwd_kernel_resident",
 SERVE_KERNELS = {"_rpa_kernel", "_kv_write_kernel"}
 SERVE_KERNELS_INT8 = {"_rpa_kernel_quant", "_int8_matmul_kernel"}
 SCAN_KERNEL = "_ssm_scan_kernel"
+LATENT_KERNELS = {"_rpa_kernel_latent", "_kv_write_kernel"}
+EXPERTS_KERNEL = "_moe_experts_kernel"
 
 # Served logits vs the float32 forward_pure on the same dense weights, as
 # ||served - ref|| / ||ref|| over the longest request's logits (prompt and
@@ -106,6 +117,16 @@ INT8_TOKEN_GAP = 1.08
 # float32 on both sides, the same equations in another order and with
 # another exponential, over at most 16 positions.
 SCAN_REL_TOL = 2e-5
+# The latent walk against the gather-and-softmax body on the same bfloat16
+# pages and queries, as ||kernel - body|| / ||body||: float32 accumulation
+# on both sides; the kernel rounds its probabilities to the pages' dtype
+# before the weighted sum (2^-9 a probability, averaged over hundreds of
+# keys) and the output to bfloat16 (2^-9): 0.004 measured on the v5e.
+LATENT_REL_TOL = 0.01
+# The grouped SwiGLU against three ragged_dot on the same bfloat16 rows and
+# weights: float32 accumulation and the same bfloat16 rounding of the
+# activation on both sides, in another order of summation.
+EXPERTS_REL_TOL = 0.01
 # dp=2 x mp=2 cross entropy against the one-chip loss on the same weights
 # and batch: the tolerance of __graft_entry__._run_variant.
 MESH_CE_TOL = 2e-4
@@ -416,6 +437,97 @@ def run_scan_parity(*, rows: tuple, inner: int, state: int, chunk: int,
                   f"selective_scan [{R}, {Tc}] touched state it does not own")
 
 
+def _rel(got, want) -> float:
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def run_latent_parity(*, rows: int, heads: int, lanes: int, v_lanes: int,
+                      blocks: int, chunk: int, page: int = 128,
+                      dtype=jnp.bfloat16, expect_kernel: bool) -> None:
+    """``paged_latent_write`` then ``latent_paged_attention`` on layer 1 of a
+    stack of three, ``rows`` requests of ragged lengths up to ``blocks``
+    pages (prefill chunks, decode rows, idle rows), both programs, against
+    their XLA bodies; what the write does not own bit for bit."""
+    layer, M = 1, 3
+    P = 1 + rows * blocks
+    tbl = jnp.asarray(1 + np.arange(rows * blocks, dtype=np.int32).reshape(
+        rows, blocks))
+    for Tc in (chunk, 1):
+        rng = np.random.default_rng(rows * 100 + Tc)
+        q = rng.choice([0, 1, 1, min(3, Tc), Tc, Tc], rows).astype(np.int32)
+        lens = np.where(q > 0, rng.integers(Tc, blocks * page, rows), 0) \
+            .astype(np.int32)
+
+        def normal(*shape):
+            return jnp.asarray(rng.standard_normal(shape), jnp.float32
+                               ).astype(dtype)
+
+        pages, new = normal(M, 1, P, page, lanes), normal(rows, Tc, 1, lanes)
+        queries = normal(rows, 1, Tc * heads, lanes)
+        tail = (tbl, jnp.asarray(lens), jnp.asarray(q))
+        write = jax.jit(functools.partial(pallas_ops.paged_latent_write,
+                                          layer=layer))
+        attend = jax.jit(functools.partial(
+            pallas_ops.latent_paged_attention, rep=heads, v_lanes=v_lanes,
+            scale=lanes ** -0.5, layer=layer))
+        names = pallas_kernels(write.lower(pages, new, *tail).as_text()) \
+            | pallas_kernels(attend.lower(queries, pages, *tail).as_text())
+        check(names == (LATENT_KERNELS if expect_kernel else set()),
+              f"latent pages [{rows}, {Tc}] run Pallas kernels {names}")
+        want_p = pallas_ops._pools_write_jnp((pages,), (new,), *tail,
+                                             layer)[0]
+        got_p = write(pages, new, *tail)
+        check(bool(jnp.array_equal(got_p, want_p)),
+              f"paged_latent_write [{rows}, {Tc}] off the XLA row scatter")
+        want = jax.jit(functools.partial(
+            pallas_ops._ragged_attention_jnp, rep=heads, layer=layer,
+            scale=lanes ** -0.5))(queries, want_p, want_p[..., :v_lanes],
+                                  *tail)
+        err = _rel(attend(queries, got_p, *tail), want)
+        log(f"latent_paged_attention [{rows}, {Tc}]: rel err {err:.2e} over "
+            f"{int(lens.sum())} cached tokens")
+        check(err <= LATENT_REL_TOL,
+              f"latent_paged_attention [{rows}, {Tc}] off its XLA body")
+
+
+def run_experts_parity(*, experts: int, hidden: int, width: int, per_token:
+                       int, tokens: tuple, dtype=jnp.bfloat16,
+                       expect_kernel: bool) -> None:
+    """``grouped_experts`` on layer 1 of a stack of two: the rows of each of
+    ``tokens`` tokens' ``per_token`` distinct experts, sorted by expert and
+    padded to whole tiles, against ``_moe_experts_jnp``."""
+    rng = np.random.default_rng(experts)
+    keys = iter(jax.random.split(jax.random.PRNGKey(experts), 8))
+
+    def normal(*shape, std=1.0):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * std).astype(dtype)
+
+    stacks = (normal(2, experts, hidden, width, std=0.02),
+              normal(2, experts, hidden, width, std=0.02),
+              normal(2, experts, width, hidden, std=0.02))
+    for T in tokens:
+        chosen = np.stack([rng.permutation(experts)[:per_token]
+                           for _ in range(T)])
+        sizes = jnp.asarray(np.bincount(chosen.reshape(-1),
+                                        minlength=experts).astype(np.int32))
+        n = T * per_token
+        xs = normal(n, hidden)
+        fn = jax.jit(functools.partial(pallas_ops.grouped_experts, layer=1))
+        names = pallas_kernels(fn.lower(xs, sizes, *stacks).as_text())
+        check(names == ({EXPERTS_KERNEL} if expect_kernel else set()),
+              f"grouped_experts [{n} rows] runs Pallas kernels {names}")
+        want = jax.jit(functools.partial(pallas_ops._moe_experts_jnp,
+                                         layer=1))(xs, sizes, *stacks)
+        err = _rel(fn(xs, sizes, *stacks)[:n], want[:n])
+        log(f"grouped_experts [{n} rows over {int((sizes > 0).sum())} of "
+            f"{experts} experts, at most {int(sizes.max())}]: rel err "
+            f"{err:.2e}")
+        check(err <= EXPERTS_REL_TOL,
+              f"grouped_experts [{n} rows] off three ragged_dot")
+
+
 def run_four_chip(cfg, *, batch: int, seq: int, steps: int) -> None:
     """Hybrid-parallel steps on four chips in this one process. What the
     compiled step does with each kernel is a rule of ``pallas_ops.kernel_axes``:
@@ -478,6 +590,16 @@ def main() -> int:
     run_scan_parity(rows=(128, 48), inner=5120, state=16, chunk=16,
                     expect_kernel=True)
     done("scan", t0, c0)
+
+    t0, c0 = time.perf_counter(), cache_counts()
+    run_latent_parity(rows=32, heads=16, lanes=640, v_lanes=512, blocks=72,
+                      chunk=16, expect_kernel=True)
+    done("latent", t0, c0)
+
+    t0, c0 = time.perf_counter(), cache_counts()
+    run_experts_parity(experts=64, hidden=2048, width=1408, per_token=6,
+                       tokens=(256, 32), expect_kernel=True)
+    done("experts", t0, c0)
 
     if device["count"] >= 4:
         t0, c0 = time.perf_counter(), cache_counts()

@@ -1831,13 +1831,31 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
     sees, ``max(0, kvlen - qlen - W + 1) // page``, not at page 0, and the
     mask gains the lower bound.  The pages before are never copied, so a
     table may map them anywhere (a ring of pages: ``models/phi4flash.py``).
-    ``window=None`` traces exactly the walk from page 0."""
+    ``window=None`` traces exactly the walk from page 0.
+
+    With ``v_hbm`` and ``vbuf`` None the pages are LATENT: a token's one
+    vector is its key, and its first ``o_ref.shape[-1]`` lanes are its value
+    (``_rpa_kernel_latent``).  A page is copied once and V is a slice of
+    the K tile already in VMEM; the probabilities go to the MXU in the
+    pages' dtype, as the scores' operands do; and a row that feeds at most
+    a 16-row tile of query rows (one token of 16 heads: a decode row inside
+    the chunk bucket) computes that tile and not its ``Tr - 16`` padding
+    rows."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     r = pl.program_id(0)
     R = pl.num_programs(0)
     Bmax = tbl_ref.shape[1]
     nkv, Tr, d = q_ref.shape[1:]
+    latent = v_hbm is None
+    dv = o_ref.shape[-1]         # V's lanes: d, or the head of a latent
+    # latent pages put every query head of a token on ONE key head, Tr =
+    # chunk x heads rows: a row of few tokens (a decode row in the chunk
+    # bucket) would pay the whole rectangle for its padding.  `short` is the
+    # fewest query rows a turn may take, whole 16-row tiles of them
+    short = -(-rep // 16) * 16 if latent else None
+    if short is not None and short >= Tr:
+        short = None
     span = kbuf.shape[2]
     G = span // page
     layer = layer_ref[0]
@@ -1866,7 +1884,8 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
 
     def copies(page_of, g, slot):
         """K's and V's copy of one page into place g of ``slot``."""
-        for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+        for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))[
+                :1 if latent else 2]:
             yield pltpu.make_async_copy(
                 hbm.at[layer, :, page_of],
                 buf.at[slot, :, pl.ds(pl.multiple_of(g * page, page), page)],
@@ -1905,7 +1924,8 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
         # a masked key's probability is an exact 0, and 0 * NaN is not:
         # what no copy has overwritten yet must be finite
         kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        if not latent:
+            vbuf[...] = jnp.zeros_like(vbuf)
         slot_ref[0] = 0
         start(r, first, 0, 0, live)
 
@@ -1949,47 +1969,66 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
                 out = jnp.where(col[:1] // page == g, sc_ref[h, pg], out)
             return out
 
-        def head(h):
-            q = q_ref[0, h]                              # [Tr, d]
+        def head(h, rows=None):
+            """Head h's turn on the group; ``rows`` (static): only the first
+            ``rows`` query rows, the others being padding."""
+            top = (lambda a: a) if rows is None else (lambda a: a[:rows])
+            at = (h,) if rows is None else (h, slice(0, rows))
+            q = q_ref[(0,) + at]                         # [Tr, d]
             k = kbuf[slot, h]                            # [span, d]
-            v = vbuf[slot, h].astype(jnp.float32)
+            v = k[:, :dv] if latent else vbuf[slot, h].astype(jnp.float32)
             if ksc_ref is not None:
                 k = k.astype(q.dtype)    # int8: exact in bf16 and float32
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
             if ksc_ref is not None:
                 s = s * page_scales(ksc_ref, h)
-            s = jnp.where(mask, s, _NEG_BIG)
-            m = m_s[h]
+            s = jnp.where(top(mask), s, _NEG_BIG)
+            m = m_s[at]
             m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
             # a masked score is _NEG_BIG under a real row's running max
             # and its exp an exact 0; padding rows, whose every score is
             # masked, gather ones here and are zeroed at the flush
             p = jnp.exp(s - _rep_cols(m_new[:, :1], span))
             corr = jnp.exp(m - m_new)
-            l_s[h] = l_s[h] * corr + jnp.sum(p, axis=-1)[:, None]
-            m_s[h] = m_new
+            l_s[at] = l_s[at] * corr + jnp.sum(p, axis=-1)[:, None]
+            m_s[at] = m_new
             if vsc_ref is not None:
                 p = p * page_scales(vsc_ref, h)
-            acc_s[h] = (acc_s[h] * _rep_cols(corr[:, :1], d)
-                        + lax.dot(p, v, preferred_element_type=jnp.float32))
+            acc_s[at] = (acc_s[at] * _rep_cols(corr[:, :1], dv)
+                         + lax.dot(p.astype(v.dtype) if latent else p, v,
+                                   preferred_element_type=jnp.float32))
 
         # unrolled: the scheduler overlaps one head's softmax with the
         # next one's matmuls (7.3 against 8.6 ms a step as a rolled loop,
         # the chat cell's shapes; PERF.md section 6, PR 28)
-        for h in range(nkv):
-            head(h)
+        if short is None:
+            for h in range(nkv):
+                head(h)
+            return carry
+
+        # a row of few tokens (a decode row inside the chunk bucket) takes
+        # its first `short` query rows through the MXU and not all Tr
+        @pl.when(qlen * rep <= short)
+        def _few_rows():
+            for h in range(nkv):
+                head(h, short)
+
+        @pl.when(qlen * rep > short)
+        def _every_row():
+            for h in range(nkv):
+                head(h)
         return carry
 
     lax.fori_loop(0, n, group, 0)
     slot_ref[0] = (slot0 + n) % 2
-    real = lax.broadcasted_iota(jnp.int32, (Tr, d), 0) // rep < qlen
+    real = lax.broadcasted_iota(jnp.int32, (Tr, dv), 0) // rep < qlen
 
     def flush(h, carry):
         l = l_s[h]
         denom = jnp.where(l == 0.0, 1.0, l)      # an idle row: 0 / 1
         o_ref[0, h] = jnp.where(
-            real, acc_s[h] / _rep_cols(denom[:, :1], d), 0.0).astype(
+            real, acc_s[h] / _rep_cols(denom[:, :1], dv), 0.0).astype(
                 o_ref.dtype)
         return carry
 
@@ -2016,6 +2055,20 @@ def _rpa_kernel_quant(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref,
     _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
               q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, m_s,
               l_s, acc_s, **tiles)
+
+
+def _rpa_kernel_latent(tbl_ref, lens_ref, qlens_ref, layer_ref, q_ref,
+                       c_hbm, o_ref, cbuf, sem, slot_ref, m_s, l_s, acc_s,
+                       **tiles):
+    """The same walk over ONE pool of latent pages (multi-head latent
+    attention in its absorbed form): a token's vector is the key of every
+    query head and its first ``o_ref.shape[-1]`` lanes their value.  A
+    sibling entry and not a flag of ``_rpa_kernel``, so that a profile and
+    the lowered text tell the latent step by name, as ``_rpa_kernel_quant``
+    tells the int8 one; the body is the one walk."""
+    _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, None, None, q_ref,
+              c_hbm, None, o_ref, cbuf, None, sem, slot_ref, m_s, l_s,
+              acc_s, **tiles)
 
 
 def _layer_operand(layer):
@@ -2047,14 +2100,16 @@ def _rpa_operands(k_pages, v_pages, k_scales, v_scales, layer):
 
 def _ragged_attention_jnp(q, k_pages, v_pages, block_tables, seq_lens,
                           q_lens, rep, k_scales=None, v_scales=None,
-                          layer=0, window=None):
+                          layer=0, window=None, scale=None):
     """Reference implementation and CPU fallback: gather every
     request's pages of layer ``layer`` straight out of the stacked pools
     into a dense [R, Bmax*page] kv span, mask, softmax.  The kernel's
     semantics (same ``_NEG_BIG`` masking, f32 accumulation, exact-zero
     padding rows; with ``window`` the keys ``p - window < j <= p`` only).
     With per-page scales (quantized int8 pools), pages dequant at the
-    gather.  Takes 4-D pools like ``_rpa_call``."""
+    gather.  Takes 4-D pools like ``_rpa_call``.  ``scale`` (default ``1 /
+    sqrt(d)``) and a V pool narrower than K's are the latent pages'
+    (``latent_paged_attention``)."""
     k_pages, v_pages, k_scales, v_scales = _rpa_operands(
         k_pages, v_pages, k_scales, v_scales, layer)
     R, nkv, Tr, d = q.shape
@@ -2069,11 +2124,12 @@ def _ragged_attention_jnp(q, k_pages, v_pages, block_tables, seq_lens,
         if scales is not None:
             seq = seq.astype(jnp.float32) \
                 * jnp.take(scales, flat, axis=1).T[:, :, None, None]
-        return seq.reshape(R, Bmax, nkv, page, d).transpose(
-            2, 0, 1, 3, 4).reshape(nkv, R, Bmax * page, d)
+        return seq.reshape(R, Bmax, nkv, page, -1).transpose(
+            2, 0, 1, 3, 4).reshape(nkv, R, Bmax * page, -1)
 
     k_seq, v_seq = span(k_pages, k_scales), span(v_pages, v_scales)
-    scale = 1.0 / math.sqrt(float(d))
+    if scale is None:
+        scale = 1.0 / math.sqrt(float(d))
     s = jnp.einsum("rhtd,hrsd->rhts", q.astype(jnp.float32),
                    k_seq.astype(jnp.float32)) * scale
     tok = jnp.arange(Tr, dtype=jnp.int32) // rep     # [Tr]
@@ -2212,6 +2268,91 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
 
 
 # ---------------------------------------------------------------------------
+# Latent pages: one vector a token that is key and value both
+# ---------------------------------------------------------------------------
+#
+# Multi-head latent attention in its absorbed form keeps, a token and layer,
+# one vector ``[c | k_pe | 0]`` (``models/deepseek_v2.py``): every query head
+# scores against the whole of it and takes the weighted sum of its first
+# ``v_lanes`` lanes.  Read as a K pool and a V pool the page would cross HBM
+# twice, which is the traffic the latent exists to save; so the walk copies a
+# page once and slices V out of the K tile in VMEM (``_rpa_walk`` with no V
+# pool).  Everything else is the walk's: one K/V "head", ``rep`` query heads
+# a token, the caller's ``scale``.
+
+def _rpa_latent_call(q, pages, block_tables, seq_lens, q_lens, *, rep,
+                     v_lanes, scale, layer=0):
+    """Raw pallas_call of ``_rpa_kernel_latent`` over the stacked latent
+    pool ``[L, 1, P, page, d]``, left in HBM like ``_rpa_call``'s pools."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    if pages.ndim == 4:
+        pages = pages[None]
+    R, nkv, Tr, d = q.shape
+    page = pages.shape[3]
+    G = _rpa_group_pages(nkv, Tr, d, page, pages.dtype.itemsize,
+                         block_tables.shape[1])
+    scalars = (block_tables, seq_lens, q_lens, _layer_operand(layer))
+
+    def row_map(r, *scalars):
+        del scalars
+        return (r, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(R,),
+        in_specs=[pl.BlockSpec((1, nkv, Tr, d), row_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, nkv, Tr, v_lanes), row_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, nkv, G * page, d), pages.dtype),  # page groups
+            pltpu.SemaphoreType.DMA((2, 1)),             # [slot, the pool]
+            pltpu.SMEM((1,), jnp.int32),                # next row's slot
+            pltpu.VMEM((nkv, Tr, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((nkv, Tr, _LANES), jnp.float32),  # running sum
+            pltpu.VMEM((nkv, Tr, v_lanes), jnp.float32),  # accumulator
+        ],
+    )
+    call = _pallas_call(
+        functools.partial(_rpa_kernel_latent, page=page, rep=rep,
+                          scale=float(scale)),
+        own_dma=True,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, nkv, Tr, v_lanes), q.dtype),
+        compiler_params=_compiler_params("arbitrary"),
+        # the block table (scalar 0) indexes axis 2 of the pool (input 1)
+        # and the layer (scalar 3) its axis 0
+        metadata={"dma_indexes": json.dumps([[0, 1, 2], [3, 1, 0]])},
+    )
+    return call(*scalars, q, pages)
+
+
+def latent_paged_attention(q, pages, block_tables, seq_lens, q_lens, *, rep,
+                           v_lanes, scale, layer=0):
+    """Mixed prefill+decode attention over paged LATENT vectors.
+
+    q            [R, 1, Tc*rep, d] the ``rep`` query heads of a token at
+                 rows tok*rep..tok*rep+rep-1, each as wide as a latent
+    pages        [L, 1, P, page, d] the stacked pool (or one layer's 4-D
+                 pool): a token's vector is its key, and its first
+                 ``v_lanes`` lanes (a multiple of 128) its value
+    scale        what the scores are multiplied by (the model's; nothing
+                 follows from ``d`` here)
+
+    Returns ``[R, 1, Tc*rep, v_lanes]``.  The ragged-batch contract, the
+    page walk and the choice between the Mosaic kernel and the jnp body are
+    ``ragged_paged_attention``'s."""
+    if not ragged_attention_available(q.shape, pages.shape, q.dtype) \
+            or v_lanes % _LANES or pages.shape[-1] % _LANES:
+        return _ragged_attention_jnp(
+            q, pages, pages[..., :v_lanes], block_tables, seq_lens, q_lens,
+            rep, layer=layer, scale=scale)
+    return _rpa_latent_call(q, pages, block_tables, seq_lens, q_lens,
+                            rep=rep, v_lanes=v_lanes, scale=scale,
+                            layer=layer)
+
+
+# ---------------------------------------------------------------------------
 # Paged KV write: a step's new tokens into the stacked pools, in place
 # ---------------------------------------------------------------------------
 #
@@ -2241,20 +2382,23 @@ def _kv_tile_rows(dtype):
 
 
 def _kv_write_kernel(layer_ref, pg_ref, sub_ref, shift_ref, qlens_ref,
-                     knew_ref, vnew_ref, kin_ref, vin_ref, kout_ref,
-                     vout_ref, *, rows, Tc):
+                     *refs, rows, Tc):
     """Grid point (r, i): the i-th ``rows``-row tile that request r's
     chunk touches, all kv heads, of layer ``layer_ref[0]``.  Row j of the
-    tile takes chunk token ``j - shift`` where that is a real token."""
+    tile takes chunk token ``j - shift`` where that is a real token.
+    ``refs`` are the new rows of every pool, then the pools in, then the
+    pools out (K and V; one pool of latent vectors)."""
     from jax.experimental import pallas as pl
     del layer_ref, pg_ref, sub_ref
+    n = len(refs) // 3
     r = pl.program_id(0)
     i = pl.program_id(1)
     qlen = qlens_ref[r]
+    kin_ref = refs[n]
     shape = kin_ref.shape[1:2] + kin_ref.shape[3:]       # (nkv, rows, d)
     tok = lax.broadcasted_iota(jnp.int32, shape, 1) - shift_ref[r, i]
-    for new_ref, in_ref, out_ref in ((knew_ref, kin_ref, kout_ref),
-                                     (vnew_ref, vin_ref, vout_ref)):
+    for new_ref, in_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                        refs[2 * n:]):
         tile = in_ref[0, :, 0].astype(jnp.float32)       # [nkv, rows, d]
         new = new_ref[0].astype(jnp.float32)             # [nkv, Tc, d]
         for t in range(Tc):
@@ -2281,14 +2425,15 @@ def _kv_write_tiles(block_tables, seq_lens, q_lens, Tc, page, rows):
             start[:, None] - pos0)
 
 
-def _kv_write_call(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
-                   q_lens, layer):
-    """Raw pallas_call of the paged KV write: pools aliased in to out."""
+def _pools_write_call(pools, news, block_tables, seq_lens, q_lens, layer):
+    """Raw pallas_call of the paged write: every pool of ``pools`` aliased
+    in to out, each taking its own new rows ``news[i] [R, Tc, nkv, d]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    R, Tc, nkv, d = k_new.shape
-    page = k_pages.shape[3]
-    rows = _kv_tile_rows(k_pages.dtype)
+    R, Tc, nkv, d = news[0].shape
+    n = len(pools)
+    page = pools[0].shape[3]
+    rows = _kv_tile_rows(pools[0].dtype)
     pg, sub, shift = _kv_write_tiles(block_tables, seq_lens, q_lens, Tc,
                                      page, rows)
     scalars = (_layer_operand(layer), pg, sub, shift, q_lens)
@@ -2308,28 +2453,26 @@ def _kv_write_call(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(R, pg.shape[1]),
-            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
-            out_specs=[pool_spec, pool_spec]),
-        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype)
-                   for p in (k_pages, v_pages)],
-        # operand numbers count the scalars: the pools are 7 and 8
-        input_output_aliases={len(scalars) + 2: 0, len(scalars) + 3: 1},
+            in_specs=[new_spec] * n + [pool_spec] * n,
+            out_specs=[pool_spec] * n),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # operand numbers count the scalars: K and V's pools are 7 and 8
+        input_output_aliases={len(scalars) + n + i: i for i in range(n)},
         compiler_params=_compiler_params("arbitrary", "arbitrary"),
     )
     # [R, Tc, nkv, d] -> [R, nkv, Tc, d]: a head's chunk rows together
     return tuple(call(*scalars,
-                      k_new.transpose(0, 2, 1, 3).astype(k_pages.dtype),
-                      v_new.transpose(0, 2, 1, 3).astype(v_pages.dtype),
-                      k_pages, v_pages))
+                      *(new.transpose(0, 2, 1, 3).astype(p.dtype)
+                        for new, p in zip(news, pools)),
+                      *pools))
 
 
-def _kv_write_jnp(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
-                  q_lens, layer):
-    """Reference and off-TPU body of ``paged_kv_write``: an XLA scatter of
+def _pools_write_jnp(pools, news, block_tables, seq_lens, q_lens, layer):
+    """Reference and off-TPU body of the paged write: an XLA scatter of
     single rows at (layer, head, page, row).  Rows, not (nkv, d) windows:
     every index leads, so the scatter works on the stack's own layout."""
-    R, Tc, nkv, d = k_new.shape
-    num_pages, page = k_pages.shape[2], k_pages.shape[3]
+    R, Tc, nkv, d = news[0].shape
+    num_pages, page = pools[0].shape[2], pools[0].shape[3]
     t_off = jnp.arange(Tc, dtype=jnp.int32)[None, :]
     qpos = (seq_lens - q_lens).astype(jnp.int32)[:, None] + t_off
     blk = jnp.clip(qpos // page, 0, block_tables.shape[1] - 1)
@@ -2342,7 +2485,7 @@ def _kv_write_jnp(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
     return tuple(
         p.at[layer, heads, pg, off].set(
             new.reshape(R * Tc, nkv, d).astype(p.dtype), mode="drop")
-        for p, new in ((k_pages, k_new), (v_pages, v_new)))
+        for p, new in zip(pools, news))
 
 
 def kv_write_available(kv_shape, dtype):
@@ -2372,11 +2515,22 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
     layer's pool.  On the TPU a Mosaic kernel (``_kv_write_kernel``);
     off-TPU and for pages or heads that are not tile-aligned an XLA
     scatter of rows."""
-    write = (_kv_write_call
+    write = (_pools_write_call
              if kv_write_available(k_pages.shape, k_pages.dtype)
-             else _kv_write_jnp)
-    return write(k_pages, v_pages, k_new, v_new, block_tables, seq_lens,
+             else _pools_write_jnp)
+    return write((k_pages, v_pages), (k_new, v_new), block_tables, seq_lens,
                  q_lens, layer)
+
+
+def paged_latent_write(pages, new, block_tables, seq_lens, q_lens, *,
+                       layer=0):
+    """``paged_kv_write`` for ONE pool: a step's new latent vectors ``new
+    [R, Tc, 1, d]`` into the stacked pool ``pages [L, 1, P, page, d]``, in
+    place, by the same kernel on one pool."""
+    write = (_pools_write_call
+             if kv_write_available(pages.shape, pages.dtype)
+             else _pools_write_jnp)
+    return write((pages,), (new,), block_tables, seq_lens, q_lens, layer)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -2682,6 +2836,174 @@ def selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh, *, layer=0):
     return _ssm_scan_jit(
         ssm, dt, x, Bm, Cm, A, q_lens, fresh, jnp.asarray(layer, jnp.int32),
         Et=_ssm_scan_tile(ssm.shape[1], ssm.shape[3], dt.shape[1]))
+
+
+# ---------------------------------------------------------------------------
+# Routed experts: one grouped SwiGLU over rows sorted by expert
+# ---------------------------------------------------------------------------
+#
+# ``y[i] = W_d[e] (silu(W_g[e] x[i]) * W_u[e] x[i])`` for the rows ``x [M, D]``
+# of a step's (token, expert) pairs sorted by expert: expert e owns the
+# ``group_sizes[e]`` rows after those of the experts before it, and rows past
+# the last group belong to none (dropped pairs, padding).  No capacity and no
+# dispatch tensor: an expert costs its weights once and a group of no rows
+# costs nothing.
+#
+# Grid ``(V,)`` of VISITS, one per (expert, 128-row tile) pair that share a
+# row, in order; ``V = M / 128 + E - 1`` bounds them whatever the routing
+# (the groups and the tiles are two partitions of one row axis), and visits
+# past the live ones hold the last live visit's blocks and do nothing.  Which
+# expert and tile a visit holds rides in by scalar prefetch, as the block
+# table rides into ``_rpa_kernel``; consecutive visits of one expert keep its
+# weights in VMEM, consecutive visits of one tile its output, so a step reads
+# every hit expert's three matrices once (17.3 MB at D 2048, F 1408: 21 us at
+# the v5e's 819 GB/s, over the three products' MXU time at 128 rows) and the
+# rows once a visit.  The weights stay the stacks of every layer ``[L, E, ..]``
+# with the layer as a scalar: nothing slices 1.1 GB out of them.
+
+_MOE_ROWS = 128    # rows of a visit's tile
+_MOE_VMEM = 100 * 2 ** 20   # two slots of an expert's three matrices, tiles
+
+
+def _expert_visits(group_sizes, M, tm=_MOE_ROWS):
+    """Which expert and which ``tm``-row tile each of the ``V = M / tm + E -
+    1`` visits holds (``[V]`` int32 each; visits past the live ones repeat
+    the last live one), the groups' first rows and ends ``[E]``, and the
+    number of live visits ``[1]``."""
+    E = group_sizes.shape[0]
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    tiles = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    vend = jnp.cumsum(tiles)
+    n = vend[-1]
+    v = jnp.minimum(jnp.arange(M // tm + E - 1, dtype=jnp.int32),
+                    jnp.maximum(n - 1, 0))
+    g = jnp.minimum(jnp.sum(v[:, None] >= vend[None, :], axis=1), E - 1)
+    tile = jnp.clip(first[g] + v - (vend - tiles)[g], 0, M // tm - 1)
+    return (g.astype(jnp.int32), tile.astype(jnp.int32), starts, ends,
+            n.reshape(1))
+
+
+def _moe_experts_kernel(layer_ref, g_ref, tile_ref, start_ref, end_ref,
+                        n_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, *, tm):
+    """Visit v: the rows of tile ``tile_ref[v]`` through expert
+    ``g_ref[v]``'s SwiGLU, kept where the row is the expert's own.  The
+    first visit of a tile zeroes what no expert owns."""
+    from jax.experimental import pallas as pl
+    del layer_ref
+    v = pl.program_id(0)
+
+    @pl.when(v < n_ref[0])
+    def _live_visit():
+        g, tile = g_ref[v], tile_ref[v]
+        x = x_ref[...]                                       # [tm, D]
+        a = lax.dot(x, wg_ref[0, 0], preferred_element_type=jnp.float32)
+        b = lax.dot(x, wu_ref[0, 0], preferred_element_type=jnp.float32)
+        h = (a * jax.nn.sigmoid(a) * b).astype(x.dtype)      # [tm, F]
+        y = lax.dot(h, wd_ref[0, 0], preferred_element_type=jnp.float32)
+        row = tile * tm + lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        mine = (row >= start_ref[g]) & (row < end_ref[g])
+        fresh = (v == 0) | (tile_ref[jnp.maximum(v - 1, 0)] != tile)
+        kept = jnp.where(fresh, 0.0, o_ref[...])
+        o_ref[...] = jnp.where(mine, y, kept).astype(o_ref.dtype)
+
+
+def _expert_stacks(w_gate, w_up, w_down):
+    """One layer's ``[E, ..]`` matrices as the stack of that one layer."""
+    if w_gate.ndim == 3:
+        return w_gate[None], w_up[None], w_down[None]
+    return w_gate, w_up, w_down
+
+
+def _moe_experts_call(xs, group_sizes, w_gate, w_up, w_down, layer=0):
+    """Raw pallas_call of the grouped SwiGLU: ``xs [M, D]`` (M a multiple
+    of 128) against the stacks ``[L, E, D, F]``, ``[L, E, D, F]``, ``[L, E,
+    F, D]``; returns ``[M, D]`` float32, rows of no group zero where a visit
+    touched their tile and undefined elsewhere."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    w_gate, w_up, w_down = _expert_stacks(w_gate, w_up, w_down)
+    M, D = xs.shape
+    E, F = w_gate.shape[1], w_gate.shape[3]
+    tm = _MOE_ROWS
+    g, tile, starts, ends, n = _expert_visits(group_sizes, M, tm)
+    scalars = (_layer_operand(layer), g, tile, starts, ends, n)
+
+    def rows_map(v, layer, g, tile, *rest):
+        del layer, g, rest
+        return (tile[v], 0)
+
+    def expert_map(v, layer, g, *rest):
+        del rest
+        return (layer[0], g[v], 0, 0)
+
+    rows = pl.BlockSpec((tm, D), rows_map)
+    call = _pallas_call(
+        functools.partial(_moe_experts_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(M // tm + E - 1,),
+            in_specs=[rows, pl.BlockSpec((1, 1, D, F), expert_map),
+                      pl.BlockSpec((1, 1, D, F), expert_map),
+                      pl.BlockSpec((1, 1, F, D), expert_map)],
+            out_specs=rows),
+        out_shape=jax.ShapeDtypeStruct((M, D), jnp.float32),
+        # a tile's output is built up over consecutive visits: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_MOE_VMEM),
+    )
+    return call(*scalars, xs, w_gate, w_up, w_down)
+
+
+def _moe_experts_jnp(xs, group_sizes, w_gate, w_up, w_down, layer=0):
+    """Reference and off-TPU body: three ``lax.ragged_dot`` over the same
+    sorted rows, the activation between them; rows of no group give zero."""
+    w_gate, w_up, w_down = (
+        w[layer] if isinstance(layer, int)
+        else lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+        for w in _expert_stacks(w_gate, w_up, w_down))
+    sizes = group_sizes.astype(jnp.int32)
+    a = lax.ragged_dot(xs, w_gate, sizes,
+                       preferred_element_type=jnp.float32)
+    b = lax.ragged_dot(xs, w_up, sizes, preferred_element_type=jnp.float32)
+    h = (a * jax.nn.sigmoid(a) * b).astype(xs.dtype)
+    return lax.ragged_dot(h, w_down, sizes,
+                          preferred_element_type=jnp.float32)
+
+
+def moe_experts_available(x_shape, w_shape, dtype=None):
+    """True when the grouped-SwiGLU kernel can serve (rows are padded to
+    whole tiles for it): lane-aligned widths, a TPU backend or interpret
+    mode."""
+    del dtype
+    if x_shape[1] % _LANES or w_shape[-1] % _LANES:
+        return False
+    return _kernels_enabled("grouped_experts")
+
+
+def grouped_experts(xs, group_sizes, w_gate, w_up, w_down, *, layer=0):
+    """The routed experts' SwiGLU over rows sorted by expert.
+
+    xs           [M, D] the (token, expert) pairs' inputs, sorted by
+                 expert; rows past ``sum(group_sizes)`` belong to none
+    group_sizes  [E] int32, rows of each expert held here, in order
+    w_gate/w_up  [L, E, D, F], w_down [L, E, F, D]: the stacks of every
+                 layer, passed whole (or one layer's ``[E, ..]``)
+    layer        which layer of the stacks (an int or a traced scalar)
+
+    Returns ``[M, D]`` float32: ``W_d[e] (silu(W_g[e] x) * W_u[e] x)`` for a
+    row of expert e; rows of no group are UNDEFINED (the kernel never
+    visits a tile that holds none), so the caller masks them.  No row is
+    dropped and no expert has a capacity."""
+    if not moe_experts_available(xs.shape, w_gate.shape, xs.dtype):
+        return _moe_experts_jnp(xs, group_sizes, w_gate, w_up, w_down, layer)
+    M = xs.shape[0]
+    xs = jnp.pad(xs, ((0, -M % _MOE_ROWS), (0, 0)))      # whole tiles of rows
+    return _moe_experts_call(xs, group_sizes, w_gate, w_up, w_down,
+                             layer)[:M]
 
 
 # ---------------------------------------------------------------------------
@@ -3121,8 +3443,8 @@ def kernel_verify_cases():
         lens_w = np.full((Rr,), page + 5 + Tc, dtype=np.int32)
 
         def fwd(kn, vn, kp, vp):
-            return _kv_write_call(kp, vp, kn, vn, tbl, lens_w, qlens,
-                                  layer)
+            return _pools_write_call((kp, vp), (kn, vn), tbl, lens_w,
+                                     qlens, layer)
         new = SDS((Rr, Tc, nkv, D), f32)
         return fwd, (new, new, kv_aval, kv_aval)
 
@@ -3151,6 +3473,47 @@ def kernel_verify_cases():
     for name, Tc in (("selective_scan", 16), ("selective_scan_decode", 1)):
         fn, avals = scan_case(Tc)
         cases.append((name, fn, avals))
+
+    # latent pages (multi-head latent attention, absorbed): the walk over
+    # ONE pool whose first 128 lanes are the value, 4 query heads on the one
+    # latent "head", both programs; and the latent's write, the write kernel
+    # on one pool.  Concrete tables, as above
+    lat_aval = SDS((Ls, 1, P, page, 2 * D), f32)
+
+    def latent_case(Tc):
+        qlens = np.full((Rr,), Tc, dtype=np.int32)
+
+        def fwd(q, pg):
+            return _rpa_latent_call(q, pg, tbl, lens, qlens, rep=4,
+                                    v_lanes=D, scale=0.1, layer=layer)
+        return fwd, (SDS((Rr, 1, Tc * 4, 2 * D), f32), lat_aval)
+
+    for name, Tc in (("latent_paged_attention", 8),
+                     ("latent_paged_attention_decode", 1)):
+        fn, avals = latent_case(Tc)
+        cases.append((name, fn, avals))
+
+    def latent_write(new, pg):
+        qlens = np.full((Rr,), 8, dtype=np.int32)
+        lens_w = np.full((Rr,), page + 5 + 8, dtype=np.int32)
+        return _pools_write_call((pg,), (new,), tbl, lens_w, qlens, layer)
+
+    cases.append(("paged_latent_write", latent_write,
+                  (SDS((Rr, 8, 1, 2 * D), f32), lat_aval)))
+
+    # the routed experts' grouped SwiGLU: three tiles of rows over five
+    # experts of a stack of Ls layers, one expert empty, one over two tiles;
+    # concrete group sizes, so the (layer, expert) and tile maps are
+    # evaluated
+    Em, Dm, Fm = 5, 2 * _LANES, _LANES
+    sizes = np.array([100, 0, 150, 3, 40], np.int32)
+
+    def experts_case(xs, wg_, wu_, wd_):
+        return _moe_experts_call(xs, sizes, wg_, wu_, wd_, layer)
+
+    cases.append(("grouped_experts", experts_case,
+                  (SDS((3 * _MOE_ROWS, Dm), f32), SDS((Ls, Em, Dm, Fm), f32),
+                   SDS((Ls, Em, Dm, Fm), f32), SDS((Ls, Em, Fm, Dm), f32))))
 
     # int8 weight-path matmul at a representative lane-aligned shape
     Mq, Kq, Nq = 256, 256, 256

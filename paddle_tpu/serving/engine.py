@@ -27,7 +27,13 @@ host arrays of every step that feeds the device; the engine puts its entries
 on the step's ``serve/engine_step`` span and sums them in
 ``serving_stats()``.  It is how a model whose layers read the cache in ways
 the engine does not know (a window, a pool shared by several layers) counts
-what a step read: the engine learns no layer kinds.
+what a step read: the engine learns no layer kinds.  What only the DEVICE
+can count (where a router sent a step's tokens) a model declares as
+``device_counts``, a tuple of names: its ``forward_paged(...,
+device_counts=True)`` then returns a third value, an int32 array of one
+number a name, which rides the step's fetch of the sampled tokens, goes on
+the same span after the fetch and is summed in ``serving_stats()`` (a name
+that ends in ``_max``: the largest seen).
 
 Compilation discipline: the batch is always [max_running, Tc] with
 Tc in {1, chunk}, so a serving process compiles at most two step
@@ -202,7 +208,7 @@ class _SafeCallback:
 class LLMEngine:
     """Continuous-batching serving engine over the model ``cfg.serving``
     names (``models/llama.py``, ``models/jamba.py``,
-    ``models/phi4flash.py``).
+    ``models/phi4flash.py``, ``models/deepseek_v2.py``).
 
     Parameters mirror the capacity plan: ``page_size`` tokens per pool
     page (default 128, the lane width — the Pallas ragged-paged-attention
@@ -493,9 +499,18 @@ class LLMEngine:
         T = self._positions(Tc)
         T = T if T < R * Tc else None
 
+        # what the model has the device count (``device_counts``): one more
+        # small result of the step, for a model that declares it
+        counted = bool(getattr(self._model, "device_counts", None))
+
         def step(params, tokens, pools, tbl, lens, qlens):
-            logits, pools = fwd(cfg, params, tokens, pools, tbl, lens, qlens,
-                                step_tokens=T)
+            if counted:
+                logits, pools, device = fwd(
+                    cfg, params, tokens, pools, tbl, lens, qlens,
+                    step_tokens=T, device_counts=True)
+            else:
+                logits, pools = fwd(cfg, params, tokens, pools, tbl, lens,
+                                    qlens, step_tokens=T)
             with jax.named_scope("sample"):
                 lay = StepLayout(qlens, Tc, T)
                 flat = logits.reshape(-1, logits.shape[-1])      # [T, V]
@@ -507,8 +522,9 @@ class LLMEngine:
                 # chk: one float per row (the max logit of its last fed
                 # token) — a cheap [R] transfer the numerics watchdog
                 # scans for NaN/Inf poisoning
-                return (lay.rows(jnp.argmax(flat, axis=-1).astype(jnp.int32)),
-                        jnp.max(flat[lay.last], axis=-1), pools)
+                out = (lay.rows(jnp.argmax(flat, axis=-1).astype(jnp.int32)),
+                       jnp.max(flat[lay.last], axis=-1), pools)
+                return out + (device,) if counted else out
 
         # the program's name in a profile: jit_serve_step_tc<Tc>
         step.__name__ = f"serve_step_tc{Tc}"
@@ -571,8 +587,9 @@ class LLMEngine:
         if self._copy_fn is None:
             def cp(pools, s, d):
                 # pages and (quantized) their dequant scales: the page
-                # axis is 2 in both
-                return tuple(p.at[:, :, d].set(p[:, :, s]) for p in pools)
+                # axis is 2 in both, whatever tree the model keeps them in
+                return jax.tree_util.tree_map(
+                    lambda p: p.at[:, :, d].set(p[:, :, s]), pools)
 
             self._copy_fn = jax.jit(
                 cp, donate_argnums=(0,) if self._donate else ())
@@ -752,7 +769,8 @@ class LLMEngine:
         try:
             with _trace.span("serve/step", step=self._steps,
                              batch=len(plan.seqs), bucket=Tc):
-                nxt = self._guarded_forward(plan, step_fn, *uploaded)
+                nxt, device = self._guarded_forward(plan, step_fn,
+                                                    *uploaded)
         except ReplicaKilled:
             # whole-replica death is the router's failure domain, not a
             # step-recoverable fault — propagate
@@ -760,6 +778,14 @@ class LLMEngine:
         except Exception as exc:  # noqa: BLE001 — classified in _recover
             return self._recover(plan, exc)
 
+        if device is not None:
+            # what the model had the device count, fetched with the tokens
+            counted = dict(zip(self._model.device_counts, map(int, device)))
+            whole.set_metadata(**counted)
+            for key, n in counted.items():
+                seen = _STATS.get(key, 0)
+                _STATS[key] = max(seen, n) if key.endswith("_max") \
+                    else seen + n
         if self._draft is not None:
             # mirror: the draft ingests the exact same feed, so its kv
             # tracks the target's fed counter in lockstep (donated
@@ -882,10 +908,11 @@ class LLMEngine:
         return [r.rid for r in finished]
 
     def _guarded_forward(self, plan: StepPlan, step_fn, tokens, tbl, lens,
-                         qlens) -> np.ndarray:
+                         qlens):
         """The device call under the serve.step watchdog phase, chaos
         point, and numerics check, on inputs already on the device.
-        Returns the sampled tokens [R]."""
+        Returns the sampled tokens ``[R, Tc]`` and the model's
+        ``device_counts`` of the step (None for a model with none)."""
         wd = self._wd()
         if wd is not None:
             wd.begin("serve.step")
@@ -894,11 +921,12 @@ class LLMEngine:
                         rids=[s.request.rid for s in plan.seqs],
                         pool=self.kv.allocator, engine=self)
             with _trace.span("serve/dispatch"):
-                nxt, chk, self._pools = step_fn(
+                nxt, chk, self._pools, *device = step_fn(
                     self.params, tokens, self._pools, tbl, lens, qlens)
             with _trace.span("serve/fetch"):
                 # the wait for the device, and the copy back
                 nxt = np.asarray(nxt)
+                device = np.asarray(device[0]) if device else None
             if _numerics.enabled():
                 rows = np.asarray(chk)[[s.slot for s in plan.seqs]]
                 _numerics.check_array(rows, "serve.step.logits",
@@ -911,7 +939,7 @@ class LLMEngine:
                 for exc in wd.poll(raise_on_expire=False):
                     if exc.phase == "serve.step":
                         raise exc
-            return nxt
+            return nxt, device
         finally:
             if wd is not None:
                 wd.end("serve.step")
@@ -983,9 +1011,9 @@ class LLMEngine:
             chaos_point("serve.step", step=self._steps,
                         rids=[r.rid for r in group],
                         pool=kv.allocator, engine=self, probe=True)
-            _, chk, _ = step_fn(
+            chk = step_fn(
                 self.params, jnp.asarray(tokens), self._fresh_pools(),
-                jnp.asarray(tbl), jnp.asarray(lens), jnp.asarray(qlens))
+                jnp.asarray(tbl), jnp.asarray(lens), jnp.asarray(qlens))[1]
             if _numerics.enabled():
                 rows = np.asarray(chk)[[s.slot for s in seqs]]
                 _numerics.check_array(rows, "serve.step.probe",

@@ -55,6 +55,25 @@ def test_scan_parity_at_debug_width(interpreted, monkeypatch):
                                    expect_kernel=True)
 
 
+@pytest.mark.parametrize("interpreted", [False, True])
+def test_latent_and_experts_parity_at_debug_width(interpreted, monkeypatch):
+    """The two phases of the DeepSeek-V2 kernels, as the scan's above."""
+    import jax.numpy as jnp
+    monkeypatch.setattr(pallas_ops, "_INTERPRET", interpreted)
+    # chunks of 8 tokens x 4 heads: rows of one and of three tokens take
+    # the walk's 16-row turn, whole chunks every row
+    chip_smoke.run_latent_parity(rows=6, heads=4, lanes=256, v_lanes=128,
+                                 blocks=3, chunk=8, dtype=jnp.float32,
+                                 expect_kernel=False)
+    chip_smoke.run_experts_parity(experts=5, hidden=128, width=128,
+                                  per_token=2, tokens=(70, 3),
+                                  dtype=jnp.float32, expect_kernel=False)
+    with pytest.raises(AssertionError, match="run Pallas kernels"):
+        chip_smoke.run_latent_parity(rows=2, heads=4, lanes=256, v_lanes=128,
+                                     blocks=2, chunk=4, dtype=jnp.float32,
+                                     expect_kernel=True)
+
+
 def test_server_check_reads_what_the_engine_served():
     cfg = llama.preset("llama-debug", max_position_embeddings=512)
     params = llama.init_params(cfg, jax.random.PRNGKey(1))

@@ -322,14 +322,14 @@ def _write_case(dtype, Tc, seed=0, L=3, nkv=2, P=17, page=128, d=128,
 def test_the_write_kernel_writes_the_new_tokens_and_nothing_else(
         dtype, Tc, layer, interpret):
     pools, new, tbl, lens, qlens = _write_case(dtype, Tc)
-    want = pallas_ops._kv_write_jnp(*pools, *new, tbl, lens, qlens,
+    want = pallas_ops._pools_write_jnp(pools, new, tbl, lens, qlens,
                                     1 if layer == "traced" else layer)
     if layer == "traced":
         got = jax.jit(lambda l: pallas_ops.paged_kv_write(
             *pools, *new, tbl, lens, qlens, layer=l))(jnp.int32(1))
         layer = 1
     else:
-        got = pallas_ops._kv_write_call(*pools, *new, tbl, lens, qlens,
+        got = pallas_ops._pools_write_call(pools, new, tbl, lens, qlens,
                                         layer)
     nkv, written = pools[0].shape[1], int(jnp.sum(qlens))
     for old, a, b, fresh in zip(pools, got, want, new):
@@ -352,7 +352,7 @@ def test_the_write_falls_back_to_the_row_scatter_off_tpu():
                                                d=16, seed=2)
     lens = jnp.minimum(lens, 20)
     got = pallas_ops.paged_kv_write(*pools, *new, tbl, lens, qlens, layer=1)
-    want = pallas_ops._kv_write_jnp(*pools, *new, tbl, lens, qlens, 1)
+    want = pallas_ops._pools_write_jnp(pools, new, tbl, lens, qlens, 1)
     assert all(bool(jnp.all(a == b)) for a, b in zip(got, want))
     assert int(jnp.sum(jnp.any(got[0] != pools[0], axis=-1))) \
         == int(jnp.sum(qlens)) * 2
@@ -384,8 +384,8 @@ def test_both_kernels_lower_for_the_tpu_on_the_stacked_pools():
             qlens = jnp.full((R,), Tc, jnp.int32)
 
             def step(q, kn, vn, kp, vp, layer):
-                kp, vp = pallas_ops._kv_write_call(
-                    kp, vp, kn, vn, tbl, lens, qlens, layer)
+                kp, vp = pallas_ops._pools_write_call(
+                    (kp, vp), (kn, vn), tbl, lens, qlens, layer)
                 return pallas_ops._rpa_call(
                     q, kp, vp, tbl, lens, qlens, rep=rep, layer=layer), kp, vp
 

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from paddle_tpu import serving
-from paddle_tpu.models import jamba, llama, phi4flash
+from paddle_tpu.models import deepseek_v2, jamba, llama, phi4flash
 from paddle_tpu.ops import pallas_ops
 from paddle_tpu.profiler import xmem
 
@@ -28,6 +28,7 @@ MODELS = {
     "llama-int8": (llama, "llama-debug", "int8"),
     "jamba": (jamba, "jamba-debug", None),
     "phi4flash": (phi4flash, "phi4flash-debug", None),
+    "deepseek_v2": (deepseek_v2, "deepseek-v2-debug", None),
 }
 
 

@@ -188,7 +188,7 @@ def _cases():
         return po.paged_kv_write(*a, layer=LAYER)
 
     def kv_write_ref(*a):
-        return po._kv_write_jnp(*a, LAYER)
+        return po._pools_write_jnp(a[:2], a[2:4], *a[4:], LAYER)
 
     cases += [
         (f"paged_kv_write[Tc={CHUNK}]", kv_write, kv_write_ref,
