@@ -1,11 +1,22 @@
 """LLMEngine: the user-facing serving front end.
 
 ``add_request()`` enqueues, ``step()`` runs one continuous-batching
-iteration (schedule -> one jitted forward_paged call -> commit), and
-streaming happens through per-request ``on_token`` callbacks.  The
-engine owns the device-side cache and threads it through the compiled
-step as one donated pytree; the scheduler and PagedKVCache own all
-host-side state.
+iteration, and streaming happens through per-request ``on_token``
+callbacks.  The engine owns the device-side cache and threads it through
+the compiled step as one donated pytree; the scheduler and PagedKVCache
+own all host-side state.
+
+One step in flight.  A call of ``step()`` schedules and builds step N+1
+from the scheduler's counts, dispatches it behind step N, which is still
+running, and only then fetches step N's tokens and commits them: the
+host's work hides behind a device step, and the device finds its next
+program queued when it ends one.  A decode row's input token may still be
+on the device then: the row carries ``IN_FLIGHT`` in its place, and the
+step program puts in the token the step before sampled, which it is
+handed as one more ``[R]`` argument.  The order falls back to fetch
+before dispatch whenever the next plan needs the results themselves (a
+draft model or the prefix cache is configured, the pool is short of
+pages) and after a failed step: docs/serving.md, "One step in flight".
 
 The model is whatever the configuration names (``cfg.serving``), through
 a protocol of a few members: ``init_cache(cfg, slots, num_pages,
@@ -110,6 +121,10 @@ from . import stats as _stats
 __all__ = ["LLMEngine", "SLOConfig", "serving_stats", "reset_stats",
            "summary_lines"]
 
+# In a step's ``tokens``, in place of a token that the step before
+# sampled and the host has not seen: the program puts that token in.
+IN_FLIGHT = -1
+
 _LOG = logging.getLogger("paddle_tpu.serving")
 
 # process-wide serving stats (Profiler "Serving" section).  The dict
@@ -144,6 +159,10 @@ def summary_lines() -> List[str]:
         lines.append(
             f"  recurrent state: {s['state_bytes'] / 2**20:.1f} MiB  "
             f"{int(s['state_resets'])} slot resets")
+    lines.append(
+        f"  pipeline: {int(s['pipelined_steps'])} steps dispatched behind "
+        f"another  {int(s['pipeline_drains'])} drains  "
+        f"{int(s['discarded_tokens'])} tokens discarded")
     if s["prefix_hit_tokens"] or s["spec_proposed"]:
         lines.append(
             f"  reuse: {int(s['prefix_hit_tokens'])} prefix-hit tokens "
@@ -354,7 +373,15 @@ class LLMEngine:
         self._donate = bool(donate_pools)
         self._step_fns: Dict[int, Callable] = {}
         self._requests: Dict[int, Request] = {}
-        self._steps = 0
+        self._steps = 0                # device steps dispatched
+        # the step on the device whose results the host has not fetched
+        self._flight: Optional[_Flight] = None
+        # the sampled token of every row of the step dispatched last, on
+        # the device: the next step's rows read their IN_FLIGHT token there
+        self._no_sampled = jax.device_put(
+            np.zeros((self.max_running,), np.int32))
+        self._sampled = self._no_sampled
+        self._fetched_s = float("-inf")   # engine clock at the last fetch
         # rids scheduled in the previous step — the edge detector for
         # per-request "admitted" trace events (incl. re-admissions)
         self._sched_rids: set = set()
@@ -382,6 +409,12 @@ class LLMEngine:
                 page_size=self.page_size, donate=self._donate)
             self._spec_k = int(spec.k)
             self.scheduler.spec_k = self._spec_k
+        # why this engine fetches every step before it plans the next:
+        # acceptance decides a verify row's length, and the prefix cache
+        # matches and registers pages by token VALUES
+        self._sync: Optional[str] = (
+            "spec" if self._draft is not None
+            else "prefix_cache" if self._prefix_enabled else None)
 
         _STATS["engines"] += 1
         _STATS["pool_bytes"] += pool_bytes
@@ -453,12 +486,16 @@ class LLMEngine:
         return self._requests[rid].error
 
     def has_work(self) -> bool:
-        return self.scheduler.has_work()
+        """Requests queued or running, or a step still in flight."""
+        return self.scheduler.has_work() or self._flight is not None
 
     def cancel(self, rid: int) -> bool:
         """Cooperative cancellation: takes effect immediately at the
-        host level (pages freed, slot opened, queue entry dropped).
-        Returns False when the request is already terminal."""
+        host level (pages freed, slot opened, queue entry dropped).  A
+        row of the request in the step in flight is discarded when that
+        step is fetched, and the step itself here, unfetched, once none
+        of its rows is wanted.  Returns False when the request is already
+        terminal."""
         req = self._requests.get(rid)
         if req is None or req.state not in (RequestState.WAITING,
                                             RequestState.RUNNING):
@@ -469,6 +506,16 @@ class LLMEngine:
         if _metrics.enabled():
             _metrics.counter("serve_cancelled_total",
                              "Requests cancelled by the caller").inc()
+        flight = self._flight
+        if flight is not None and not any(
+                map(self.scheduler.holds, flight.plan.seqs)):
+            # the last row the step in flight was wanted for: nothing waits
+            # for its results, so a drained engine has no work left
+            self._flight = None
+            _STATS["steps"] += 1
+            _STATS["discarded_tokens"] += sum(
+                int(s.produces) for s in flight.plan.seqs)
+            self.scheduler.complete(flight.plan, {}, now_s=self._clock())
         return True
 
     # -- the compiled step ----------------------------------------------
@@ -503,7 +550,10 @@ class LLMEngine:
         # small result of the step, for a model that declares it
         counted = bool(getattr(self._model, "device_counts", None))
 
-        def step(params, tokens, pools, tbl, lens, qlens):
+        def step(params, tokens, pools, tbl, lens, qlens, sampled):
+            # a token still in flight when the host built ``tokens``: the
+            # one the step before sampled for the row
+            tokens = jnp.where(tokens < 0, sampled[:, None], tokens)
             if counted:
                 logits, pools, device = fwd(
                     cfg, params, tokens, pools, tbl, lens, qlens,
@@ -522,8 +572,11 @@ class LLMEngine:
                 # chk: one float per row (the max logit of its last fed
                 # token) — a cheap [R] transfer the numerics watchdog
                 # scans for NaN/Inf poisoning
-                out = (lay.rows(jnp.argmax(flat, axis=-1).astype(jnp.int32)),
-                       jnp.max(flat[lay.last], axis=-1), pools)
+                # the last of them on its own, [R]: the next step's input
+                # where the host has not seen it yet
+                best = jnp.argmax(flat, axis=-1).astype(jnp.int32)
+                out = (lay.rows(best), jnp.max(flat[lay.last], axis=-1),
+                       pools, best[lay.last])
                 return out + (device,) if counted else out
 
         # the program's name in a profile: jit_serve_step_tc<Tc>
@@ -532,7 +585,7 @@ class LLMEngine:
         return jax.jit(
             step, donate_argnums=(2,) if self._donate else ()).lower(
             self.params, i32(R, Tc), self._pools, i32(R, self.max_blocks),
-            i32(R), i32(R))
+            i32(R), i32(R), i32(R))
 
     def _positions(self, Tc: int) -> int:
         """How many positions the program of bucket ``Tc`` computes: a row
@@ -559,7 +612,8 @@ class LLMEngine:
                       drafts: Optional[Dict[int, List[int]]] = None):
         """Host-side input assembly for one step over ``seqs``.  A
         spec row feeds its one known token followed by the draft's
-        proposals (the verify chunk)."""
+        proposals (the verify chunk).  A decode row whose token a step in
+        flight is still sampling feeds ``IN_FLIGHT``."""
         tokens = np.zeros((R, Tc), np.int32)
         tbl = np.zeros((R, Bmax), np.int32)
         lens = np.zeros((R,), np.int32)
@@ -570,7 +624,7 @@ class LLMEngine:
                 row = (req.known[req.fed:req.fed + 1]
                        + drafts[s.slot][:s.q_len - 1])
             else:
-                row = req.known[req.fed:req.fed + s.q_len]
+                row = req.known[req.fed:req.fed + s.q_len] or [IN_FLIGHT]
             tokens[s.slot, :s.q_len] = row
             tbl[s.slot] = kv.block_row(req.rid)
             lens[s.slot] = s.seq_len
@@ -636,16 +690,27 @@ class LLMEngine:
                     "Requests failed at their deadline").inc()
 
     def step(self) -> List[int]:
-        """One continuous-batching iteration.  Returns the request ids
-        that finished at this step boundary (empty list when idle,
-        still mid-flight, or after a recovered step failure).
+        """One continuous-batching iteration: dispatch the next device
+        step, then fetch and commit the one before it.  Returns the request
+        ids that finished at the step it fetched (empty list when idle,
+        still mid-flight, when it only dispatched — the first call after
+        idleness — or after a recovered step failure).  ``has_work()``
+        stays true while a step is in flight, so ``while eng.has_work():
+        eng.step()`` leaves none.
 
         Spans (``profiler.trace.span``: on the profiler's clock whenever
         a ``jax.profiler`` trace runs, in the flight recorder under
         ``FLAGS_tpu_trace``): ``serve/engine_step`` around the whole
         call, and inside it, in order, ``serve/schedule``,
         ``serve/batch``, ``serve/step`` (the guarded forward, itself
-        ``serve/dispatch`` then ``serve/fetch``) and ``serve/commit``."""
+        ``serve/dispatch`` of the new step then ``serve/fetch`` of the one
+        before) and ``serve/commit``.  A call with nothing to dispatch has
+        no ``serve/batch`` and no ``serve/dispatch``, one with nothing to
+        fetch no ``serve/fetch`` and no ``serve/commit``.  The counts on
+        ``serve/engine_step`` are those of the step the call dispatched
+        (``in_flight``: whether another was on the device then); what the
+        device counted (``device_counts``) is that of the step it
+        fetched."""
         whole = _trace.span("serve/engine_step", step=self._steps)
         with whole:
             return self._step(whole)
@@ -657,9 +722,18 @@ class LLMEngine:
         self._expire_deadlines(now)
         plan = self.scheduler.schedule()
         tracing = _trace.enabled()
-        if tracing:
-            for req in plan.preempted:
-                _trace.request_event("preempted", req.rid, t=now)
+        if plan.preempted:
+            # counted where they are decided: a plan that preempts its
+            # last row feeds nothing, and is never committed
+            _STATS["requests_preempted"] += len(plan.preempted)
+            if _metrics.enabled():
+                _metrics.counter(
+                    "serve_preemptions_total",
+                    "Requests preempted for pool pressure").inc(
+                    len(plan.preempted))
+            if tracing:
+                for req in plan.preempted:
+                    _trace.request_event("preempted", req.rid, t=now)
         for s in plan.seqs:
             req = s.request
             if tracing and req.rid not in self._sched_rids:
@@ -671,10 +745,12 @@ class LLMEngine:
                 # first admission only: preemption replay keeps the
                 # original stamp so queue time stays arrival->admission
                 req.admitted_s = now
-        # a row the budget deferred stays admitted: it is not announced
-        # again when it is next fed
-        self._sched_rids = {s.request.rid for s in plan.seqs} | (
-            {r.rid for r in plan.deferred} & self._sched_rids)
+        if plan.drain is None:
+            # a row the budget deferred stays admitted: it is not announced
+            # again when it is next fed (a plan that only waits for the step
+            # in flight names no row, and admits none)
+            self._sched_rids = {s.request.rid for s in plan.seqs} | (
+                {r.rid for r in plan.deferred} & self._sched_rids)
         if plan.admission_blocked:
             # the pool (not the slot array) is the bottleneck: the
             # head-of-line request stays queued, never dropped
@@ -695,116 +771,158 @@ class LLMEngine:
     def _step(self, whole) -> List[int]:
         """``step()`` inside its ``serve/engine_step`` span ``whole``,
         which is told what the step fed once the batch is built."""
+        flying = self._flight
         with _trace.span("serve/schedule"):
             plan = self._schedule()
-        if not plan.seqs:
+        new = None
+        if plan.drain is not None:
+            # the plan needs what the step in flight decides: fetch before
+            # the next dispatch, which the next call makes
+            self._drained(plan.drain)
+        elif plan.seqs:
+            with _trace.span("serve/batch"):
+                new = self._build(plan, whole, in_flight=flying is not None)
+            if self._sync is not None:
+                self._drained(self._sync)
+        # the step this call fetches: the one in flight, or at depth 0 its own
+        landing = new if self._sync is not None else flying
+        if new is None and landing is None:
             return []
-        R, Tc = self.max_running, plan.bucket
-        with _trace.span("serve/batch"):
-            drafts: Optional[Dict[int, List[int]]] = None
-            if self._draft is not None:
-                spec_rows = [
-                    (s.slot, s.request.known[s.request.fed],
-                     s.request.fed, self.kv.block_row(s.request.rid))
-                    for s in plan.seqs if s.spec]
-                if spec_rows:
-                    drafts = self._draft.propose(
-                        spec_rows, self._spec_k, R, self.max_blocks)
-            tokens, tbl, lens, qlens = self._batch_arrays(
-                plan.seqs, R, Tc, self.max_blocks, self.kv, drafts)
-            # what this step feeds, from the arrays just built (the
-            # readers of benchmark/ take their fill and kernel-cost counts
-            # from here)
-            decode_rows = int((qlens == 1).sum())
-            # the pages the attention kernel walks (a live row's, to its
-            # length) of those the block table has room for
-            kv_pages = int(_cdiv(lens, self.page_size)[qlens > 0].sum())
-            table_pages = R * self.max_blocks
-            # slot_tokens: the positions the program computes, of which
-            # fed_tokens hold a token
-            counts = dict(
-                bucket=Tc, rows=len(plan.seqs),
-                prefill_rows=len(plan.seqs) - decode_rows,
-                decode_rows=decode_rows, fed_tokens=int(qlens.sum()),
-                slot_tokens=self._positions(Tc),
-                deferred_rows=len(plan.deferred),
-                kv_tokens=int(lens.sum()),
-                qk_pairs=int(np.dot(qlens.astype(np.int64), lens)),
-                kv_pages=kv_pages, table_pages=table_pages)
-            if counts["fed_tokens"] > counts["slot_tokens"]:
-                # the tokens past the program's positions would vanish
-                # without a trace: a broken scheduler, not a run-time fault
-                raise RuntimeError(
-                    f"the plan feeds {counts['fed_tokens']} tokens and the "
-                    f"Tc={Tc} step computes {counts['slot_tokens']} "
-                    "(scheduler.step_tokens)")
-            _STATS["kv_pages"] += kv_pages
-            _STATS["table_pages"] += table_pages
-            _STATS["slot_tokens"] += counts["slot_tokens"]
-            _STATS["deferred_rows"] += counts["deferred_rows"]
-            if self._model.recurrent_state:
-                # rows whose state the step advances, and those among
-                # them that it first zeroes (a chunk that starts at 0)
-                resets = int(((qlens > 0) & (lens == qlens)).sum())
-                counts.update(state_rows=int((qlens > 0).sum()),
-                              state_resets=resets)
-                _STATS["state_resets"] += resets
-            model_counts = getattr(self._model, "step_counts", None)
-            if model_counts is not None:
-                # what the model's own layers read this step
-                for key, n in model_counts(self.cfg, lens, qlens).items():
-                    counts[key] = int(n)
-                    _STATS[key] = _STATS.get(key, 0) + int(n)
-            whole.set_metadata(**counts)
-            # build (or fetch) the bucket's executable before the guarded
-            # call: a step that cannot be compiled is a broken program,
-            # not a run-time fault, and must not be "recovered" into
-            # quarantines
-            step_fn = self._step_fn(Tc)
-            # _step_wall_s (the service model's step cost) runs from the
-            # uploads to the fetch, as it always has
-            t_fwd = self._clock()
-            uploaded = (jnp.asarray(tokens), jnp.asarray(tbl),
-                        jnp.asarray(lens), jnp.asarray(qlens))
+        # the span is the landing step's (``wall_s`` is its cost, not the
+        # span's own length); a call that only dispatches says that it
+        # landed none, so that no reader takes its length for a step's
+        about = dict(landed=0) if landing is None else dict(
+            batch=len(landing.plan.seqs), bucket=landing.plan.bucket)
         try:
-            with _trace.span("serve/step", step=self._steps,
-                             batch=len(plan.seqs), bucket=Tc):
-                nxt, device = self._guarded_forward(plan, step_fn,
-                                                    *uploaded)
+            with _trace.span("serve/step", step=(landing or new).step,
+                             **about) as forward:
+                self._guarded_forward(new, landing)
+                if landing is None:
+                    return []
+                # the step's cost to the service: how long it held the
+                # head of the pipeline, from the later of its own dispatch
+                # (its uploads) and the fetch before it to its own fetch
+                now = self._clock()
+                wall = now - max(landing.t_start, self._fetched_s)
+                self._fetched_s = now
+                forward.set_metadata(wall_s=wall)
         except ReplicaKilled:
             # whole-replica death is the router's failure domain, not a
             # step-recoverable fault — propagate
             raise
         except Exception as exc:  # noqa: BLE001 — classified in _recover
-            return self._recover(plan, exc)
+            # the step at fault: the new one if it never reached the
+            # device, else the one whose results were being fetched
+            failed = landing if landing is not None and (
+                new is None or new.nxt is not None) else new
+            return self._recover(failed.plan, exc)
 
-        if device is not None:
+        self._step_wall_s.setdefault(landing.plan.bucket, []).append(wall)
+        if landing.device is not None:
             # what the model had the device count, fetched with the tokens
-            counted = dict(zip(self._model.device_counts, map(int, device)))
+            counted = dict(zip(self._model.device_counts,
+                               map(int, landing.device)))
             whole.set_metadata(**counted)
             for key, n in counted.items():
                 seen = _STATS.get(key, 0)
                 _STATS[key] = max(seen, n) if key.endswith("_max") \
                     else seen + n
-        if self._draft is not None:
-            # mirror: the draft ingests the exact same feed, so its kv
-            # tracks the target's fed counter in lockstep (donated
-            # pages then carry valid draft kv for future borrowers)
-            self._draft.forward(tokens, tbl, lens, qlens)
-        now = self._clock()
-        self._step_wall_s.setdefault(Tc, []).append(now - t_fwd)
         with _trace.span("serve/commit"):
-            return self._commit(plan, nxt, drafts, now)
+            return self._commit(landing, now)
 
-    def _commit(self, plan: StepPlan, nxt, drafts, now: float) -> List[int]:
-        """Acceptance, ``scheduler.apply``, the ``on_token`` callbacks
-        and the step's stats (the ``serve/commit`` span)."""
+    def _drained(self, reason: str) -> None:
+        """Count a step that is fetched before the next is dispatched."""
+        _STATS["pipeline_drains"] += 1
+        by_reason = f"pipeline_drains.{reason}"
+        _STATS[by_reason] = _STATS.get(by_reason, 0) + 1
+
+    def _build(self, plan: StepPlan, whole, in_flight: bool) -> "_Flight":
+        """The arrays of ``plan``'s step, its counts (onto the span
+        ``whole`` and into the stats) and its uploads: the ``serve/batch``
+        span.  ``in_flight``: another step is on the device meanwhile."""
+        R, Tc = self.max_running, plan.bucket
+        drafts: Optional[Dict[int, List[int]]] = None
+        if self._draft is not None:
+            spec_rows = [
+                (s.slot, s.request.known[s.request.fed],
+                 s.request.fed, self.kv.block_row(s.request.rid))
+                for s in plan.seqs if s.spec]
+            if spec_rows:
+                drafts = self._draft.propose(
+                    spec_rows, self._spec_k, R, self.max_blocks)
+        tokens, tbl, lens, qlens = arrays = self._batch_arrays(
+            plan.seqs, R, Tc, self.max_blocks, self.kv, drafts)
+        # what this step feeds, from the arrays just built (the
+        # readers of benchmark/ take their fill and kernel-cost counts
+        # from here)
+        decode_rows = int((qlens == 1).sum())
+        # the pages the attention kernel walks (a live row's, to its
+        # length) of those the block table has room for
+        kv_pages = int(_cdiv(lens, self.page_size)[qlens > 0].sum())
+        table_pages = R * self.max_blocks
+        # slot_tokens: the positions the program computes, of which
+        # fed_tokens hold a token
+        counts = dict(
+            bucket=Tc, rows=len(plan.seqs),
+            prefill_rows=len(plan.seqs) - decode_rows,
+            decode_rows=decode_rows, fed_tokens=int(qlens.sum()),
+            slot_tokens=self._positions(Tc),
+            deferred_rows=len(plan.deferred),
+            kv_tokens=int(lens.sum()),
+            qk_pairs=int(np.dot(qlens.astype(np.int64), lens)),
+            kv_pages=kv_pages, table_pages=table_pages,
+            in_flight=int(in_flight))
+        if counts["fed_tokens"] > counts["slot_tokens"]:
+            # the tokens past the program's positions would vanish
+            # without a trace: a broken scheduler, not a run-time fault
+            raise RuntimeError(
+                f"the plan feeds {counts['fed_tokens']} tokens and the "
+                f"Tc={Tc} step computes {counts['slot_tokens']} "
+                "(scheduler.step_tokens)")
+        _STATS["kv_pages"] += kv_pages
+        _STATS["table_pages"] += table_pages
+        _STATS["slot_tokens"] += counts["slot_tokens"]
+        _STATS["deferred_rows"] += counts["deferred_rows"]
+        _STATS["pipelined_steps"] += int(in_flight)
+        if self._model.recurrent_state:
+            # rows whose state the step advances, and those among
+            # them that it first zeroes (a chunk that starts at 0)
+            resets = int(((qlens > 0) & (lens == qlens)).sum())
+            counts.update(state_rows=int((qlens > 0).sum()),
+                          state_resets=resets)
+            _STATS["state_resets"] += resets
+        model_counts = getattr(self._model, "step_counts", None)
+        if model_counts is not None:
+            # what the model's own layers read this step
+            for key, n in model_counts(self.cfg, lens, qlens).items():
+                counts[key] = int(n)
+                _STATS[key] = _STATS.get(key, 0) + int(n)
+        whole.set_metadata(**counts)
+        # build (or fetch) the bucket's executable before the guarded
+        # call: a step that cannot be compiled is a broken program,
+        # not a run-time fault, and must not be "recovered" into
+        # quarantines
+        self._step_fn(Tc)
+        t_start = self._clock()
+        return _Flight(plan=plan, step=self._steps, arrays=arrays,
+                       drafts=drafts, t_start=t_start,
+                       uploaded=tuple(map(jnp.asarray, arrays)))
+
+    def _commit(self, flight: "_Flight", now: float) -> List[int]:
+        """Acceptance, ``scheduler.complete``, the ``on_token`` callbacks
+        and the stats of the fetched step ``flight`` (the ``serve/commit``
+        span)."""
+        plan, nxt, drafts = flight.plan, flight.nxt, flight.drafts
         tracing = _trace.enabled()
         out: Dict[int, object] = {}
         prefill = decode = 0
         spec_proposed = spec_accepted = 0
         for s in plan.seqs:
-            if s.spec:
+            if not self.scheduler.holds(s):
+                # fed past its end: the request finished by eos_token_id,
+                # was cancelled or expired while the row was in flight
+                _STATS["discarded_tokens"] += int(s.produces)
+            elif s.spec:
                 row = [int(t) for t in nxt[s.slot, :s.q_len]]
                 emitted = greedy_accept(drafts[s.slot], row)
                 out[s.slot] = emitted
@@ -834,13 +952,11 @@ class LLMEngine:
                     _trace.request_event(
                         "prefill", s.request.rid, t=now,
                         tokens=s.q_len, last_chunk=False)
-        finished = self.scheduler.apply(plan, out, now_s=now)
-        self._steps += 1
+        finished = self.scheduler.complete(plan, out, now_s=now)
 
         _STATS["steps"] += 1
         _STATS["prefill_tokens"] += prefill
         _STATS["decode_tokens"] += decode
-        _STATS["requests_preempted"] += len(plan.preempted)
         _STATS["requests_finished"] += len(finished)
         _STATS["peak_running"] = max(_STATS["peak_running"],
                                      len(plan.seqs))
@@ -873,11 +989,6 @@ class LLMEngine:
                              "Prompt tokens fed to the model").inc(prefill)
             _metrics.counter("serve_decode_tokens_total",
                              "Decode tokens generated").inc(decode)
-            if plan.preempted:
-                _metrics.counter(
-                    "serve_preemptions_total",
-                    "Requests preempted for pool pressure").inc(
-                    len(plan.preempted))
             if plan.prefix_hit_tokens:
                 _metrics.counter(
                     "serve_prefix_hit_tokens_total",
@@ -907,30 +1018,47 @@ class LLMEngine:
                     now - r.arrival_s)
         return [r.rid for r in finished]
 
-    def _guarded_forward(self, plan: StepPlan, step_fn, tokens, tbl, lens,
-                         qlens):
-        """The device call under the serve.step watchdog phase, chaos
-        point, and numerics check, on inputs already on the device.
-        Returns the sampled tokens ``[R, Tc]`` and the model's
-        ``device_counts`` of the step (None for a model with none)."""
+    def _guarded_forward(self, new: Optional["_Flight"],
+                         landing: Optional["_Flight"]) -> None:
+        """The device calls under the serve.step watchdog phase, chaos
+        point, and numerics check: the dispatch of the step ``new`` on
+        inputs already on the device, then the fetch of ``landing`` (the
+        step dispatched a call ago, or ``new`` itself), whose sampled
+        tokens ``[R, Tc]`` and ``device_counts`` (None for a model with
+        none) it is left holding as host arrays."""
         wd = self._wd()
         if wd is not None:
             wd.begin("serve.step")
         try:
-            chaos_point("serve.step", step=self._steps,
-                        rids=[s.request.rid for s in plan.seqs],
-                        pool=self.kv.allocator, engine=self)
-            with _trace.span("serve/dispatch"):
-                nxt, chk, self._pools, *device = step_fn(
-                    self.params, tokens, self._pools, tbl, lens, qlens)
-            with _trace.span("serve/fetch"):
-                # the wait for the device, and the copy back
-                nxt = np.asarray(nxt)
-                device = np.asarray(device[0]) if device else None
-            if _numerics.enabled():
-                rows = np.asarray(chk)[[s.slot for s in plan.seqs]]
-                _numerics.check_array(rows, "serve.step.logits",
-                                      action="raise")
+            if new is not None:
+                chaos_point("serve.step", step=new.step,
+                            rids=[s.request.rid for s in new.plan.seqs],
+                            pool=self.kv.allocator, engine=self)
+                with _trace.span("serve/dispatch"):
+                    (new.nxt, new.chk, self._pools, self._sampled,
+                     *device) = self._step_fn(new.plan.bucket)(
+                        self.params, new.uploaded[0], self._pools,
+                        *new.uploaded[1:], self._sampled)
+                    new.device = device[0] if device else None
+                    new.uploaded = None
+                    self._steps += 1
+                    self.scheduler.dispatch(new.plan)
+                    self._flight = new
+            if landing is not None:
+                with _trace.span("serve/fetch"):
+                    # the wait for the device, and the copy back
+                    self._fetch(landing)
+                    self._flight = None if landing is new else new
+                if self._draft is not None:
+                    # mirror: the draft ingests the exact same feed, so its
+                    # kv tracks the target's fed counter in lockstep (donated
+                    # pages then carry valid draft kv for future borrowers)
+                    self._draft.forward(*landing.arrays)
+                if _numerics.enabled():
+                    rows = np.asarray(landing.chk)[
+                        [s.slot for s in landing.plan.seqs]]
+                    _numerics.check_array(rows, "serve.step.logits",
+                                          action="raise")
             if wd is not None:
                 # synchronous expiry: a device call that *eventually*
                 # returned past its deadline is still a hang — convert
@@ -939,10 +1067,16 @@ class LLMEngine:
                 for exc in wd.poll(raise_on_expire=False):
                     if exc.phase == "serve.step":
                         raise exc
-            return nxt, device
         finally:
             if wd is not None:
                 wd.end("serve.step")
+
+    @staticmethod
+    def _fetch(flight: "_Flight") -> None:
+        """Wait for ``flight``'s results and copy them to the host."""
+        flight.nxt = np.asarray(flight.nxt)
+        if flight.device is not None:
+            flight.device = np.asarray(flight.device)
 
     # -- crash recovery --------------------------------------------------
     @staticmethod
@@ -974,6 +1108,10 @@ class LLMEngine:
             self._evicted_seen = 0
         self.scheduler.kv = self.kv
         self._pools = self._fresh_pools()
+        # whatever was in flight ran on the suspect pools: its results are
+        # dropped, and reset_running() forgets the tokens that were pending
+        self._flight = None
+        self._sampled = self._no_sampled
         if self._draft is not None:
             self._draft.reset()
         demoted = self.scheduler.reset_running()
@@ -1013,7 +1151,8 @@ class LLMEngine:
                         pool=kv.allocator, engine=self, probe=True)
             chk = step_fn(
                 self.params, jnp.asarray(tokens), self._fresh_pools(),
-                jnp.asarray(tbl), jnp.asarray(lens), jnp.asarray(qlens))[1]
+                jnp.asarray(tbl), jnp.asarray(lens), jnp.asarray(qlens),
+                self._no_sampled)[1]
             if _numerics.enabled():
                 rows = np.asarray(chk)[[s.slot for s in seqs]]
                 _numerics.check_array(rows, "serve.step.probe",
@@ -1046,7 +1185,10 @@ class LLMEngine:
         rest.  Always returns [] — no request finishes at a failed
         step boundary."""
         failure = self._classify(exc)
-        suspects = [s.request for s in plan.seqs]
+        # a row cancelled, expired or finished while it was in flight has
+        # had its terminal event: it is neither probed nor quarantined
+        suspects = [s.request for s in plan.seqs if s.request.state in (
+            RequestState.WAITING, RequestState.RUNNING)]
         self._rebuild()
         culprit = None
         if failure != "hang":
@@ -1179,10 +1321,28 @@ class LLMEngine:
         if self._state_bytes:
             _xmem.record_reservation("serving.state", 0)
         self._pools = None
+        self._flight = None
         self._step_fns.clear()
         self._copy_fn = None
         if self._draft is not None:
             self._draft.shutdown()
+
+
+@dataclasses.dataclass
+class _Flight:
+    """One device step from its build to its commit: what the host planned
+    and uploaded, then what the device returns, on the device until
+    ``LLMEngine._fetch``."""
+
+    plan: StepPlan
+    step: int                 # its index among the steps dispatched
+    arrays: tuple             # host tokens, tbl, lens, qlens (the draft's feed)
+    drafts: Optional[Dict[int, List[int]]]
+    t_start: float            # engine clock before its uploads
+    uploaded: Optional[tuple]  # ``arrays`` on the device, until dispatch
+    nxt: object = None        # argmax at every fed position [R, Tc]
+    chk: object = None        # max logit of each row's last fed token [R]
+    device: object = None     # the model's device_counts, if it has any
 
 
 @dataclasses.dataclass

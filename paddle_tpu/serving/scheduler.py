@@ -14,6 +14,16 @@ preempted request (pages freed, ``fed`` reset to 0) re-prefills its
 whole history through the same code path.  A step that closes the gap
 samples the next token from the last fed position.
 
+A step has two moments, and the engine keeps one step between them
+(docs/serving.md, "One step in flight").  At ``dispatch`` a row's ``fed``
+advances and a row that samples has one token ``pending``: a token that
+counts, whose value is still on the device.  At ``complete`` the value
+arrives, callbacks fire, requests finish.  ``schedule`` plans on counts
+alone (``num_known + pending``), so the next step is planned while this
+one runs; a plan that would have to preempt while results are outstanding
+is not made (``StepPlan.drain``), and the engine completes the step in
+flight first.
+
 Per step boundary:
   * completions free their pages and open their slot;
   * WAITING requests are admitted into free slots when the page pool
@@ -70,7 +80,9 @@ class Request:
     eos_token_id: Optional[int] = None
     on_token: Optional[Callable] = None   # (rid, token, finished) -> None
     state: RequestState = RequestState.WAITING
-    fed: int = 0                          # tokens written to kv
+    fed: int = 0                          # tokens fed (dispatched) to kv
+    # tokens sampled by dispatched steps whose values have not arrived
+    pending: int = 0
     output: List[int] = dataclasses.field(default_factory=list)
     arrival_s: float = 0.0
     admitted_s: Optional[float] = None    # first admission (engine clock)
@@ -86,6 +98,13 @@ class Request:
     @property
     def num_known(self) -> int:
         return len(self.prompt) + len(self.output)
+
+    @property
+    def ending(self) -> bool:
+        """The tokens in flight complete ``max_new_tokens``: the request
+        ends when they arrive, and is fed nothing more."""
+        return (self.pending > 0 and
+                len(self.output) + self.pending >= self.max_new_tokens)
 
     @property
     def done(self) -> bool:
@@ -124,6 +143,10 @@ class StepPlan:
     # running prefill rows the token budget left out of this step: they
     # keep slot and pages and are not in ``seqs``
     deferred: List[Request] = dataclasses.field(default_factory=list)
+    # why no plan was made (``seqs`` is empty, nothing was preempted): it
+    # needs what a step still in flight decides.  "page_pressure": a
+    # running request cannot grow without a preemption
+    drain: Optional[str] = None
 
 
 class AdmissionGate:
@@ -170,6 +193,8 @@ class Scheduler:
         # widened to a verify chunk of 1 + spec_k tokens (the engine
         # sets this iff a draft model is attached)
         self.spec_k: int = 0
+        # steps dispatched and not completed
+        self.in_flight: int = 0
 
     @property
     def step_tokens(self) -> int:
@@ -224,7 +249,7 @@ class Scheduler:
 
     # -- internals ------------------------------------------------------
     def _q_len(self, req: Request) -> int:
-        gap = req.num_known - req.fed
+        gap = req.num_known + req.pending - req.fed
         q = min(self.chunk, gap)
         if (self.spec_k > 0 and gap == 1
                 and 1 + self.spec_k <= self.chunk
@@ -261,6 +286,7 @@ class Scheduler:
     def _release_slot(self, req: Request) -> None:
         slot = self._slot_of.pop(req.rid)
         self.slots[slot] = None
+        req.pending = 0     # a row still in flight is discarded (``holds``)
         if self.kv.prefix is not None and req.fed >= self.kv.page_size:
             # donate the valid full pages (fed tokens of kv) so a
             # preempted request keeps its prefix hit on replay and a
@@ -308,8 +334,10 @@ class Scheduler:
             self.slots[slot] = None
             req.state = RequestState.WAITING
             req.fed = 0
+            req.pending = 0      # what was in flight is dropped with it
             demoted.append(req)
         self._slot_of.clear()
+        self.in_flight = 0
         return demoted
 
     def requeue_front(self, reqs: List[Request]) -> None:
@@ -334,10 +362,14 @@ class Scheduler:
         # 1) running requests first — their next chunk must fit
         for slot in range(self.max_running):
             req = self.slots[slot]
-            if req is None:
+            if req is None or req.ending:
                 continue
             target = req.fed + self._q_len(req)
             while not self._try_grow(req, target):
+                if self.in_flight:
+                    # a victim's replay would need the value of its pending
+                    # token: complete the step in flight, then plan again
+                    return StepPlan(seqs=[], bucket=1, drain="page_pressure")
                 victim = self._evict_youngest(but_not=req)
                 if victim is None:
                     # alone and still can't grow — another tenant holds
@@ -400,10 +432,11 @@ class Scheduler:
         by_slot: Dict[int, ScheduledSeq] = {}
         deferred: List[Request] = []
         rows = [self.slots[slot] for slot in self._slot_of.values()]
+        rows = [req for req in rows if not req.ending]
         rows.sort(key=lambda req: req.num_known - req.fed > 1)  # stable
         for req in rows:
             q_len = self._q_len(req)
-            gap = req.num_known - req.fed
+            gap = req.num_known + req.pending - req.fed
             if q_len > left:
                 deferred.append(req)
                 continue
@@ -412,7 +445,7 @@ class Scheduler:
             by_slot[slot] = ScheduledSeq(
                 request=req, slot=slot, q_len=q_len,
                 seq_len=req.fed + q_len,
-                produces=req.fed + q_len >= req.num_known,
+                produces=req.fed + q_len >= req.num_known + req.pending,
                 spec=q_len - gap if gap == 1 and q_len > 1 else 0)
         seqs = [by_slot[slot] for slot in sorted(by_slot)]
         bucket = self.chunk if any(s.q_len > 1 for s in seqs) else 1
@@ -421,21 +454,42 @@ class Scheduler:
                         prefix_hit_tokens=prefix_hit_tokens,
                         deferred=deferred)
 
-    def apply(self, plan: StepPlan, next_tokens: Dict[int, object],
-              now_s: float = 0.0) -> List[Request]:
-        """Commit a computed step: advance fed counters, append sampled
-        tokens, fire callbacks, finish completed requests.
-        ``next_tokens`` maps slot -> sampled token id for slots whose
-        step produced one; a *spec verify* slot maps to the accepted
-        token list instead (1..spec+1 tokens, in stream order).
-        Returns the requests that finished."""
+    def dispatch(self, plan: StepPlan) -> None:
+        """The first moment of a step, when the engine hands it to the
+        device: every row's ``fed`` advances, and a row that samples has
+        one more token pending.  From here on ``schedule`` plans the next
+        step as if this one had run."""
+        for s in plan.seqs:
+            s.request.fed = s.seq_len
+            s.request.pending += int(s.produces)
+        self.in_flight += 1
+
+    def holds(self, s: ScheduledSeq) -> bool:
+        """Is ``s.request`` still in the slot the step was dispatched with?
+        Not after it ended (an ``eos_token_id`` a step earlier), was
+        cancelled or missed its deadline with the row in flight: the row's
+        result is then discarded."""
+        return self.slots[s.slot] is s.request
+
+    def complete(self, plan: StepPlan, next_tokens: Dict[int, object],
+                 now_s: float = 0.0) -> List[Request]:
+        """The second moment, when the step's results are on the host:
+        append sampled tokens, fire callbacks, register the written kv,
+        finish completed requests.  ``next_tokens`` maps slot -> sampled
+        token id for slots whose step produced one; a *spec verify* slot
+        maps to the accepted token list instead (1..spec+1 tokens, in
+        stream order).  Rows whose request no longer holds its slot are
+        skipped.  Returns the requests that finished."""
+        self.in_flight -= 1
         finished: List[Request] = []
         for s in plan.seqs:
             req = s.request
-            if not s.produces:
-                req.fed = s.seq_len
-                self.kv.commit(req.rid, req.fed)
+            if not self.holds(s):
                 continue
+            if not s.produces:
+                self.kv.commit(req.rid, s.seq_len)
+                continue
+            req.pending -= 1
             out = next_tokens[s.slot]
             toks = ([int(t) for t in out] if isinstance(out, (list, tuple))
                     else [int(out)])
@@ -451,14 +505,13 @@ class Scheduler:
                     req.on_token(req.rid, tok, done)
                 if done:
                     break
-            # a verify chunk's kv is valid only through the accepted
-            # tokens — the rejected tail is stale scratch the next
-            # step's feed overwrites before any read.  Non-spec rows
-            # keep the exact old bookkeeping: every fed token's kv is
-            # real, fed advances by the full chunk.
-            req.fed = (s.seq_len - s.q_len + appended if s.spec
-                       else s.seq_len)
-            self.kv.commit(req.rid, req.fed)
+            written = s.seq_len
+            if s.spec:
+                # a verify chunk's kv is valid only through the accepted
+                # tokens — the rejected tail is stale scratch the next
+                # step's feed overwrites before any read
+                written = req.fed = s.seq_len - s.q_len + appended
+            self.kv.commit(req.rid, written)
             if req.done:
                 finished.append(req)
         for req in finished:

@@ -31,6 +31,13 @@ def stats_zero() -> Dict[str, float]:
         # recurrent state beside the pages: bytes held by live engines, and
         # rows whose state a step zeroed (a request's first chunk in a slot)
         "state_bytes": 0, "state_resets": 0,
+        # one step in flight: steps dispatched while another was on the
+        # device (of ``steps``), steps fetched before the next dispatch
+        # (in all and by reason), and sampled tokens of rows fed past their
+        # request's end
+        "pipelined_steps": 0, "pipeline_drains": 0,
+        "pipeline_drains.spec": 0, "pipeline_drains.prefix_cache": 0,
+        "pipeline_drains.page_pressure": 0, "discarded_tokens": 0,
         # work reuse (prefix cache + speculative decoding)
         "prefix_hit_tokens": 0, "prefix_evicted_pages": 0,
         "spec_proposed": 0, "spec_accepted": 0,

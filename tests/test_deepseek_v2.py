@@ -502,20 +502,28 @@ def test_the_counts_are_on_the_engine_step_span(model):
         eng = engine(model)
         eng.add_request(prompts_of(30, seed=8)[0], 2)
         drain(eng)
-        steps = [e for e in trace.events()
-                 if e["name"] == "serve/engine_step" and "fed_tokens" in e]
+        calls = [e for e in trace.events()
+                 if e["name"] == "serve/engine_step"]
     finally:
         paddle.set_flags({"FLAGS_tpu_trace": False})
         trace.clear()
+    # what the host counted is on the span of the call that dispatched the
+    # step, what the device counted on the next one, which fetched it
+    steps = [e for e in calls if "fed_tokens" in e]
+    fetched = [e for e in calls if "experts_hit" in e]
+    assert len(steps) == len(fetched) == 3
+    assert [calls.index(e) for e in fetched] == \
+        [calls.index(e) + 1 for e in steps]
     first, last = steps[0], steps[-1]
+    assert "experts_hit" not in first and "fed_tokens" not in fetched[-1]
     assert first["moe_pairs"] == 16 * 2 * 2
     assert first["latent_kv_tokens"] == first["kv_tokens"] == 16
     assert first["latent_qk_pairs"] == first["qk_pairs"] == 256
     # 16 tokens, 2 experts each, in each of 2 layers of 8 experts
-    assert 2 * 2 <= first["experts_hit"] <= 2 * 8
-    assert 16 * 2 / 8 <= first["expert_rows_max"] <= 16
-    assert last["bucket"] == 1 and last["experts_hit"] == 2 * 2 \
-        and last["expert_rows_max"] == 1
+    assert 2 * 2 <= fetched[0]["experts_hit"] <= 2 * 8
+    assert 16 * 2 / 8 <= fetched[0]["expert_rows_max"] <= 16
+    assert last["bucket"] == 1 and fetched[-1]["experts_hit"] == 2 * 2 \
+        and fetched[-1]["expert_rows_max"] == 1
 
 
 def test_chunk_4_on_a_flat_budget_serves_the_same_streams(model, workload):

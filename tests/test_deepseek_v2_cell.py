@@ -221,6 +221,11 @@ def test_the_cell_runs_end_to_end_traced(root, monkeypatch):
     assert steps and all(
         s["moe_pairs"] == s["fed_tokens"] * 2 * 2
         and s["latent_kv_tokens"] == s["kv_tokens"]
-        and s["latent_qk_pairs"] == s["qk_pairs"]
-        and 4 <= s["experts_hit"] <= 16
-        and 1 <= s["expert_rows_max"] <= s["fed_tokens"] for s in steps)
+        and s["latent_qk_pairs"] == s["qk_pairs"] for s in steps)
+    # the engine keeps one step in flight: what the device counted in the
+    # step a call dispatched is on the span of the next call, which fetched it
+    fetched = [(a, b) for a, b in zip(steps, steps[1:])
+               if b["step"] == a["step"] + 1 and "experts_hit" in b]
+    assert len(fetched) >= len(steps) // 2 and all(
+        4 <= b["experts_hit"] <= 16
+        and 1 <= b["expert_rows_max"] <= a["fed_tokens"] for a, b in fetched)

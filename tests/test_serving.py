@@ -334,12 +334,14 @@ def test_scheduler_completion_frees_slot_for_waiting_request():
     plan = s.schedule()
     assert [q.request for q in plan.seqs] == [r1]
     assert plan.seqs[0].produces  # whole prompt fits in one chunk
-    s.apply(plan, {plan.seqs[0].slot: 7}, now_s=1.0)
+    s.dispatch(plan)
+    s.complete(plan, {plan.seqs[0].slot: 7}, now_s=1.0)
     assert r1.done and r1.output == [7] and r1.finish_s == 1.0
     plan2 = s.schedule()  # the freed slot goes to the waiting request
     assert [q.request for q in plan2.seqs] == [r2]
     assert s.kv.allocator.num_allocated > 0
-    s.apply(plan2, {plan2.seqs[0].slot: 9}, now_s=2.0)
+    s.dispatch(plan2)
+    s.complete(plan2, {plan2.seqs[0].slot: 9}, now_s=2.0)
     assert s.kv.allocator.num_allocated == 0  # everything released
 
 
@@ -348,7 +350,8 @@ def test_scheduler_eos_finishes_early():
     req = Request(prompt=[1, 2], max_new_tokens=5, eos_token_id=3)
     s.add(req)
     plan = s.schedule()
-    s.apply(plan, {plan.seqs[0].slot: 3}, now_s=0.0)
+    s.dispatch(plan)
+    s.complete(plan, {plan.seqs[0].slot: 3}, now_s=0.0)
     assert req.done and req.output == [3]
 
 
@@ -356,7 +359,8 @@ def test_scheduler_decode_bucket_is_one():
     s = _sched(max_running=2, chunk=8)
     s.add(Request(prompt=[1, 2], max_new_tokens=4))
     plan = s.schedule()
-    s.apply(plan, {plan.seqs[0].slot: 5}, now_s=0.0)
+    s.dispatch(plan)
+    s.complete(plan, {plan.seqs[0].slot: 5}, now_s=0.0)
     plan2 = s.schedule()
     assert plan2.bucket == 1 and plan2.seqs[0].q_len == 1
     assert plan2.seqs[0].produces
@@ -382,7 +386,8 @@ def test_scheduler_preemption_requeues_and_replays():
     s.add(r1)
     plan = s.schedule()
     assert [q.request for q in plan.seqs] == [r1]
-    s.apply(plan, {plan.seqs[0].slot: 2}, now_s=0.0)
+    s.dispatch(plan)
+    s.complete(plan, {plan.seqs[0].slot: 2}, now_s=0.0)
     r2 = Request(prompt=[2] * 4, max_new_tokens=8)
     s.add(r2)
     preempted_total = 0
@@ -392,7 +397,8 @@ def test_scheduler_preemption_requeues_and_replays():
         plan = s.schedule()
         preempted_total += len(plan.preempted)
         assert plan.seqs, "live requests but an empty step plan"
-        s.apply(plan, {q.slot: 3 for q in plan.seqs}, now_s=float(step))
+        s.dispatch(plan)
+        s.complete(plan, {q.slot: 3 for q in plan.seqs}, now_s=float(step))
     assert r1.done and r2.done
     assert preempted_total > 0  # the tiny pool forced at least one
     assert len(r1.output) == 8 and len(r2.output) == 8

@@ -125,12 +125,16 @@ def engine_trace(tmp_path_factory):
     """A debug-width engine drained under a CPU profiler trace: the slice
     and, step by step, what the scheduler planned.  Its mixed step computes
     a budget of 8 tokens, not its 4 x 4 positions, so that three prompts at
-    once make the scheduler defer a row."""
+    once make the scheduler defer a row.  The first step is dispatched
+    before the trace starts (it compiles the prefill bucket) and is in
+    flight when it does: ``plans`` are those of the steps dispatched inside
+    the trace, ``eng.fed_before`` the tokens of the one before."""
     from test_token_major import with_budget
     eng = with_budget(tiny_engine(), 8)
     for i in range(3):
         eng.add_request(list(range(1, 8 + 3 * i)), 4 + i)
     eng.step()                          # compile the prefill bucket
+    eng.fed_before = sum(r.fed for r in eng._requests.values())
     plans = []
     schedule = eng.scheduler.schedule
 
@@ -157,10 +161,19 @@ def engine_trace(tmp_path_factory):
 
 def test_every_engine_step_has_one_engine_step_span_with_its_children_in_order(
         engine_trace):
-    sl, plans, _ = engine_trace
+    sl, plans, eng = engine_trace
     steps = sl.whole("serve/engine_step")
     fed = [s for s in steps if "fed_tokens" in s.stats]
-    assert len(fed) == len(plans) >= 6 and len(steps) == len(plans) + 1
+    # after the calls that dispatched a step, one that only fetched the
+    # last and an idle one
+    assert len(fed) == len(plans) >= 6 and len(steps) == len(plans) + 2
+    assert fed == steps[:-2]
+    # every device step is on exactly one span: the tokens of the spans are
+    # the tokens the requests were fed (all they know but the last sampled)
+    assert eng.fed_before + sum(int(s.stats["fed_tokens"]) for s in fed) == \
+        sum(r.num_known - 1 for r in eng._requests.values()) == 42
+    # each was dispatched behind the step before it, still on the device
+    assert {int(s.stats["in_flight"]) for s in fed} == {1}
     uncovered = []
     for step in fed:
         kids = [e for e in sl.spans if e.name in CHILDREN
@@ -185,11 +198,16 @@ def test_every_engine_step_has_one_engine_step_span_with_its_children_in_order(
 def test_an_idle_engine_step_has_a_schedule_span_and_nothing_else(
         engine_trace):
     sl, _, _ = engine_trace
-    (idle,) = [s for s in sl.whole("serve/engine_step")
-               if "fed_tokens" not in s.stats]
-    kids = [e.name for e in sl.spans if e is not idle
-            and idle.start <= e.start and e.end <= idle.end]
-    assert kids == ["serve/schedule"]
+    landing, idle = [s for s in sl.whole("serve/engine_step")
+                     if "fed_tokens" not in s.stats]
+
+    def kids(step):
+        return [e.name for e in sl.spans if e is not step
+                and step.start <= e.start and e.end <= step.end]
+    # the call that found nothing to dispatch and the last step to fetch
+    assert kids(landing) == ["serve/schedule", "serve/step", "serve/fetch",
+                             "serve/commit"]
+    assert kids(idle) == ["serve/schedule"]
 
 
 def test_the_engine_step_spans_arguments_are_what_the_scheduler_planned(
@@ -258,17 +276,30 @@ def test_serve_step_keeps_its_fields_and_the_ring_gets_every_phase(trace_on):
     for e in evs:
         by_name.setdefault(e["name"], []).append(e)
     n = len(by_name["serve/engine_step"])
-    assert n >= 4
-    for name in CHILDREN + ["serve/dispatch", "serve/fetch"]:
-        assert len(by_name[name]) == n
-    # what tools/fleet_sim.py calibrates from
-    assert all({"dur", "bucket", "batch", "step"} <= set(e)
-               for e in by_name["serve/step"])
+    assert n >= 5
+    # n - 1 device steps: the first call only dispatches, the last only
+    # fetches
+    assert len(by_name["serve/schedule"]) == len(by_name["serve/step"]) == n
+    for name in ("serve/batch", "serve/dispatch", "serve/fetch",
+                 "serve/commit"):
+        assert len(by_name[name]) == n - 1
+    assert [e.get("in_flight") for e in by_name["serve/engine_step"]] == \
+        [0] + [1] * (n - 2) + [None]
+    # what tools/fleet_sim.py calibrates from: the step the span fetched
+    # and its cost to the service, once each
+    only_dispatched, *landed = by_name["serve/step"]
+    assert only_dispatched["landed"] == 0 and not \
+        {"bucket", "batch", "wall_s"} & set(only_dispatched)
+    assert all({"dur", "bucket", "batch", "step", "wall_s"} <= set(e)
+               and "landed" not in e for e in landed)
+    assert [e["step"] for e in landed] == list(range(n - 1))
+    assert sorted(e["wall_s"] for e in landed) == sorted(
+        t for ts in eng._step_wall_s.values() for t in ts)
     assert {e["parent"] for e in by_name["serve/step"]} == \
         {"serve/engine_step"}
     assert {e["parent"] for e in by_name["serve/fetch"]} == {"serve/step"}
     assert all(e["fed_tokens"] <= e["slot_tokens"]
-               for e in by_name["serve/engine_step"])
+               for e in by_name["serve/engine_step"] if "fed_tokens" in e)
 
 
 def test_the_engines_programs_are_named_for_their_bucket():

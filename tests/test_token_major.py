@@ -238,7 +238,8 @@ def test_no_step_passes_the_budget_and_no_decode_row_ever_waits(seed, spec_k):
         # what the step "computed": accept one token of a verify chunk
         out = {q.slot: ([5] if q.spec else 5) for q in plan.seqs
                if q.produces}
-        s.apply(plan, out)
+        s.dispatch(plan)
+        s.complete(plan, out)
     assert deferrals > 0, "the budget never bound: the test shows nothing"
     # a deferred row waits for the prefill rows admitted before it, at most
     # R - 1 of them with at most 30 / chunk chunks each

@@ -150,9 +150,11 @@ class SimEngine:
     (``add_request/step/state_of/error_of/cancel/scheduler``) on the
     real host-side machinery — Scheduler, PagedKVCache,
     AdmissionGate — so admission, batching, paging and preemption
-    behave exactly like a live engine.  The device forward is
-    replaced by a clock advance: one ServiceModel step cost per
-    scheduled step, bucket-dependent."""
+    behave exactly like a live engine, in the live engine's order: one
+    step in flight, the next planned on the scheduler's counts and
+    dispatched before the one before it completes.  The device forward
+    is replaced by a clock advance: one ServiceModel step cost per
+    scheduled step, bucket-dependent, paid when the step completes."""
 
     def __init__(self, pt: _Paddle, model, clock: SimClock,
                  name: str = "sim0"):
@@ -172,6 +174,7 @@ class SimEngine:
         self.shed = 0
         self.steps = 0
         self.busy_s = 0.0
+        self._flight = None     # the plan dispatched and not completed
 
     # engine surface ------------------------------------------------------
     def add_request(self, prompt, max_new_tokens: int,
@@ -204,7 +207,7 @@ class SimEngine:
         return self._requests[rid].error
 
     def has_work(self) -> bool:
-        return self.scheduler.has_work()
+        return self.scheduler.has_work() or self._flight is not None
 
     def cancel(self, rid: int) -> bool:
         RequestState = self.pt.scheduler.RequestState
@@ -231,19 +234,22 @@ class SimEngine:
 
     def step(self) -> List[int]:
         self.clock.enter(self.name)
-        now = self.clock.now()
-        self._expire_deadlines(now)
+        self._expire_deadlines(self.clock.now())
         plan = self.scheduler.schedule()
         self.kv.drain_copies()
-        if not plan.seqs:
+        landing, self._flight = self._flight, None
+        if plan.seqs:       # none where the plan waits for ``landing``
+            self.scheduler.dispatch(plan)
+            self._flight = plan
+        if landing is None:
             return []
-        dur = (self.model.prefill_chunk_s if plan.bucket > 1
+        dur = (self.model.prefill_chunk_s if landing.bucket > 1
                else self.model.decode_step_s)
         now = self.clock.advance(dur)
         self.steps += 1
         self.busy_s += dur
-        out = {s.slot: 1 for s in plan.seqs if s.produces}
-        finished = self.scheduler.apply(plan, out, now_s=now)
+        out = {s.slot: 1 for s in landing.seqs if s.produces}
+        finished = self.scheduler.complete(landing, out, now_s=now)
         return [r.rid for r in finished]
 
 
@@ -302,8 +308,12 @@ def load_trace(pt: _Paddle, trace_dir: str):
     for e in events:
         if (e.get("kind") == "span" and e.get("name") == "serve/step"
                 and "dur" in e and "bucket" in e):
+            # a step's cost to the service (``wall_s``, the engine's own
+            # sample) where the span carries it: with a step in flight the
+            # span itself is only the wait for what was left of the step,
+            # and the span of a call that only dispatched names no bucket
             steps.setdefault(int(e["bucket"]), []).append(
-                float(e["dur"]))
+                float(e.get("wall_s", e["dur"])))
     if not queued:
         die(2, f"{trace_dir}: trace holds no serve/queued request "
                f"events — record with FLAGS_tpu_trace=1 while "
@@ -417,8 +427,9 @@ def simulate(pt: _Paddle, model, arrivals, n_replicas: int, *,
                 if policy is not None:
                     policy.mark_applied(rec)
                 scale_events[-1]["applied"] = True
-        if clock.now() <= before:
-            # no replica made progress (e.g. orphans waiting): let
+        if clock.now() <= before and not any(e._flight for e in engines):
+            # no replica made progress (e.g. orphans waiting; a replica that
+            # only dispatched pays for the step when it completes it): let
             # virtual time flow to the next arrival or one decode
             if i < len(pending):
                 clock.jump_to(pending[i].t_s)

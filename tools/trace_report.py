@@ -240,16 +240,22 @@ def _p95(vals: List[float]) -> float:
 # ---------------------------------------------------------------------------
 
 def step_stats(events: List[dict]) -> Dict[str, Any]:
-    """Duration stats for train/serve step spans, per rank."""
+    """Duration stats for train/serve step spans, per rank.  A serve
+    step's cost is the engine's own ``wall_s`` where the span carries it:
+    with a step in flight the span itself is only the wait for what was
+    left of the step, and the span of a call that only dispatched
+    (``landed=0``) is no step at all."""
     out: Dict[str, Any] = {}
     for name in ("train/step", "serve/step"):
         spans = [e for e in events
-                 if e.get("kind") == "span" and e.get("name") == name]
+                 if e.get("kind") == "span" and e.get("name") == name
+                 and e.get("landed") != 0]
         if not spans:
             continue
         per_rank: Dict[int, List[float]] = {}
         for e in spans:
-            per_rank.setdefault(e.get("rank", 0), []).append(e["dur"])
+            per_rank.setdefault(e.get("rank", 0), []).append(
+                e.get("wall_s", e["dur"]))
         durs = [d for ds in per_rank.values() for d in ds]
         out[name] = {
             "count": len(durs),
