@@ -247,31 +247,42 @@ def _mamba_mixer(cfg, lp, h, conv, ssm, l, q_lens, fresh, lay):
     the stack in place and walks only the live positions), so a reader of
     the scope's time times every byte that
     ``benchmark/kernel_costs_ssm.py`` counts, and the moves between the
-    layouts stay outside them."""
+    layouts stay outside them.  The mixer has four names in a profile:
+    ``ssm_proj`` around its three per-token stretches (norm and ``w_in``;
+    ``w_x``, the ``dt`` chain and the B and C norms; the gate, ``D_skip``
+    and ``w_out``), ``ssm_conv``, ``ssm_scan``, and ``step_layout`` (the
+    ``lay.rows`` and ``lay.flat`` between them, named inside
+    ``StepLayout``); what is left under ``mamba`` and under none of the
+    four is the caller's residual add."""
     from ..ops.pallas_ops import selective_scan
     N, r, eps = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.rms_norm_eps
     f32 = jnp.float32
-    x, z = jnp.split(_rms_norm(h, lp["ln1"], eps) @ lp["w_in"], 2, axis=-1)
+    with jax.named_scope("ssm_proj"):
+        x, z = jnp.split(_rms_norm(h, lp["ln1"], eps) @ lp["w_in"], 2,
+                         axis=-1)
     x = lay.rows(x)
     with jax.named_scope("ssm_conv"):
         c = jnp.where(fresh[None, :, None], 0, _layer_at(conv, l))
         x, c = _ssm_conv(lp, x, c, q_lens)                  # x float32
         conv = lax.dynamic_update_index_in_dim(conv, c, l, 0)
     xf = lay.flat(x)                                         # [T, E]
-    dt, Bm, Cm = jnp.split(xf.astype(h.dtype) @ lp["w_x"], [r, r + N],
-                           axis=-1)
-    dt = _rms_norm(dt, lp["dt_norm"], eps) @ lp["w_dt"]
-    dt = jax.nn.softplus(dt.astype(f32) + lp["b_dt"].astype(f32))
-    dt = lay.rows(dt)
-    Bm = lay.rows(_rms_norm(Bm, lp["b_norm"], eps).astype(f32))
-    Cm = lay.rows(_rms_norm(Cm, lp["c_norm"], eps).astype(f32))
+    with jax.named_scope("ssm_proj"):
+        dt, Bm, Cm = jnp.split(xf.astype(h.dtype) @ lp["w_x"], [r, r + N],
+                               axis=-1)
+        dt = _rms_norm(dt, lp["dt_norm"], eps) @ lp["w_dt"]
+        dt = jax.nn.softplus(dt.astype(f32) + lp["b_dt"].astype(f32))
+        Bm = _rms_norm(Bm, lp["b_norm"], eps).astype(f32)
+        Cm = _rms_norm(Cm, lp["c_norm"], eps).astype(f32)
+    dt, Bm, Cm = lay.rows(dt), lay.rows(Bm), lay.rows(Cm)
     with jax.named_scope("ssm_scan"):
         A = -jnp.exp(lp["A_log"].astype(f32))
         y, ssm = selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh,
                                 layer=l)
-    y = ((lay.flat(y) + lp["D_skip"].astype(f32) * xf)
-         * jax.nn.silu(z.astype(f32)))
-    return y.astype(h.dtype) @ lp["w_out"], conv, ssm
+    y = lay.flat(y)
+    with jax.named_scope("ssm_proj"):
+        y = (y + lp["D_skip"].astype(f32) * xf) * jax.nn.silu(z.astype(f32))
+        out = y.astype(h.dtype) @ lp["w_out"]
+    return out, conv, ssm
 
 
 def _mamba_run(cfg, stack, lo, hi, h, conv, ssm, q_lens, fresh, lay):

@@ -326,31 +326,42 @@ def _mamba_layer(cfg, lp, h, state, l, q_lens, fresh, lay):
     scan's output ``m [T, E]`` before the gate, and the state.  The layouts
     and the scopes are ``jamba._mamba_mixer``'s: matmuls, norm and gate
     flat, the convolution and the scan on the padded rows, ``ssm_conv`` and
-    ``ssm_scan`` around everything that touches their state."""
+    ``ssm_scan`` around everything that touches their state.  The mixer has
+    four names in a profile: ``ssm_proj`` around its three per-token
+    stretches (norm and ``w_in``; ``w_x`` and the ``dt`` chain; the gate,
+    ``D_skip`` and ``w_out``), ``ssm_conv``, ``ssm_scan``, and
+    ``step_layout`` (the ``lay.rows`` and ``lay.flat`` between them, named
+    inside ``StepLayout``); what is left under ``mamba`` and under none of
+    the four is the residual add."""
     from ..ops.pallas_ops import selective_scan
     N, r, f32 = cfg.mamba_d_state, cfg.mamba_dt_rank, jnp.float32
     conv, ssm = state["conv"], state["ssm"]
     with jax.named_scope("mamba"):
-        xn = _layer_norm(h, lp["ln1_w"], lp["ln1_b"], cfg.layer_norm_eps)
-        x, z = jnp.split(xn @ lp["w_in"], 2, axis=-1)
+        with jax.named_scope("ssm_proj"):
+            xn = _layer_norm(h, lp["ln1_w"], lp["ln1_b"], cfg.layer_norm_eps)
+            x, z = jnp.split(xn @ lp["w_in"], 2, axis=-1)
         x = lay.rows(x)
         with jax.named_scope("ssm_conv"):
             c = jnp.where(fresh[None, :, None], 0, _layer_at(conv, l))
             x, c = _ssm_conv(lp, x, c, q_lens)                  # x float32
             conv = lax.dynamic_update_index_in_dim(conv, c, l, 0)
         xf = lay.flat(x)                                         # [T, E]
-        dt, Bm, Cm = jnp.split(xf.astype(h.dtype) @ lp["w_x"], [r, r + N],
-                               axis=-1)
-        dt = jax.nn.softplus((dt @ lp["w_dt"]).astype(f32)
-                             + lp["b_dt"].astype(f32))
-        dt, Bm, Cm = (lay.rows(dt), lay.rows(Bm.astype(f32)),
-                      lay.rows(Cm.astype(f32)))
+        with jax.named_scope("ssm_proj"):
+            dt, Bm, Cm = jnp.split(xf.astype(h.dtype) @ lp["w_x"],
+                                   [r, r + N], axis=-1)
+            dt = jax.nn.softplus((dt @ lp["w_dt"]).astype(f32)
+                                 + lp["b_dt"].astype(f32))
+            Bm, Cm = Bm.astype(f32), Cm.astype(f32)
+        dt, Bm, Cm = lay.rows(dt), lay.rows(Bm), lay.rows(Cm)
         with jax.named_scope("ssm_scan"):
             A = -jnp.exp(lp["A_log"].astype(f32))
             y, ssm = selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh,
                                     layer=l)
-        m = lay.flat(y) + lp["D_skip"].astype(f32) * xf
-        out = (m * jax.nn.silu(z.astype(f32))).astype(h.dtype) @ lp["w_out"]
+        y = lay.flat(y)
+        with jax.named_scope("ssm_proj"):
+            m = y + lp["D_skip"].astype(f32) * xf
+            out = ((m * jax.nn.silu(z.astype(f32))).astype(h.dtype)
+                   @ lp["w_out"])
         h = h + out
     return _mlp(cfg, lp, h), m.astype(h.dtype), dict(state, conv=conv,
                                                      ssm=ssm)

@@ -22,9 +22,14 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["StepLayout"]
+
+# the name of every operation of this module in a profile (metadata only):
+# ``layout_ms_per_step`` reads the device time under it
+SCOPE = "step_layout"
 
 
 class StepLayout:
@@ -36,7 +41,11 @@ class StepLayout:
         self.R, self.Tc = R, int(Tc)
         self.compact = step_tokens is not None
         self.T = int(step_tokens) if self.compact else R * self.Tc
-        q = q_lens.astype(jnp.int32)
+        with jax.named_scope(SCOPE):
+            self._index(q_lens.astype(jnp.int32))
+
+    def _index(self, q):
+        R = self.R
         t = jnp.arange(self.Tc, dtype=jnp.int32)
         if not self.compact:
             self.last = (jnp.arange(R, dtype=jnp.int32) * self.Tc
@@ -58,10 +67,12 @@ class StepLayout:
 
     def flat(self, x):
         """``x [R, Tc, ...]`` as ``[T, ...]``: the fed tokens, then zeros."""
-        if not self.compact:
-            return x.reshape((self.R * self.Tc,) + x.shape[2:])
-        x = jnp.swapaxes(x, 0, 1).reshape((self.Tc * self.R,) + x.shape[2:])
-        return jnp.take(x, self._src, axis=0, mode="fill", fill_value=0)
+        with jax.named_scope(SCOPE):
+            if not self.compact:
+                return x.reshape((self.R * self.Tc,) + x.shape[2:])
+            x = jnp.swapaxes(x, 0, 1).reshape(
+                (self.Tc * self.R,) + x.shape[2:])
+            return jnp.take(x, self._src, axis=0, mode="fill", fill_value=0)
 
     def rows(self, x):
         """``x [T, ...]`` as ``[R, Tc, ...]``: each row's tokens, then zeros
@@ -74,9 +85,11 @@ class StepLayout:
         row-major, the Jamba step paid 3.3 ms for ``dt * x`` and 3.3 ms for
         the stack of the scan's outputs in full passes that the padded
         program never makes (PERF.md section 6, PR 30)."""
-        if not self.compact:
-            return x.reshape((self.R, self.Tc) + x.shape[1:])
-        # a zero row past the flat tokens for the positions that hold none:
-        # a "fill" gather would go over the padded result once more
-        x = jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
-        return jnp.swapaxes(jnp.take(x, self._dst, axis=0, mode="clip"), 0, 1)
+        with jax.named_scope(SCOPE):
+            if not self.compact:
+                return x.reshape((self.R, self.Tc) + x.shape[1:])
+            # a zero row past the flat tokens for the positions that hold
+            # none: a "fill" gather would go over the padded result once more
+            x = jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+            return jnp.swapaxes(
+                jnp.take(x, self._dst, axis=0, mode="clip"), 0, 1)
