@@ -70,14 +70,13 @@ from paddle_tpu.profiler import compile_tracker  # noqa: E402
 # ops/pallas_ops.py, as Mosaic custom calls name them): one missing is a
 # reference served quietly, one too many — an int8 kernel in the dense
 # engine — is a different model.
-TRAIN_KERNELS = {"_qkv_fused_kernel", "_attn_epi_kernel", "_mlp_fused_kernel",
+# The train step runs the unfused decoder blocks on the flash kernels (per
+# device wherever the mesh splits batch or heads); the forward kernel once
+# a layer, as the layers keep its output for the backward pass: run_trainer
+# checks in every plan's lowered text that none sits in the recomputation.
+TRAIN_KERNELS = {"_flash_fwd_kernel_resident",
                  "_flash_bwd_dq_kernel_resident",
-                 "_flash_bwd_dkv_kernel_resident", "_mlp_bwd_dx_kernel"}
-# under tensor parallelism the fused blocks are excluded by rule
-# (llama._fused_block_modes) and flash attention runs per device
-TRAIN_KERNELS_MP = {"_flash_fwd_kernel_resident",
-                    "_flash_bwd_dq_kernel_resident",
-                    "_flash_bwd_dkv_kernel_resident"}
+                 "_flash_bwd_dkv_kernel_resident"}
 SERVE_KERNELS = {"_rpa_kernel", "_kv_write_kernel"}
 SERVE_KERNELS_INT8 = {"_rpa_kernel_quant", "_int8_matmul_kernel"}
 SCAN_KERNEL = "_ssm_scan_kernel"
@@ -146,6 +145,15 @@ def pallas_kernels(lowered_text: str) -> set:
     return set(re.findall(r'kernel_name = "([^"]+)"', lowered_text))
 
 
+def rematted_kernels(lowered_text: str) -> set:
+    """Kernels that a lowered program (its text with debug_info) runs a
+    second time, in the backward pass's recomputation of a layer: the
+    ``pallas/<kernel>`` scopes under ``rematted_computation``."""
+    return {loc.split("pallas/")[1].split("/")[0]
+            for loc in re.findall(r'loc\("([^"]*pallas/[^"]*)"', lowered_text)
+            if "rematted_computation" in loc}
+
+
 def seeded_batch(cfg, batch: int, seq: int, seed: int = 0) -> dict:
     ids = np.random.default_rng(seed).integers(
         0, cfg.vocab_size, (batch, seq + 1), dtype=np.int32)
@@ -166,11 +174,15 @@ def run_trainer(cfg, *, batch: int, seq: int, steps: int, plan: Plan,
     placed = {k: jax.device_put(v, step_fn.batch_shardings[k])
               for k, v in host.items()}
 
-    found = pallas_kernels(
-        step_fn.lower(params, opt_state, placed).as_text())
+    lowered = step_fn.lower(params, opt_state, placed).as_text(
+        debug_info=True)
+    found = pallas_kernels(lowered)
     log(f"train step kernels: {sorted(found)}")
     check(found == expect_kernels, f"train step runs Pallas kernels "
           f"{sorted(found)}, not {sorted(expect_kernels)}")
+    again = rematted_kernels(lowered)
+    check(not again, f"the backward pass runs {sorted(again)} a second "
+          f"time: the layers do not keep the flash forward's output")
 
     plain_ce = None
     if len(devices) > 1:
@@ -532,11 +544,11 @@ def run_four_chip(cfg, *, batch: int, seq: int, steps: int) -> None:
     """Hybrid-parallel steps on four chips in this one process. What the
     compiled step does with each kernel is a rule of ``pallas_ops.kernel_axes``:
     ``Plan(dp=2, mp=2)`` — GSPMD step, flash attention per device (batch on
-    dp, heads on mp), fused blocks excluded under mp; ``Plan(dp=4)`` — fused
-    blocks per device over the batch; ``Plan(pp=2, mp=2)`` 1F1B — no Pallas
-    kernel inside the pipeline's partially manual region."""
+    dp, heads on mp); ``Plan(dp=4)`` — flash attention per device over the
+    batch; ``Plan(pp=2, mp=2)`` 1F1B — no Pallas kernel inside the
+    pipeline's partially manual region."""
     for plan, kernels in (
-            (Plan(dp=2, mp=2), TRAIN_KERNELS_MP),
+            (Plan(dp=2, mp=2), TRAIN_KERNELS),
             (Plan(dp=4), TRAIN_KERNELS),
             (Plan(pp=2, mp=2, schedule="1f1b", n_microbatches=4), set())):
         log(f"plan {plan.to_spec()['axes']} schedule {plan.schedule}")

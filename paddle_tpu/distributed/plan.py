@@ -136,6 +136,10 @@ def _wrap_step_tracing(plan: "Plan", step_fn: Callable) -> Callable:
     compute measured overlap with the simulator's exact event schema.
     """
     counter = {"n": 0}
+    # what the layers keep for the backward pass (llama.saved_residuals),
+    # worked out once a batch shape; a pipelined step has no such rule
+    residuals = getattr(step_fn, "residuals", None)
+    kept = {"args": {}}
 
     def traced(params, opt_state, batch):
         n = counter["n"]
@@ -146,15 +150,27 @@ def _wrap_step_tracing(plan: "Plan", step_fn: Callable) -> Callable:
                     plan.pp, plan.n_microbatches or plan.pp,
                     overlap=plan.overlap, step=n)
             _trace.barrier(f"train/step{n}")
+        if residuals is not None and \
+                batch["input_ids"].shape != kept.get("shape"):
+            names, nbytes, _ = residuals(batch["input_ids"].shape)
+            kept.update(shape=batch["input_ids"].shape,
+                        args={"saved": ",".join(names),
+                              "saved_bytes": nbytes})
         with _trace.span("train/step", step=n, pp=plan.pp,
-                         schedule=plan.schedule):
+                         schedule=plan.schedule, **kept["args"]):
             return step_fn(params, opt_state, batch)
 
-    for attr in ("jitted", "lower", "abstract_state", "batch_shardings",
-                 "plan", "plan_topology"):
-        if hasattr(step_fn, attr):
-            setattr(traced, attr, getattr(step_fn, attr))
+    _copy_step_attrs(step_fn, traced)
     return traced
+
+
+def _copy_step_attrs(step_fn: Callable, wrapper: Callable) -> None:
+    """What ``build_train_step`` and ``Plan.train_step`` hang on a step
+    function, carried over to a wrapper of it."""
+    for attr in ("jitted", "lower", "abstract_state", "batch_shardings",
+                 "residuals", "plan", "plan_topology"):
+        if hasattr(step_fn, attr):
+            setattr(wrapper, attr, getattr(step_fn, attr))
 
 
 @dataclasses.dataclass
@@ -365,10 +381,10 @@ class Plan:
             n_microbatches=n_micro, zero=zero, schedule=schedule,
             overlap=self.overlap)
 
+        step_fn.plan = self
+        step_fn.plan_topology = topo
         do_verify = flag("FLAGS_tpu_lint") if verify is None else verify
         if not do_verify:
-            step_fn.plan = self
-            step_fn.plan_topology = topo
             return _wrap_step_tracing(self, step_fn), init_fn
 
         state = {"checked": False}
@@ -383,12 +399,7 @@ class Plan:
                 state["checked"] = True
             return inner(params, opt_state, batch)
 
-        verified_step.jitted = inner.jitted
-        verified_step.lower = inner.lower
-        verified_step.abstract_state = inner.abstract_state
-        verified_step.batch_shardings = inner.batch_shardings
-        verified_step.plan = self
-        verified_step.plan_topology = topo
+        _copy_step_attrs(inner, verified_step)
         return _wrap_step_tracing(self, verified_step), init_fn
 
     # -- spec round-trip ----------------------------------------------------

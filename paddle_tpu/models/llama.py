@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import math
 import types
 from typing import Any, Dict, Optional, Tuple
@@ -37,6 +38,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 from .decoding import GenerationMixin
@@ -63,19 +65,17 @@ class LlamaConfig:
     moe_num_experts: int = 0          # 0 => dense FFN
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
-    # training
+    # training: each layer rematerialised in the backward pass, keeping of
+    # what that pass reads (SAVED_NAMES) as much as the device's memory
+    # holds (saved_residuals: the train step decides, from the shapes)
     use_remat: bool = True
-    # remat policy: "full" recomputes everything (min memory);
-    # "dots" saves matmul outputs and recomputes only elementwise chains
-    # (near-zero extra FLOPs — the right default when activations fit)
-    remat_policy: str = "dots"
     # fused decoder-block Pallas kernels (ops.pallas_ops
     # fused_attention_block / fused_mlp_block) — same math as the unfused
-    # composition, so a kernel choice: "auto" = on TPU where shape and
-    # mesh qualify (_fused_block_modes), "on" = wherever the kernels can
-    # run (incl. the interpreter — what parity tests use), "off" = always
-    # the unfused composition
-    fused_blocks: str = "auto"
+    # composition on the flash kernels, at 1.7x its step time on a v5e
+    # (PERF.md section 6, PR 36): "on" = wherever the kernels can run
+    # (_fused_block_modes; incl. the interpreter — what parity tests
+    # use), "off" = the unfused composition
+    fused_blocks: str = "off"
     # int8 weight path for serving (quantize_params + the pallas_ops
     # int8_matmul kernels): a different model, so never chosen from the
     # platform — "on" quantizes at engine build everywhere (CPU runs the
@@ -83,11 +83,8 @@ class LlamaConfig:
     quantized: str = "off"
 
     def __post_init__(self):
-        assert self.remat_policy in ("full", "dots"), \
-            f"remat_policy must be 'full' or 'dots', got " \
-            f"{self.remat_policy!r}"
-        assert self.fused_blocks in ("auto", "on", "off"), \
-            f"fused_blocks must be 'auto', 'on' or 'off', got " \
+        assert self.fused_blocks in ("on", "off"), \
+            f"fused_blocks must be 'on' or 'off', got " \
             f"{self.fused_blocks!r}"
         assert self.quantized in ("on", "off"), \
             f"quantized must be 'on' or 'off', got {self.quantized!r}"
@@ -272,6 +269,12 @@ def _attention(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
     v = _qmm(x, lp["wv"]).reshape(B, S, nkv, d)
     q = _apply_rope(q, sin, cos)
     k = _apply_rope(k, sin, cos)
+    if cp_axis_level or cp_mesh is not None:
+        # the ring has no backward rule of its own, so a rematerialised
+        # layer runs it again whatever is kept (no attn_out here); q, k
+        # and v kept spare that pass the projections and rotary products
+        q, k, v = (checkpoint_name(t, n) for t, n in
+                   ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
     if cp_axis_level:
         # already inside a shard_map that maps cp_axis (the pipeline's
         # pp x sp region): call the axis-level ring directly — nesting
@@ -302,8 +305,8 @@ def _attention(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
 
 
 def _dense_mlp(lp, x):
-    gate = jax.nn.silu(_qmm(x, lp["w_gate"]))
-    up = _qmm(x, lp["w_up"])
+    gate = jax.nn.silu(checkpoint_name(_qmm(x, lp["w_gate"]), "mlp_gate"))
+    up = checkpoint_name(_qmm(x, lp["w_up"]), "mlp_up")
     return _qmm(gate * up, lp["w_down"])
 
 
@@ -410,8 +413,10 @@ def _moe_mlp(cfg: LlamaConfig, lp, x):
     combine = disp * gate_vals[..., None, None].astype(x.dtype)
     disp2 = disp.sum(1)                                     # [T, E, C]
     expert_in = jnp.einsum("tec,th->ech", disp2, xt)        # [E, C, H]
-    gate = jax.nn.silu(jnp.einsum("ech,ehi->eci", expert_in, lp["w_gate"]))
-    up = jnp.einsum("ech,ehi->eci", expert_in, lp["w_up"])
+    gate = jax.nn.silu(checkpoint_name(
+        jnp.einsum("ech,ehi->eci", expert_in, lp["w_gate"]), "mlp_gate"))
+    up = checkpoint_name(
+        jnp.einsum("ech,ehi->eci", expert_in, lp["w_up"]), "mlp_up")
     expert_out = jnp.einsum("eci,eih->ech", gate * up, lp["w_down"])
     out = jnp.einsum("tkec,ech->th", combine, expert_out)
     # aux load-balancing loss (GShard)
@@ -422,21 +427,123 @@ def _moe_mlp(cfg: LlamaConfig, lp, x):
     return out.reshape(B, S, H), aux
 
 
+# ---------------------------------------------------------------------------
+# what a layer keeps for its backward pass
+# ---------------------------------------------------------------------------
+
+# Checkpoint names of what a rematerialised layer's backward reads and the
+# layer's input does not give for free: the flash forward rule's residuals
+# (ops.pallas_ops.causal_attention: q, k and v after the rotary product in
+# the kernels' layout, the output with its log-sum-exp under one name) and
+# the feed-forward's gate and up products. Saving none of them is full
+# rematerialisation; saving all of them leaves norms, rotary products and
+# the wo matmul to recompute.
+SAVED_NAMES = ("attn_out", "attn_q", "attn_k", "attn_v", "mlp_gate",
+               "mlp_up")
+# the share of the device's memory that the estimate leaves free: what the
+# estimate cannot see (the compiler's own scratch, a caller's arrays beside
+# the step's) and its own error
+_MEMORY_MARGIN = 1 / 16
+_LOG = logging.getLogger(__name__)
+
+
+def _memory_limit(mesh):
+    """Bytes a device of ``mesh`` reports room for, asked of one this
+    process addresses (another's raises; every host has the same kind);
+    None where it reports none (a CPU)."""
+    return (mesh.local_devices[0].memory_stats() or {}).get("bytes_limit")
+
+
+def saved_residuals(cfg: LlamaConfig, tokens: int, seq: int,
+                    param_bytes: int, opt_bytes: int, limit, mp: int = 1,
+                    names=SAVED_NAMES):
+    """(names, their bytes, the step's estimated peak bytes): the longest
+    prefix of ``names`` (those of SAVED_NAMES the traced layer has, in
+    SAVED_NAMES' order: layer_names) that the layers of a train step can
+    keep for the backward pass on a device that holds ``tokens``
+    positions of sequences of ``seq``, ``param_bytes`` of parameters and
+    ``opt_bytes`` of optimizer state and reports room for ``limit`` bytes
+    (None: no limit, keep everything) less _MEMORY_MARGIN. SAVED_NAMES
+    stands in the order of what a kept byte spares the backward pass: the
+    flash forward (for sequences of half the hidden size or more), then
+    a projection with its rotary product and relayout, then a projection.
+
+    The estimate: parameters and optimizer state; every layer's input,
+    which the layer scan keeps whatever is saved; what is saved (k and v
+    at q's width: grouped heads are repeated before the flash kernels);
+    and the larger transient of the two below. Fitted to libtpu's peak
+    for the step at hidden 2048 on one v5e (within 0.2% at 16 and 24
+    layers); compiled elsewhere it reads from 1.4% low (hidden 4096) to
+    9-22% high, the safe side (internlm2-1.8b; four chips): PERF.md
+    section 4."""
+    H, I, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_hidden_layers
+    w = jnp.dtype(cfg.dtype).itemsize
+    ffn_rows = tokens
+    if cfg.moe_num_experts > 0:   # the experts' capacity buffers
+        ffn_rows = math.ceil(cfg.moe_capacity_factor * tokens * cfg.moe_top_k)
+    proj, ffn = tokens * H * w // mp, ffn_rows * I * w // mp
+    layer_bytes = {
+        "attn_out": tokens * (H * w + cfg.num_attention_heads * 4) // mp,
+        "attn_q": proj, "attn_k": proj, "attn_v": proj,
+        "mlp_gate": ffn, "mlp_up": ffn}
+    # the head's float32 logits and their cotangent live before the
+    # gradients do; one layer's backward (gate, up, their product and the
+    # cotangents of the three) lives beside all the gradients, and where
+    # the attention is XLA's (q named and no output: a ring, the body
+    # that serves without a flash kernel) so do its float32 scores, its
+    # probabilities and the cotangents of both
+    scores = 0
+    if "attn_q" in names and "attn_out" not in names:
+        scores = tokens * cfg.num_attention_heads * seq * 4 // mp
+    transient = max(2 * tokens * cfg.vocab_size * 4 // mp,
+                    param_bytes + 5 * ffn + 4 * scores)
+    estimate = param_bytes + opt_bytes + L * tokens * H * w + transient
+    room = math.inf if limit is None else limit * (1 - _MEMORY_MARGIN)
+    saved, kept = [], 0
+    for name in names:
+        if estimate + kept + L * layer_bytes[name] > room:
+            break
+        saved.append(name)
+        kept += L * layer_bytes[name]
+    return tuple(saved), kept, estimate + kept
+
+
+def layer_names(cfg: LlamaConfig, x, cp_mesh=None, kernels=True):
+    """Those of SAVED_NAMES that ``decoder_layer`` names when traced on
+    ``x`` at this point of the trace: the fused blocks name nothing, and
+    the attention's output is named by the flash forward rule only: not
+    by the ring of a context-parallel plan (``cp_mesh``), nor by the XLA
+    body that serves where no Pallas kernel can run (off the chip, or
+    ``kernels`` False for a region that is manual over a part of the
+    mesh)."""
+    from ..ops import pallas_ops
+    fused_attn, fused_mlp = (False, False) if not kernels else \
+        _fused_block_modes(cfg, x, cp_mesh, False)
+    flash = (kernels and cp_mesh is None
+             and pallas_ops.flash_attention_available(
+                 x.shape[:2] + (cfg.num_attention_heads, cfg.head_dim),
+                 x.dtype))
+    gone = set()
+    if fused_attn:
+        gone |= {"attn_out", "attn_q", "attn_k", "attn_v"}
+    elif not flash:
+        gone.add("attn_out")
+    if fused_mlp:
+        gone |= {"mlp_gate", "mlp_up"}
+    return tuple(n for n in SAVED_NAMES if n not in gone)
+
+
 def _fused_block_modes(cfg: LlamaConfig, x, cp_mesh, cp_axis_level):
     """(use_fused_attention, use_fused_mlp) — resolved at trace time from
-    cfg.fused_blocks, the platform, the shape and the mesh. "auto" engages
-    only on real TPU (never the CPU jnp path a test traces); "on" engages
-    wherever the kernels can run, including the Pallas interpreter —
-    which is how parity tests exercise this. Mesh rule, on top of
+    cfg.fused_blocks, the shape and the mesh. "on" engages wherever the
+    kernels can run, including the Pallas interpreter — which is how
+    parity tests exercise this. Mesh rule, on top of
     pallas_ops.kernel_axes: the kernels take whole [H, H] / [H, I]
     weights and add the residual inside, so under tensor parallelism
     (mp > 1: column/row weight shards, a psum before the residual) they
     are excluded; with mp == 1 they run per device over the batch axes."""
     from ..ops import pallas_ops
-    mode = cfg.fused_blocks
-    if mode == "off":
-        return False, False
-    if mode == "auto" and not pallas_ops._on_tpu():
+    if cfg.fused_blocks == "off":
         return False, False
     if pallas_ops.kernel_axes() and \
             jax.sharding.get_abstract_mesh().shape.get("mp", 1) > 1:
@@ -495,9 +602,11 @@ def decoder_layer(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
 
 def run_layer_stack(cfg: LlamaConfig, stacked, x, sin, cos,
                     cp_mesh=None, cp_axis="sp", cp_axis_level=False,
-                    grad_sync_axis=None):
+                    grad_sync_axis=None, save=SAVED_NAMES):
     """lax.scan over the stacked layer axis (compiler-friendly sequential
-    control flow; remat per layer = the recompute strategy).
+    control flow; remat per layer = the recompute strategy: the backward
+    pass keeps a layer's input and, of SAVED_NAMES, those in ``save``,
+    and recomputes the rest of the layer).
 
     grad_sync_axis: when set (manual shard_map data parallelism), each
     layer's parameter slice is routed through ``reduce_in_backward`` so
@@ -515,11 +624,9 @@ def run_layer_stack(cfg: LlamaConfig, stacked, x, sin, cos,
             lp = reduce_tree_in_backward(lp, grad_sync_axis)
         fn = layer_fn
         if cfg.use_remat:
-            policy = None  # "full": save nothing, recompute the layer
-            if cfg.remat_policy == "dots":
-                policy = jax.checkpoint_policies.dots_saveable
-            fn = jax.checkpoint(layer_fn, static_argnums=(0,),
-                                policy=policy)
+            fn = jax.checkpoint(
+                layer_fn, static_argnums=(0,),
+                policy=jax.checkpoint_policies.save_only_these_names(*save))
         h, a = fn(cfg, lp, h, sin, cos)
         return (h, aux + a), None
     with jax.named_scope("layers"):
@@ -529,12 +636,14 @@ def run_layer_stack(cfg: LlamaConfig, stacked, x, sin, cos,
 
 
 def forward_pure(cfg: LlamaConfig, params, input_ids, sp_axis=None,
-                 cp_mesh=None, cp_axis="sp", grad_sync_axis=None):
+                 cp_mesh=None, cp_axis="sp", grad_sync_axis=None,
+                 save=SAVED_NAMES):
     """Full forward: ids -> logits (fp32). sp_axis: mesh axis name to shard
     the sequence dimension of activations on (Megatron-style sequence
     parallelism for the elementwise/norm work). cp_mesh: enable ring-
     attention context parallelism over the mesh's 'sp' axis — sequence
-    sharded end to end, exact causal attention at O(S/sp) memory."""
+    sharded end to end, exact causal attention at O(S/sp) memory. save:
+    what the layers keep for a backward pass (run_layer_stack)."""
     B, S = input_ids.shape
     sin, cos = _rope_tables(cfg, S)
     with jax.named_scope("embed"):
@@ -549,7 +658,7 @@ def forward_pure(cfg: LlamaConfig, params, input_ids, sp_axis=None,
         x = lax.with_sharding_constraint(x, P("dp", sp_axis, None))
     x, aux = run_layer_stack(cfg, params["layers"], x, sin, cos,
                              cp_mesh=cp_mesh, cp_axis=cp_axis,
-                             grad_sync_axis=grad_sync_axis)
+                             grad_sync_axis=grad_sync_axis, save=save)
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
         logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
@@ -557,11 +666,12 @@ def forward_pure(cfg: LlamaConfig, params, input_ids, sp_axis=None,
 
 
 def loss_fn(cfg: LlamaConfig, params, batch, sp_axis=None,
-            cp_mesh=None, cp_axis="sp", grad_sync_axis=None):
+            cp_mesh=None, cp_axis="sp", grad_sync_axis=None,
+            save=SAVED_NAMES):
     ids, labels = batch["input_ids"], batch["labels"]
     logits, aux = forward_pure(cfg, params, ids, sp_axis, cp_mesh=cp_mesh,
                                cp_axis=cp_axis,
-                               grad_sync_axis=grad_sync_axis)
+                               grad_sync_axis=grad_sync_axis, save=save)
     # logsumexp form: ce = lse - target_logit. Avoids materializing the
     # full [B, S, V] log-softmax (1 GB fp32 at bench shapes) — XLA fuses
     # the reduction into the lm_head matmul epilogue.
@@ -1003,11 +1113,12 @@ def build_train_step(cfg: LlamaConfig, topo, optimizer=None, use_pp=None,
         if overlap_dp:
             from ..distributed.overlap import bucketed_psum
 
-            def _dp_body(params, batch):
+            def _dp_body(save, params, batch):
                 def local_loss(p):
                     # local mean loss scaled by 1/dp: psum of its grads
                     # over 'dp' is exactly the global-batch gradient
-                    t, c = loss_fn(cfg, p, batch, grad_sync_axis="dp")
+                    t, c = loss_fn(cfg, p, batch, grad_sync_axis="dp",
+                                   save=save)
                     return t / dp_deg, (t, c)
                 (_, (t, c)), grads = jax.value_and_grad(
                     local_loss, has_aux=True)(params)
@@ -1022,8 +1133,9 @@ def build_train_step(cfg: LlamaConfig, topo, optimizer=None, use_pp=None,
 
             def grad_fn(params, batch):
                 param_p = jax.tree_util.tree_map(lambda _: P(), params)
+                save = kept(batch)
                 total, ce, grads = jax.shard_map(
-                    _dp_body, mesh=mesh,
+                    functools.partial(_dp_body, save), mesh=mesh,
                     in_specs=(param_p,
                               {"input_ids": P("dp", None),
                                "labels": P("dp", None)}),
@@ -1032,9 +1144,11 @@ def build_train_step(cfg: LlamaConfig, topo, optimizer=None, use_pp=None,
                 return (total, ce), grads
         else:
             def loss(params, batch):
+                save = kept(batch)
                 if cp_mesh is not None:  # ring-attention context parallel
-                    return loss_fn(cfg, params, batch, cp_mesh=cp_mesh)
-                return loss_fn(cfg, params, batch, sp_axis="mp")
+                    return loss_fn(cfg, params, batch, cp_mesh=cp_mesh,
+                                   save=save)
+                return loss_fn(cfg, params, batch, sp_axis="mp", save=save)
 
     from ._sharding_utils import sharding_tree
     param_sh = sharding_tree(mesh, specs)
@@ -1155,9 +1269,49 @@ def build_train_step(cfg: LlamaConfig, topo, optimizer=None, use_pp=None,
         o_abs = jax.tree_util.tree_map_with_path(leaf_abs, o_abs)
         return p_abs, o_abs
 
+    def device_bytes(tree):
+        return sum(math.prod(l.sharding.shard_shape(l.shape))
+                   * l.dtype.itemsize
+                   for l in jax.tree_util.tree_leaves(tree))
+
+    @functools.lru_cache(maxsize=None)
+    def residuals(batch_shape):
+        """``saved_residuals`` for a global batch of this shape on this
+        topology: what the layers of the step keep for the backward pass
+        of what they name (``layer_names``), decided (and logged) once a
+        shape, as the step is traced."""
+        B, S = batch_shape
+        split = math.prod(topo.dims.get(a, 1)
+                          for a in ("dp", "sharding", "sp"))
+        p_abs, o_abs = abstract_state()
+        limit = _memory_limit(mesh)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            named = layer_names(
+                cfg, jax.ShapeDtypeStruct((B, S, cfg.hidden_size),
+                                          cfg.dtype),
+                cp_mesh=cp_mesh, kernels=not overlap_dp)
+        names, nbytes, estimate = saved_residuals(
+            cfg, B * S // split, S, device_bytes(p_abs),
+            device_bytes(o_abs), limit, mp=topo.dims.get("mp", 1),
+            names=named)
+        _LOG.info("train step of %s x %s tokens: layers keep %s for the "
+                  "backward pass (%d bytes a device); estimated peak %d "
+                  "bytes a device of %s", B, S, ",".join(names) or "nothing",
+                  nbytes, estimate, limit)
+        return names, nbytes, estimate
+
+    def kept(batch):
+        if not cfg.use_remat:   # nothing is rematerialised: no rule
+            return ()
+        return residuals(batch["input_ids"].shape)[0]
+
     step_fn.jitted = step_jit
     step_fn.lower = lower
     step_fn.abstract_state = abstract_state
+    # the pipeline schedules keep all of SAVED_NAMES, and a step whose
+    # layers are not rematerialised keeps everything: no rule to report
+    if cfg.use_remat and not use_pp:
+        step_fn.residuals = residuals
     step_fn.batch_shardings = batch_sh
     return step_fn, init_fn
 

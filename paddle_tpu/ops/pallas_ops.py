@@ -802,11 +802,22 @@ def causal_attention(q, k, v):
 
 
 def _fwd(q, k, v):
+    # The residuals carry checkpoint names (the identity outside a
+    # jax.checkpoint): a policy that saves them by name keeps what the
+    # backward kernels read, as they read it, and a rematerialised layer
+    # runs no second flash forward. The output and its log-sum-exp share
+    # a name: either alone buys nothing, the forward would run again for
+    # the other.
+    name = jax.ad_checkpoint.checkpoint_name
     if flash_attention_available(q.shape, q.dtype):
         B, H = q.shape[0], q.shape[2]
-        qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
+        qb, kb, vb = (name(_to_bh(t), n) for t, n in
+                      ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
         out, lse = _flash_fwd(qb, kb, vb)
+        # every lane of a row of lse holds the row's value: keep one
+        out, lse = name(out, "attn_out"), name(lse[..., 0], "attn_out")
         return _from_bh(out, B, H), (qb, kb, vb, out, lse)
+    q, k, v = name(q, "attn_q"), name(k, "attn_k"), name(v, "attn_v")
     return _attention_jnp(q, k, v), (q, k, v)
 
 
@@ -815,6 +826,7 @@ def _bwd(res, g):
         qb, kb, vb, out, lse = res
         B, H = g.shape[0], g.shape[2]
         gb = _to_bh(g)
+        lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LANES,))
         dq, dk, dv = _flash_bwd(qb, kb, vb, gb, out, lse)
         return (_from_bh(dq, B, H), _from_bh(dk, B, H), _from_bh(dv, B, H))
     q, k, v = res

@@ -55,9 +55,11 @@ def _run_variant(label, *, dp, pp, sp, mp, schedule, nprocs,
               hidden_size=16 * mp * max(pp, 1) * max(sp, 1),
               intermediate_size=32 * mp,
               vocab_size=64 * mp)
+    # layers rematerialised, the default: the step then sizes what they
+    # keep to the memory a device reports, which only a device this
+    # process addresses answers (the cp and zero variants go that way)
     cfg = llama.LlamaConfig(
-        max_position_embeddings=64, dtype=jnp.float32, use_remat=False,
-        **kw)
+        max_position_embeddings=64, dtype=jnp.float32, **kw)
     n_micro = 2 * pp if pp > 1 else None
     step_fn, init_fn = llama.build_train_step(
         cfg, topo, use_pp=(pp > 1), n_microbatches=n_micro,

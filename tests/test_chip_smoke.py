@@ -7,6 +7,7 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from paddle_tpu.models import llama
@@ -34,6 +35,24 @@ def test_trainer_function_at_debug_width():
             plan=Plan(), expect_kernels=chip_smoke.TRAIN_KERNELS)
 
 
+def test_trainer_refuses_a_flash_forward_run_twice(monkeypatch):
+    """Layers that keep q, k and v but not the flash forward's output run
+    that kernel again in the backward pass: the check reads it from the
+    lowered text's scopes."""
+    from paddle_tpu.distributed.plan import Plan
+    cfg = llama.LlamaConfig(
+        vocab_size=256, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+        max_position_embeddings=256, dtype=jnp.float32)
+    kw = dict(batch=2, seq=256, steps=2, plan=Plan(), expect_kernels=set())
+    chip_smoke.run_trainer(cfg, **kw)
+    monkeypatch.setattr(llama, "layer_names",
+                        lambda *a, **k: llama.SAVED_NAMES[1:])
+    with pytest.raises(AssertionError,
+                       match=r"_flash_fwd_kernel_resident'\] a second time"):
+        chip_smoke.run_trainer(cfg, **kw)
+
+
 def test_server_functions_at_debug_width():
     # room for the prompt that spans three default (128-token) pages
     cfg = llama.preset("llama-debug", max_position_embeddings=512)
@@ -58,7 +77,6 @@ def test_scan_parity_at_debug_width(interpreted, monkeypatch):
 @pytest.mark.parametrize("interpreted", [False, True])
 def test_latent_and_experts_parity_at_debug_width(interpreted, monkeypatch):
     """The two phases of the DeepSeek-V2 kernels, as the scan's above."""
-    import jax.numpy as jnp
     monkeypatch.setattr(pallas_ops, "_INTERPRET", interpreted)
     # chunks of 8 tokens x 4 heads: rows of one and of three tokens take
     # the walk's 16-row turn, whole chunks every row

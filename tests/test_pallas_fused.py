@@ -216,8 +216,8 @@ def test_decoder_layer_fused_matches_unfused():
 
 
 def test_decoder_layer_policy_defaults_off_on_cpu():
-    """The default fused_blocks='auto' is a TPU-only kernel choice: on CPU
-    (even under the interpreter) it must keep the unfused path."""
+    """The default fused_blocks='off' keeps the unfused path (also under
+    the interpreter, and on the chip: tests/test_remat_rule.py)."""
     from paddle_tpu.models import llama
 
     cfg = llama.LlamaConfig(
