@@ -82,8 +82,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import mla
 from .experts import route_top_k, routed_experts
 from .jamba import _layer_at
+from .mla import rms_norm as _rms_norm, swiglu as _swiglu
 from .step_layout import StepLayout
 
 __all__ = ["DeepseekV2Config", "PRESETS", "preset", "config_from_fields",
@@ -93,7 +95,6 @@ __all__ = ["DeepseekV2Config", "PRESETS", "preset", "config_from_fields",
 YARN_LITE = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
              "mscale": 0.707, "mscale_all_dim": 0.707,
              "original_max_position_embeddings": 4096}
-_LANES = 128
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 # what the device counts of a step, in the order of ``forward_paged``'s
 # third result: experts with at least one row, summed over the expert
@@ -142,9 +143,11 @@ class DeepseekV2Config:
                 or self.topk_method != "greedy" or self.moe_layer_freq != 1
                 or self.n_group != 1 or self.norm_topk_prob):
             raise ValueError(
-                "written for queries without a latent, softmax scores, a "
-                "greedy top-k over one group taken as it is, and an expert "
-                f"layer in every layer after the dense ones; got {self}")
+                "this model's parameters hold no query latent (models/mla.py "
+                "computes one, for models/longcat_flash.py), and its router "
+                "is softmax scores, a greedy top-k over one group taken as "
+                "it is, an expert layer in every layer after the dense "
+                f"ones; got {self}")
         if not 0 < self.first_k_dense_replace < self.num_hidden_layers:
             raise ValueError("dense layers first, then expert layers: "
                              f"{self.first_k_dense_replace} of "
@@ -166,8 +169,7 @@ class DeepseekV2Config:
     @property
     def latent_lanes(self) -> int:
         """Lanes of a cached token's vector: ``r + dr`` in whole tiles."""
-        return -(-(self.kv_lora_rank + self.qk_rope_head_dim)
-                 // _LANES) * _LANES
+        return mla.latent_lanes(self)
 
     @property
     def softmax_scale(self) -> float:
@@ -319,48 +321,6 @@ def init_params(cfg: DeepseekV2Config, key) -> Dict[str, Any]:
 # the layers, on the flat tokens of a step
 # ---------------------------------------------------------------------------
 
-def _rms_norm(x, w, eps):
-    xf = x.astype(jnp.float32)
-    return (xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-            * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _swiglu(x, w_gate, w_up, w_down):
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
-
-
-def _rotate(x, sin, cos):
-    """``x [T, heads, dr]`` turned by its token's angle (``sin, cos [T, dr /
-    2]`` float32): pair j is lanes ``(j, j + dr/2)``."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half].astype(jnp.float32), \
-        x[..., half:].astype(jnp.float32)
-    sin, cos = sin[:, None, :], cos[:, None, :]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _mla_project(cfg, lp, xn, sin, cos):
-    """The projections of one layer for the normed tokens ``xn [T, D]``:
-    ``q_nope [T, nh, dn]``, rotated ``q_pe [T, nh, dr]``, the normed latent
-    ``c [T, r]`` and the rotated ``k_pe [T, dr]``."""
-    nh, r = cfg.num_attention_heads, cfg.kv_lora_rank
-    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    T = xn.shape[0]
-    q = (xn @ lp["wq"]).reshape(T, nh, dn + dr)
-    kv = xn @ lp["wkva"]
-    c = _rms_norm(kv[:, :r], lp["kv_norm"], cfg.rms_norm_eps)
-    return (q[..., :dn], _rotate(q[..., dn:], sin, cos), c,
-            _rotate(kv[:, None, r:], sin, cos)[:, 0])
-
-
-def _up_projections(cfg, lp):
-    """``W_UK [r, nh, dn]`` and ``W_UV [r, nh, dv]`` out of ``W_kvb``."""
-    w = lp["wkvb"].reshape(cfg.kv_lora_rank, cfg.num_attention_heads,
-                           cfg.qk_nope_head_dim + cfg.v_head_dim)
-    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
-
-
 def _expert_ffn(cfg, lp, experts, xn, l, live):
     """The expert layer ``l`` of the stacks for the normed tokens ``xn [T,
     D]``: ``shared(x) + sum_e p_e expert_e(x)``, and the rows each held
@@ -436,23 +396,9 @@ def forward_pure(cfg: DeepseekV2Config, params, input_ids):
     the MATERIALISED form of the attention (every head's K and V expanded
     from the latent, plain causal softmax)."""
     B, S = input_ids.shape
-    nh, dv = cfg.num_attention_heads, cfg.v_head_dim
-    causal = jnp.tril(jnp.ones((S, S), bool))
 
     def mixer(lp, xn, i, sin, cos, carry):
-        q_nope, q_pe, c, k_pe = _mla_project(cfg, lp, xn, sin, cos)
-        w_uk, w_uv = _up_projections(cfg, lp)
-        k_nope = jnp.einsum("tc,chn->thn", c, w_uk)
-        v = jnp.einsum("tc,chv->thv", c, w_uv)
-        rows = lambda t: t.reshape((B, S) + t.shape[1:])   # noqa: E731
-        s = (jnp.einsum("bqhn,bkhn->bhqk", rows(q_nope), rows(k_nope),
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bqhr,bkr->bhqk", rows(q_pe), rows(k_pe),
-                          preferred_element_type=jnp.float32))
-        p = jax.nn.softmax(jnp.where(causal, s * cfg.softmax_scale, -jnp.inf),
-                           axis=-1)
-        o = jnp.einsum("bhqk,bkhv->bqhv", p.astype(v.dtype), rows(v))
-        return o.reshape(B * S, nh * dv), carry
+        return mla.materialised(cfg, lp, xn, sin, cos, B, S), carry
 
     pos = jnp.tile(jnp.arange(S, dtype=jnp.int32), B)
     logits, _, _ = _forward(cfg, params, input_ids.reshape(-1), pos,
@@ -518,39 +464,16 @@ def forward_paged(cfg: DeepseekV2Config, params, tokens, cache, block_tables,
     (``latent_paged_attention``).  Projections, rotation, the absorption and
     the expert layers are per token, on the flat layout; padding tokens are
     routed to no expert."""
-    from ..ops.pallas_ops import latent_paged_attention, paged_latent_write
     R, Tc = tokens.shape
-    nh, r, dr = (cfg.num_attention_heads, cfg.kv_lora_rank,
-                 cfg.qk_rope_head_dim)
-    lanes, scale = cfg.latent_lanes, cfg.softmax_scale
     lay = StepLayout(q_lens, Tc, step_tokens)
-    T = lay.T
     t_off = jnp.arange(Tc, dtype=jnp.int32)[None, :]
     start = (seq_lens - q_lens).astype(jnp.int32)[:, None]
     pos = lay.flat(jnp.maximum(start + t_off, 0))
     live = lay.flat(t_off < q_lens[:, None])
 
     def mixer(lp, xn, i, sin, cos, pages):
-        q_nope, q_pe, c, k_pe = _mla_project(cfg, lp, xn, sin, cos)
-        w_uk, w_uv = _up_projections(cfg, lp)
-        q_abs = jnp.einsum("thn,chn->thc", q_nope, w_uk)
-        pad = lanes - r - dr
-        q_lat = jnp.concatenate(
-            [q_abs, q_pe, jnp.zeros((T, nh, pad), q_abs.dtype)], axis=-1)
-        new = jnp.concatenate([c, k_pe, jnp.zeros((T, pad), c.dtype)],
-                              axis=-1)
-        new = lay.rows(new)[:, :, None, :]                  # [R, Tc, 1, lanes]
-        q_lat = lay.rows(q_lat).reshape(R, 1, Tc * nh, lanes)
-        with jax.named_scope("kv_write"):
-            pages = paged_latent_write(pages, new, block_tables, seq_lens,
-                                       q_lens, layer=i)
-        with jax.named_scope("mla_core"):
-            o_lat = latent_paged_attention(
-                q_lat, pages, block_tables, seq_lens, q_lens, rep=nh,
-                v_lanes=r, scale=scale, layer=i)
-        o_lat = lay.flat(o_lat.reshape(R, Tc, nh, r))
-        o = jnp.einsum("thc,chv->thv", o_lat, w_uv)
-        return o.reshape(T, nh * cfg.v_head_dim), pages
+        return mla.absorbed(cfg, lp, xn, sin, cos, pages, lay, block_tables,
+                            seq_lens, q_lens, i)
 
     logits, pages, counts = _forward(cfg, params, lay.flat(tokens), pos, live,
                                      mixer, cache["latent"])

@@ -63,20 +63,36 @@ class StepLayout:
                               R * self.Tc)
         self._dst = jnp.where(t[:, None] < q[None, :],
                               start[None, :] + t[:, None], self.T)
+        # the same two maps for a padded side kept row-major, [R, Tc]
+        self._src_rows = jnp.where(i < ends[-1],
+                                   row * self.Tc + i - start[row],
+                                   R * self.Tc)
         self.last = jnp.clip(ends - 1, 0, self.T - 1)
 
-    def flat(self, x):
-        """``x [R, Tc, ...]`` as ``[T, ...]``: the fed tokens, then zeros."""
+    def flat(self, x, row_major=False):
+        """``x [R, Tc, ...]`` as ``[T, ...]``: the fed tokens, then zeros.
+        ``row_major``: ``x`` lies row after row in memory (a kernel wrote
+        it so) and is gathered as it lies; the default takes it
+        position-major, as ``rows`` leaves an array."""
         with jax.named_scope(SCOPE):
             if not self.compact:
                 return x.reshape((self.R * self.Tc,) + x.shape[2:])
+            if row_major:
+                return jnp.take(
+                    x.reshape((self.R * self.Tc,) + x.shape[2:]),
+                    self._src_rows, axis=0, mode="fill", fill_value=0)
             x = jnp.swapaxes(x, 0, 1).reshape(
                 (self.Tc * self.R,) + x.shape[2:])
             return jnp.take(x, self._src, axis=0, mode="fill", fill_value=0)
 
-    def rows(self, x):
+    def rows(self, x, row_major=False):
         """``x [T, ...]`` as ``[R, Tc, ...]``: each row's tokens, then zeros
         (the identity layout keeps what its padding positions computed).
+        ``row_major``: the result is gathered row after row, for a reader
+        that takes one row's positions at a time (an attention kernel's
+        query block); at 64 heads of 640 lanes the position-major gather and
+        the transpose into such a block were 0.17 GB a sublayer moved twice
+        (PERF.md section 6, PR 37).
 
         The gather writes ``[Tc, R, ...]`` and the result is its transpose,
         which costs nothing and tells XLA to keep the array position-major
@@ -91,5 +107,7 @@ class StepLayout:
             # a zero row past the flat tokens for the positions that hold
             # none: a "fill" gather would go over the padded result once more
             x = jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+            if row_major:
+                return jnp.take(x, self._dst.T, axis=0, mode="clip")
             return jnp.swapaxes(
                 jnp.take(x, self._dst, axis=0, mode="clip"), 0, 1)

@@ -1795,6 +1795,7 @@ def fused_parity_cases():
 # zeros.  ``G`` comes from the shapes (``_rpa_group_pages``).
 
 _NEG_BIG = -1e30  # finite mask value: -inf would NaN fully-masked rows
+_RPA_Q_BLOCK = 256  # query rows of a block, where a latent row has more
 
 
 def _rep_cols(col, n):
@@ -1806,7 +1807,15 @@ def _rep_cols(col, n):
     return jnp.broadcast_to(col, (col.shape[0], n))
 
 
-def _rpa_group_pages(nkv, Tr, d, page, itemsize, Bmax):
+def _rpa_row_bytes(nkv, Tr, d, itemsize):
+    """What a row takes of VMEM whatever the walk's group is: its q and o
+    blocks (the pipeline keeps two of each) and the float32 statistics and
+    accumulator of every head."""
+    return 4 * nkv * Tr * d * itemsize + nkv * Tr * (2 * _LANES + d) * 4
+
+
+def _rpa_group_pages(nkv, Tr, d, page, itemsize, Bmax, qblk=None,
+                     budget=None):
     """``G``, the pages of one DMA group: the largest power of two, at
     most the table's width, whose working set fits ``_VMEM_BUDGET``
     beside what a row needs whatever G is.  A page of the group costs K
@@ -1829,7 +1838,7 @@ def _rpa_group_pages(nkv, Tr, d, page, itemsize, Bmax):
 
 def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
               q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, m_s,
-              l_s, acc_s, *, page, rep, scale, window=None):
+              l_s, acc_s, *, page, rep, scale, window=None, qblk=None):
     """Grid step r: request r's q rows, all kv heads, against its live kv
     pages in layer ``layer_ref[0]`` of the stacked pools, ``G`` pages a
     loop turn (``kbuf``/``vbuf`` are ``[2, nkv, G * page, d]``: two slots
@@ -1952,13 +1961,23 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
     def _idle_row():
         start(nxt, first_nxt, 0, slot0, live_nxt)
 
-    tok = lax.broadcasted_iota(jnp.int32, (Tr, span), 0) // rep
-    col = lax.broadcasted_iota(jnp.int32, (Tr, span), 1)
-    # a q row sees the keys up to its own position; a padding row none
-    horizon = jnp.where(tok < qlen, kvlen - qlen + tok, -1)
-    # a key column's position, less the group's offset: the walk starts at
-    # page 0 unless a window moved it
-    pos = col if window is None else first * page + col
+    if qblk is None:
+        tok = lax.broadcasted_iota(jnp.int32, (Tr, span), 0) // rep
+        col = lax.broadcasted_iota(jnp.int32, (Tr, span), 1)
+        # a q row sees the keys up to its own position; a padding row none
+        horizon = jnp.where(tok < qlen, kvlen - qlen + tok, -1)
+        # a key column's position, less the group's offset: the walk starts
+        # at page 0 unless a window moved it
+        pos = col if window is None else first * page + col
+    elif window is not None or not latent:
+        raise ValueError("query blocks are the latent walk's, from page 0")
+
+    def block_mask(i, lo, n):
+        """The mask of group i for the query rows ``lo .. lo + n``."""
+        tok = (lo + lax.broadcasted_iota(jnp.int32, (n, span), 0)) // rep
+        col = lax.broadcasted_iota(jnp.int32, (n, span), 1)
+        return i * span + col <= jnp.where(tok < qlen, kvlen - qlen + tok,
+                                           -1)
 
     def group(i, carry):
         slot = (slot0 + i) % 2
@@ -1968,9 +1987,10 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
               jnp.where(more, i + 1, 0), 1 - slot,
               jnp.where(more, live, live_nxt))
         wait(i, slot, live)
-        mask = i * span + pos <= horizon
-        if window is not None:
-            mask &= i * span + pos > horizon - window
+        if qblk is None:
+            mask = i * span + pos <= horizon
+            if window is not None:
+                mask &= i * span + pos > horizon - window
 
         def page_scales(sc_ref, h):
             """[1, span]: each key column's page's dequant scale."""
@@ -1981,11 +2001,15 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
                 out = jnp.where(col[:1] // page == g, sc_ref[h, pg], out)
             return out
 
-        def head(h, rows=None):
-            """Head h's turn on the group; ``rows`` (static): only the first
-            ``rows`` query rows, the others being padding."""
-            top = (lambda a: a) if rows is None else (lambda a: a[:rows])
-            at = (h,) if rows is None else (h, slice(0, rows))
+        def head(h, rows=None, lo=0):
+            """Head h's turn on the group; ``rows`` (static): only the
+            ``rows`` query rows from ``lo`` on, the others being padding or
+            another block's."""
+            if qblk is not None:
+                seen = block_mask(i, lo, rows)
+            else:
+                seen = mask if rows is None else mask[:rows]
+            at = (h,) if rows is None else (h, slice(lo, lo + rows))
             q = q_ref[(0,) + at]                         # [Tr, d]
             k = kbuf[slot, h]                            # [span, d]
             v = k[:, :dv] if latent else vbuf[slot, h].astype(jnp.float32)
@@ -1995,7 +2019,7 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
                                 preferred_element_type=jnp.float32) * scale
             if ksc_ref is not None:
                 s = s * page_scales(ksc_ref, h)
-            s = jnp.where(top(mask), s, _NEG_BIG)
+            s = jnp.where(seen, s, _NEG_BIG)
             m = m_s[at]
             m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
             # a masked score is _NEG_BIG under a real row's running max
@@ -2026,10 +2050,18 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
             for h in range(nkv):
                 head(h, short)
 
-        @pl.when(qlen * rep > short)
-        def _every_row():
-            for h in range(nkv):
-                head(h)
+        if qblk is None:
+            @pl.when(qlen * rep > short)
+            def _every_row():
+                for h in range(nkv):
+                    head(h)
+            return carry
+
+        for lo in range(0, Tr, qblk):
+            @pl.when((qlen * rep > short) & (qlen * rep > lo))
+            def _block_of_rows(lo=lo):
+                for h in range(nkv):
+                    head(h, qblk, lo)
         return carry
 
     lax.fori_loop(0, n, group, 0)
@@ -2302,8 +2334,15 @@ def _rpa_latent_call(q, pages, block_tables, seq_lens, q_lens, *, rep,
         pages = pages[None]
     R, nkv, Tr, d = q.shape
     page = pages.shape[3]
+    # a row of more query rows than a block (64 heads x 16 tokens: 1024)
+    # walks them a block at a time, under a limit sized to what the row's
+    # own blocks take whatever the walk does, as much again for the walk
+    qblk = _RPA_Q_BLOCK if Tr > _RPA_Q_BLOCK and Tr % _RPA_Q_BLOCK == 0 \
+        else None
+    budget = None if qblk is None else max(
+        _VMEM_BUDGET, 2 * _rpa_row_bytes(nkv, Tr, d, pages.dtype.itemsize))
     G = _rpa_group_pages(nkv, Tr, d, page, pages.dtype.itemsize,
-                         block_tables.shape[1])
+                         block_tables.shape[1], qblk, budget)
     scalars = (block_tables, seq_lens, q_lens, _layer_operand(layer))
 
     def row_map(r, *scalars):
@@ -2327,11 +2366,13 @@ def _rpa_latent_call(q, pages, block_tables, seq_lens, q_lens, *, rep,
     )
     call = _pallas_call(
         functools.partial(_rpa_kernel_latent, page=page, rep=rep,
-                          scale=float(scale)),
+                          scale=float(scale), qblk=qblk),
         own_dma=True,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, nkv, Tr, v_lanes), q.dtype),
-        compiler_params=_compiler_params("arbitrary"),
+        compiler_params=_compiler_params("arbitrary") if budget is None
+        else pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                  vmem_limit_bytes=budget + budget // 2),
         # the block table (scalar 0) indexes axis 2 of the pool (input 1)
         # and the layer (scalar 3) its axis 0
         metadata={"dma_indexes": json.dumps([[0, 1, 2], [3, 1, 0]])},
@@ -2872,9 +2913,39 @@ def selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh, *, layer=0):
 # the v5e's 819 GB/s, over the three products' MXU time at 128 rows) and the
 # rows once a visit.  The weights stay the stacks of every layer ``[L, E, ..]``
 # with the layer as a scalar: nothing slices 1.1 GB out of them.
+#
+# An expert too wide for VMEM (D 6144, F 2048: 151 MB for two slots of its
+# three matrices) is visited a BLOCK of its width at a time, grid ``(V, F /
+# f)``: a turn holds ``(D, f)`` of the gate and up matrices and ``(f, D)`` of
+# the down matrix, and the down products of a visit's blocks add up in a
+# float32 scratch that the visit's last block folds into the tile.  ``f`` is
+# the largest divisor of F in whole 128-lane tiles whose working set fits
+# ``_MOE_BUDGET`` (``_moe_width_block``; 1408 at D 2048 stays one block and
+# the one-block kernel is the one above, untouched).  With several blocks an
+# expert's weights are read once per 128-row tile that visits it, not once a
+# step: consecutive visits of one expert no longer share a block.
 
 _MOE_ROWS = 128    # rows of a visit's tile
 _MOE_VMEM = 100 * 2 ** 20   # two slots of an expert's three matrices, tiles
+_MOE_BUDGET = 90 * 2 ** 20  # what a turn's blocks and tiles may take of it
+
+
+def _moe_width_block(D, F, itemsize, tm=_MOE_ROWS):
+    """``f``, the lanes of an expert's width one turn holds: the largest
+    divisor of F in whole 128-lane tiles whose working set fits
+    ``_MOE_BUDGET`` (F itself where the whole expert does; None where no
+    block does).  A lane of the block costs both slots of the three
+    matrices' ``D`` elements and three float32 columns of a tile (gate, up
+    and their product); a turn, whatever f, the tile of rows in both slots,
+    the float32 output in both and the accumulator."""
+    fixed = tm * D * (2 * itemsize + 2 * 4 + 4)
+    per_lane = 2 * 3 * D * itemsize + 3 * tm * 4
+    for n in range(1, F // _LANES + 1):
+        f = F // n
+        if F % n == 0 and f % _LANES == 0 \
+                and fixed + per_lane * f <= _MOE_BUDGET:
+            return f
+    return None
 
 
 def _expert_visits(group_sizes, M, tm=_MOE_ROWS):
@@ -2922,6 +2993,44 @@ def _moe_experts_kernel(layer_ref, g_ref, tile_ref, start_ref, end_ref,
         o_ref[...] = jnp.where(mine, y, kept).astype(o_ref.dtype)
 
 
+def _moe_experts_kernel_blocked(layer_ref, g_ref, tile_ref, start_ref,
+                                end_ref, n_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                                o_ref, acc_ref, *, tm):
+    """Turn (v, j): block j of expert ``g_ref[v]``'s width on the rows of
+    tile ``tile_ref[v]``.  The down products of a visit's blocks add up in
+    ``acc_ref``; the last block keeps the sum where the row is the expert's
+    own, as ``_moe_experts_kernel`` keeps its one product."""
+    from jax.experimental import pallas as pl
+    del layer_ref
+    v, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(v < n_ref[0])
+    def _live_visit():
+        g, tile = g_ref[v], tile_ref[v]
+        x = x_ref[...]                                       # [tm, D]
+        a = lax.dot(x, wg_ref[0, 0], preferred_element_type=jnp.float32)
+        b = lax.dot(x, wu_ref[0, 0], preferred_element_type=jnp.float32)
+        h = (a * jax.nn.sigmoid(a) * b).astype(x.dtype)      # [tm, f]
+        y = lax.dot(h, wd_ref[0, 0], preferred_element_type=jnp.float32)
+
+        @pl.when(j == 0)
+        def _first_block():
+            acc_ref[...] = y
+
+        @pl.when(j > 0)
+        def _later_block():
+            acc_ref[...] += y
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _last_block():
+            row = tile * tm + lax.broadcasted_iota(jnp.int32, y.shape, 0)
+            mine = (row >= start_ref[g]) & (row < end_ref[g])
+            fresh = (v == 0) | (tile_ref[jnp.maximum(v - 1, 0)] != tile)
+            kept = jnp.where(fresh, 0.0, o_ref[...])
+            o_ref[...] = jnp.where(mine, acc_ref[...], kept).astype(
+                o_ref.dtype)
+
+
 def _expert_stacks(w_gate, w_up, w_down):
     """One layer's ``[E, ..]`` matrices as the stack of that one layer."""
     if w_gate.ndim == 3:
@@ -2933,38 +3042,82 @@ def _moe_experts_call(xs, group_sizes, w_gate, w_up, w_down, layer=0):
     """Raw pallas_call of the grouped SwiGLU: ``xs [M, D]`` (M a multiple
     of 128) against the stacks ``[L, E, D, F]``, ``[L, E, D, F]``, ``[L, E,
     F, D]``; returns ``[M, D]`` float32, rows of no group zero where a visit
-    touched their tile and undefined elsewhere."""
+    touched their tile and undefined elsewhere.  One block of the experts'
+    width a visit where an expert fits VMEM, else ``F / f`` of them
+    (``_moe_width_block``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     w_gate, w_up, w_down = _expert_stacks(w_gate, w_up, w_down)
     M, D = xs.shape
     E, F = w_gate.shape[1], w_gate.shape[3]
     tm = _MOE_ROWS
+    f = _moe_width_block(D, F, w_gate.dtype.itemsize, tm)
+    if f is None:
+        raise ValueError(f"no block of experts of {D} x {F} fits "
+                         f"{_MOE_BUDGET} bytes of VMEM")
     g, tile, starts, ends, n = _expert_visits(group_sizes, M, tm)
     scalars = (_layer_operand(layer), g, tile, starts, ends, n)
+    visits = M // tm + E - 1
+    out_shape = jax.ShapeDtypeStruct((M, D), jnp.float32)
+    if f == F:
+        def rows_map(v, layer, g, tile, *rest):
+            del layer, g, rest
+            return (tile[v], 0)
 
-    def rows_map(v, layer, g, tile, *rest):
-        del layer, g, rest
+        def expert_map(v, layer, g, *rest):
+            del rest
+            return (layer[0], g[v], 0, 0)
+
+        rows = pl.BlockSpec((tm, D), rows_map)
+        call = _pallas_call(
+            functools.partial(_moe_experts_kernel, tm=tm),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(scalars),
+                grid=(visits,),
+                in_specs=[rows, pl.BlockSpec((1, 1, D, F), expert_map),
+                          pl.BlockSpec((1, 1, D, F), expert_map),
+                          pl.BlockSpec((1, 1, F, D), expert_map)],
+                out_specs=rows),
+            out_shape=out_shape,
+            # a tile's output is built up over consecutive visits: in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_MOE_VMEM),
+        )
+        return call(*scalars, xs, w_gate, w_up, w_down)
+
+    last = F // f - 1
+
+    def rows_map(v, j, layer, g, tile, *rest):
+        del j, layer, g, rest
         return (tile[v], 0)
 
-    def expert_map(v, layer, g, *rest):
-        del rest
-        return (layer[0], g[v], 0, 0)
+    def block_of(v, j, n):
+        # a visit past the live ones holds the last live turn's blocks
+        return jnp.where(v < n[0], j, last)
+
+    def in_map(v, j, layer, g, tile, starts, ends, n):
+        del tile, starts, ends
+        return (layer[0], g[v], 0, block_of(v, j, n))
+
+    def down_map(v, j, layer, g, tile, starts, ends, n):
+        del tile, starts, ends
+        return (layer[0], g[v], block_of(v, j, n), 0)
 
     rows = pl.BlockSpec((tm, D), rows_map)
     call = _pallas_call(
-        functools.partial(_moe_experts_kernel, tm=tm),
+        functools.partial(_moe_experts_kernel_blocked, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(M // tm + E - 1,),
-            in_specs=[rows, pl.BlockSpec((1, 1, D, F), expert_map),
-                      pl.BlockSpec((1, 1, D, F), expert_map),
-                      pl.BlockSpec((1, 1, F, D), expert_map)],
-            out_specs=rows),
-        out_shape=jax.ShapeDtypeStruct((M, D), jnp.float32),
-        # a tile's output is built up over consecutive visits: in order
+            grid=(visits, F // f),
+            in_specs=[rows, pl.BlockSpec((1, 1, D, f), in_map),
+                      pl.BlockSpec((1, 1, D, f), in_map),
+                      pl.BlockSpec((1, 1, f, D), down_map)],
+            out_specs=rows,
+            scratch_shapes=[pltpu.VMEM((tm, D), jnp.float32)]),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_MOE_VMEM),
     )
     return call(*scalars, xs, w_gate, w_up, w_down)
@@ -2988,10 +3141,13 @@ def _moe_experts_jnp(xs, group_sizes, w_gate, w_up, w_down, layer=0):
 
 def moe_experts_available(x_shape, w_shape, dtype=None):
     """True when the grouped-SwiGLU kernel can serve (rows are padded to
-    whole tiles for it): lane-aligned widths, a TPU backend or interpret
+    whole tiles for it): lane-aligned widths, a block of the experts' width
+    that fits VMEM (``_moe_width_block``), a TPU backend or interpret
     mode."""
-    del dtype
     if x_shape[1] % _LANES or w_shape[-1] % _LANES:
+        return False
+    itemsize = 2 if dtype is None else jnp.dtype(dtype).itemsize
+    if _moe_width_block(x_shape[1], w_shape[-1], itemsize) is None:
         return False
     return _kernels_enabled("grouped_experts")
 

@@ -227,7 +227,8 @@ class _SafeCallback:
 class LLMEngine:
     """Continuous-batching serving engine over the model ``cfg.serving``
     names (``models/llama.py``, ``models/jamba.py``,
-    ``models/phi4flash.py``, ``models/deepseek_v2.py``).
+    ``models/phi4flash.py``, ``models/deepseek_v2.py``,
+    ``models/longcat_flash.py``).
 
     Parameters mirror the capacity plan: ``page_size`` tokens per pool
     page (default 128, the lane width — the Pallas ragged-paged-attention

@@ -298,15 +298,16 @@ class Rows:
     """``forward_paged`` on a cache of ``R`` slots, fed by hand: each call of
     ``feed`` is one engine step over ``{slot: tokens}``, on the padded
     layout or, with ``flat``, on a flat batch of that many positions."""
+    module = deepseek_v2        # whose ``init_cache`` and ``forward_paged``
 
     def __init__(self, model, R=3, blocks=8, flat=None, page=PAGE):
         self.cfg, self.params, _ = model
         self.R, self.flat = R, flat
-        self.cache = deepseek_v2.init_cache(self.cfg, R, 1 + R * blocks, page,
+        self.cache = self.module.init_cache(self.cfg, R, 1 + R * blocks, page,
                                             jnp.float32)
         self.tbl = 1 + np.arange(R * blocks, dtype=np.int32).reshape(R, blocks)
         self.lens = np.zeros((R,), np.int32)
-        self.fwd = jax.jit(functools.partial(deepseek_v2.forward_paged,
+        self.fwd = jax.jit(functools.partial(self.module.forward_paged,
                                              self.cfg),
                            static_argnames=("step_tokens",))
 

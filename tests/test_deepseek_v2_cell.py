@@ -113,7 +113,8 @@ def test_the_cell_is_the_published_model_cut_in_depth_alone():
     assert all(m["moves"] == "serve_gap_p95_ms" for m in layers.values())
     for name in NEW_METRICS:
         (m,) = [m for m in spec["per_layer"] if m["name"] == name]
-        assert m["workloads"] == [CELL] and m["layer"] == "kernels"
+        # (a later model's cell may follow it on the two times' lists)
+        assert m["workloads"][0] == CELL and m["layer"] == "kernels"
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
