@@ -404,29 +404,36 @@ def run_scan_parity(*, rows: tuple, inner: int, state: int, chunk: int,
                     expect_kernel: bool) -> None:
     """``selective_scan`` on layer 1 of a stack of three, ``R`` rows of
     ragged chunks (idle rows, decode rows, a few positions, whole chunks,
-    fresh rows; NaN in the dead positions), both programs, against
-    ``_ssm_scan_jnp``; the other layers and the idle rows bit for bit."""
+    fresh rows) on the compact flat batch of their tokens, NaN at every flat
+    position that holds none, both programs, against ``_ssm_scan_jnp``; the
+    other layers and the idle rows bit for bit."""
     layer, M = 1, 3
     for R in rows:
         for Tc in (chunk, 1):
             rng = np.random.default_rng(R * 100 + Tc)
             q = rng.choice([0, 1, 1, 1, 1, 1, min(3, Tc), Tc], R).astype(
                 np.int32)
+            start = np.cumsum(q) - q
+            T = max(8, -(-int(q.sum()) // 8) * 8) + 8
             fresh = jnp.asarray((rng.random(R) < 0.25) & (q > 0))
-            dead = jnp.asarray(np.arange(Tc)[None, :] >= q[:, None])[..., None]
+            fed = np.zeros(T, bool)
+            for r in range(R):
+                fed[start[r]:start[r] + q[r]] = True
+            dead = jnp.asarray(~fed)[:, None]
 
             def normal(*shape):
                 return jnp.asarray(rng.standard_normal(shape), jnp.float32)
 
             ssm = normal(M, state, R, inner)
-            dt = jax.nn.softplus(normal(R, Tc, inner) - 2.0)
-            args = (dt, normal(R, Tc, inner), normal(R, Tc, state),
-                    normal(R, Tc, state), -jnp.exp(0.3 * normal(state, inner)))
-            tail = (jnp.asarray(q), fresh)
-            want_y, want_s = jax.jit(pallas_ops._ssm_scan_jnp)(
-                ssm, *args, *tail, layer)
+            dt = jax.nn.softplus(normal(T, inner) - 2.0)
+            args = (dt, normal(T, inner), normal(T, state),
+                    normal(T, state), -jnp.exp(0.3 * normal(state, inner)))
+            tail = (jnp.asarray(q), jnp.asarray(start, jnp.int32), fresh)
+            want_y, want_s = jax.jit(
+                pallas_ops._ssm_scan_jnp, static_argnums=10)(
+                    ssm, *args, *tail, layer, Tc)
             scan = jax.jit(functools.partial(pallas_ops.selective_scan,
-                                             layer=layer))
+                                             Tc=Tc, layer=layer))
             poisoned = tuple(jnp.where(dead, jnp.nan, a)
                              for a in args[:4]) + args[4:]
             names = pallas_kernels(scan.lower(ssm, *poisoned, *tail).as_text())
@@ -434,9 +441,8 @@ def run_scan_parity(*, rows: tuple, inner: int, state: int, chunk: int,
                   f"selective_scan [{R}, {Tc}] runs Pallas kernels {names}")
             got_y, got_s = (np.asarray(a) for a in scan(ssm, *poisoned, *tail))
             err_s = float(np.abs(got_s - want_s).max() / np.abs(want_s).max())
-            live = ~np.asarray(dead)[..., 0]
-            err_y = float(np.abs(got_y - want_y)[live].max()
-                          / np.abs(np.asarray(want_y)[live]).max())
+            err_y = float(np.abs(got_y - want_y)[fed].max()
+                          / np.abs(np.asarray(want_y)[fed]).max())
             log(f"selective_scan [{R}, {Tc}]: state rel err {err_s:.2e}, "
                 f"y rel err {err_y:.2e}")
             check(max(err_s, err_y) <= SCAN_REL_TOL,

@@ -199,26 +199,55 @@ def init_params(cfg: JambaConfig, key) -> Dict[str, Any]:
 # the Mamba mixer on a ragged batch of chunks, with carried state
 # ---------------------------------------------------------------------------
 
-def _ssm_conv(lp, x, conv, q_lens):
-    """The causal depthwise convolution of ``x [R, Tc, E]`` continued from
-    ``conv [K-1, R, E]``, the K-1 inputs before the chunk.  Returns the
-    activations ``[R, Tc, E]`` float32 and the last K-1 REAL inputs of each
-    row (those before position ``q_lens[r]`` of the window), so padding
-    never enters the state and a row with ``q_lens == 0`` keeps its own."""
-    K = conv.shape[0] + 1
-    Tc = x.shape[1]
-    win = [conv[j] for j in range(K - 1)] + [x[:, t] for t in range(Tc)]
-    w = lp["conv_w"].astype(jnp.float32)
-    out = [lp["conv_b"].astype(jnp.float32)
-           + sum(w[j] * win[t + j].astype(jnp.float32) for j in range(K))
-           for t in range(Tc)]
-    new = []
-    for j in range(K - 1):
-        kept = win[j]
-        for q in range(1, Tc + 1):
-            kept = jnp.where((q_lens == q)[:, None], win[q + j], kept)
-        new.append(kept)
-    return jax.nn.silu(jnp.stack(out, 1)), jnp.stack(new, 0)
+def _ssm_conv(lp, x, conv, l, q_lens, fresh, lay):
+    """The causal depthwise convolution of the flat tokens ``x [T, E]`` of
+    the step ``lay``, each row's chunk continued from the K-1 inputs before
+    it in layer ``l`` of the stack ``conv [M, K-1, R, E]`` (from zeros for a
+    ``fresh`` row).  Returns the activations ``[T, E]`` float32 and the
+    stack with the last K-1 REAL inputs of each row in layer ``l``, so
+    padding never enters the state and a row with ``q_lens == 0`` keeps its
+    own.
+
+    A row's window is its carried inputs, then its chunk: tap j of the token
+    at position t reads entry ``t + j``, which is the flat token ``K-1-j``
+    before it where that is still in the chunk and the row's carried input
+    otherwise, by select; its new carried inputs are entries ``q_lens ..
+    q_lens + K-2``.  No array here has a padded row's positions.  The scope
+    ``ssm_conv`` holds everything that touches the state (its slice out of
+    the stack, the reset, the update, the write-back); the two moves between
+    a per-row array and the flat batch (each token's row's carried inputs,
+    each row's last tokens) are ``StepLayout``'s, under ``step_layout``."""
+    K = conv.shape[1] + 1
+    T = x.shape[0]
+    f32 = jnp.float32
+    with jax.named_scope("ssm_conv"):
+        # the reset on the state itself, once: the gathers and the update
+        # read the one array (folded into their selects XLA writes it
+        # twice); as its K-1 entries, sliced under this scope
+        c = list(jnp.where(fresh[None, :, None], 0, _layer_at(conv, l)))
+    # one gather an entry, each of ``[R, E]``: gathered as one ``[K-1, R,
+    # E]`` array by its middle axis, XLA re-lays the whole stack of states
+    # out around the layer loop, 0.1 GB moved twice a step
+    carried = [lay.of_rows(c[m]) for m in range(K - 1)]     # K-1 x [T, E]
+    last = lay.last_tokens(x, K - 1)                         # [K-1, R, E]
+    with jax.named_scope("ssm_conv"):
+        w = lp["conv_w"].astype(f32)
+        taps = []
+        for j in range(K):
+            back = K - 1 - j
+            tap = jnp.pad(x, ((back, 0), (0, 0)))[:T]        # x[i - back]
+            for m in range(j, K - 1):                        # t + j == m
+                tap = jnp.where((lay.pos == m - j)[:, None], carried[m], tap)
+            taps.append(w[j] * tap.astype(f32))
+        out = jax.nn.silu(lp["conv_b"].astype(f32) + sum(taps))
+        new = []
+        for m in range(K - 1):
+            kept = last[m]                  # a flat token from entry K-1 on
+            for q in range(K - 1 - m):
+                kept = jnp.where((q_lens == q)[:, None], c[q + m], kept)
+            new.append(kept)
+        conv = lax.dynamic_update_index_in_dim(conv, jnp.stack(new, 0), l, 0)
+    return out, conv
 
 
 def _layer_at(stack, l):
@@ -229,59 +258,78 @@ def _layer_at(stack, l):
         lambda w: lax.dynamic_index_in_dim(w, l, 0, keepdims=False), stack)
 
 
+def _mixer_core(lp, x, z, conv, ssm, l, q_lens, fresh, lay, dt_b_c):
+    """What every Mamba-1 mixer of this repo does between ``w_in`` and
+    ``w_out``, on the flat tokens of the step ``lay``: the convolution of
+    ``x [T, E]``, ``dt_b_c(x)`` (the caller's ``w_x``, ``dt`` chain and
+    norms: ``dt [T, E]`` after softplus and ``B, C [T, N]``, float32), the
+    selective scan, ``D_skip`` and the gate ``z [T, E]``.  The state of each
+    row is read from and written back into layer ``l`` of the stacks ``conv
+    [M, K-1, R, E]`` and ``ssm [M, N, R, E]`` float32; a row whose chunk is
+    ``fresh`` starts from zero state, a position ``t >= q_lens[r]``
+    advances neither.  Returns the gated ``[T, E]`` in ``z``'s dtype, the
+    scan's output with its skip ``m [T, E]`` float32, and both stacks.
+
+    Everything stays flat: the convolution and the scan read a row's fed
+    tokens where they lie in the batch (``_ssm_conv``;
+    ``pallas_ops.selective_scan``, on the TPU a kernel that updates the
+    stack in place and walks only the live positions).  The scopes
+    ``ssm_conv`` and ``ssm_scan`` hold everything that touches their state
+    and nothing else: its slice out of the stack, the reset, the update and
+    the write-back, so a reader of the scope's time times every byte that
+    ``benchmark/kernel_costs_ssm.py`` counts; the convolution's two moves
+    between a per-row array and the flat batch are ``step_layout``'s, beside
+    ``ssm_conv`` and not inside it.  The scan writes ``y`` at the
+    fed tokens alone; the select that makes every other position zero
+    (``StepLayout``'s contract) rides in the gate's pass."""
+    from ..ops.pallas_ops import selective_scan
+    f32 = jnp.float32
+    x, conv = _ssm_conv(lp, x, conv, l, q_lens, fresh, lay)  # x float32
+    with jax.named_scope("ssm_proj"):
+        dt, Bm, Cm = dt_b_c(x)
+    with jax.named_scope("ssm_scan"):
+        A = -jnp.exp(lp["A_log"].astype(f32))
+        y, ssm = selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, lay.start,
+                                fresh, Tc=lay.Tc, layer=l)
+    with jax.named_scope("ssm_proj"):
+        m = jnp.where(lay.valid[:, None],
+                      y + lp["D_skip"].astype(f32) * x, 0.0)
+        gated = (m * jax.nn.silu(z.astype(f32))).astype(z.dtype)
+    return gated, m, conv, ssm
+
+
 def _mamba_mixer(cfg, lp, h, conv, ssm, l, q_lens, fresh, lay):
     """Mixer ``l`` on the flat tokens ``h [T, D]`` of the step ``lay`` with
-    the state of each row, read from and written back into layer ``l`` of
-    the stacks ``conv [M, K-1, R, E]`` and ``ssm [M, N, R, E]`` float32.  A
-    row whose chunk is ``fresh`` starts from zero state; positions ``t >=
-    q_lens[r]`` advance neither state.  Returns the mixer's output ``[T, D]``
-    and both stacks.
+    the state of each row in layer ``l`` of the stacks ``conv`` and ``ssm``
+    (``_mixer_core``, which ``phi4flash._mamba_layer`` shares).  Returns
+    the mixer's output ``[T, D]`` and both stacks.  What is Jamba's own:
+    the RMSNorm before ``w_in`` and those of ``dt``, ``B`` and ``C``.
 
-    The four matmuls, the norms and the gate are per token and stay flat;
-    the convolution and the scan need a row's positions in order, so their
-    inputs go to the padded ``[R, Tc, ...]`` rows and their results come
-    back flat.  The scopes ``ssm_conv`` and ``ssm_scan`` hold everything
-    that touches their state and nothing else: its slice out of the stack,
-    the reset, the update and the write-back (for the scan all of it one
-    call, ``pallas_ops.selective_scan``: on the TPU a kernel that updates
-    the stack in place and walks only the live positions), so a reader of
-    the scope's time times every byte that
-    ``benchmark/kernel_costs_ssm.py`` counts, and the moves between the
-    layouts stay outside them.  The mixer has four names in a profile:
-    ``ssm_proj`` around its three per-token stretches (norm and ``w_in``;
-    ``w_x``, the ``dt`` chain and the B and C norms; the gate, ``D_skip``
-    and ``w_out``), ``ssm_conv``, ``ssm_scan``, and ``step_layout`` (the
-    ``lay.rows`` and ``lay.flat`` between them, named inside
-    ``StepLayout``); what is left under ``mamba`` and under none of the
-    four is the caller's residual add."""
-    from ..ops.pallas_ops import selective_scan
+    The mixer has four names in a profile: ``ssm_proj`` around its three
+    per-token stretches (norm and ``w_in``; ``w_x``, the ``dt`` chain and
+    the B and C norms; the gate, ``D_skip`` and ``w_out``), ``ssm_conv``,
+    ``ssm_scan``, and ``step_layout`` (each token's row's carried inputs
+    and each row's last tokens, the two places where the convolution goes
+    between a per-row array and the flat batch); what is left under
+    ``mamba`` and under none of the four is the caller's residual add."""
     N, r, eps = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.rms_norm_eps
     f32 = jnp.float32
+
+    def dt_b_c(x):
+        dt, Bm, Cm = jnp.split(x.astype(h.dtype) @ lp["w_x"], [r, r + N],
+                               axis=-1)
+        dt = _rms_norm(dt, lp["dt_norm"], eps) @ lp["w_dt"]
+        return (jax.nn.softplus(dt.astype(f32) + lp["b_dt"].astype(f32)),
+                _rms_norm(Bm, lp["b_norm"], eps).astype(f32),
+                _rms_norm(Cm, lp["c_norm"], eps).astype(f32))
+
     with jax.named_scope("ssm_proj"):
         x, z = jnp.split(_rms_norm(h, lp["ln1"], eps) @ lp["w_in"], 2,
                          axis=-1)
-    x = lay.rows(x)
-    with jax.named_scope("ssm_conv"):
-        c = jnp.where(fresh[None, :, None], 0, _layer_at(conv, l))
-        x, c = _ssm_conv(lp, x, c, q_lens)                  # x float32
-        conv = lax.dynamic_update_index_in_dim(conv, c, l, 0)
-    xf = lay.flat(x)                                         # [T, E]
+    gated, _, conv, ssm = _mixer_core(lp, x, z, conv, ssm, l, q_lens, fresh,
+                                      lay, dt_b_c)
     with jax.named_scope("ssm_proj"):
-        dt, Bm, Cm = jnp.split(xf.astype(h.dtype) @ lp["w_x"], [r, r + N],
-                               axis=-1)
-        dt = _rms_norm(dt, lp["dt_norm"], eps) @ lp["w_dt"]
-        dt = jax.nn.softplus(dt.astype(f32) + lp["b_dt"].astype(f32))
-        Bm = _rms_norm(Bm, lp["b_norm"], eps).astype(f32)
-        Cm = _rms_norm(Cm, lp["c_norm"], eps).astype(f32)
-    dt, Bm, Cm = lay.rows(dt), lay.rows(Bm), lay.rows(Cm)
-    with jax.named_scope("ssm_scan"):
-        A = -jnp.exp(lp["A_log"].astype(f32))
-        y, ssm = selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh,
-                                layer=l)
-    y = lay.flat(y)
-    with jax.named_scope("ssm_proj"):
-        y = (y + lp["D_skip"].astype(f32) * xf) * jax.nn.silu(z.astype(f32))
-        out = y.astype(h.dtype) @ lp["w_out"]
+        out = gated @ lp["w_out"]
     return out, conv, ssm
 
 

@@ -103,7 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .jamba import _layer_at, _ssm_conv
+from .jamba import _layer_at, _mixer_core
 from .step_layout import StepLayout
 
 __all__ = ["Phi4FlashConfig", "PRESETS", "preset", "config_from_fields",
@@ -323,45 +323,30 @@ def _mamba_layer(cfg, lp, h, state, l, q_lens, fresh, lay):
     """Mamba layer ``l`` of the stack on the flat tokens ``h [T, D]``, its
     state read from and written back into ``state["conv"] [M, K-1, R, E]`` and
     ``state["ssm"] [M, N, R, E]`` at ``l``; returns the layer's output, the
-    scan's output ``m [T, E]`` before the gate, and the state.  The layouts
-    and the scopes are ``jamba._mamba_mixer``'s: matmuls, norm and gate
-    flat, the convolution and the scan on the padded rows, ``ssm_conv`` and
-    ``ssm_scan`` around everything that touches their state.  The mixer has
-    four names in a profile: ``ssm_proj`` around its three per-token
-    stretches (norm and ``w_in``; ``w_x`` and the ``dt`` chain; the gate,
-    ``D_skip`` and ``w_out``), ``ssm_conv``, ``ssm_scan``, and
-    ``step_layout`` (the ``lay.rows`` and ``lay.flat`` between them, named
-    inside ``StepLayout``); what is left under ``mamba`` and under none of
-    the four is the residual add."""
-    from ..ops.pallas_ops import selective_scan
+    scan's output ``m [T, E]`` before the gate, and the state.  Between
+    ``w_in`` and ``w_out`` it is ``jamba._mixer_core``, flat throughout,
+    under the same four names in a profile (``ssm_proj``, ``ssm_conv``,
+    ``ssm_scan``, ``step_layout``); what is this model's own: the LayerNorm
+    before ``w_in``, no norm on ``dt``, ``B`` and ``C``, and ``m`` handed to
+    the gated memory units."""
     N, r, f32 = cfg.mamba_d_state, cfg.mamba_dt_rank, jnp.float32
-    conv, ssm = state["conv"], state["ssm"]
+
+    def dt_b_c(x):
+        dt, Bm, Cm = jnp.split(x.astype(h.dtype) @ lp["w_x"], [r, r + N],
+                               axis=-1)
+        dt = jax.nn.softplus((dt @ lp["w_dt"]).astype(f32)
+                             + lp["b_dt"].astype(f32))
+        return dt, Bm.astype(f32), Cm.astype(f32)
+
     with jax.named_scope("mamba"):
         with jax.named_scope("ssm_proj"):
             xn = _layer_norm(h, lp["ln1_w"], lp["ln1_b"], cfg.layer_norm_eps)
             x, z = jnp.split(xn @ lp["w_in"], 2, axis=-1)
-        x = lay.rows(x)
-        with jax.named_scope("ssm_conv"):
-            c = jnp.where(fresh[None, :, None], 0, _layer_at(conv, l))
-            x, c = _ssm_conv(lp, x, c, q_lens)                  # x float32
-            conv = lax.dynamic_update_index_in_dim(conv, c, l, 0)
-        xf = lay.flat(x)                                         # [T, E]
+        gated, m, conv, ssm = _mixer_core(
+            lp, x, z, state["conv"], state["ssm"], l, q_lens, fresh, lay,
+            dt_b_c)
         with jax.named_scope("ssm_proj"):
-            dt, Bm, Cm = jnp.split(xf.astype(h.dtype) @ lp["w_x"],
-                                   [r, r + N], axis=-1)
-            dt = jax.nn.softplus((dt @ lp["w_dt"]).astype(f32)
-                                 + lp["b_dt"].astype(f32))
-            Bm, Cm = Bm.astype(f32), Cm.astype(f32)
-        dt, Bm, Cm = lay.rows(dt), lay.rows(Bm), lay.rows(Cm)
-        with jax.named_scope("ssm_scan"):
-            A = -jnp.exp(lp["A_log"].astype(f32))
-            y, ssm = selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh,
-                                    layer=l)
-        y = lay.flat(y)
-        with jax.named_scope("ssm_proj"):
-            m = y + lp["D_skip"].astype(f32) * xf
-            out = ((m * jax.nn.silu(z.astype(f32))).astype(h.dtype)
-                   @ lp["w_out"])
+            out = gated @ lp["w_out"]
         h = h + out
     return _mlp(cfg, lp, h), m.astype(h.dtype), dict(state, conv=conv,
                                                      ssm=ssm)

@@ -2594,7 +2594,7 @@ def paged_latent_write(pages, new, block_tables, seq_lens, q_lens, *,
 # live positions of every row's chunk, on layer ``layer`` of the stacked
 # state ``ssm [M, N, R, E]`` float32, which goes in whole and comes back as
 # the same buffer (``input_output_aliases``; the layer rides in by scalar
-# prefetch, as for the K/V pools).  Grid ``(R / 8, E / Et)``: a grid point
+# prefetch, as for the K/V pools).  Grid ``(E / Et, R / 8)``: a grid point
 # holds the ``[N, 8, Et]`` tile of 8 rows' state in VMEM, read once and
 # written once whatever the chunk's length, and walks ``t`` from 0 to the
 # largest ``q_lens`` of its 8 rows: one position for a group of decode rows,
@@ -2603,14 +2603,17 @@ def paged_latent_write(pages, new, block_tables, seq_lens, q_lens, *,
 # ``q_len``: its ``dt``, ``dt x`` and ``B`` are zero there by select, so
 # whatever the dead positions of the inputs hold never reaches a state.
 #
-# The per-position inputs ``dt, x [Tc, R, E]`` are position-major, so the
-# ``[8, Et]`` slab of one position of a group is contiguous rows: position 0
-# comes with the grid's own pipeline (every live group has one), positions
-# from 1 on by the kernel's own double-buffered copies, and ``y`` goes out
-# the same way, a live position at a time, its wait left to the position
-# two later (``count_ref`` carries the slot across grid points).  So a step
-# reads and writes ``scan_positions x E`` of each, not ``R x Tc x E``, and
-# ``y`` at positions no group walks is not written at all.
+# The per-position inputs ``dt, x [T, E]``, ``B, C [T, N]`` are the step's
+# FLAT tokens, row after row (``models/step_layout.py``): position t of row r
+# is flat token ``start[r] + t``.  No padded ``[R, Tc, E]`` array exists
+# (PR 38).  A lane tile's ``[T, Et]`` blocks of ``dt`` and ``x`` stay in VMEM
+# for all its groups (the lane tiles are the outer grid axis, so the
+# pipeline fetches them once a tile), and a position's 8 rows are picked out
+# of them one live row at a time, by the row's offset from SMEM, into an
+# ``[8, Et]`` slab; ``y`` goes back the same way, a live row at a time into
+# the tile's resident ``[T, Et]`` block, which is written to HBM once.  So a
+# step reads and writes ``T x E`` of each, and ``y`` at a flat position that
+# holds no fed token is whatever VMEM held: the caller selects it away.
 
 _SSM_ROWS = 8      # rows of a group: the float32 sublane tile of the R axis
 
@@ -2625,15 +2628,15 @@ def scan_positions(q_lens, rows=_SSM_ROWS) -> int:
     return int(rows * q.max(axis=1).sum())
 
 
-def _ssm_scan_tile(N, E, Tc):
+def _ssm_scan_tile(N, E, T):
     """``Et``: the widest tile of E, a multiple of the lanes that divides
     E, whose working set fits ``_VMEM_BUDGET`` (None: no tile does).  A
-    lane of the tile costs the state's block in and out, two buffers each,
-    A's block, the two blocks of position 0 and the three scratch slabs in
-    two slots; the ``[Tc, 8, N]`` blocks of B and C pad N to the lanes, and
-    a position's columns of them take ``[N, 8, 128]`` each."""
-    per_lane = 4 * (4 * N * _SSM_ROWS + 2 * N + 10 * _SSM_ROWS)
-    fixed = 4 * (4 * Tc + 2 * N) * _SSM_ROWS * _LANES
+    lane of the tile costs the state's block in and out, A's block and the
+    ``[T, Et]`` blocks of dt, x and y, two buffers each, and the three
+    ``[8, Et]`` slabs; the ``[T, N]`` blocks of B and C pad N to the lanes,
+    and a position's columns of them take ``[N, 8, 128]`` each."""
+    per_lane = 4 * (4 * N * _SSM_ROWS + 2 * N + 6 * T + 3 * _SSM_ROWS)
+    fixed = 4 * (4 * T + 2 * N * _SSM_ROWS + 2 * _SSM_ROWS) * _LANES
     for k in range(1, E // _LANES + 1):
         Et = E // k
         if E % k == 0 and Et % _LANES == 0 \
@@ -2642,46 +2645,41 @@ def _ssm_scan_tile(N, E, Tc):
     return None
 
 
-def _ssm_scan_kernel(layer_ref, qlens_ref, fresh_ref, dt0_ref, x0_ref, b_ref,
-                     c_ref, a_ref, dt_hbm, x_hbm, s_in, s_out, y_hbm, dtbuf,
-                     xbuf, in_sem, ybuf, out_sem, count_ref, bp_ref, cp_ref):
-    """Grid point (g, e): rows ``8g .. 8g+7``, lanes ``e Et .. (e+1) Et`` of
-    the state of layer ``layer_ref[0]``.  ``dt0_ref``, ``x0_ref`` are the
-    group's blocks of position 0 of the arrays that ``dt_hbm``, ``x_hbm``
-    hold whole; the decode program (``Tc == 1``) reads nothing else."""
+def _ssm_scan_kernel(layer_ref, qlens_ref, start_ref, fresh_ref, dt_ref,
+                     x_ref, b_ref, c_ref, a_ref, s_in, s_out, y_ref, dtbuf,
+                     xbuf, ybuf, bbuf, cbuf, bp_ref, cp_ref, *, Tc):
+    """Grid point (e, g): rows ``8g .. 8g+7``, lanes ``e Et .. (e+1) Et`` of
+    the state of layer ``layer_ref[0]``.  ``dt_ref``, ``x_ref``, ``y_ref``
+    are the lane tile's ``[T, Et]`` blocks of the flat tokens, ``b_ref``,
+    ``c_ref`` the whole ``[T, N]``; ``Tc`` bounds a row's chunk."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
     del layer_ref
-    Tc = b_ref.shape[0]
+    T = dt_ref.shape[0]
     G8 = _SSM_ROWS
-    g, e = pl.program_id(0), pl.program_id(1)
+    g = pl.program_id(1)
     N, Et = a_ref.shape
-    rows = pl.ds(pl.multiple_of(g * G8, G8), G8)
-    cols = pl.ds(pl.multiple_of(e * Et, _LANES), Et)
 
     # the 8 rows' lengths down the sublanes of a vreg, from SMEM
     sub = lax.broadcasted_iota(jnp.int32, (G8, _LANES), 0)
     qv = jnp.zeros((G8, _LANES), jnp.int32)
     fv = jnp.zeros((G8, _LANES), jnp.int32)
     maxq = jnp.int32(0)
+    qs, starts = [], []
     for j in range(G8):
         q = jnp.minimum(qlens_ref[g * G8 + j], Tc)
         maxq = jnp.maximum(maxq, q)
         qv = jnp.where(sub == j, q, qv)
         fv = jnp.where(sub == j, fresh_ref[g * G8 + j], fv)
+        qs.append(q)
+        starts.append(start_ref[g * G8 + j])
 
-    @pl.when((g == 0) & (e == 0))
-    def _first_point():
-        count_ref[0] = 0
-
-    def y_copy(t, slot):
-        return pltpu.make_async_copy(ybuf.at[slot], y_hbm.at[t, rows, cols],
-                                     out_sem.at[slot])
-
-    def in_copies(t, slot):
-        for hbm, buf, which in ((dt_hbm, dtbuf, 0), (x_hbm, xbuf, 1)):
-            yield pltpu.make_async_copy(hbm.at[t, rows, cols], buf.at[slot],
-                                        in_sem.at[slot, which])
+    def live_rows(t, body):
+        """``body(j, i)`` for every row j of the group that lives at
+        position t, i its flat token."""
+        for j in range(G8):
+            @pl.when(t < qs[j])
+            def _row(j=j):
+                body(j, jnp.minimum(starts[j] + t, T - 1))
 
     @pl.when(maxq == 0)
     def _idle_group():
@@ -2689,13 +2687,6 @@ def _ssm_scan_kernel(layer_ref, qlens_ref, fresh_ref, dt0_ref, x0_ref, b_ref,
 
     @pl.when(maxq > 0)
     def _live_group():
-        if Tc > 1:
-            @pl.when(maxq > 1)
-            def _second_position():
-                for copy in in_copies(1, 1):
-                    copy.start()
-            dtbuf[0] = dt0_ref[0]
-            xbuf[0] = x0_ref[0]
         keep = jnp.tile(fv, (1, Et // _LANES)) == 0
         s_out[0] = jnp.where(keep[None], s_in[0], 0.0)
         onehot = (lax.broadcasted_iota(jnp.int32, (N, G8, N), 0)
@@ -2708,36 +2699,25 @@ def _ssm_scan_kernel(layer_ref, qlens_ref, fresh_ref, dt0_ref, x0_ref, b_ref,
                           keepdims=True)
             return jnp.broadcast_to(col, (N, G8, _LANES))
 
+        def pick(j, i):
+            dtbuf[pl.ds(j, 1)] = dt_ref[pl.ds(i, 1)]
+            xbuf[pl.ds(j, 1)] = x_ref[pl.ds(i, 1)]
+            bbuf[pl.ds(j, 1)] = b_ref[pl.ds(i, 1)]
+            cbuf[pl.ds(j, 1)] = c_ref[pl.ds(i, 1)]
+
+        def put(j, i):
+            y_ref[pl.ds(i, 1)] = ybuf[pl.ds(j, 1)]
+
         def position(t, carry):
-            if Tc == 1:
-                dt, x = dt0_ref[0], x0_ref[0]
-            else:
-                slot = t % 2
-
-                @pl.when(t > 0)
-                def _landed():
-                    for copy in in_copies(t, slot):
-                        copy.wait()
-
-                @pl.when((t > 0) & (t + 1 < maxq))
-                def _next_position():
-                    for copy in in_copies(t + 1, 1 - slot):
-                        copy.start()
-                dt, x = dtbuf[slot], xbuf[slot]
+            live_rows(t, pick)
             live = t < qv                                    # [8, 128]
             livee = jnp.tile(live, (1, Et // _LANES))
-            count = count_ref[0]
-            yslot = count % 2
-
-            @pl.when(count >= 2)
-            def _slot_is_free():
-                y_copy(0, yslot).wait()
-
-            # a dead row's dt, dt x and B are zero by select
-            dt = jnp.where(livee, dt, 0.0)
-            dx = jnp.where(livee, dt * x, 0.0)
-            bp_ref[...] = planes(jnp.where(live[:, :N], b_ref[t], 0.0))
-            cp_ref[...] = planes(c_ref[t])
+            # a dead row's slab rows are stale or never written: its dt,
+            # dt x and B are zero by select
+            dt = jnp.where(livee, dtbuf[...], 0.0)
+            dx = jnp.where(livee, dt * xbuf[...], 0.0)
+            bp_ref[...] = planes(jnp.where(live[:, :N], bbuf[...], 0.0))
+            cp_ref[...] = planes(cbuf[...])
 
             def plane(n, y):
                 s = (jnp.exp(dt * a_ref[pl.ds(n, 1)]) * s_out[0, n]
@@ -2751,93 +2731,92 @@ def _ssm_scan_kernel(layer_ref, qlens_ref, fresh_ref, dt0_ref, x0_ref, b_ref,
             # in Python are four hundred equations that every engine start
             # traces (PERF.md section 6, PR 32).  Whole [8, Et] rows an
             # operation: Mosaic unrolls them over the lane tiles itself
-            ybuf[yslot] = lax.fori_loop(
+            ybuf[...] = lax.fori_loop(
                 0, N, plane, jnp.zeros((G8, Et), jnp.float32), unroll=True)
-            y_copy(t, yslot).start()
-            count_ref[0] = count + 1
+            live_rows(t, put)
             return carry
 
         lax.fori_loop(0, maxq, position, 0)
 
-    @pl.when((g == pl.num_programs(0) - 1) & (e == pl.num_programs(1) - 1))
-    def _last_point():
-        count = count_ref[0]
-        for back in (1, 2):
-            @pl.when(count >= back)
-            def _drain():
-                y_copy(0, (count - back) % 2).wait()
 
-
-def _ssm_scan_jnp(ssm, dt, x, Bm, Cm, A, q_lens, fresh, layer):
-    """Reference and off-TPU body of ``selective_scan``: the layer's state
-    out of its stack, the reset, the recurrence unrolled over the chunk's
-    positions as plain array expressions, no loop carry, so that XLA may
-    fuse several positions into one pass over the state (on a v5e a chunk of
-    16 costs 1.9 ms a layer alone against 2.3 ms as a ``lax.scan`` over
-    positions: PERF.md section 6, PR 27), and the write-back.  A position
-    ``t >= q_lens[r]`` has ``dt``, ``dt x`` and ``B`` zero by select and so
-    leaves the state as it was, whatever the inputs hold there."""
+def _ssm_scan_jnp(ssm, dt, x, Bm, Cm, A, q_lens, start, fresh, layer, Tc):
+    """Reference and off-TPU body of ``selective_scan``, on the same flat
+    arguments (it pads them to rows itself): the layer's state out of its
+    stack, the reset, the recurrence unrolled over the chunk's positions as
+    plain array expressions, no loop carry, so that XLA may fuse several
+    positions into one pass over the state (on a v5e a chunk of 16 costs
+    1.9 ms a layer alone against 2.3 ms as a ``lax.scan`` over positions:
+    PERF.md section 6, PR 27), and the write-back.  A position ``t >=
+    q_lens[r]`` has ``dt``, ``dt x`` and ``B`` zero by select and so leaves
+    the state as it was, whatever the inputs hold there; ``y`` is zero at
+    every flat position that holds no fed token."""
+    T = dt.shape[0]
+    q_lens = jnp.minimum(q_lens.astype(jnp.int32), Tc)
+    start = start.astype(jnp.int32)
+    # padded rows [R, Tc, ..]: position t of row r is flat start[r] + t; a
+    # dead position holds some other token's values, selected away below
+    at = jnp.minimum(start[:, None] + jnp.arange(Tc, dtype=jnp.int32), T - 1)
+    dt, x, Bm, Cm = (jnp.take(a, at, axis=0) for a in (dt, x, Bm, Cm))
     s = jnp.where(fresh[None, :, None], 0,
                   lax.dynamic_index_in_dim(ssm, layer, 0, keepdims=False))
-    real = (jnp.arange(dt.shape[1])[None, :] < q_lens[:, None])[:, :, None]
+    real = (jnp.arange(Tc)[None, :] < q_lens[:, None])[:, :, None]
     dt = jnp.where(real, dt, 0.0)
     dx = jnp.where(real, dt * x, 0.0)
     Bm = jnp.where(real, Bm, 0.0)
     ys = []
-    for t in range(dt.shape[1]):
+    for t in range(Tc):
         s = (jnp.exp(dt[None, :, t] * A[:, None, :]) * s
              + dx[None, :, t] * Bm[:, t].T[:, :, None])
         ys.append(jnp.sum(s * Cm[:, t].T[:, :, None], axis=0))
-    return jnp.stack(ys, 1), lax.dynamic_update_index_in_dim(
+    dst = jnp.where(real[:, :, 0], at, T)
+    y = jnp.zeros((T, x.shape[-1]), jnp.float32).at[dst].set(
+        jnp.stack(ys, 1), mode="drop")
+    return y, lax.dynamic_update_index_in_dim(
         ssm, s.astype(ssm.dtype), layer, 0)
 
 
-def _ssm_scan_call(ssm, dt, x, Bm, Cm, A, q_lens, fresh, layer, Et=None):
+def _ssm_scan_call(ssm, dt, x, Bm, Cm, A, q_lens, start, fresh, layer, Tc,
+                   Et=None):
     """Raw pallas_call of the selective scan: the state stack aliased in to
-    out, the rows' inputs position-major, tiles of ``Et`` lanes."""
+    out, the step's flat tokens, tiles of ``Et`` lanes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     M, N, R, E = ssm.shape
-    Tc = dt.shape[1]
-    Et = Et or _ssm_scan_tile(N, E, Tc)
+    T = dt.shape[0]
+    Et = Et or _ssm_scan_tile(N, E, T)
     f32 = jnp.float32
-    # [Tc, R, ...]: what ``StepLayout.rows`` gathered, before its transpose
-    dt, x, Bm, Cm = (jnp.swapaxes(a.astype(f32), 0, 1)
-                     for a in (dt, x, Bm, Cm))
     scalars = (_layer_operand(layer), q_lens.astype(jnp.int32),
-               fresh.astype(jnp.int32))
+               start.astype(jnp.int32), fresh.astype(jnp.int32))
 
-    def state_map(g, e, layer, *rest):
+    def state_map(e, g, layer, *rest):
         del rest
         return (layer[0], 0, g, e)
 
-    pos0 = pl.BlockSpec((1, _SSM_ROWS, Et), lambda g, e, *s: (0, g, e))
-    cols = pl.BlockSpec((Tc, _SSM_ROWS, N), lambda g, e, *s: (0, g, 0))
-    a_spec = pl.BlockSpec((N, Et), lambda g, e, *s: (0, e))
+    tokens = pl.BlockSpec((T, Et), lambda e, g, *s: (0, e))
+    cols = pl.BlockSpec((T, N), lambda e, g, *s: (0, 0))
+    a_spec = pl.BlockSpec((N, Et), lambda e, g, *s: (0, e))
     state = pl.BlockSpec((1, N, _SSM_ROWS, Et), state_map)
-    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    slab = pltpu.VMEM((2, _SSM_ROWS, Et), f32)
+    slab = pltpu.VMEM((_SSM_ROWS, Et), f32)
+    col = pltpu.VMEM((_SSM_ROWS, N), f32)
     planes = pltpu.VMEM((N, _SSM_ROWS, _LANES), f32)     # B_t's, C_t's columns
-    operands = (dt, x, Bm, Cm, A.astype(f32), dt, x, ssm)
+    operands = tuple(a.astype(f32) for a in (dt, x, Bm, Cm, A)) + (ssm,)
     call = _pallas_call(
-        _ssm_scan_kernel, own_dma=True,
+        functools.partial(_ssm_scan_kernel, Tc=Tc),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(R // _SSM_ROWS, E // Et),
-            in_specs=[pos0, pos0, cols, cols, a_spec, in_hbm, in_hbm, state],
-            out_specs=[state, in_hbm],
-            scratch_shapes=[slab, slab, pltpu.SemaphoreType.DMA((2, 2)),
-                            slab, pltpu.SemaphoreType.DMA((2,)),
-                            pltpu.SMEM((1,), jnp.int32), planes, planes]),
+            grid=(E // Et, R // _SSM_ROWS),
+            in_specs=[tokens, tokens, cols, cols, a_spec, state],
+            out_specs=[state, tokens],
+            scratch_shapes=[slab, slab, slab, col, col, planes, planes]),
         out_shape=[jax.ShapeDtypeStruct(ssm.shape, ssm.dtype),
-                   jax.ShapeDtypeStruct((Tc, R, E), f32)],
+                   jax.ShapeDtypeStruct((T, E), f32)],
         # operand numbers count the scalars: the state stack is the last
         input_output_aliases={len(scalars) + len(operands) - 1: 0},
-        # a point leaves the wait for its last y to a later one: in order
-        compiler_params=_compiler_params("arbitrary", "arbitrary"),
+        # a lane tile's y block is written by every group of its rows
+        compiler_params=_compiler_params("parallel", "arbitrary"),
     )
     ssm, y = call(*scalars, *operands)
-    return jnp.swapaxes(y, 0, 1), ssm
+    return y, ssm
 
 
 # A model calls the scan from every run of Mamba layers it scans over (three
@@ -2845,50 +2824,60 @@ def _ssm_scan_call(ssm, dt, x, Bm, Cm, A, q_lens, fresh, layer, Et=None):
 # once a program, which every engine start pays for, compile cache or not
 # (PERF.md section 6, PR 32).  The tile is static: it follows
 # ``_VMEM_BUDGET``, which the trace cache does not see.
-_ssm_scan_jit = jax.jit(_ssm_scan_call, static_argnames="Et")
+_ssm_scan_jit = jax.jit(_ssm_scan_call, static_argnames=("Tc", "Et"))
 
 
-def ssm_scan_available(ssm_shape, dtype, Tc):
+def ssm_scan_available(ssm_shape, dtype, T):
     """True when the Pallas scan can serve this state stack ``[M, N, R,
-    E]``: float32, whole groups of 8 rows, lane-aligned E, a tile within
-    ``_VMEM_BUDGET``, and a TPU backend or interpret mode."""
+    E]`` on ``T`` flat tokens: float32, whole groups of 8 rows, lane-aligned
+    E, whole sublane tiles of tokens, a tile within ``_VMEM_BUDGET``, and a
+    TPU backend or interpret mode."""
     N, R, E = ssm_shape[1:]
     if jnp.dtype(dtype) != jnp.float32 or R % _SSM_ROWS or E % _LANES \
-            or _ssm_scan_tile(N, E, Tc) is None:
+            or T % _SSM_ROWS or _ssm_scan_tile(N, E, T) is None:
         return False
     return _kernels_enabled("selective_scan")
 
 
-def selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, fresh, *, layer=0):
+def selective_scan(ssm, dt, x, Bm, Cm, A, q_lens, start, fresh, *, Tc,
+                   layer=0):
     """The selective scan of one Mamba layer over a step's ragged chunks,
-    its state updated in place in the stack.
+    its state updated in place in the stack, on the step's flat tokens.
 
     ssm          [M, N, R, E] float32, the recurrent state of every Mamba
                  layer and engine slot; returned as the same buffer with
                  layer ``layer`` advanced
-    dt, x        [R, Tc, E] the step sizes (after softplus) and the
-                 convolution's activations of each row's chunk
-    Bm, Cm       [R, Tc, N]; A [N, E] (``-exp(A_log)``)
-    q_lens       [R] i32: row r has ``q_lens[r]`` live positions; the
-                 positions past them advance no state, whatever the inputs
-                 hold there (NaN included)
+    dt, x        [T, E] the step sizes (after softplus) and the
+                 convolution's activations of the step's tokens, row after
+                 row (``StepLayout``)
+    Bm, Cm       [T, N]; A [N, E] (``-exp(A_log)``)
+    q_lens       [R] i32: row r has ``q_lens[r]`` live positions, at most
+                 ``Tc`` (static)
+    start        [R] i32: position t of row r is flat token ``start[r] +
+                 t``; the caller guarantees ``start[r] + q_lens[r] <= T``
+                 (``cumsum(q_lens) - q_lens`` for the compact batch, ``r x
+                 Tc`` for padded rows laid end to end).  What the flat
+                 arrays hold anywhere else advances no state (NaN included)
     fresh        [R] bool: the row's chunk starts a request, its state
                  starts from zero
     layer        which layer of the stack (an int or a traced scalar)
 
-    Returns ``(y [R, Tc, E] float32, ssm)`` with ``S_t = exp(dt_t A)
-    S_{t-1} + (dt_t x_t) B_t`` and ``y_t = S_t C_t`` at the live positions,
-    all in float32; ``y`` at a position ``t >= q_lens[r]`` is unspecified
-    (the kernel writes only what some row of the group of 8 lives to).  On
-    the TPU a Mosaic kernel (``_ssm_scan_kernel``) for both programs,
-    ``Tc = chunk`` and ``Tc = 1``; off-TPU, for a row count that is no
-    multiple of 8 and for an E that is no multiple of 128, the XLA body."""
-    if not ssm_scan_available(ssm.shape, ssm.dtype, dt.shape[1]):
-        return _ssm_scan_jnp(ssm, dt, x, Bm, Cm, A, q_lens, fresh, layer)
+    Returns ``(y [T, E] float32, ssm)`` with ``S_t = exp(dt_t A) S_{t-1} +
+    (dt_t x_t) B_t`` and ``y_t = S_t C_t`` at the fed tokens, all in
+    float32; ``y`` at a flat position that holds no fed token is
+    unspecified (the kernel writes the fed tokens alone): select it away.
+    On the TPU a Mosaic kernel (``_ssm_scan_kernel``) for both programs,
+    ``Tc = chunk`` and ``Tc = 1``; off-TPU, for a row or token count that is
+    no multiple of 8 and for an E that is no multiple of 128, the XLA
+    body."""
+    if not ssm_scan_available(ssm.shape, ssm.dtype, dt.shape[0]):
+        return _ssm_scan_jnp(ssm, dt, x, Bm, Cm, A, q_lens, start, fresh,
+                             layer, Tc)
     # (the layer as an int32 array: a Python int would be another trace)
     return _ssm_scan_jit(
-        ssm, dt, x, Bm, Cm, A, q_lens, fresh, jnp.asarray(layer, jnp.int32),
-        Et=_ssm_scan_tile(ssm.shape[1], ssm.shape[3], dt.shape[1]))
+        ssm, dt, x, Bm, Cm, A, q_lens, start, fresh,
+        jnp.asarray(layer, jnp.int32), Tc=Tc,
+        Et=_ssm_scan_tile(ssm.shape[1], ssm.shape[3], dt.shape[0]))
 
 
 # ---------------------------------------------------------------------------
@@ -3620,21 +3609,24 @@ def kernel_verify_cases():
         fn, avals = kv_write_case(Tc)
         cases.append((name, fn, avals))
 
-    # the selective scan of a Mamba layer, both programs: a layer other
-    # than 0 of a stack of Ls, two groups of 8 rows of which one holds a
-    # whole chunk beside decode rows and an idle row; concrete lengths, so
-    # the state's (layer, group, tile) index map is evaluated
+    # the selective scan of a Mamba layer, both programs, on the compact
+    # flat batch: a layer other than 0 of a stack of Ls, two groups of 8
+    # rows of which one holds a whole chunk beside decode rows and an idle
+    # row; concrete lengths, so the state's (layer, group, tile) index map
+    # is evaluated
     Rs, Es, Ns = 16, 2 * _LANES, 16
 
     def scan_case(Tc):
         qlens = np.array([1] * 8 + [Tc, 1, 0, 1, 1, 1, 1, 1], np.int32)
         fresh = qlens > 1
+        start = np.cumsum(qlens) - qlens
+        Ts = -(-int(qlens.sum()) // 8) * 8       # the flat batch, compact
 
         def fwd(ssm, dt, x, bm, cm, a):
-            return _ssm_scan_call(ssm, dt, x, bm, cm, a, qlens, fresh,
-                                  layer)
-        row = SDS((Rs, Tc, Es), f32)
-        col = SDS((Rs, Tc, Ns), f32)
+            return _ssm_scan_call(ssm, dt, x, bm, cm, a, qlens, start,
+                                  fresh, layer, Tc)
+        row = SDS((Ts, Es), f32)
+        col = SDS((Ts, Ns), f32)
         return fwd, (SDS((Ls, Ns, Rs, Es), f32), row, row, col, col,
                      SDS((Ns, Es), f32))
 
