@@ -83,7 +83,7 @@ import numpy as np
 from jax import lax
 
 from . import mla
-from .experts import route_top_k, routed_experts
+from .experts import route_top_k, routed_experts, shared_experts
 from .jamba import _layer_at
 from .mla import rms_norm as _rms_norm, swiglu as _swiglu
 from .step_layout import StepLayout
@@ -335,9 +335,9 @@ def _expert_ffn(cfg, lp, experts, xn, l, live):
             xn, weights, chosen, *(experts[n] for n in EXPERT_LEAVES),
             num_experts=cfg.n_routed_experts, held=cfg.experts_held, layer=l,
             live=live)
-        with jax.named_scope("moe_shared"):
-            shared = _swiglu(xn, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
-        return (shared.astype(jnp.float32) + routed).astype(xn.dtype), rows
+        shared = shared_experts(xn, lp["ws_gate"], lp["ws_up"],
+                                lp["ws_down"])
+        return (shared + routed).astype(xn.dtype), rows
 
 
 def _forward(cfg, params, ids, pos, live, mixer, carry):

@@ -10,13 +10,18 @@ chip's share: ``model-configs`` guide section 4), ``K`` experts a token and
     y[t]    = sum over k with idx[t, k] held of  w[t, k] expert_idx[t,k](x[t])
     expert_e(x) = W_d[e] (silu(W_g[e] x) * W_u[e] x)
 
-A router may choose by ``p + b`` (a bias an expert, which balances the load
-and is no part of the weight: ``route_top_k(bias=)``), and may score ``Z``
+A router may score by ``sigmoid(x W_r)`` in place of the softmax and
+renormalise the ``K`` weights it keeps to sum to 1 (``route_top_k(score=
+"sigmoid", renormalize=True)``: Cohere's), may choose by ``p + b`` (a bias
+an expert, which balances the load and is no part of the weight:
+``route_top_k(bias=)``), and may score ``Z``
 ZERO-COMPUTE experts after the ``E`` routed ones (ids ``E .. E + Z - 1``,
 ``routed_experts(zero_experts=Z)``): such an expert is the identity, has no
 weights, is held by every chip, costs no row of the grouped product and adds
 ``w x`` to the token, so the experts a token computes number ``K`` less a
-draw.
+draw.  SHARED experts, which every token computes and every chip holds,
+are ``shared_experts``: ``n`` of them held as ONE SwiGLU of ``n`` times the
+width, summed (DeepSeek's) or averaged (Cohere's).
 
 The router always scores all ``E`` experts; a pair whose expert is not held
 adds nothing HERE (its chip adds it; on one chip nothing stands in for the
@@ -36,24 +41,51 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-__all__ = ["route_top_k", "routed_experts"]
+__all__ = ["route_top_k", "routed_experts", "shared_experts"]
 
 
-def route_top_k(x, w_router, k: int, bias=None):
+def route_top_k(x, w_router, k: int, bias=None, score: str = "softmax",
+                renormalize: bool = False):
     """``(weights [T, k] float32, experts [T, k] int32)``: the ``k`` largest
-    of ``softmax(x W_r)``, the softmax in float32 over every expert from the
-    float32-accumulated logits, the probabilities as they are (not
+    of ``p = softmax(x W_r)``, the softmax in float32 over every expert from
+    the float32-accumulated logits, the probabilities as they are (not
     renormalised over the chosen), equal ones to the lower index.  With
     ``bias [E]`` the choice is by ``p + bias`` and the weight is still
-    ``p``: the bias steers the load and never scales an expert's output."""
+    ``p``: the bias steers the load and never scales an expert's output.
+    ``score="sigmoid"``: ``p = sigmoid(x W_r)``, each expert's score on its
+    own; ``renormalize``: the ``k`` weights kept are divided by their sum."""
     logits = jnp.dot(x, w_router, preferred_element_type=jnp.float32)
-    p = jax.nn.softmax(logits, axis=-1)
+    if score == "softmax":
+        p = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        p = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"a router scores by softmax or sigmoid, not "
+                         f"{score!r}")
     if bias is None:
         weights, experts = lax.top_k(p, k)
     else:
         _, experts = lax.top_k(p + bias.astype(jnp.float32), k)
         weights = jnp.take_along_axis(p, experts, axis=-1)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, experts.astype(jnp.int32)
+
+
+def shared_experts(x, w_gate, w_up, w_down, *, count: int = 1,
+                   combine: str = "sum"):
+    """``[T, D]`` float32: what the ``count`` shared experts add for the
+    tokens ``x [T, D]``, held as ONE SwiGLU whose width is theirs side by
+    side (``w_gate, w_up [D, count F]``, ``w_down [count F, D]``), so that
+    the down product sums them; ``combine="average"`` divides that sum by
+    ``count`` (exact in float32 for a power of two)."""
+    if combine not in ("sum", "average"):
+        raise ValueError(f"shared experts are summed or averaged, not "
+                         f"{combine!r}")
+    with jax.named_scope("moe_shared"):
+        y = ((jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down).astype(
+            jnp.float32)
+        return y / count if combine == "average" else y
 
 
 def routed_experts(x, weights, experts, w_gate, w_up, w_down, *,

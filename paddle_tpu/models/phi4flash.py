@@ -64,32 +64,15 @@ configuration for).  Three kinds of state live in one cache dict:
   layer AND every cross layer read this one pool;
 * ``kw_pages / vw_pages [L/4, nkv/2, 1 + R x Wp, page, 2d]``: for the window
   layers a RING of ``Wp = ceil(W / page) + 2`` pages a slot (6 at W 512 and
-  page 128: what ``ceil((W + chunk - 1) / page) + 1`` gives at chunk 16),
-  logical page b of slot r at pool page ``1 + r x Wp + b % Wp``, through a
-  table built inside the program from ``arange`` (nothing is uploaded);
-  pool page 0 is the null page that ``paged_kv_write`` parks its idle tiles
-  on, as the allocator's page 0 is in the engine's pool;
+  page 128), ``models/rings.py``'s, which says why that is enough;
 * ``conv [M, K-1, R, E]`` and ``ssm [M, N, R, E]`` float32: the Mamba layers'
   recurrent state of every slot, as in ``models/jamba.py``.
-
-Why a ring of ``Wp`` pages is enough.  A step writes row r's chunk, positions
-``p0 .. p0 + q - 1`` with ``q <= page``, and then its queries read the keys
-``p0 - W + 1 .. p0 + q - 1``: at most ``W + page - 1`` positions in a row,
-which touch at most ``ceil(W / page) + 2 = Wp`` logical pages in a row, and
-``Wp`` pages in a row sit on ``Wp`` different ring pages.  So the page a
-chunk overwrites when it enters logical page b (the ring page that held
-page ``b - Wp``) is out of every live query's window: at W 512, page 128 the
-chunk's first query, at ``p0 >= 128 b - 15``, needs keys ``> p0 - 512 >= 128
-(b - 5) + 113``, and the page overwritten ends at ``128 (b - 5) - 1``.
-The windowed kernel never reads before the first page a row's queries see
-(``pallas_ops._rpa_walk``), and stale rows of the pages it does read are
-masked as the future or as out of the window.
 
 Replay from token 0 (admission into a used slot, preemption, a rebuilt
 engine) rewrites rings and state as it rewrites pages: see
 ``forward_paged``.  A prefix-cache hit would skip tokens the rings and the
 state never saw, a rejected draft would have advanced them:
-``recurrent_state`` makes the engine refuse both.
+``recurrent_state`` and ``rings`` make the engine refuse both.
 """
 from __future__ import annotations
 
@@ -100,9 +83,9 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
+from . import rings
 from .jamba import _layer_at, _mixer_core
 from .step_layout import StepLayout
 
@@ -175,7 +158,7 @@ class Phi4FlashConfig:
 
     def ring_pages(self, page_size: int) -> int:
         """``Wp``: the pages of one slot's ring in a window layer."""
-        return -(-self.sliding_window // page_size) + 2
+        return rings.ring_pages(self.sliding_window, page_size)
 
     @property
     def serving(self):
@@ -519,12 +502,12 @@ def init_cache(cfg: Phi4FlashConfig, slots: int, num_pages: int,
             "pools that only models/llama.py's step writes")
     nkvp, width = cfg.num_key_value_heads // 2, 2 * cfg.head_dim
     pool = (1, nkvp, num_pages, page_size, width)
-    ring = (cfg.num_window_layers, nkvp,
-            1 + slots * cfg.ring_pages(page_size), page_size, width)
     return {"k_pages": jnp.zeros(pool, kv_dtype),
             "v_pages": jnp.zeros(pool, kv_dtype),
-            "kw_pages": jnp.zeros(ring, kv_dtype),
-            "vw_pages": jnp.zeros(ring, kv_dtype), **_fresh_state(cfg, slots)}
+            **rings.init_rings(cfg.num_window_layers, nkvp, slots,
+                               cfg.sliding_window, page_size, width,
+                               kv_dtype),
+            **_fresh_state(cfg, slots)}
 
 
 def cache_bytes(cfg: Phi4FlashConfig, kv_dtype_bytes: int = 2,
@@ -536,13 +519,13 @@ def cache_bytes(cfg: Phi4FlashConfig, kv_dtype_bytes: int = 2,
     page (``fixed``)."""
     E = cfg.mamba_inner
     token = 2 * cfg.num_key_value_heads * cfg.head_dim * kv_dtype_bytes
-    ring_page = cfg.num_window_layers * token * page_size
+    ring = rings.ring_bytes(cfg.num_window_layers, token, cfg.sliding_window,
+                            page_size)
     state = cfg.num_mamba_layers * E * (
         cfg.mamba_d_state * 4
         + (cfg.mamba_d_conv - 1) * jnp.dtype(cfg.dtype).itemsize)
     return {"per_token": token, "scales_per_page": 0,
-            "per_slot": cfg.ring_pages(page_size) * ring_page + state,
-            "fixed": ring_page}
+            "per_slot": ring["per_slot"] + state, "fixed": ring["fixed"]}
 
 
 def step_counts(cfg: Phi4FlashConfig, seq_lens, q_lens) -> dict:
@@ -555,13 +538,8 @@ def step_counts(cfg: Phi4FlashConfig, seq_lens, q_lens) -> dict:
     ``serve/engine_step`` span beside ``kv_tokens`` and ``qk_pairs``, which
     count the full layer."""
     from ..ops.pallas_ops import scan_positions
-    W = cfg.sliding_window
-    seq, q = np.asarray(seq_lens, np.int64), np.asarray(q_lens, np.int64)
-    t = np.arange(int(q.max(initial=0)))[None, :]
-    seen = np.minimum((seq - q)[:, None] + t + 1, W)
-    return {"window_kv_tokens": int(np.minimum(seq, W + q - 1)[q > 0].sum()),
-            "window_qk_pairs": int(seen[t < q[:, None]].sum()),
-            "scan_positions": scan_positions(q_lens)}
+    return dict(rings.window_counts(seq_lens, q_lens, cfg.sliding_window),
+                scan_positions=scan_positions(q_lens))
 
 
 def forward_paged(cfg: Phi4FlashConfig, params, tokens, cache, block_tables,
@@ -586,17 +564,10 @@ def forward_paged(cfg: Phi4FlashConfig, params, tokens, cache, block_tables,
     a row with ``q_lens[r] == 0`` comes back as it was."""
     from ..ops.pallas_ops import paged_kv_write, ragged_paged_attention
     R, Tc = tokens.shape
-    page = cache["k_pages"].shape[3]
-    Wp = cfg.ring_pages(page)
-    if Tc > page:
-        raise ValueError(
-            f"a chunk of {Tc} tokens is longer than a page of {page}: a "
-            f"ring of {Wp} pages a slot holds a window and a chunk of at "
-            "most a page")
+    ring_table = rings.ring_table(R, block_tables.shape[1],
+                                  cfg.sliding_window,
+                                  cache["k_pages"].shape[3], Tc)
     rep = 2 * cfg.num_attention_heads // cfg.num_key_value_heads
-    ring_table = (1 + jnp.arange(R, dtype=jnp.int32)[:, None] * Wp
-                  + jnp.arange(block_tables.shape[1],
-                               dtype=jnp.int32)[None, :] % Wp)
 
     def attend(kind, l, q, k, v, state):
         names = ("kw_pages", "vw_pages") if kind == "window" else \
@@ -626,4 +597,4 @@ SERVING = types.SimpleNamespace(
     forward_paged=forward_paged, init_cache=init_cache,
     cache_bytes=cache_bytes, param_count=param_count,
     prepare_params=lambda cfg, params: params,   # no weight is converted
-    step_counts=step_counts, recurrent_state=True)
+    step_counts=step_counts, recurrent_state=True, rings=True)

@@ -32,7 +32,10 @@ holds something that grows with none of them, ``fixed``) and
 ``param_count(cfg)`` size it, ``prepare_params(cfg, params)`` converts weights
 once at build, and ``recurrent_state`` says whether the cache holds state
 that depends on every token fed, in order (docs/serving.md, "Recurrent
-state", "Window rings and shared K/V").  One member is optional:
+state", "Window rings and shared K/V").  Two members are optional:
+``rings``, true where the cache keeps window layers' K/V in a ring a slot
+(``models/rings.py``; the engine then refuses the prefix cache and
+speculative decoding, as for recurrent state), and
 ``step_counts(cfg, seq_lens, q_lens) -> dict`` of integers, called with the
 host arrays of every step that feeds the device; the engine puts its entries
 on the step's ``serve/engine_step`` span and sums them in
@@ -228,7 +231,7 @@ class LLMEngine:
     """Continuous-batching serving engine over the model ``cfg.serving``
     names (``models/llama.py``, ``models/jamba.py``,
     ``models/phi4flash.py``, ``models/deepseek_v2.py``,
-    ``models/longcat_flash.py``).
+    ``models/longcat_flash.py``, ``models/cohere2_moe.py``).
 
     Parameters mirror the capacity plan: ``page_size`` tokens per pool
     page (default 128, the lane width — the Pallas ragged-paged-attention
@@ -270,8 +273,9 @@ class LLMEngine:
     attaches a draft model for speculative decoding — every decode row
     widens to a 1+k verify chunk through the prefill bucket
     (``serving/spec_decode.py``).  Both raise ``ValueError`` for a model
-    with recurrent state: a page hit would skip tokens the state never
-    saw, and a rejected draft would have advanced it.
+    with recurrent state or window rings: a page hit would skip tokens the
+    state or the ring never saw, and a rejected draft would have advanced
+    the state or overwritten ring entries still in the window.
     """
 
     def __init__(self, cfg, params, *, max_running: int = 8,
@@ -294,6 +298,15 @@ class LLMEngine:
                 "prefix_cache=True would skip the tokens of a page hit and "
                 "spec= would feed draft tokens that cannot be taken back. "
                 "Neither is built for such a model")
+        if getattr(model, "rings", False) and (prefix_cache
+                                               or spec is not None):
+            raise ValueError(
+                f"{type(cfg).__name__} keeps its window layers' K/V in a "
+                "ring a slot (models/rings.py), which only the tokens fed "
+                "into the slot fill: prefix_cache=True would skip the "
+                "tokens of a page hit and spec= would overwrite ring "
+                "entries still in the window with drafts that are then "
+                "rejected. Neither is built for such a model")
         self.params = model.prepare_params(cfg, params)
         self.max_running = int(max_running)
         self.chunk = int(chunk)
