@@ -1836,6 +1836,17 @@ def _rpa_group_pages(nkv, Tr, d, page, itemsize, Bmax, qblk=None,
     return 1 << (int(fit).bit_length() - 1)
 
 
+def _rpa_short_rows(rep, Tr):
+    """The query rows of the walk's few-row turn: the fewest whole 16-row
+    tiles that hold one token's ``rep`` query heads, or None where that is
+    all ``Tr`` rows (every ``[R, 1]`` program).  A row with ``q_len x rep
+    <= short`` computes those rows alone and not its padding: at rep 16
+    and ``Tr`` 256 a one-token row, at rep 20 and 320 too (32 rows), at
+    rep 4 and 64 a row of up to 4 tokens, at rep 2 and 32 up to 8."""
+    short = -(-rep // 16) * 16
+    return short if short < Tr else None
+
+
 def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
               q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, m_s,
               l_s, acc_s, *, page, rep, scale, window=None, qblk=None):
@@ -1854,14 +1865,20 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
     table may map them anywhere (a ring of pages: ``models/phi4flash.py``).
     ``window=None`` traces exactly the walk from page 0.
 
+    A row that feeds at most ``short`` query rows (``_rpa_short_rows``:
+    whole 16-row tiles, e.g. a decode row inside the chunk bucket at 16
+    query heads a K/V head) computes those rows and not its ``Tr - short``
+    padding rows, under a mask built for them alone (the row's first page
+    and the window's lower bound with it), on dense and latent pools
+    alike.
+
     With ``v_hbm`` and ``vbuf`` None the pages are LATENT: a token's one
     vector is its key, and its first ``o_ref.shape[-1]`` lanes are its value
     (``_rpa_kernel_latent``).  A page is copied once and V is a slice of
     the K tile already in VMEM; the probabilities go to the MXU in the
-    pages' dtype, as the scores' operands do; and a row that feeds at most
-    a 16-row tile of query rows (one token of 16 heads: a decode row inside
-    the chunk bucket) computes that tile and not its ``Tr - 16`` padding
-    rows."""
+    pages' dtype, as the scores' operands do; and a row of more query rows
+    than ``qblk`` takes them a block at a time.  Its one head slices a
+    few-row turn out of the whole mask where it has no blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     r = pl.program_id(0)
@@ -1870,13 +1887,10 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
     nkv, Tr, d = q_ref.shape[1:]
     latent = v_hbm is None
     dv = o_ref.shape[-1]         # V's lanes: d, or the head of a latent
-    # latent pages put every query head of a token on ONE key head, Tr =
-    # chunk x heads rows: a row of few tokens (a decode row in the chunk
-    # bucket) would pay the whole rectangle for its padding.  `short` is the
-    # fewest query rows a turn may take, whole 16-row tiles of them
-    short = -(-rep // 16) * 16 if latent else None
-    if short is not None and short >= Tr:
-        short = None
+    # Tr = chunk x rep rows a K/V head: a row of few tokens (a decode row in
+    # the chunk bucket) would pay the whole rectangle for its padding.
+    # `short` is the fewest query rows a turn may take, dense or latent
+    short = _rpa_short_rows(rep, Tr)
     span = kbuf.shape[2]
     G = span // page
     layer = layer_ref[0]
@@ -1973,11 +1987,19 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
         raise ValueError("query blocks are the latent walk's, from page 0")
 
     def block_mask(i, lo, n):
-        """The mask of group i for the query rows ``lo .. lo + n``."""
+        """The mask of group i for the query rows ``lo .. lo + n`` alone:
+        a key column's position from the walk's first page on, under the
+        window's lower bound where there is one."""
         tok = (lo + lax.broadcasted_iota(jnp.int32, (n, span), 0)) // rep
         col = lax.broadcasted_iota(jnp.int32, (n, span), 1)
-        return i * span + col <= jnp.where(tok < qlen, kvlen - qlen + tok,
-                                           -1)
+        if first is not None:
+            col = first * page + col
+        key = i * span + col
+        horizon = jnp.where(tok < qlen, kvlen - qlen + tok, -1)
+        seen = key <= horizon
+        if window is not None:
+            seen &= key > horizon - window
+        return seen
 
     def group(i, carry):
         slot = (slot0 + i) % 2
@@ -1987,10 +2009,19 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
               jnp.where(more, i + 1, 0), 1 - slot,
               jnp.where(more, live, live_nxt))
         wait(i, slot, live)
-        if qblk is None:
+
+        def whole_mask():
+            """The mask of group i for all ``Tr`` query rows."""
             mask = i * span + pos <= horizon
             if window is not None:
                 mask &= i * span + pos > horizon - window
+            return mask
+
+        # where every row takes the whole rectangle, and for the latent
+        # walk's one head, the mask is made before the turns; the dense
+        # walk's few-row turn builds its own and the whole-row turn this one
+        mask = whole_mask() if qblk is None and (short is None or latent) \
+            else None
 
         def page_scales(sc_ref, h):
             """[1, span]: each key column's page's dequant scale."""
@@ -2001,14 +2032,10 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
                 out = jnp.where(col[:1] // page == g, sc_ref[h, pg], out)
             return out
 
-        def head(h, rows=None, lo=0):
-            """Head h's turn on the group; ``rows`` (static): only the
-            ``rows`` query rows from ``lo`` on, the others being padding or
-            another block's."""
-            if qblk is not None:
-                seen = block_mask(i, lo, rows)
-            else:
-                seen = mask if rows is None else mask[:rows]
+        def head(h, seen, rows=None, lo=0):
+            """Head h's turn on the group under the mask ``seen``; ``rows``
+            (static): only the ``rows`` query rows from ``lo`` on, the
+            others being padding or another block's."""
             at = (h,) if rows is None else (h, slice(lo, lo + rows))
             q = q_ref[(0,) + at]                         # [Tr, d]
             k = kbuf[slot, h]                            # [span, d]
@@ -2040,28 +2067,31 @@ def _rpa_walk(tbl_ref, lens_ref, qlens_ref, layer_ref, ksc_ref, vsc_ref,
         # the chat cell's shapes; PERF.md section 6, PR 28)
         if short is None:
             for h in range(nkv):
-                head(h)
+                head(h, mask)
             return carry
 
         # a row of few tokens (a decode row inside the chunk bucket) takes
-        # its first `short` query rows through the MXU and not all Tr
+        # its first `short` query rows through the MXU and the softmax, and
+        # not all Tr
         @pl.when(qlen * rep <= short)
         def _few_rows():
+            seen = block_mask(i, 0, short) if mask is None else mask[:short]
             for h in range(nkv):
-                head(h, short)
+                head(h, seen, short)
 
         if qblk is None:
             @pl.when(qlen * rep > short)
             def _every_row():
+                seen = whole_mask() if mask is None else mask
                 for h in range(nkv):
-                    head(h)
+                    head(h, seen)
             return carry
 
         for lo in range(0, Tr, qblk):
             @pl.when((qlen * rep > short) & (qlen * rep > lo))
             def _block_of_rows(lo=lo):
                 for h in range(nkv):
-                    head(h, qblk, lo)
+                    head(h, block_mask(i, lo, qblk), qblk, lo)
         return carry
 
     lax.fori_loop(0, n, group, 0)

@@ -172,8 +172,13 @@ _WALK_ROWS = (
 )
 
 
-def _walk_case(rep, nkv, Tc, kind, G, layer=1, L=3, d=128, page=128,
-               Bmax=4, seed=0):
+# under a window of 100 a row of 256 tokens or more starts its walk past
+# page 0; a ring of ceil(100 / 128) + 2 pages a row, as models/rings.py
+WALK_WINDOW, WALK_RING = 100, 3
+
+
+def _walk_case(rep, nkv, Tc, kind, G, window=None, layer=1, L=3, d=128,
+               page=128, Bmax=4, seed=0):
     rng = np.random.RandomState(seed)
     R, P = len(_WALK_ROWS), 40
     dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
@@ -196,6 +201,11 @@ def _walk_case(rep, nkv, Tc, kind, G, layer=1, L=3, d=128, page=128,
     # a shuffled table; slots past a row's length hold the null page, as
     # the engine's do; row 6 names row 4's first two pages (a shared prefix)
     tbl = (1 + rng.permutation(P - 1)[:R * Bmax]).reshape(R, Bmax)
+    if window is not None:
+        # a ring of pages a row (``models/rings.py``): logical page b at
+        # ring page b % WALK_RING, so a row whose walk starts past page 0
+        # finds its earlier columns naming the pages its later ones use
+        tbl = tbl[:, np.arange(Bmax) % WALK_RING]
     tbl[np.arange(Bmax)[None, :] * page >= lens[:, None]] = 0
     tbl[6, :2] = tbl[4, :2]
     return (q, kp, vp, jnp.asarray(tbl, jnp.int32), jnp.asarray(lens),
@@ -205,21 +215,33 @@ def _walk_case(rep, nkv, Tc, kind, G, layer=1, L=3, d=128, page=128,
 @pytest.mark.parametrize("G", [1, 2, 4])
 @pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
 @pytest.mark.parametrize("Tc", [1, 16])
-@pytest.mark.parametrize("heads", [(1, 2), (2, 2), (20, 1)],
-                         ids=["mha", "gqa2", "mqa20"])
+@pytest.mark.parametrize(
+    "heads", [(1, 2, None), (2, 2, None), (20, 1, None), (16, 2, None),
+              (4, 2, None), (16, 2, WALK_WINDOW)],
+    ids=["mha", "gqa2", "mqa20", "gqa16", "rep4", "gqa16-window"])
 def test_rpa_walks_a_rows_live_pages_in_groups(heads, Tc, kind, G,
                                                monkeypatch):
     """The kernel against the reference on one batch that holds every
     kind of row the walk meets, on layer 1 of a stack of 3, with the
-    pages fetched 1, 2 and 4 (the whole table) at a time."""
-    rep, nkv = heads
-    q, kp, vp, tbl, lens, qlens, scales = _walk_case(rep, nkv, Tc, kind, G)
+    pages fetched 1, 2 and 4 (the whole table) at a time.  At 16 query
+    heads a K/V head (Command A+'s), 20 (Jamba's) and 4 (Phi-4-mini-
+    flash's paired heads) a chunk's one-token rows take the few-row turn;
+    under the window, rows of one token past it and rows whose walk
+    starts past page 0 of a ring table take it too."""
+    rep, nkv, window = heads
+    q, kp, vp, tbl, lens, qlens, scales = _walk_case(rep, nkv, Tc, kind, G,
+                                                     window)
     _forced_group(monkeypatch, G, nkv, Tc * rep, 128, 128,
                   kp.dtype.itemsize, tbl.shape[1])
     out = pallas_ops._rpa_call(q, kp, vp, tbl, lens, qlens, rep=rep,
-                               layer=1, **scales)
+                               layer=1, window=window, **scales)
     ref = pallas_ops._ragged_attention_jnp(
-        q, kp, vp, tbl, lens, qlens, rep, *scales.values(), layer=1)
+        q, kp, vp, tbl, lens, qlens, rep, *scales.values(), layer=1,
+        window=window)
+    if window is not None:
+        # the batch holds a one-token row whose walk starts past page 0
+        first = np.maximum(np.asarray(lens - qlens) - window + 1, 0) // 128
+        assert np.any((first > 0) & (np.asarray(qlens) == 1))
     assert not bool(jnp.any(jnp.isnan(out.astype(jnp.float32))))
     assert _maxerr(out, ref) < (2e-2 if kind == "bf16" else 2e-5)
     # padding rows and the idle request are exact zeros
@@ -248,6 +270,33 @@ def test_rpa_group_size_follows_the_shapes_and_the_budget():
         assert group(2 * nkv, Tr, 128, 128, itemsize, Bmax) <= G
         assert group(nkv, 2 * Tr, 128, 128, itemsize, Bmax) <= G
         assert group(nkv, Tr, 128, 256, itemsize, Bmax) <= G
+
+
+@pytest.mark.parametrize("rep,Tc,short,longest", [
+    (2, 16, 16, 8),       # chat's mixed step: rows of up to 8 tokens
+    (4, 16, 16, 4),       # Phi-4-mini-flash's paired heads
+    (16, 16, 16, 1),      # Command A+: a one-token row
+    (20, 16, 32, 1),      # Jamba: two tiles for one token's 20 heads
+    (64, 16, 64, 1),      # LongCat-Flash-Chat's latent walk
+    (16, 2, 16, 1),
+    (2, 1, None, 0),      # every [R, 1] program: the tile is all its rows
+    (4, 1, None, 0),
+    (16, 1, None, 0),
+    (20, 1, None, 0),
+    (1, 16, None, 0),     # MHA's chunk is one tile
+    (4, 4, None, 0),
+])
+def test_rpa_short_rows_is_one_tokens_tiles_below_the_chunk(rep, Tc, short,
+                                                           longest):
+    """The walk's few-row turn: whole 16-row tiles for one token's query
+    heads, and None wherever they would be all ``Tr`` rows; ``longest`` is
+    the most tokens a row that takes it may feed."""
+    got = pallas_ops._rpa_short_rows(rep, Tc * rep)
+    assert got == short
+    if short is not None:
+        assert short % 16 == 0 and rep <= short < Tc * rep
+        assert max(t for t in range(1, Tc + 1) if t * rep <= short) \
+            == longest
 
 
 def test_rpa_public_entry_falls_back_off_tpu():
