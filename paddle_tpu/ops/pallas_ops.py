@@ -1817,22 +1817,24 @@ def _rpa_row_bytes(nkv, Tr, d, itemsize):
 def _rpa_group_pages(nkv, Tr, d, page, itemsize, Bmax, qblk=None,
                      budget=None):
     """``G``, the pages of one DMA group: the largest power of two, at
-    most the table's width, whose working set fits ``_VMEM_BUDGET``
-    beside what a row needs whatever G is.  A page of the group costs K
-    and V of all heads in both slots, and eight 4-byte ``[Tr, page]``
-    tiles of the walk (token, column and horizon, which live through the
-    loop; a head's mask, scores, exponent, probabilities and their
-    scaled copy); a row its q and o blocks (the pipeline keeps two of
-    each) and the float32 statistics and accumulator of every head.
-    Fewer, fatter turns win while the copies bound the row (8 kv heads,
-    Tr 32: 7.1 ms a step of the chat cell's shapes at G 8, 7.3 at 4, 10
-    at 2); where the scores bound it, a wide group's dead key columns
-    cost more than its turns save (1 kv head, Tr 320: 1.45 ms at G 8,
-    1.81 at 16), which the tiles' share of the budget stands for
-    (PERF.md section 6, PR 28)."""
-    fixed = 4 * nkv * Tr * d * itemsize + nkv * Tr * (2 * _LANES + d) * 4
-    per_page = 4 * nkv * page * d * itemsize + 8 * Tr * page * 4
-    fit = max(1, min((_VMEM_BUDGET - fixed) // per_page, Bmax))
+    most the table's width, whose working set fits ``budget`` (the
+    call's, where it grants one; else ``_VMEM_BUDGET``) beside what a row
+    needs whatever G is (``_rpa_row_bytes``: the whole row's, blocks or
+    not).  A page of the group costs K and V of all heads in both slots,
+    and eight 4-byte tiles of the rows a turn computes, ``[qblk, page]``
+    where the row is walked ``qblk`` query rows at a time, else ``[Tr,
+    page]`` (token, column and horizon, which live through the loop; a
+    head's mask, scores, exponent, probabilities and their scaled copy).
+    Fewer, fatter turns win while the copies or the turns' own cost bound
+    the row (8 kv heads, Tr 32: 7.1 ms a step of the chat cell's shapes
+    at G 8, 7.3 at 4, 10 at 2); where the scores bound it, a wide group's
+    dead key columns cost more than its turns save (1 kv head, Tr 320:
+    1.45 ms at G 8, 1.81 at 16), which the tiles' share of the budget
+    stands for (PERF.md section 6, PR 28)."""
+    room = (_VMEM_BUDGET if budget is None else budget) \
+        - _rpa_row_bytes(nkv, Tr, d, itemsize)
+    per_page = 4 * nkv * page * d * itemsize + 8 * (qblk or Tr) * page * 4
+    fit = max(1, min(room // per_page, Bmax))
     return 1 << (int(fit).bit_length() - 1)
 
 

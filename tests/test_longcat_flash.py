@@ -23,6 +23,7 @@ from paddle_tpu.models import deepseek_v2, experts, longcat_flash, mla
 from paddle_tpu.ops import pallas_ops
 from paddle_tpu.testing import chaos
 import test_deepseek_v2
+import test_serving
 from test_deepseek_v2 import fields_of
 from test_jamba import close, drain, prompts_of
 from test_spans import scopes_of
@@ -406,27 +407,42 @@ def test_the_width_tiled_expert_kernel_equals_the_ragged_products(
 
 # -- the latent walk at many heads ------------------------------------------------
 
+@pytest.mark.parametrize("G, lanes", [(1, 128), (2, 256), (4, 256)])
 def test_the_latent_walk_in_query_blocks_equals_its_jnp_body(interpret,
-                                                             monkeypatch):
+                                                             monkeypatch, G,
+                                                             lanes):
     """``latent_paged_attention`` at 32 heads x 16 tokens = 512 query rows,
-    two blocks of ``_RPA_Q_BLOCK``: a chunk that fills both, one that fills
-    the first alone, a decode row (the short path), an idle row."""
-    rep, Tc, lanes, v_lanes, page, R, L = 32, 16, 256, 128, 128, 4, 2
+    two blocks of ``_RPA_Q_BLOCK``, over tables of 8 pages walked G pages a
+    turn: a chunk that fills both blocks and ends in a partial group, one
+    that fills the first block alone over the whole table, a one-token row
+    a token past a group's edge, a decode row (the short path), an idle
+    row.  At 256 lanes what a row keeps leaves room for two pages at least
+    under the budget its call grants, so one page a turn is forced at 128."""
+    rep, Tc, v_lanes, page, R, L, Bmax = 32, 16, 128, 128, 5, 2, 8
     rng = np.random.default_rng(0)
-    pages = jnp.asarray(rng.normal(size=(L, 1, 9, page, lanes)), jnp.float32)
+    pages = jnp.asarray(rng.normal(size=(L, 1, 1 + R * Bmax, page, lanes)),
+                        jnp.float32)
     q = jnp.asarray(rng.normal(size=(R, 1, Tc * rep, lanes)), jnp.float32)
-    tbl = jnp.asarray(1 + np.arange(R * 2).reshape(R, 2), jnp.int32)
-    lens = jnp.asarray([200, 135, 77, 0], jnp.int32)
-    qlens = jnp.asarray([16, 5, 1, 0], jnp.int32)
+    tbl = jnp.asarray(1 + rng.permutation(R * Bmax).reshape(R, Bmax),
+                      jnp.int32)
+    lens = jnp.asarray([4 * page + 77, Bmax * page, G * page + 1, 77, 0],
+                       jnp.int32)
+    qlens = jnp.asarray([16, 5, 1, 1, 0], jnp.int32)
+    test_serving._forced_group(monkeypatch, G, 1, Tc * rep, lanes, page, 4,
+                               Bmax, qblk=pallas_ops._RPA_Q_BLOCK)
     kw = dict(rep=rep, v_lanes=v_lanes, scale=0.11, layer=jnp.int32(1))
-    seen = []
+    seen, groups = [], []
     real = pallas_ops._rpa_kernel_latent
-    monkeypatch.setattr(
-        pallas_ops, "_rpa_kernel_latent",
-        functools.wraps(real)(lambda *a, **k: (seen.append(k["qblk"]),
-                                               real(*a, **k))[1]))
+
+    def spy(*a, **k):
+        seen.append(k["qblk"])
+        groups.append(a[7].shape[2] // k["page"])     # cbuf [2, 1, span, d]
+        return real(*a, **k)
+
+    monkeypatch.setattr(pallas_ops, "_rpa_kernel_latent",
+                        functools.wraps(real)(spy))
     got = pallas_ops.latent_paged_attention(q, pages, tbl, lens, qlens, **kw)
-    assert seen == [256]
+    assert seen == [256] and groups == [G]
     want = pallas_ops._ragged_attention_jnp(
         q, pages, pages[..., :v_lanes], tbl, lens, qlens, rep, layer=1,
         scale=0.11)

@@ -141,14 +141,20 @@ def test_rpa_gqa_bf16_lane_aligned_page():
     assert _maxerr(out, ref) < 2e-2  # bf16 has ~8 mantissa bits
 
 
-def _forced_group(monkeypatch, G, nkv, Tr, d, page, itemsize, Bmax):
+def _forced_group(monkeypatch, G, nkv, Tr, d, page, itemsize, Bmax,
+                  qblk=None):
     """Shrink the kernel's VMEM budget until the shapes give groups of G
-    pages: the group size has no argument, it follows from what fits."""
+    pages: the group size has no argument, it follows from what fits.
+    With ``qblk`` the row is a latent walk's in query blocks, whose call
+    grants at least twice what the row keeps (``_rpa_latent_call``)."""
     monkeypatch.setattr(pallas_ops, "_VMEM_BUDGET", 0)   # restored after
     for kib in range(16, 64 << 10, 16):
         pallas_ops._VMEM_BUDGET = kib << 10
-        if pallas_ops._rpa_group_pages(nkv, Tr, d, page, itemsize,
-                                       Bmax) == G:
+        budget = None if qblk is None else max(
+            pallas_ops._VMEM_BUDGET,
+            2 * pallas_ops._rpa_row_bytes(nkv, Tr, d, itemsize))
+        if pallas_ops._rpa_group_pages(nkv, Tr, d, page, itemsize, Bmax,
+                                       qblk, budget) == G:
             return
     raise AssertionError(f"no budget gives groups of {G} pages")
 
@@ -270,6 +276,22 @@ def test_rpa_group_size_follows_the_shapes_and_the_budget():
         assert group(2 * nkv, Tr, 128, 128, itemsize, Bmax) <= G
         assert group(nkv, 2 * Tr, 128, 128, itemsize, Bmax) <= G
         assert group(nkv, Tr, 128, 256, itemsize, Bmax) <= G
+    # the latent walks, one head of 640 bf16 lanes: DeepSeek-V2-Lite's
+    # [32, 16] (16 heads x 16 tokens) and LongCat's [64, 1] take no blocks
+    assert group(1, 256, 640, 128, 2, 72) == 4
+    assert group(1, 64, 640, 128, 2, 40) == 8
+    # LongCat's [64, 16]: 1,024 query rows walked 256 at a time, under the
+    # budget its call grants; charged as whole [Tr, page] tiles under the
+    # default budget the same row would take one page a turn
+    Tr, qblk = 1024, pallas_ops._RPA_Q_BLOCK
+    row = pallas_ops._rpa_row_bytes(1, Tr, 640, 2)
+    budget = max(pallas_ops._VMEM_BUDGET, 2 * row)
+    G = group(1, Tr, 640, 128, 2, 40, qblk=qblk, budget=budget)
+    assert G == 4
+    assert group(1, Tr, 640, 128, 2, 40) == 1
+    # what the row keeps and G pages of [qblk, page] tiles fit the budget
+    assert row + G * (4 * 128 * 640 * 2 + 8 * qblk * 128 * 4) <= budget
+    assert row + 2 * G * (4 * 128 * 640 * 2 + 8 * qblk * 128 * 4) > budget
 
 
 @pytest.mark.parametrize("rep,Tc,short,longest", [
