@@ -115,9 +115,9 @@ def pipeline_forward(cfg, mesh, n_micro, params, ids, cp_axis=None):
 
 def _head_loss(cfg, params, h, labels, aux):
     """Shared norm/lm_head/CE epilogue for every pipelined forward."""
-    from ..models.llama import _rms_norm
+    from ..models.llama import rms_norm
 
-    h = _rms_norm(h, params["norm_f"], cfg.rms_norm_eps)
+    h = rms_norm(h, params["norm_f"], cfg.rms_norm_eps)
     logits = (h @ params["lm_head"]).astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
@@ -180,7 +180,7 @@ def pipeline_1f1b_value_and_grad(cfg, mesh, n_micro, params, batch,
     is ever materialized — per-stage live state really is the ring
     buffer plus param-sized accumulators.
     """
-    from ..models.llama import _rope_tables, _rms_norm, run_layer_stack
+    from ..models.llama import _rope_tables, rms_norm, run_layer_stack
     from .overlap import schedule_constants
 
     # host-side build marker (the scan body itself is opaque to the
@@ -214,7 +214,7 @@ def pipeline_1f1b_value_and_grad(cfg, mesh, n_micro, params, batch,
             return run_layer_stack(cfg, ll, xin, sin_, cos_)  # (y, aux)
 
         def head_ce(nw, hw, y, lab):
-            h = _rms_norm(y, nw, cfg.rms_norm_eps)
+            h = rms_norm(y, nw, cfg.rms_norm_eps)
             logits = (h @ hw).astype(jnp.float32)
             lse = jax.nn.logsumexp(logits, axis=-1)
             tgt = jnp.take_along_axis(logits, lab[..., None], -1)[..., 0]

@@ -2,15 +2,17 @@
 
 - llama: RoPE/GQA/SwiGLU decoder with 4-D parallel train step (the
   Llama-2 pretrain north star), optional MoE layers, ring-attention CP.
-- jamba: Mamba-1 layers with an attention layer every few (forward and the
-  serving engine's ragged step with per-slot recurrent state; no train step).
-- phi4flash: Mamba-1 and window attention interleaved, then gated memory
-  units and cross layers on one full layer's K/V (forward and the serving
-  engine's step with window rings per slot; no train step).
-- deepseek_v2: latent (MLA) attention on a paged cache of latent vectors,
-  then expert layers that drop no token beside shared experts (``experts``:
-  the routed layer itself); forward and the serving engine's step, no train
-  step.
+- Served by ``serving.LLMEngine``, with no train step: jamba (Mamba-1
+  layers, an attention layer every few: state per slot), phi4flash (Mamba-1
+  and window attention, then gated memory units and cross layers on one full
+  layer's K/V: rings), deepseek_v2 (latent attention on paged latents,
+  experts without drops beside shared ones), longcat_flash (two latent
+  sublayers a layer, a shortcut-connected expert layer, zero-compute
+  experts) and cohere2_moe (parallel blocks of window and global attention
+  and sigmoid-routed experts beside averaged shared ones).
+- What those share: protocol (the engine's ``ServingModel``, the config
+  reader), step_layout (a step's frame), paged (dense attention on pages),
+  mla, experts, rings.
 - gpt: GPT-2-style decoder (learned positions, fused QKV, GELU, tied head).
 - ernie: encoder pretraining family (MLM+NSP).
 - decoding: shared KV-cache autoregressive generation.

@@ -60,7 +60,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import types
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -68,10 +68,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import rings
+from . import paged, protocol, rings
 from .experts import route_top_k, routed_experts, shared_experts
 from .mla import rotate
-from .step_layout import StepLayout
+from .step_layout import step_frame
 
 __all__ = ["Cohere2MoeConfig", "PRESETS", "preset", "config_from_fields",
            "init_params", "param_count", "forward_pure", "forward_paged",
@@ -166,19 +166,8 @@ class Cohere2MoeConfig:
                 or self.head_dim % 2:
             raise ValueError(f"{self.num_attention_heads} query heads on "
                              f"{self.num_key_value_heads}, lanes pair up")
-        if self.experts_held is not None:
-            self.experts_held = tuple(int(e) for e in self.experts_held)
-            if len(self.experts_held) != self.num_experts or not all(
-                    0 <= e < self.router_experts for e in self.experts_held):
-                raise ValueError(
-                    f"experts_held names {len(self.experts_held)} experts; "
-                    f"num_experts says {self.num_experts} are here of the "
-                    f"{self.router_experts} the router scores")
-        elif self.router_experts != self.num_experts:
-            raise ValueError("a device that holds a share of the experts "
-                             "says which (experts_held)")
-        if self.vocab_size > (self.vocab_size_published or self.vocab_size):
-            raise ValueError("a slice of the vocabulary is no larger than it")
+        self.experts_held = protocol.chip_share(
+            self, self.num_experts, self.router_experts, "num_experts")
 
     @property
     def router_experts(self) -> int:
@@ -198,10 +187,6 @@ class Cohere2MoeConfig:
         return ["global" if i % self.layer_switch == self.layer_switch - 1
                 else "window" for i in range(self.num_hidden_layers)]
 
-    @property
-    def serving(self):
-        return SERVING
-
 
 PRESETS: Dict[str, Dict[str, Any]] = {
     "command-a-plus": {},
@@ -217,25 +202,8 @@ PRESETS: Dict[str, Dict[str, Any]] = {
 }
 
 
-def preset(name: str, **overrides) -> Cohere2MoeConfig:
-    if name not in PRESETS:
-        raise KeyError(f"unknown cohere2_moe preset {name!r}; available: "
-                       f"{sorted(PRESETS)}")
-    return Cohere2MoeConfig(**dict(PRESETS[name], **overrides))
-
-
-def config_from_fields(fields: dict) -> Cohere2MoeConfig:
-    """A ``Cohere2MoeConfig`` from a ``config.json``-shaped dict: every key
-    that is a field, ``dtype`` by name; where the file states a chip's share
-    (``experts_held``; a sliced ``vocab_size``) its ``published`` group gives
-    the counts the share is of.  Other keys are not this model's."""
-    names = {f.name for f in dataclasses.fields(Cohere2MoeConfig)}
-    kw = {k: v for k, v in fields.items() if k in names}
-    for key in ("num_experts", "vocab_size"):
-        if key in fields.get("published", {}):
-            kw.setdefault(key + "_published", fields["published"][key])
-    kw["dtype"] = jnp.dtype(kw.get("dtype", "bfloat16")).type
-    return Cohere2MoeConfig(**kw)
+preset = partial(protocol.preset, Cohere2MoeConfig, PRESETS)
+config_from_fields = partial(protocol.config_from_fields, Cohere2MoeConfig)
 
 
 # ---------------------------------------------------------------------------
@@ -429,10 +397,7 @@ def init_cache(cfg: Cohere2MoeConfig, slots: int, num_pages: int,
                page_size: int, kv_dtype):
     """The cache of an engine with ``slots`` rows: the global layers' zeroed
     page pools and the window layers' rings (and their null page)."""
-    if jnp.dtype(kv_dtype).itemsize < 2:
-        raise ValueError(
-            f"kv_dtype {jnp.dtype(kv_dtype)} pages need the per-page scale "
-            "pools that only models/llama.py's step writes")
+    protocol.refuse_narrow_pages(kv_dtype)
     nkv, d = cfg.num_key_value_heads, cfg.head_dim
     pool = (cfg.num_global_layers, nkv, num_pages, page_size, d)
     return {"k_pages": jnp.zeros(pool, kv_dtype),
@@ -465,64 +430,40 @@ def step_counts(cfg: Cohere2MoeConfig, seq_lens, q_lens) -> dict:
 
 def forward_paged(cfg: Cohere2MoeConfig, params, tokens, cache, block_tables,
                   seq_lens, q_lens, step_tokens=None, device_counts=False):
-    """The engine's step: ragged mixed prefill and decode rows ``tokens [R,
-    Tc]`` (row r feeds ``tokens[r, :q_lens[r]]`` and then holds ``seq_lens[r]``
-    tokens) over ``cache`` (``init_cache``).  Returns ``(logits [R, Tc, V]
-    float32, cache)``, or with ``step_tokens = T`` the logits flat ``[T, V]``
-    (``StepLayout``); with ``device_counts`` a third result, the int32 ``[3]``
-    of ``DEVICE_COUNTS``.
-
-    A global layer writes its K/V into the engine's pages through
-    ``block_tables`` and reads them there; a window layer writes and reads
-    its slot's ring through a table made here, with ``window=W``.  A request
-    that starts at position 0 rewrites every ring page before it reads it,
-    so admission into a used slot, preemption-replay and a rebuilt engine
-    need no reset.  Projections and the expert layer are per token, on the
-    flat layout; padding tokens write no K/V and are routed to no expert."""
-    from ..ops.pallas_ops import paged_kv_write, ragged_paged_attention
+    """The engine's step (``protocol.ServingModel``) over ``cache``
+    (``init_cache``); ``device_counts``: the ``[3]`` of ``DEVICE_COUNTS``
+    third.  A global layer writes its K/V into the engine's pages through
+    ``block_tables`` and reads them there; a window layer writes and reads its
+    slot's ring through a table made here, with ``window=W``.  A request that
+    starts at position 0 rewrites every ring page before it reads it, so
+    admission into a used slot, preemption-replay and a rebuilt engine need no
+    reset.  Projections and the expert layer are per token, on the flat layout;
+    padding tokens write no K/V and are routed to no expert."""
     R, Tc = tokens.shape
-    nh, nkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
-        cfg.head_dim
-    rep = nh // nkv
     ring_table = rings.ring_table(R, block_tables.shape[1],
                                   cfg.sliding_window,
                                   cache["k_pages"].shape[3], Tc)
-    lay = StepLayout(q_lens, Tc, step_tokens)
-    t_off = jnp.arange(Tc, dtype=jnp.int32)[None, :]
-    start = (seq_lens - q_lens).astype(jnp.int32)[:, None]
-    pos = lay.flat(jnp.maximum(start + t_off, 0))
-    live = lay.flat(t_off < q_lens[:, None])
+    lay, ids, pos, live = step_frame(tokens, seq_lens, q_lens, step_tokens)
 
     def attend(kind, at, q, k, v, pools):
         window = kind == "window"
         names = ("kw_pages", "vw_pages") if window else ("k_pages", "v_pages")
         table = ring_table if window else block_tables
-        k, v = lay.rows(k), lay.rows(v)                   # [R, Tc, nkv, d]
-        with jax.named_scope("kv_write"):
-            kp, vp = paged_kv_write(pools[names[0]], pools[names[1]], k, v,
-                                    table, seq_lens, q_lens, layer=at)
-        pools = dict(pools, **dict(zip(names, (kp, vp))))
-        # kernel layout [R, nkv, Tc x rep, d]: row t x rep + j = query head
-        # g x rep + j of token t
-        qk = lay.rows(q).reshape(R, Tc, nkv, rep, d).transpose(
-            0, 2, 1, 3, 4).reshape(R, nkv, Tc * rep, d)
-        with jax.named_scope("attn_kernel"):
-            out = ragged_paged_attention(
-                qk, kp, vp, table, seq_lens, q_lens, rep=rep, layer=at,
-                window=cfg.sliding_window if window else None)
-        return lay.flat(out.reshape(R, nkv, Tc, rep, d).transpose(
-            0, 2, 1, 3, 4).reshape(R, Tc, nh * d)), pools
+        kv = paged.write((pools[names[0]], pools[names[1]]), lay.rows(k),
+                         lay.rows(v), table, seq_lens, q_lens, at)
+        out = paged.attend(lay.rows(q), kv, table, seq_lens, q_lens,
+                           layer=at,
+                           window=cfg.sliding_window if window else None,
+                           scope="attn_kernel")
+        return lay.flat(out), dict(pools, **dict(zip(names, kv)))
 
-    logits, pools, counts = _forward(cfg, params, lay.flat(tokens), pos,
-                                     live, attend, dict(cache))
-    out = (logits if lay.compact else lay.rows(logits)), pools
-    return out + (counts,) if device_counts else out
+    logits, pools, counts = _forward(cfg, params, ids, pos, live, attend,
+                                     dict(cache))
+    return lay.result(logits, pools, counts if device_counts else None)
 
 
-# what serving.LLMEngine asks a configuration for (``cfg.serving``)
-SERVING = types.SimpleNamespace(
+SERVING = protocol.ServingModel(
     forward_paged=forward_paged, init_cache=init_cache,
     cache_bytes=cache_bytes, param_count=param_count,
-    prepare_params=lambda cfg, params: params,   # no weight is converted
-    step_counts=step_counts, device_counts=DEVICE_COUNTS,
-    recurrent_state=False, rings=True)
+    step_counts=step_counts, device_counts=DEVICE_COUNTS, rings=True)
+Cohere2MoeConfig.serving = SERVING   # cfg.serving, the engine's model

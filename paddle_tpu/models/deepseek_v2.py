@@ -49,7 +49,7 @@ w_gate w_up w_down``) and ``moe`` (the same attention leaves, ``router [n,
 D, E]``, the routed experts ``w_gate w_up [n, Eh, D, F]``, ``w_down [n, Eh,
 F, D]``, the shared ``ws_gate ws_up [n, D, S F]``, ``ws_down [n, S F, D]``).
 
-**Serving** (``SERVING``, the protocol ``serving.LLMEngine`` asks a
+**Serving** (``SERVING``, the ``protocol.ServingModel`` the engine asks a
 configuration for).  The cache is one pool of LATENT pages, ``latent [L, 1,
 P, page, lanes]`` through the engine's block table like every pool: a token
 costs, a layer, ``[c | rotated k_pe | 0]`` of ``lanes = r + dr`` rounded up
@@ -74,7 +74,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import types
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -82,11 +82,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import mla
+from . import mla, protocol
 from .experts import route_top_k, routed_experts, shared_experts
-from .jamba import _layer_at
 from .mla import rms_norm as _rms_norm, swiglu as _swiglu
-from .step_layout import StepLayout
+from .protocol import layer_at as _layer_at
+from .step_layout import step_frame
 
 __all__ = ["DeepseekV2Config", "PRESETS", "preset", "config_from_fields",
            "init_params", "param_count", "forward_pure", "forward_paged",
@@ -179,10 +179,6 @@ class DeepseekV2Config:
             scale *= _yarn_mscale(rs["factor"], rs["mscale_all_dim"]) ** 2
         return scale
 
-    @property
-    def serving(self):
-        return SERVING
-
 
 PRESETS: Dict[str, Dict[str, Any]] = {
     "deepseek-v2-lite": {},
@@ -202,20 +198,8 @@ PRESETS: Dict[str, Dict[str, Any]] = {
 }
 
 
-def preset(name: str, **overrides) -> DeepseekV2Config:
-    if name not in PRESETS:
-        raise KeyError(f"unknown deepseek_v2 preset {name!r}; available: "
-                       f"{sorted(PRESETS)}")
-    return DeepseekV2Config(**dict(PRESETS[name], **overrides))
-
-
-def config_from_fields(fields: dict) -> DeepseekV2Config:
-    """A ``DeepseekV2Config`` from a ``config.json``-shaped dict: every key
-    that is a field, ``dtype`` by name; other keys are not this model's."""
-    names = {f.name for f in dataclasses.fields(DeepseekV2Config)}
-    kw = {k: v for k, v in fields.items() if k in names}
-    kw["dtype"] = jnp.dtype(kw.get("dtype", "bfloat16")).type
-    return DeepseekV2Config(**kw)
+preset = partial(protocol.preset, DeepseekV2Config, PRESETS)
+config_from_fields = partial(protocol.config_from_fields, DeepseekV2Config)
 
 
 # ---------------------------------------------------------------------------
@@ -415,10 +399,7 @@ def init_cache(cfg: DeepseekV2Config, slots: int, num_pages: int,
     """The cache of an engine: one zeroed pool of latent pages, every layer
     stacked.  Nothing is kept a slot."""
     del slots
-    if jnp.dtype(kv_dtype).itemsize < 2:
-        raise ValueError(
-            f"kv_dtype {jnp.dtype(kv_dtype)} pages need the per-page scale "
-            "pools that only models/llama.py's step writes")
+    protocol.refuse_narrow_pages(kv_dtype)
     return {"latent": jnp.zeros((cfg.num_hidden_layers, 1, num_pages,
                                  page_size, cfg.latent_lanes), kv_dtype)}
 
@@ -450,41 +431,28 @@ def step_counts(cfg: DeepseekV2Config, seq_lens, q_lens) -> dict:
 
 def forward_paged(cfg: DeepseekV2Config, params, tokens, cache, block_tables,
                   seq_lens, q_lens, step_tokens=None, device_counts=False):
-    """The engine's step: ragged mixed prefill and decode rows ``tokens [R,
-    Tc]`` (row r feeds ``tokens[r, :q_lens[r]]`` and then holds ``seq_lens[r]``
-    tokens) over ``cache`` (``init_cache``).  Returns ``(logits [R, Tc, V]
-    float32, cache)``, or with ``step_tokens = T`` the logits flat ``[T, V]``
-    (``StepLayout``), as ``jamba.forward_paged``; with ``device_counts`` a
-    third result, the int32 ``[2]`` of ``DEVICE_COUNTS``.
-
-    The absorbed form (module docstring): a layer writes its tokens' ``[c |
-    k_pe | 0]`` into the latent pool through ``block_tables``
+    """The engine's step (``protocol.ServingModel``) over ``cache``
+    (``init_cache``); ``device_counts``: the ``[2]`` of ``DEVICE_COUNTS``
+    third.  The absorbed form (module docstring): a layer writes its tokens'
+    ``[c | k_pe | 0]`` into the latent pool through ``block_tables``
     (``paged_latent_write``) and every query head, as ``[q_abs | q_pe | 0]``,
-    attends over the row's pages of that ONE pool
-    (``latent_paged_attention``).  Projections, rotation, the absorption and
-    the expert layers are per token, on the flat layout; padding tokens are
-    routed to no expert."""
-    R, Tc = tokens.shape
-    lay = StepLayout(q_lens, Tc, step_tokens)
-    t_off = jnp.arange(Tc, dtype=jnp.int32)[None, :]
-    start = (seq_lens - q_lens).astype(jnp.int32)[:, None]
-    pos = lay.flat(jnp.maximum(start + t_off, 0))
-    live = lay.flat(t_off < q_lens[:, None])
+    attends over the row's pages of that ONE pool (``latent_paged_attention``).
+    Projections, rotation, the absorption and the expert layers are per token,
+    on the flat layout; padding tokens are routed to no expert."""
+    lay, ids, pos, live = step_frame(tokens, seq_lens, q_lens, step_tokens)
 
     def mixer(lp, xn, i, sin, cos, pages):
         return mla.absorbed(cfg, lp, xn, sin, cos, pages, lay, block_tables,
                             seq_lens, q_lens, i)
 
-    logits, pages, counts = _forward(cfg, params, lay.flat(tokens), pos, live,
-                                     mixer, cache["latent"])
-    out = (logits if lay.compact else lay.rows(logits)), {"latent": pages}
-    return out + (counts,) if device_counts else out
+    logits, pages, counts = _forward(cfg, params, ids, pos, live, mixer,
+                                     cache["latent"])
+    return lay.result(logits, {"latent": pages},
+                      counts if device_counts else None)
 
 
-# what serving.LLMEngine asks a configuration for (``cfg.serving``)
-SERVING = types.SimpleNamespace(
+SERVING = protocol.ServingModel(
     forward_paged=forward_paged, init_cache=init_cache,
     cache_bytes=cache_bytes, param_count=param_count,
-    prepare_params=lambda cfg, params: params,   # no weight is converted
-    step_counts=step_counts, device_counts=DEVICE_COUNTS,
-    recurrent_state=False)
+    step_counts=step_counts, device_counts=DEVICE_COUNTS)
+DeepseekV2Config.serving = SERVING   # cfg.serving, the engine's model

@@ -171,14 +171,14 @@ def forward_with_cache(cfg: GPTConfig, params, tokens, cache, pos):
 def generate(cfg: GPTConfig, params, input_ids, max_new_tokens,
              temperature=0.0, top_k=0, rng=None, eos_token_id=None):
     from .decoding import model_generate
-    from .llama import _cfg_key
+    from .llama import cfg_key
 
     return model_generate(
         functools.partial(forward_with_cache, cfg),
         num_layers=cfg.num_hidden_layers,
         kv_heads=cfg.num_attention_heads, head_dim=cfg.head_dim,
         max_positions=cfg.max_position_embeddings, cache_dtype=cfg.dtype,
-        cache_key=("gpt", _cfg_key(cfg)), params=params,
+        cache_key=("gpt", cfg_key(cfg)), params=params,
         input_ids=input_ids, max_new_tokens=max_new_tokens,
         temperature=temperature, top_k=top_k, rng=rng,
         eos_token_id=eos_token_id)
@@ -193,11 +193,11 @@ class GPTForCausalLM(GenerationMixin, Layer):
     def __init__(self, config: GPTConfig):
         super().__init__()
         self.config = config
-        from .llama import _flatten_params, _unflatten_params
-        self._unflatten = _unflatten_params
+        from .llama import flatten_params, unflatten_params
+        self._unflatten = unflatten_params
         raw = init_params(config, jax.random.PRNGKey(0))
         self._flat = {}
-        for name, arr in _flatten_params(raw):
+        for name, arr in flatten_params(raw):
             p = Parameter(arr)
             p.name = name
             self.add_parameter(name.replace(".", "_"), p)

@@ -26,10 +26,10 @@ both holding their layers' ``ln2 / w_gate / w_up / w_down`` too.  What has a
 state axis keeps it second to last (``A_log [M, N, E]``, ``conv_w [M, K,
 E]``): E fills the 128 lanes of a TPU tile, N or K the sublanes.
 
-Serving (``SERVING``, the protocol ``serving.LLMEngine`` asks a
+Serving (``SERVING``, the ``protocol.ServingModel`` the engine asks a
 configuration for): the cache is a dict of the two attention layers' page
-pools ``k_pages / v_pages [A, nkv, P, page, d]``, written and read exactly
-as Llama's, and the recurrent state of every engine slot, ``conv [M, K-1, R,
+pools ``k_pages / v_pages [A, nkv, P, page, d]``, written and read exactly as
+Llama's, and the recurrent state of every engine slot, ``conv [M, K-1, R,
 E]`` (the last K-1 inputs of the convolution) and ``ssm [M, N, R, E]``
 float32.  The state belongs to a slot, not to pages: see ``forward_paged``.
 """
@@ -37,15 +37,17 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import types
+from functools import partial
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .llama import _dense_mlp, _rms_norm
-from .step_layout import StepLayout
+from . import paged, protocol
+from .llama import dense_mlp as _dense_mlp, rms_norm as _rms_norm
+from .protocol import layer_at as _layer_at
+from .step_layout import StepLayout, step_frame
 
 __all__ = ["JambaConfig", "PRESETS", "preset", "config_from_fields",
            "init_params", "param_count", "forward_pure", "forward_paged",
@@ -101,10 +103,6 @@ class JambaConfig:
         last = self.num_mamba_layers
         return runs + [("mamba", lo, last)] * (last > lo)
 
-    @property
-    def serving(self):
-        return SERVING
-
 
 PRESETS: Dict[str, Dict[str, Any]] = {
     "jamba2-3b": {},
@@ -117,20 +115,8 @@ PRESETS: Dict[str, Dict[str, Any]] = {
 }
 
 
-def preset(name: str, **overrides) -> JambaConfig:
-    if name not in PRESETS:
-        raise KeyError(f"unknown jamba preset {name!r}; available: "
-                       f"{sorted(PRESETS)}")
-    return JambaConfig(**dict(PRESETS[name], **overrides))
-
-
-def config_from_fields(fields: dict) -> JambaConfig:
-    """A ``JambaConfig`` from a ``config.json``-shaped dict: every key that
-    is a field, ``dtype`` by name; other keys are not this model's."""
-    names = {f.name for f in dataclasses.fields(JambaConfig)}
-    kw = {k: v for k, v in fields.items() if k in names}
-    kw["dtype"] = jnp.dtype(kw.get("dtype", "bfloat16")).type
-    return JambaConfig(**kw)
+preset = partial(protocol.preset, JambaConfig, PRESETS)
+config_from_fields = partial(protocol.config_from_fields, JambaConfig)
 
 
 def param_count(cfg: JambaConfig) -> int:
@@ -250,14 +236,6 @@ def _ssm_conv(lp, x, conv, l, q_lens, fresh, lay):
     return out, conv
 
 
-def _layer_at(stack, l):
-    """Layer ``l`` (traced) of a stack of per-layer leaves: the slice a
-    ``lax.scan`` over the stack would take, from the whole stack, so that a
-    run of layers scans the one stack by index and copies none of it."""
-    return jax.tree_util.tree_map(
-        lambda w: lax.dynamic_index_in_dim(w, l, 0, keepdims=False), stack)
-
-
 def _mixer_core(lp, x, z, conv, ssm, l, q_lens, fresh, lay, dt_b_c):
     """What every Mamba-1 mixer of this repo does between ``w_in`` and
     ``w_out``, on the flat tokens of the step ``lay``: the convolution of
@@ -358,8 +336,8 @@ def _forward(cfg, params, tokens, conv, ssm, q_lens, fresh, attend, lay):
     tokens ``[T, ...]`` of the step ``lay`` (``step_layout.StepLayout``);
     returns the logits ``[T, V]``.  ``attend(a, q, k, v)`` is attention
     layer ``a``'s mixing of ``q [R, Tc, nh, d]`` with ``k, v [R, Tc, nkv,
-    d]`` and whatever came before them."""
-    R, Tc, T = lay.R, lay.Tc, lay.T
+    d]`` and whatever came before them, ``[R, Tc, nh x d]``."""
+    T = lay.T
     nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                   cfg.head_dim)
     with jax.named_scope("embed"):
@@ -378,8 +356,7 @@ def _forward(cfg, params, tokens, conv, ssm, q_lens, fresh, attend, lay):
                     run[1], lay.rows((xn @ lp["wq"]).reshape(T, nh, d)),
                     lay.rows((xn @ lp["wk"]).reshape(T, nkv, d)),
                     lay.rows((xn @ lp["wv"]).reshape(T, nkv, d)))
-                out = lay.flat(out.reshape(R, Tc, nh * d))
-                h = h + out.astype(h.dtype) @ lp["wo"]
+                h = h + lay.flat(out).astype(h.dtype) @ lp["wo"]
             with jax.named_scope("mlp"):
                 h = h + _dense_mlp(
                     lp, _rms_norm(h, lp["ln2"], cfg.rms_norm_eps))
@@ -390,11 +367,19 @@ def _forward(cfg, params, tokens, conv, ssm, q_lens, fresh, attend, lay):
     return logits, conv, ssm
 
 
-def _fresh_state(cfg, rows: int):
-    E, f32 = cfg.mamba_inner, jnp.float32
-    M = cfg.num_mamba_layers
-    return (jnp.zeros((M, cfg.mamba_d_conv - 1, rows, E), cfg.dtype),
-            jnp.zeros((M, cfg.mamba_d_state, rows, E), f32))
+def mamba_state(cfg, rows: int) -> dict:
+    """Zero state of ``rows`` slots in every Mamba layer of ``cfg`` (this
+    model's or ``phi4flash``'s): ``conv [M, K-1, R, E]`` and ``ssm [M, N, R,
+    E]`` float32, ``mamba_state_bytes(cfg)`` a slot."""
+    E, M = cfg.mamba_inner, cfg.num_mamba_layers
+    return {"conv": jnp.zeros((M, cfg.mamba_d_conv - 1, rows, E), cfg.dtype),
+            "ssm": jnp.zeros((M, cfg.mamba_d_state, rows, E), jnp.float32)}
+
+
+def mamba_state_bytes(cfg) -> int:
+    return cfg.num_mamba_layers * cfg.mamba_inner * (
+        cfg.mamba_d_state * 4
+        + (cfg.mamba_d_conv - 1) * jnp.dtype(cfg.dtype).itemsize)
 
 
 def forward_pure(cfg: JambaConfig, params, input_ids):
@@ -412,13 +397,15 @@ def forward_pure(cfg: JambaConfig, params, input_ids):
                        preferred_element_type=jnp.float32)
         p = jax.nn.softmax(
             jnp.where(causal, s / math.sqrt(cfg.head_dim), -jnp.inf), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v).reshape(
+            B, S, -1)
 
-    conv, ssm = _fresh_state(cfg, B)
+    state = mamba_state(cfg, B)
     q_lens = jnp.full((B,), S, jnp.int32)
     lay = StepLayout(q_lens, S)
-    return lay.rows(_forward(cfg, params, input_ids, conv, ssm, q_lens,
-                             jnp.ones((B,), bool), attend, lay)[0])
+    return lay.rows(_forward(cfg, params, input_ids, state["conv"],
+                             state["ssm"], q_lens, jnp.ones((B,), bool),
+                             attend, lay)[0])
 
 
 # ---------------------------------------------------------------------------
@@ -429,15 +416,11 @@ def init_cache(cfg: JambaConfig, slots: int, num_pages: int, page_size: int,
                kv_dtype):
     """The cache of an engine with ``slots`` rows: zeroed page pools of the
     attention layers and zero recurrent state of every slot."""
-    if jnp.dtype(kv_dtype).itemsize < 2:
-        raise ValueError(
-            f"kv_dtype {jnp.dtype(kv_dtype)} pages need the per-page scale "
-            "pools that only models/llama.py's step writes")
+    protocol.refuse_narrow_pages(kv_dtype)
     pool = (len(cfg.attn_layers), cfg.num_key_value_heads, num_pages,
             page_size, cfg.head_dim)
-    conv, ssm = _fresh_state(cfg, slots)
     return {"k_pages": jnp.zeros(pool, kv_dtype),
-            "v_pages": jnp.zeros(pool, kv_dtype), "conv": conv, "ssm": ssm}
+            "v_pages": jnp.zeros(pool, kv_dtype), **mamba_state(cfg, slots)}
 
 
 def cache_bytes(cfg: JambaConfig, kv_dtype_bytes: int = 2,
@@ -445,14 +428,10 @@ def cache_bytes(cfg: JambaConfig, kv_dtype_bytes: int = 2,
     """What the cache costs: K/V bytes a token (attention layers only),
     scale bytes a page (none: no quantized pages) and recurrent-state bytes
     a slot, whatever the length of the request in it."""
-    E = cfg.mamba_inner
     return {
         "per_token": (2 * len(cfg.attn_layers) * cfg.num_key_value_heads
                       * cfg.head_dim * kv_dtype_bytes),
-        "scales_per_page": 0,
-        "per_slot": cfg.num_mamba_layers * E * (
-            cfg.mamba_d_state * 4
-            + (cfg.mamba_d_conv - 1) * jnp.dtype(cfg.dtype).itemsize)}
+        "scales_per_page": 0, "per_slot": mamba_state_bytes(cfg)}
 
 
 def step_counts(cfg: JambaConfig, seq_lens, q_lens) -> dict:
@@ -467,54 +446,36 @@ def step_counts(cfg: JambaConfig, seq_lens, q_lens) -> dict:
 
 def forward_paged(cfg: JambaConfig, params, tokens, cache, block_tables,
                   seq_lens, q_lens, step_tokens=None):
-    """The engine's step: ragged mixed prefill and decode rows ``tokens [R,
-    Tc]`` (row r feeds ``tokens[r, :q_lens[r]]`` and then holds ``seq_lens[r]``
-    tokens) over ``cache`` (``init_cache``).  Returns ``(logits [R, Tc, V]
-    float32, cache)``; logits of padding positions are garbage.  With
-    ``step_tokens = T`` the program computes ``T`` positions in place of
-    ``R x Tc`` and returns the logits flat, ``[T, V]`` (``StepLayout``); the
-    caller guarantees ``sum(q_lens) <= T``.
-
-    Attention layers write and read their page pools through the block
-    table with the kernels Llama's step uses (``paged_kv_write``,
-    ``ragged_paged_attention``).  The recurrent state is per ROW (engine
-    slot).  Row r's state is zeroed inside this step where its chunk starts
-    at position 0 (``seq_lens[r] == q_lens[r] > 0``): that is all admission
-    into a used slot, preemption-replay and a rebuilt engine need, since
-    each feeds a request from its first token.  Positions ``t >= q_lens[r]``
-    advance neither state, and a row with ``q_lens[r] == 0`` comes back as it
-    was.  A step that skipped tokens (a prefix-cache hit) or fed tokens to
-    take back (a rejected draft) would leave the state wrong, which is why
+    """The engine's step (``protocol.ServingModel``) over ``cache``
+    (``init_cache``).  Attention layers write and read their page pools
+    through the block table as Llama's do (``models/paged.py``).  The
+    recurrent state is per ROW (engine slot).  Row r's state is zeroed
+    inside this step where its chunk starts at position 0 (``seq_lens[r] ==
+    q_lens[r] > 0``): that is all admission into a used slot,
+    preemption-replay and a rebuilt engine need, since each feeds a request
+    from its first token.  Positions ``t >= q_lens[r]`` advance neither
+    state, and a row with ``q_lens[r] == 0`` comes back as it was.  A step
+    that skipped tokens (a prefix-cache hit) or fed tokens to take back (a
+    rejected draft) would leave the state wrong, which is why
     ``recurrent_state`` makes the engine refuse both."""
-    from ..ops.pallas_ops import paged_kv_write, ragged_paged_attention
-    R, Tc = tokens.shape
-    nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                  cfg.head_dim)
-    rep = nh // nkv
-    pools = [cache["k_pages"], cache["v_pages"]]
+    pools = (cache["k_pages"], cache["v_pages"])
 
     def attend(a, q, k, v):
-        with jax.named_scope("kv_write"):
-            pools[:] = paged_kv_write(*pools, k, v, block_tables, seq_lens,
-                                      q_lens, layer=a)
-        # the kernel's layout [R, nkv, Tc*rep, d], as in llama.forward_paged
-        qk = q.reshape(R, Tc, nkv, rep, d).transpose(
-            0, 2, 1, 3, 4).reshape(R, nkv, Tc * rep, d)
-        out = ragged_paged_attention(qk, *pools, block_tables, seq_lens,
-                                     q_lens, rep=rep, layer=a)
-        return out.reshape(R, nkv, Tc, rep, d).transpose(0, 2, 1, 3, 4)
+        nonlocal pools
+        pools = paged.write(pools, k, v, block_tables, seq_lens, q_lens, a)
+        return paged.attend(q, pools, block_tables, seq_lens, q_lens,
+                            layer=a)
 
     fresh = (q_lens > 0) & (seq_lens == q_lens)
-    lay = StepLayout(q_lens, Tc, step_tokens)
+    lay = step_frame(tokens, seq_lens, q_lens, step_tokens)[0]
     logits, conv, ssm = _forward(cfg, params, tokens, cache["conv"],
                                  cache["ssm"], q_lens, fresh, attend, lay)
-    return (logits if lay.compact else lay.rows(logits)), {
-        "k_pages": pools[0], "v_pages": pools[1], "conv": conv, "ssm": ssm}
+    return lay.result(logits, {"k_pages": pools[0], "v_pages": pools[1],
+                               "conv": conv, "ssm": ssm})
 
 
-# what serving.LLMEngine asks a configuration for (``cfg.serving``)
-SERVING = types.SimpleNamespace(
+SERVING = protocol.ServingModel(
     forward_paged=forward_paged, init_cache=init_cache,
     cache_bytes=cache_bytes, param_count=param_count,
-    prepare_params=lambda cfg, params: params,   # no weight is converted
     step_counts=step_counts, recurrent_state=True)
+JambaConfig.serving = SERVING   # cfg.serving, the engine's model

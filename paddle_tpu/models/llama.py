@@ -31,7 +31,6 @@ import dataclasses
 import functools
 import logging
 import math
-import types
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
@@ -41,8 +40,9 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P, NamedSharding
 
+from . import paged, protocol
 from .decoding import GenerationMixin
-from .step_layout import StepLayout
+from .step_layout import step_frame
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "init_params", "forward_pure",
            "forward_with_cache", "forward_paged", "build_train_step",
@@ -93,11 +93,6 @@ class LlamaConfig:
     def head_dim(self):
         return self.hidden_size // self.num_attention_heads
 
-    @property
-    def serving(self):
-        """What ``serving.LLMEngine`` asks a configuration for."""
-        return SERVING
-
 
 # Named shapes for tools (tools/pod_report.py, chip_smoke.py, tests). The
 # LlamaConfig defaults ARE the 7B shape, so llama7b overrides nothing.
@@ -113,14 +108,7 @@ PRESETS: Dict[str, Dict[str, Any]] = {
 }
 
 
-def preset(name: str, **overrides) -> LlamaConfig:
-    """LlamaConfig from a named preset, with field overrides on top."""
-    if name not in PRESETS:
-        raise KeyError(f"unknown llama preset {name!r}; "
-                       f"available: {sorted(PRESETS)}")
-    kw = dict(PRESETS[name])
-    kw.update(overrides)
-    return LlamaConfig(**kw)
+preset = functools.partial(protocol.preset, LlamaConfig, PRESETS)
 
 
 def _split_key(key, n):
@@ -224,7 +212,8 @@ def _apply_rope(x, sin, cos):
     return x * cos_ + rot * sin_
 
 
-def _rms_norm(x, w, eps):
+def rms_norm(x, w, eps):
+    """The weight after the cast back (``mla.rms_norm``'s goes before)."""
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     return (x32 * lax.rsqrt(ms + eps)).astype(x.dtype) * w
@@ -304,7 +293,8 @@ def _attention(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
     return _qmm(out.reshape(B, S, H), lp["wo"])
 
 
-def _dense_mlp(lp, x):
+def dense_mlp(lp, x):
+    """SwiGLU, its products named for the train step's residual rule."""
     gate = jax.nn.silu(checkpoint_name(_qmm(x, lp["w_gate"]), "mlp_gate"))
     up = checkpoint_name(_qmm(x, lp["w_up"]), "mlp_up")
     return _qmm(gate * up, lp["w_down"])
@@ -581,13 +571,13 @@ def decoder_layer(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
                        sin, cos))
         else:
             h = x + _attention(cfg, lp,
-                               _rms_norm(x, lp["ln1"], cfg.rms_norm_eps),
+                               rms_norm(x, lp["ln1"], cfg.rms_norm_eps),
                                sin, cos, cp_mesh=cp_mesh, cp_axis=cp_axis,
                                cp_axis_level=cp_axis_level)
     with jax.named_scope("mlp"):
         if cfg.moe_num_experts > 0:
             mlp_out, aux = _moe_mlp(
-                cfg, lp, _rms_norm(h, lp["ln2"], cfg.rms_norm_eps))
+                cfg, lp, rms_norm(h, lp["ln2"], cfg.rms_norm_eps))
             return h + mlp_out, aux
         if fused_mlp:
             # norm + gate/up + silu + down + residual in one Pallas kernel
@@ -596,8 +586,8 @@ def decoder_layer(cfg: LlamaConfig, lp, x, sin, cos, cp_mesh=None,
                                   eps=cfg.rms_norm_eps),
                 (h,), (lp["ln2"], lp["w_gate"], lp["w_up"], lp["w_down"]))
             return out, jnp.zeros((), jnp.float32)
-        normed = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
-        return h + _dense_mlp(lp, normed), jnp.zeros((), jnp.float32)
+        normed = rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
+        return h + dense_mlp(lp, normed), jnp.zeros((), jnp.float32)
 
 
 def run_layer_stack(cfg: LlamaConfig, stacked, x, sin, cos,
@@ -660,7 +650,7 @@ def forward_pure(cfg: LlamaConfig, params, input_ids, sp_axis=None,
                              cp_mesh=cp_mesh, cp_axis=cp_axis,
                              grad_sync_axis=grad_sync_axis, save=save)
     with jax.named_scope("lm_head"):
-        x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+        x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
         logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
     return logits, aux
 
@@ -709,23 +699,23 @@ def forward_with_cache(cfg: LlamaConfig, params, tokens, cache, pos):
 
     def body(h, inp):
         lp, ck, cv = inp
-        xn = _rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
+        xn = rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
         q = _apply_rope(_qmm(xn, lp["wq"]).reshape(B, T, nh, d), sin, cos)
         k = _apply_rope(_qmm(xn, lp["wk"]).reshape(B, T, nkv, d), sin, cos)
         v = _qmm(xn, lp["wv"]).reshape(B, T, nkv, d)
         out, ck, cv = cached_attention_core(q, k, v, ck, cv, pos)
         h = h + _qmm(out.reshape(B, T, H), lp["wo"])
-        hn = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
+        hn = rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
         if cfg.moe_num_experts > 0:
             mlp_out, _aux = _moe_mlp(cfg, lp, hn)
             h = h + mlp_out
         else:
-            h = h + _dense_mlp(lp, hn)
+            h = h + dense_mlp(lp, hn)
         return h, (ck, cv)
 
     x, (new_k, new_v) = lax.scan(body, x,
                                  (params["layers"], cache.k, cache.v))
-    x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+    x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
     logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
     return logits, KVCache(new_k, new_v)
 
@@ -733,43 +723,15 @@ def forward_with_cache(cfg: LlamaConfig, params, tokens, cache, pos):
 def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
                   block_tables, seq_lens, q_lens, *,
                   k_scales=None, v_scales=None, step_tokens=None):
-    """Ragged mixed prefill+decode forward over a paged KV cache (the
-    serving engine's step function).
-
-    tokens        [R, Tc] int32   current-chunk token slots; request r
-                                  uses tokens[r, :q_lens[r]]
-    k/v_pages     [L, nkv, P, page, d] the stacked pools of all layers
-    block_tables  [R, Bmax] i32   pool page of each logical kv block
-                                  (page 0 = the allocator's reserved
-                                  null page: never written, never read)
-    seq_lens      [R] i32         total kv length incl. this chunk
-    q_lens        [R] i32         chunk lengths (0 = inactive slot)
-    k/v_scales    [L, nkv, P] f32 per-page dequant scales — presence
-                                  selects the quantized-KV path: pools
-                                  hold int8 pages, new k/v are
-                                  quantize-on-write requantized per
-                                  page, and attention dequants on read
-    step_tokens   int or None     how many positions the program
-                                  computes: None is all ``R x Tc``; a
-                                  count ``T`` is the token-major step,
-                                  whose caller guarantees
-                                  ``sum(q_lens) <= T``
-
-    Fixed shapes throughout — one compilation per (R, Tc, pool)
-    signature.  Rope runs at each token's absolute position
-    (seq_lens - q_lens + t), new k/v are written through the block
-    table (``ops.pallas_ops.paged_kv_write``), and attention is
-    ``ops.pallas_ops.ragged_paged_attention`` (jnp reference off-TPU).
-    Returns (logits [R, Tc, V] fp32, (k_pages, v_pages)) — with
-    scales, (k_pages, v_pages, k_scales, v_scales); logits in padding
-    rows are garbage by contract — callers read row q_lens[r] - 1.
-
-    What is per token (embedding, norms, projections and rope, the MLP,
-    the head) runs on the flat batch ``[T, ...]`` of ``StepLayout``; the
-    K/V write and the attention take the padded ``[R, Tc, ...]`` rows
-    and hand their result back flat.  With ``step_tokens`` the logits
-    come back flat too, ``[T, V]``: token ``start[r] + t`` is row r's
-    position t (``StepLayout.last`` is each row's last fed token).
+    """The serving engine's step (``protocol.ServingModel``, which the
+    engine calls as ``_serve_step`` on the cache as one pytree) with the
+    stacked pools ``k/v_pages [L, nkv, P, page, d]`` apart.  Given, the
+    float32 ``k/v_scales [L, nkv, P]`` are the scales of int8 pages: new
+    k/v are quantized on write, per page, and attention dequantizes on
+    read.  Returns ``(logits, (k_pages, v_pages))``, with scales ``(logits,
+    (k_pages, v_pages, k_scales, v_scales))``.  Rope runs at each token's
+    absolute position ``seq_lens - q_lens + t``; new k/v are written through
+    the block table and attended over there (``models/paged.py``).
 
     The pools stay one buffer.  They go round the ONE ``lax.scan`` over
     the layers whole, in its carry beside the hidden state (the scanned
@@ -802,16 +764,12 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
     Window slots whose block-table entry is 0 (unallocated → the
     reserved null page) are dropped from the scatter, keeping the
     null page zero."""
-    from ..ops.pallas_ops import paged_kv_write, ragged_paged_attention
-
     R, Tc = tokens.shape
     nh, nkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
         cfg.head_dim
-    H = cfg.hidden_size
-    rep = nh // nkv
     page = k_pages.shape[3]
     num_pages = k_pages.shape[2]
-    lay = StepLayout(q_lens, Tc, step_tokens)
+    lay = step_frame(tokens, seq_lens, q_lens, step_tokens)[0]
     T = lay.T
 
     # absolute position of each token slot, clipped for the rope gather
@@ -885,34 +843,24 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], lay.flat(tokens), axis=0)   # [T, H]
 
-    def kv_write(pools, l, k, v):
-        # k, v [R, Tc, nkv, d]; only the new tokens are written, into
-        # the stack: nothing here yields a layer's pool
-        if quant_kv:
-            kp, vp, ks, vs = pools
-            kp, ks = quant_write(kp, ks, l, k.astype(jnp.float32))
-            vp, vs = quant_write(vp, vs, l, v.astype(jnp.float32))
-            return kp, vp, ks, vs
-        return paged_kv_write(*pools, k, v, block_tables, seq_lens,
-                              q_lens, layer=l)
-
     def attn(h, lp, pools, l):
-        xn = _rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
+        xn = rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
         q = lay.rows(rope(_qmm(xn, lp["wq"]).reshape(T, nh, d)))
         k = lay.rows(rope(_qmm(xn, lp["wk"]).reshape(T, nkv, d)))
         v = lay.rows(_qmm(xn, lp["wv"]).reshape(T, nkv, d))
-        with jax.named_scope("kv_write"):
-            pools = kv_write(pools, l, k, v)
-        # kernel layout [R, nkv, Tc*rep, d]: row t*rep + j = q head
-        # k*rep + j of token t (the h // rep GQA mapping)
-        qk = q.reshape(R, Tc, nkv, rep, d).transpose(
-            0, 2, 1, 3, 4).reshape(R, nkv, Tc * rep, d)
-        out = ragged_paged_attention(
-            qk, pools[0], pools[1], block_tables, seq_lens, q_lens,
-            rep=rep, layer=l,
-            **dict(zip(("k_scales", "v_scales"), pools[2:])))
-        out = lay.flat(out.reshape(R, nkv, Tc, rep, d).transpose(
-            0, 2, 1, 3, 4).reshape(R, Tc, H))
+        # only the new tokens are written, into the stack: nothing here
+        # yields a layer's pool
+        if quant_kv:
+            kp, vp, ks, vs = pools
+            with jax.named_scope("kv_write"):
+                kp, ks = quant_write(kp, ks, l, k.astype(jnp.float32))
+                vp, vs = quant_write(vp, vs, l, v.astype(jnp.float32))
+            pools = kp, vp, ks, vs
+        else:
+            pools = paged.write(pools, k, v, block_tables, seq_lens, q_lens,
+                                l)
+        out = lay.flat(paged.attend(q, pools, block_tables, seq_lens, q_lens,
+                                    layer=l))
         return h + _qmm(out.astype(h.dtype), lp["wo"]), pools
 
     def body(carry, inp):
@@ -921,12 +869,12 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
         with jax.named_scope("attn"):
             h, pools = attn(h, lp, pools, l)
         with jax.named_scope("mlp"):
-            hn = _rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
+            hn = rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
             if cfg.moe_num_experts > 0:
                 mlp_out, _aux = _moe_mlp(cfg, lp, hn[None])
                 h = h + mlp_out[0]
             else:
-                h = h + _dense_mlp(lp, hn)
+                h = h + dense_mlp(lp, hn)
         return (h, pools), None
 
     # the pools go round the layer loop whole, in the carry: a scanned
@@ -939,9 +887,9 @@ def forward_paged(cfg: LlamaConfig, params, tokens, k_pages, v_pages,
             body, (x, pools),
             (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
     with jax.named_scope("lm_head"):
-        x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+        x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
         logits = _qmm(x, params["lm_head"]).astype(jnp.float32)
-    return (logits if lay.compact else lay.rows(logits)), pools
+    return lay.result(logits, pools)
 
 
 # ---------------------------------------------------------------------------
@@ -997,17 +945,17 @@ def _serve_step(cfg, params, tokens, cache, block_tables, seq_lens, q_lens,
                          **dict(zip(("k_scales", "v_scales"), scales)))
 
 
-SERVING = types.SimpleNamespace(
+SERVING = protocol.ServingModel(
     forward_paged=_serve_step, init_cache=init_cache,
     cache_bytes=cache_bytes, param_count=param_count,
     # int8 weight path: PTQ the serving weights once at engine build; asked
     # for by the config, never by the platform
     prepare_params=lambda cfg, params: (
-        quantize_params(cfg, params) if cfg.quantized == "on" else params),
-    recurrent_state=False)
+        quantize_params(cfg, params) if cfg.quantized == "on" else params))
+LlamaConfig.serving = SERVING   # cfg.serving, the engine's model
 
 
-def _cfg_key(cfg):
+def cfg_key(cfg):
     return tuple(sorted((k, str(v))
                         for k, v in dataclasses.asdict(cfg).items()))
 
@@ -1023,7 +971,7 @@ def generate(cfg: LlamaConfig, params, input_ids, max_new_tokens,
         num_layers=cfg.num_hidden_layers,
         kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
         max_positions=cfg.max_position_embeddings, cache_dtype=cfg.dtype,
-        cache_key=("llama", _cfg_key(cfg)), params=params,
+        cache_key=("llama", cfg_key(cfg)), params=params,
         input_ids=input_ids, max_new_tokens=max_new_tokens,
         temperature=temperature, top_k=top_k, rng=rng,
         eos_token_id=eos_token_id)
@@ -1335,7 +1283,7 @@ class LlamaForCausalLM(GenerationMixin, Layer):
         key = jax.random.PRNGKey(0)
         raw = init_params(config, key)
         self._flat = {}
-        for name, arr in _flatten_params(raw):
+        for name, arr in flatten_params(raw):
             p = Parameter(arr)
             p.name = name
             self.add_parameter(name.replace(".", "_"), p)
@@ -1345,7 +1293,7 @@ class LlamaForCausalLM(GenerationMixin, Layer):
         raw = {}
         for name, p in self._flat.items():
             raw[name] = p._array
-        return _unflatten_params(raw)
+        return unflatten_params(raw)
 
     def forward(self, input_ids, labels=None):
         cfg = self.config
@@ -1354,7 +1302,7 @@ class LlamaForCausalLM(GenerationMixin, Layer):
 
         def _f(ids, *arrs):
             raw = dict(zip(flat_names, arrs))
-            params = _unflatten_params(raw)
+            params = unflatten_params(raw)
             logits, aux = forward_pure(cfg, params, ids)
             return logits
         ids_t = input_ids if isinstance(input_ids, Tensor) \
@@ -1373,18 +1321,18 @@ class LlamaForCausalLM(GenerationMixin, Layer):
         return logits
 
 
-def _flatten_params(tree, prefix=""):
+def flatten_params(tree, prefix=""):
     out = []
     for k, v in tree.items():
         name = f"{prefix}.{k}" if prefix else k
         if isinstance(v, dict):
-            out.extend(_flatten_params(v, name))
+            out.extend(flatten_params(v, name))
         else:
             out.append((name, v))
     return out
 
 
-def _unflatten_params(flat):
+def unflatten_params(flat):
     tree = {}
     for name, v in flat.items():
         parts = name.split(".")

@@ -60,7 +60,7 @@ independence from the second sublayer is the compiler's (ROADMAP R1).
 from __future__ import annotations
 
 import dataclasses
-import types
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -68,9 +68,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import mla
+from . import mla, protocol
 from .experts import route_top_k, routed_experts
-from .step_layout import StepLayout
+from .step_layout import step_frame
 
 __all__ = ["LongcatFlashConfig", "PRESETS", "preset", "config_from_fields",
            "init_params", "param_count", "forward_pure", "forward_paged",
@@ -133,19 +133,9 @@ class LongcatFlashConfig:
                 f"{self}")
         if self.qk_rope_head_dim % 2:
             raise ValueError("rotary lanes pair up")
-        if self.experts_held is not None:
-            self.experts_held = tuple(int(e) for e in self.experts_held)
-            if len(self.experts_held) != self.n_routed_experts or not all(
-                    0 <= e < self.router_experts for e in self.experts_held):
-                raise ValueError(
-                    f"experts_held names {len(self.experts_held)} experts; "
-                    f"n_routed_experts says {self.n_routed_experts} are here "
-                    f"of the {self.router_experts} the router scores")
-        elif self.router_experts != self.n_routed_experts:
-            raise ValueError("a device that holds a share of the experts "
-                             "says which (experts_held)")
-        if self.vocab_size > (self.vocab_size_published or self.vocab_size):
-            raise ValueError("a slice of the vocabulary is no larger than it")
+        self.experts_held = protocol.chip_share(
+            self, self.n_routed_experts, self.router_experts,
+            "n_routed_experts")
 
     @property
     def router_experts(self) -> int:
@@ -159,10 +149,6 @@ class LongcatFlashConfig:
     @property
     def softmax_scale(self) -> float:
         return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
-
-    @property
-    def serving(self):
-        return SERVING
 
 
 PRESETS: Dict[str, Dict[str, Any]] = {
@@ -180,25 +166,8 @@ PRESETS: Dict[str, Dict[str, Any]] = {
 }
 
 
-def preset(name: str, **overrides) -> LongcatFlashConfig:
-    if name not in PRESETS:
-        raise KeyError(f"unknown longcat_flash preset {name!r}; available: "
-                       f"{sorted(PRESETS)}")
-    return LongcatFlashConfig(**dict(PRESETS[name], **overrides))
-
-
-def config_from_fields(fields: dict) -> LongcatFlashConfig:
-    """A ``LongcatFlashConfig`` from a ``config.json``-shaped dict: every key
-    that is a field, ``dtype`` by name; where the file states a chip's share
-    (``experts_held``; a sliced ``vocab_size``) its ``published`` group gives
-    the counts the share is of.  Other keys are not this model's."""
-    names = {f.name for f in dataclasses.fields(LongcatFlashConfig)}
-    kw = {k: v for k, v in fields.items() if k in names}
-    for key in ("n_routed_experts", "vocab_size"):
-        if key in fields.get("published", {}):
-            kw.setdefault(key + "_published", fields["published"][key])
-    kw["dtype"] = jnp.dtype(kw.get("dtype", "bfloat16")).type
-    return LongcatFlashConfig(**kw)
+preset = partial(protocol.preset, LongcatFlashConfig, PRESETS)
+config_from_fields = partial(protocol.config_from_fields, LongcatFlashConfig)
 
 
 # ---------------------------------------------------------------------------
@@ -385,10 +354,7 @@ def init_cache(cfg: LongcatFlashConfig, slots: int, num_pages: int,
     sublayers of every layer stacked (position ``2 l + j``).  Nothing is kept
     a slot."""
     del slots
-    if jnp.dtype(kv_dtype).itemsize < 2:
-        raise ValueError(
-            f"kv_dtype {jnp.dtype(kv_dtype)} pages need the per-page scale "
-            "pools that only models/llama.py's step writes")
+    protocol.refuse_narrow_pages(kv_dtype)
     return {"latent": jnp.zeros((2 * cfg.num_layers, 1, num_pages, page_size,
                                  cfg.latent_lanes), kv_dtype)}
 
@@ -422,38 +388,26 @@ def step_counts(cfg: LongcatFlashConfig, seq_lens, q_lens) -> dict:
 def forward_paged(cfg: LongcatFlashConfig, params, tokens, cache,
                   block_tables, seq_lens, q_lens, step_tokens=None,
                   device_counts=False):
-    """The engine's step: ragged mixed prefill and decode rows ``tokens [R,
-    Tc]`` (row r feeds ``tokens[r, :q_lens[r]]`` and then holds ``seq_lens[r]``
-    tokens) over ``cache`` (``init_cache``).  Returns ``(logits [R, Tc, V]
-    float32, cache)``, or with ``step_tokens = T`` the logits flat ``[T, V]``
-    (``StepLayout``), as ``deepseek_v2.forward_paged``; with ``device_counts``
-    a third result, the int32 ``[4]`` of ``DEVICE_COUNTS``.
-
-    Every attention sublayer is the absorbed form on its own position of the
-    one latent pool (``mla.absorbed``); projections, the dense FFNs and the
-    expert layer are per token, on the flat layout; padding tokens are routed
-    to no expert."""
-    R, Tc = tokens.shape
-    lay = StepLayout(q_lens, Tc, step_tokens)
-    t_off = jnp.arange(Tc, dtype=jnp.int32)[None, :]
-    start = (seq_lens - q_lens).astype(jnp.int32)[:, None]
-    pos = lay.flat(jnp.maximum(start + t_off, 0))
-    live = lay.flat(t_off < q_lens[:, None])
+    """The engine's step (``protocol.ServingModel``) over ``cache``
+    (``init_cache``); ``device_counts``: the ``[4]`` of ``DEVICE_COUNTS``
+    third.  Every attention sublayer is the absorbed form on its own position
+    of the one latent pool (``mla.absorbed``); projections, the dense FFNs and
+    the expert layer are per token, on the flat layout; padding tokens are
+    routed to no expert."""
+    lay, ids, pos, live = step_frame(tokens, seq_lens, q_lens, step_tokens)
 
     def mixer(lp, xn, i, sin, cos, pages):
         return mla.absorbed(cfg, lp, xn, sin, cos, pages, lay, block_tables,
                             seq_lens, q_lens, i)
 
-    logits, pages, counts = _forward(cfg, params, lay.flat(tokens), pos, live,
-                                     mixer, cache["latent"])
-    out = (logits if lay.compact else lay.rows(logits)), {"latent": pages}
-    return out + (counts,) if device_counts else out
+    logits, pages, counts = _forward(cfg, params, ids, pos, live, mixer,
+                                     cache["latent"])
+    return lay.result(logits, {"latent": pages},
+                      counts if device_counts else None)
 
 
-# what serving.LLMEngine asks a configuration for (``cfg.serving``)
-SERVING = types.SimpleNamespace(
+SERVING = protocol.ServingModel(
     forward_paged=forward_paged, init_cache=init_cache,
     cache_bytes=cache_bytes, param_count=param_count,
-    prepare_params=lambda cfg, params: params,   # no weight is converted
-    step_counts=step_counts, device_counts=DEVICE_COUNTS,
-    recurrent_state=False)
+    step_counts=step_counts, device_counts=DEVICE_COUNTS)
+LongcatFlashConfig.serving = SERVING   # cfg.serving, the engine's model

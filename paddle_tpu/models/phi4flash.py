@@ -54,7 +54,7 @@ window layers, then the full one), ``gmu`` and ``cross`` (L/4 - 1 each);
 every stack holds its layers' ``ln1_w ln1_b ln2_w ln2_b w1 w2`` too.  What
 has a state axis keeps it second to last (``A_log [M, N, E]``).
 
-**Serving** (``SERVING``, the protocol ``serving.LLMEngine`` asks a
+**Serving** (``SERVING``, the ``protocol.ServingModel`` the engine asks a
 configuration for).  Three kinds of state live in one cache dict:
 
 * ``k_pages / v_pages [1, nkv/2, P, page, 2d]``: the full layer's K and V of
@@ -78,16 +78,17 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import types
+from functools import partial
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import rings
-from .jamba import _layer_at, _mixer_core
-from .step_layout import StepLayout
+from . import paged, protocol, rings
+from .jamba import _mixer_core, mamba_state, mamba_state_bytes
+from .protocol import layer_at as _layer_at
+from .step_layout import StepLayout, step_frame
 
 __all__ = ["Phi4FlashConfig", "PRESETS", "preset", "config_from_fields",
            "init_params", "param_count", "forward_pure", "forward_paged",
@@ -160,10 +161,6 @@ class Phi4FlashConfig:
         """``Wp``: the pages of one slot's ring in a window layer."""
         return rings.ring_pages(self.sliding_window, page_size)
 
-    @property
-    def serving(self):
-        return SERVING
-
 
 PRESETS: Dict[str, Dict[str, Any]] = {
     "phi4-mini-flash": {},
@@ -178,20 +175,8 @@ PRESETS: Dict[str, Dict[str, Any]] = {
 }
 
 
-def preset(name: str, **overrides) -> Phi4FlashConfig:
-    if name not in PRESETS:
-        raise KeyError(f"unknown phi4flash preset {name!r}; available: "
-                       f"{sorted(PRESETS)}")
-    return Phi4FlashConfig(**dict(PRESETS[name], **overrides))
-
-
-def config_from_fields(fields: dict) -> Phi4FlashConfig:
-    """A ``Phi4FlashConfig`` from a ``config.json``-shaped dict: every key
-    that is a field, ``dtype`` by name; other keys are not this model's."""
-    names = {f.name for f in dataclasses.fields(Phi4FlashConfig)}
-    kw = {k: v for k, v in fields.items() if k in names}
-    kw["dtype"] = jnp.dtype(kw.get("dtype", "bfloat16")).type
-    return Phi4FlashConfig(**kw)
+preset = partial(protocol.preset, Phi4FlashConfig, PRESETS)
+config_from_fields = partial(protocol.config_from_fields, Phi4FlashConfig)
 
 
 def _lambdas(lp, i):
@@ -337,10 +322,11 @@ def _mamba_layer(cfg, lp, h, state, l, q_lens, fresh, lay):
 
 def _padded_queries(cfg, q):
     """``q [R, Tc, nh x d]`` as the kernel's rows ``[R, nkv/2, Tc x rep, 2d]``,
-    ``rep = 2 nh / nkv``: for a token and a K/V pair-head g the query heads
-    ``rep g .. rep g + rep - 1`` in order, an even one (a ``q1``) as ``[q |
-    0]`` against ``[k1 | k2]``, an odd one (a ``q2``) as ``[0 | q]``, scaled
-    so that the kernel's ``1 / sqrt(2d)`` leaves ``q k^T / sqrt(d)``."""
+    ``rep = 2 nh / nkv`` (``paged.queries``): for a token and a K/V pair-head
+    g the query heads ``rep g .. rep g + rep - 1`` in order, an even one (a
+    ``q1``) as ``[q | 0]`` against ``[k1 | k2]``, an odd one (a ``q2``) as
+    ``[0 | q]``, scaled so that the kernel's ``1 / sqrt(2d)`` leaves ``q k^T
+    / sqrt(d)``."""
     R, Tc, _ = q.shape
     d, nkvp = cfg.head_dim, cfg.num_key_value_heads // 2
     rep = cfg.num_attention_heads // nkvp
@@ -350,8 +336,7 @@ def _padded_queries(cfg, q):
     q = jnp.concatenate(
         [jnp.concatenate([q[..., :1, :], zero], -2),       # [q1 ; 0]: left
          jnp.concatenate([zero, q[..., 1:, :]], -2)], -1)  # [0 ; q2]: right
-    return q.reshape(R, Tc, nkvp, rep, 2 * d).transpose(
-        0, 2, 1, 3, 4).reshape(R, nkvp, Tc * rep, 2 * d)
+    return paged.queries(q, nkvp)
 
 
 def _diff_attention(cfg, lp, h, i, kind, l, state, attend, lay):
@@ -377,8 +362,7 @@ def _diff_attention(cfg, lp, h, i, kind, l, state, attend, lay):
         a, state = attend(kind, l, _padded_queries(cfg, lay.rows(q)), k, v,
                           state)
         # [R, nkv/2, Tc x rep, 2d] -> flat [T, nh/2 pairs, (a1, a2), 2d]
-        a = a.reshape(R, nkv // 2, Tc, nh // nkv, 2, 2 * d).transpose(
-            0, 2, 1, 3, 4, 5).reshape(R, Tc, nh * 2 * d)
+        a = paged.outputs(a, Tc, heads=(nh // nkv, 2))
         a = lay.flat(a).reshape(T, nh // 2, 2, 2 * d).astype(f32)
         lam, lam0 = _lambdas(lp, i)
         o = a[:, :, 0] - lam * a[:, :, 1]
@@ -446,12 +430,6 @@ def _forward(cfg, params, tokens, state, q_lens, fresh, attend, lay):
     return logits, state
 
 
-def _fresh_state(cfg, rows: int) -> dict:
-    E, M = cfg.mamba_inner, cfg.num_mamba_layers
-    return {"conv": jnp.zeros((M, cfg.mamba_d_conv - 1, rows, E), cfg.dtype),
-            "ssm": jnp.zeros((M, cfg.mamba_d_state, rows, E), jnp.float32)}
-
-
 def forward_pure(cfg: Phi4FlashConfig, params, input_ids):
     """Logits ``[B, S, V]`` float32 of whole sequences ``[B, S]``: no cache,
     plain masked attention on the same zero-padded query rows that the
@@ -482,7 +460,7 @@ def forward_pure(cfg: Phi4FlashConfig, params, input_ids):
     q_lens = jnp.full((B,), S, jnp.int32)
     lay = StepLayout(q_lens, S)
     blank = jnp.zeros((B, S, nkvp, 2 * d), cfg.dtype)
-    state = dict(_fresh_state(cfg, B), k=blank, v=blank)
+    state = dict(mamba_state(cfg, B), k=blank, v=blank)
     return lay.rows(_forward(cfg, params, input_ids, state, q_lens,
                              jnp.ones((B,), bool), attend, lay)[0])
 
@@ -496,10 +474,7 @@ def init_cache(cfg: Phi4FlashConfig, slots: int, num_pages: int,
     """The cache of an engine with ``slots`` rows: the full layer's zeroed
     page pool, the window layers' rings (and their null page), zero
     recurrent state of every slot."""
-    if jnp.dtype(kv_dtype).itemsize < 2:
-        raise ValueError(
-            f"kv_dtype {jnp.dtype(kv_dtype)} pages need the per-page scale "
-            "pools that only models/llama.py's step writes")
+    protocol.refuse_narrow_pages(kv_dtype)
     nkvp, width = cfg.num_key_value_heads // 2, 2 * cfg.head_dim
     pool = (1, nkvp, num_pages, page_size, width)
     return {"k_pages": jnp.zeros(pool, kv_dtype),
@@ -507,7 +482,7 @@ def init_cache(cfg: Phi4FlashConfig, slots: int, num_pages: int,
             **rings.init_rings(cfg.num_window_layers, nkvp, slots,
                                cfg.sliding_window, page_size, width,
                                kv_dtype),
-            **_fresh_state(cfg, slots)}
+            **mamba_state(cfg, slots)}
 
 
 def cache_bytes(cfg: Phi4FlashConfig, kv_dtype_bytes: int = 2,
@@ -517,26 +492,18 @@ def cache_bytes(cfg: Phi4FlashConfig, kv_dtype_bytes: int = 2,
     ``page_size`` tokens each) and its recurrent state, whatever the length
     of the request in it; and, whatever the engine's size, the rings' null
     page (``fixed``)."""
-    E = cfg.mamba_inner
     token = 2 * cfg.num_key_value_heads * cfg.head_dim * kv_dtype_bytes
     ring = rings.ring_bytes(cfg.num_window_layers, token, cfg.sliding_window,
                             page_size)
-    state = cfg.num_mamba_layers * E * (
-        cfg.mamba_d_state * 4
-        + (cfg.mamba_d_conv - 1) * jnp.dtype(cfg.dtype).itemsize)
     return {"per_token": token, "scales_per_page": 0,
-            "per_slot": ring["per_slot"] + state, "fixed": ring["fixed"]}
+            "per_slot": ring["per_slot"] + mamba_state_bytes(cfg),
+            "fixed": ring["fixed"]}
 
 
 def step_counts(cfg: Phi4FlashConfig, seq_lens, q_lens) -> dict:
-    """What one step's window and Mamba layers read, a layer, from the
-    host's arrays ``seq_lens, q_lens [R]``: ``window_kv_tokens``, over the
-    fed rows the keys some query of the row sees (``min(seq_len, W + q_len -
-    1)``), ``window_qk_pairs``, over the fed tokens the keys each sees
-    (``min(p + 1, W)`` at position p), and ``scan_positions``, as
-    ``jamba.step_counts`` counts them.  The engine puts them on its
-    ``serve/engine_step`` span beside ``kv_tokens`` and ``qk_pairs``, which
-    count the full layer."""
+    """What one step's window and Mamba layers read, a layer
+    (``rings.window_counts``; ``scan_positions`` as ``jamba.step_counts``),
+    beside the engine's ``kv_tokens`` and ``qk_pairs`` of the full layer."""
     from ..ops.pallas_ops import scan_positions
     return dict(rings.window_counts(seq_lens, q_lens, cfg.sliding_window),
                 scan_positions=scan_positions(q_lens))
@@ -544,25 +511,19 @@ def step_counts(cfg: Phi4FlashConfig, seq_lens, q_lens) -> dict:
 
 def forward_paged(cfg: Phi4FlashConfig, params, tokens, cache, block_tables,
                   seq_lens, q_lens, step_tokens=None):
-    """The engine's step: ragged mixed prefill and decode rows ``tokens [R,
-    Tc]`` (row r feeds ``tokens[r, :q_lens[r]]`` and then holds ``seq_lens[r]``
-    tokens) over ``cache`` (``init_cache``).  Returns ``(logits [R, Tc, V]
-    float32, cache)``, or with ``step_tokens = T`` the logits flat ``[T, V]``
-    (``StepLayout``), as ``jamba.forward_paged``.
-
-    The full layer writes its K/V into the engine's pages through
-    ``block_tables`` and the cross layers read them there
-    (``ragged_paged_attention(..., layer=0)`` on the one pool); a window
-    layer writes and reads its slot's ring through a table made here, with
-    ``window=W``.  Rings and recurrent state are per ROW (engine slot): a
-    row whose chunk starts at position 0 (``seq_lens[r] == q_lens[r] > 0``)
-    has its state zeroed inside this step, and its ring needs no reset,
-    since a request that starts at 0 rewrites every ring page before it
-    reads it (a query never sees past its own position).  That is all
-    admission into a used slot, preemption-replay and a rebuilt engine
-    need.  Positions ``t >= q_lens[r]`` write no K/V and advance no state;
-    a row with ``q_lens[r] == 0`` comes back as it was."""
-    from ..ops.pallas_ops import paged_kv_write, ragged_paged_attention
+    """The engine's step (``protocol.ServingModel``) over ``cache``
+    (``init_cache``).  The full layer writes its K/V into the engine's pages
+    through ``block_tables`` and the cross layers read them there
+    (``ragged_paged_attention(..., layer=0)`` on the one pool); a window layer
+    writes and reads its slot's ring through a table made here, with
+    ``window=W``.  Rings and recurrent state are per ROW (engine slot): a row
+    whose chunk starts at position 0 (``seq_lens[r] == q_lens[r] > 0``) has its
+    state zeroed inside this step, and its ring needs no reset, since a request
+    that starts at 0 rewrites every ring page before it reads it (a query never
+    sees past its own position).  That is all admission into a used slot,
+    preemption-replay and a rebuilt engine need.  Positions ``t >= q_lens[r]``
+    write no K/V and advance no state; a row with ``q_lens[r] == 0`` comes back
+    as it was."""
     R, Tc = tokens.shape
     ring_table = rings.ring_table(R, block_tables.shape[1],
                                   cfg.sliding_window,
@@ -573,28 +534,25 @@ def forward_paged(cfg: Phi4FlashConfig, params, tokens, cache, block_tables,
         names = ("kw_pages", "vw_pages") if kind == "window" else \
             ("k_pages", "v_pages")
         table = ring_table if kind == "window" else block_tables
-        pools = [state[n] for n in names]
+        pools = tuple(state[n] for n in names)
         if kind != "cross":
-            with jax.named_scope("kv_write"):
-                pools = paged_kv_write(*pools, k, v, table, seq_lens, q_lens,
-                                       layer=l)
+            pools = paged.write(pools, k, v, table, seq_lens, q_lens, l)
             state = dict(state, **dict(zip(names, pools)))
-        out = ragged_paged_attention(
-            q, *pools, table, seq_lens, q_lens, rep=rep,
+        out = paged.walk(
+            q, pools, table, seq_lens, q_lens, rep=rep,
             layer=l if kind == "window" else 0,
             window=cfg.sliding_window if kind == "window" else None)
         return out, state
 
     fresh = (q_lens > 0) & (seq_lens == q_lens)
-    lay = StepLayout(q_lens, Tc, step_tokens)
+    lay = step_frame(tokens, seq_lens, q_lens, step_tokens)[0]
     logits, cache = _forward(cfg, params, tokens, dict(cache), q_lens, fresh,
                              attend, lay)
-    return (logits if lay.compact else lay.rows(logits)), cache
+    return lay.result(logits, cache)
 
 
-# what serving.LLMEngine asks a configuration for (``cfg.serving``)
-SERVING = types.SimpleNamespace(
+SERVING = protocol.ServingModel(
     forward_paged=forward_paged, init_cache=init_cache,
     cache_bytes=cache_bytes, param_count=param_count,
-    prepare_params=lambda cfg, params: params,   # no weight is converted
     step_counts=step_counts, recurrent_state=True, rings=True)
+Phi4FlashConfig.serving = SERVING   # cfg.serving, the engine's model

@@ -20,6 +20,9 @@ positions; the caller guarantees ``sum(q_lens) <= T`` (the scheduler's token
 budget, checked by the engine on the host: tokens past ``T`` would vanish).
 The maps follow from ``q_lens`` inside the program, so a step uploads
 nothing for them.
+
+Every model's ``forward_paged`` starts from ``step_frame`` and ends with
+``StepLayout.result``.
 """
 from __future__ import annotations
 
@@ -28,7 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["StepLayout"]
+__all__ = ["StepLayout", "step_frame"]
 
 # the name of every operation of this module in a profile (metadata only):
 # ``layout_ms_per_step`` reads the device time under it
@@ -126,6 +129,12 @@ class StepLayout:
             return jnp.swapaxes(
                 jnp.take(x, self._dst, axis=0, mode="clip"), 0, 1)
 
+    def result(self, logits, cache, counts=None):
+        """A step's ``(logits, cache)``, then ``counts`` where given; the
+        logits ``[T, V]`` with ``step_tokens``, else ``[R, Tc, V]``."""
+        out = (logits if self.compact else self.rows(logits)), cache
+        return out if counts is None else out + (counts,)
+
     def of_rows(self, a):
         """``a [R, ...]`` as ``[T, ...]``: flat token i gets the entry of its
         row ``row[i]`` (a token past the fed ones that of some row: select
@@ -152,3 +161,18 @@ class StepLayout:
             for t in range(1, self.Tc):
                 out = jnp.where(at == t, rows[:, t], out)
             return out
+
+
+def step_frame(tokens, seq_lens, q_lens, step_tokens=None):
+    """The engine's step on ``tokens [R, Tc]`` (row r feeds ``tokens[r,
+    :q_lens[r]]`` and then holds ``seq_lens[r]`` tokens) as ``(lay, ids,
+    pos, live)``: the ``StepLayout`` and, for its flat tokens ``[T]``, the
+    ids, the positions in their rows and which are fed.  What a model does
+    not read leaves no operation in its program."""
+    Tc = tokens.shape[1]
+    lay = StepLayout(q_lens, Tc, step_tokens)
+    t_off = jnp.arange(Tc, dtype=jnp.int32)[None, :]
+    start = (seq_lens - q_lens).astype(jnp.int32)[:, None]
+    pos = lay.flat(jnp.maximum(start + t_off, 0))
+    live = lay.flat(t_off < q_lens[:, None])
+    return lay, lay.flat(tokens), pos, live

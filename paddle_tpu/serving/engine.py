@@ -18,36 +18,30 @@ before dispatch whenever the next plan needs the results themselves (a
 draft model or the prefix cache is configured, the pool is short of
 pages) and after a failed step: docs/serving.md, "One step in flight".
 
-The model is whatever the configuration names (``cfg.serving``), through
-a protocol of a few members: ``init_cache(cfg, slots, num_pages,
-page_size, kv_dtype)`` builds the cache pytree (page pools, and for a
-model with recurrent layers also fixed-size state per engine slot),
-``forward_paged(cfg, params, tokens, cache, block_tables, seq_lens,
-q_lens, step_tokens=None) -> (logits [R, Tc, V] f32, cache)`` is the step
-(with ``step_tokens = T`` it computes ``T`` positions, the step's fed
-tokens row after row, and returns their logits ``[T, V]``:
-``models/step_layout.py``), ``cache_bytes(cfg, kv_dtype_bytes, page_size)``
-(bytes ``per_token``, ``scales_per_page``, ``per_slot`` and, where the cache
-holds something that grows with none of them, ``fixed``) and
-``param_count(cfg)`` size it, ``prepare_params(cfg, params)`` converts weights
-once at build, and ``recurrent_state`` says whether the cache holds state
-that depends on every token fed, in order (docs/serving.md, "Recurrent
-state", "Window rings and shared K/V").  Two members are optional:
-``rings``, true where the cache keeps window layers' K/V in a ring a slot
-(``models/rings.py``; the engine then refuses the prefix cache and
-speculative decoding, as for recurrent state), and
-``step_counts(cfg, seq_lens, q_lens) -> dict`` of integers, called with the
-host arrays of every step that feeds the device; the engine puts its entries
-on the step's ``serve/engine_step`` span and sums them in
-``serving_stats()``.  It is how a model whose layers read the cache in ways
-the engine does not know (a window, a pool shared by several layers) counts
-what a step read: the engine learns no layer kinds.  What only the DEVICE
-can count (where a router sent a step's tokens) a model declares as
-``device_counts``, a tuple of names: its ``forward_paged(...,
-device_counts=True)`` then returns a third value, an int32 array of one
-number a name, which rides the step's fetch of the sampled tokens, goes on
-the same span after the fetch and is summed in ``serving_stats()`` (a name
-that ends in ``_max``: the largest seen).
+The model is whatever the configuration names: ``cfg.serving``, a
+``models.protocol.ServingModel``, read field by field.  Required:
+``forward_paged(cfg, params, tokens, cache, block_tables, seq_lens, q_lens,
+step_tokens=None) -> (logits [R, Tc, V] f32, cache)``, the step (with
+``step_tokens = T``, logits ``[T, V]`` of the step's fed tokens row after
+row: ``models/step_layout.py``), ``init_cache(cfg, slots, num_pages,
+page_size, kv_dtype)``, the cache pytree (page pools, and per engine slot
+what a model keeps beside them), and ``cache_bytes(cfg, kv_dtype_bytes,
+page_size)`` (``per_token``, ``scales_per_page``, ``per_slot``, and
+``fixed`` where the cache holds what grows with none of them) and
+``param_count(cfg)``, its sizes.  With defaults: ``prepare_params(cfg,
+params)`` (as given), weights converted once at build; ``recurrent_state``
+and ``rings`` (False), a cache with state that depends on every token fed,
+in order, or with window layers' K/V in a ring a slot: either makes the
+engine refuse the prefix cache and speculative decoding (docs/serving.md,
+"Recurrent state", "Window rings and shared K/V"); ``step_counts(cfg,
+seq_lens, q_lens) -> dict`` (None), what a step's layers read that the
+engine does not know how to count (a window, a pool several layers read),
+from the host arrays of every step, put on the step's ``serve/engine_step``
+span and summed in ``serving_stats()``; ``device_counts`` (``()``), the
+names of what only the DEVICE counts (where a router sent the tokens):
+``forward_paged(..., device_counts=True)`` returns an int32 array of one
+number a name third, fetched with the sampled tokens, put on the same span
+and summed (a name that ends in ``_max``: the largest seen).
 
 Compilation discipline: the batch is always [max_running, Tc] with
 Tc in {1, chunk}, so a serving process compiles at most two step
@@ -291,22 +285,17 @@ class LLMEngine:
                  spec: Optional["SpecDecodeConfig"] = None):
         self.cfg = cfg
         self._model = model = cfg.serving
-        if model.recurrent_state and (prefix_cache or spec is not None):
+        kept = ("recurrent state beside its K/V pages, which only advances "
+                "token by token" if model.recurrent_state else
+                "its window layers' K/V in a ring a slot (models/rings.py), "
+                "which only the tokens fed into the slot fill"
+                if model.rings else None)
+        if kept and (prefix_cache or spec is not None):
             raise ValueError(
-                f"{type(cfg).__name__} keeps recurrent state beside its "
-                "K/V pages, which only advances token by token: "
-                "prefix_cache=True would skip the tokens of a page hit and "
-                "spec= would feed draft tokens that cannot be taken back. "
-                "Neither is built for such a model")
-        if getattr(model, "rings", False) and (prefix_cache
-                                               or spec is not None):
-            raise ValueError(
-                f"{type(cfg).__name__} keeps its window layers' K/V in a "
-                "ring a slot (models/rings.py), which only the tokens fed "
-                "into the slot fill: prefix_cache=True would skip the "
-                "tokens of a page hit and spec= would overwrite ring "
-                "entries still in the window with drafts that are then "
-                "rejected. Neither is built for such a model")
+                f"{type(cfg).__name__} keeps {kept}: prefix_cache=True would "
+                "skip the tokens of a page hit and spec= would feed draft "
+                "tokens that cannot be taken back. Neither is built for such "
+                "a model")
         self.params = model.prepare_params(cfg, params)
         self.max_running = int(max_running)
         self.chunk = int(chunk)
@@ -562,19 +551,14 @@ class LLMEngine:
 
         # what the model has the device count (``device_counts``): one more
         # small result of the step, for a model that declares it
-        counted = bool(getattr(self._model, "device_counts", None))
+        counted = {"device_counts": True} if self._model.device_counts else {}
 
         def step(params, tokens, pools, tbl, lens, qlens, sampled):
             # a token still in flight when the host built ``tokens``: the
             # one the step before sampled for the row
             tokens = jnp.where(tokens < 0, sampled[:, None], tokens)
-            if counted:
-                logits, pools, device = fwd(
-                    cfg, params, tokens, pools, tbl, lens, qlens,
-                    step_tokens=T, device_counts=True)
-            else:
-                logits, pools = fwd(cfg, params, tokens, pools, tbl, lens,
-                                    qlens, step_tokens=T)
+            logits, pools, *device = fwd(cfg, params, tokens, pools, tbl,
+                                         lens, qlens, step_tokens=T, **counted)
             with jax.named_scope("sample"):
                 lay = StepLayout(qlens, Tc, T)
                 flat = logits.reshape(-1, logits.shape[-1])      # [T, V]
@@ -591,7 +575,7 @@ class LLMEngine:
                 best = jnp.argmax(flat, axis=-1).astype(jnp.int32)
                 out = (lay.rows(best), jnp.max(flat[lay.last], axis=-1),
                        pools, best[lay.last])
-                return out + (device,) if counted else out
+                return out + tuple(device)
 
         # the program's name in a profile: jit_serve_step_tc<Tc>
         step.__name__ = f"serve_step_tc{Tc}"
@@ -905,10 +889,10 @@ class LLMEngine:
             counts.update(state_rows=int((qlens > 0).sum()),
                           state_resets=resets)
             _STATS["state_resets"] += resets
-        model_counts = getattr(self._model, "step_counts", None)
-        if model_counts is not None:
+        if self._model.step_counts is not None:
             # what the model's own layers read this step
-            for key, n in model_counts(self.cfg, lens, qlens).items():
+            for key, n in self._model.step_counts(self.cfg, lens,
+                                                  qlens).items():
                 counts[key] = int(n)
                 _STATS[key] = _STATS.get(key, 0) + int(n)
         whole.set_metadata(**counts)

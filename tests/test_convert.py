@@ -26,8 +26,8 @@ def test_roundtrip_paddlenlp_names():
     assert sd["llama.layers.0.self_attn.q_proj.weight"].shape == (32, 32)
     back = convert.llama_from_external_state_dict(cfg, sd)
     for (n1, a1), (n2, a2) in zip(
-            sorted(llama._flatten_params(params)),
-            sorted(llama._flatten_params(back))):
+            sorted(llama.flatten_params(params)),
+            sorted(llama.flatten_params(back))):
         assert n1 == n2
         np.testing.assert_array_equal(np.asarray(a1), np.asarray(a2))
 

@@ -565,6 +565,32 @@ def test_fleet_sim_tool_is_lint_clean():
 # ---------------------------------------------------------------------------
 
 
+class _StepClock:
+    """The clock of a live fleet under test: it stands still while the host
+    works and moves on by a fixed cost a step, by bucket, when an engine
+    fetches the step, one replica after another, as ``SimClock(serial=True)``
+    and ``SimEngine`` pay the simulator's steps.  The live fleets' queueing
+    is then the engines' own, not the CPU's load under the test's workers."""
+    COST_S = {1: 0.010, 4: 0.025}      # a decode step, a chunk-4 step
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self) -> float:
+        return self.t
+
+    def pay(self, eng):
+        """Have ``eng``'s every fetch of a step advance the clock."""
+        fetch = eng._fetch
+
+        def fetched(flight):
+            fetch(flight)
+            self.t += self.COST_S[flight.plan.bucket]
+
+        eng._fetch = fetched
+        return eng
+
+
 class TestSimVsLive:
     @pytest.fixture(autouse=True)
     def _interpret_mode(self):
@@ -592,15 +618,14 @@ class TestSimVsLive:
                                   horizon_s=60.0, prompt_len=8,
                                   max_new_tokens=6, vocab=128)
 
-    def _live_engines(self, tiny, n_replicas, max_queue):
+    def _live_engines(self, tiny, n_replicas, max_queue, clock):
         from paddle_tpu import serving
         cfg, params = tiny
         engines = []
         for i in range(n_replicas):
-            eng = serving.LLMEngine(cfg, params, max_running=4,
-                                    chunk=4, page_size=8,
-                                    max_model_len=32,
-                                    max_queue=max_queue)
+            eng = clock.pay(serving.LLMEngine(
+                cfg, params, max_running=4, chunk=4, page_size=8,
+                max_model_len=32, max_queue=max_queue, clock=clock))
             # compile both buckets before the measured drive
             eng.add_request([1, 2, 3, 4], 2)
             while eng.has_work():
@@ -610,8 +635,9 @@ class TestSimVsLive:
 
     def _drive_live(self, tiny, n_replicas, sched, last, max_queue):
         from paddle_tpu import serving
-        engines = self._live_engines(tiny, n_replicas, max_queue)
-        router = serving.Router(engines, heartbeat_timeout=1e9)
+        clock = _StepClock()
+        engines = self._live_engines(tiny, n_replicas, max_queue, clock)
+        router = serving.Router(engines, heartbeat_timeout=1e9, clock=clock)
         admitted = shed = 0
         step = 0
         while step <= last or router.has_work():
@@ -683,8 +709,8 @@ class TestSimVsLive:
         assert (admitted_s, shed_s) == (admitted_l, shed_l)
         assert len(ttfts_s) == len(ttfts_l)
         # latency is as good as the calibration: p95 within 3x (the
-        # stated tolerance — step-time variance on a loaded CPU host
-        # is the error source, admission above is exact)
+        # stated tolerance; on the live fleet's step clock the calibration
+        # is the steps' own cost, admission above is exact)
         p_live, p_sim = self._p95(ttfts_l), self._p95(ttfts_s)
         assert p_live / 3.0 <= p_sim <= p_live * 3.0, \
             f"sim p95 {p_sim:.4f}s vs live {p_live:.4f}s"

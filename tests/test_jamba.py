@@ -294,13 +294,13 @@ def test_the_state_check_sees_a_state_kept_in_bfloat16(model, workload,
                                                        monkeypatch):
     """The precision the recurrent state is stored in between steps, which
     the logits hardly show, is what ``state_rel_err`` reads."""
-    fresh = jamba._fresh_state
+    fresh = jamba.mamba_state
 
     def fresh_bf16(cfg, rows):
-        conv, ssm = fresh(cfg, rows)
-        return conv, ssm.astype(jnp.bfloat16)
+        state = fresh(cfg, rows)
+        return dict(state, ssm=state["ssm"].astype(jnp.bfloat16))
 
-    monkeypatch.setattr(jamba, "_fresh_state", fresh_bf16)
+    monkeypatch.setattr(jamba, "mamba_state", fresh_bf16)
     prompts, n_new, _ = workload
     eng = engine(model)
     assert eng._pools["ssm"].dtype == jnp.bfloat16

@@ -3,20 +3,27 @@
 serves today. The test to run first for a new ``model_config``: add a row
 to ``MODELS``.
 
-What is checked: the six members are there; ``init_cache``'s leaves weigh
+What is checked: every model's ``cfg.serving`` is a
+``models.protocol.ServingModel`` and its members are there; ``init_cache``'s leaves weigh
 exactly what ``cache_bytes`` says (a token, a page's scales, a slot, and
 where a model says so a ``fixed`` amount, at the engine's page size); ``forward_paged`` returns a cache of
 the tree, shapes and dtypes it was given, which is what donating the
 cache into the step relies on; and ``kv_bytes_per_token``,
 ``plan_capacity`` and the engine's reservations in ``profiler.xmem``
-agree with the bytes of the live cache.
+agree with the bytes of the live cache.  And what every model shares
+through ``models/protocol.py``: pages of fewer than two bytes are refused
+wherever the step writes no scales, and ``config_from_fields`` reads a chip's
+share from a configuration file's ``published`` group.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from paddle_tpu import serving
-from paddle_tpu.models import deepseek_v2, jamba, llama, phi4flash
+from paddle_tpu.models import (cohere2_moe, deepseek_v2, jamba, llama,
+                               longcat_flash, phi4flash, protocol)
 from paddle_tpu.ops import pallas_ops
 from paddle_tpu.profiler import xmem
 
@@ -30,6 +37,13 @@ MODELS = {
     "phi4flash": (phi4flash, "phi4flash-debug", None),
     "deepseek_v2": (deepseek_v2, "deepseek-v2-debug", None),
 }
+
+
+# every model the engine serves, at its debug preset
+PRESETS = {llama: "llama-debug", jamba: "jamba-debug",
+           phi4flash: "phi4flash-debug", deepseek_v2: "deepseek-v2-debug",
+           longcat_flash: "longcat-flash-debug",
+           cohere2_moe: "cohere2-moe-debug"}
 
 
 @pytest.fixture(autouse=True)
@@ -126,3 +140,58 @@ def test_capacity_arithmetic_agrees_with_the_live_cache(served):
         eng.shutdown()
     assert not any(r["name"] == "serving.kv_pages"
                    for r in xmem.reservations())
+
+
+@pytest.mark.parametrize("module", list(PRESETS),
+                         ids=lambda m: m.__name__.rsplit(".", 1)[-1])
+def test_every_model_is_served_through_one_record(module):
+    cfg = module.preset(PRESETS[module])
+    assert isinstance(cfg.serving, protocol.ServingModel)
+    assert cfg.serving is module.SERVING
+    assert isinstance(cfg.serving.device_counts, tuple)
+    with pytest.raises(dataclasses.FrozenInstanceError):
+        cfg.serving.rings = True
+    with pytest.raises(KeyError, match="unknown"):
+        module.preset("no-such-preset")
+
+
+@pytest.mark.parametrize("module", [jamba, phi4flash, deepseek_v2,
+                                    longcat_flash, cohere2_moe],
+                         ids=lambda m: m.__name__.rsplit(".", 1)[-1])
+def test_pages_of_one_byte_are_refused_where_no_step_writes_scales(module):
+    cfg = module.preset(PRESETS[module])
+    with pytest.raises(ValueError, match="per-page scale"):
+        module.init_cache(cfg, 2, 5, PAGE, jnp.int8)
+
+
+# a configuration file that states a chip's share: the held counts at the
+# top, what they are a share of under ``published`` (with keys that are no
+# field of either model), and a key that is no field at all
+SHARE = {"longcat_flash": (longcat_flash, "n_routed_experts"),
+         "cohere2_moe": (cohere2_moe, "num_experts")}
+
+
+@pytest.mark.parametrize("name", sorted(SHARE))
+def test_config_from_fields_reads_the_published_counts(name):
+    module, count = SHARE[name]
+    fields = dict(module.PRESETS[PRESETS[module]], model=name,
+                  experts_held=[0, 1, 2, 3], dtype="float32",
+                  architectures=["NotAField"],
+                  published={count: 32, "vocab_size": 1024, "num_layers": 28,
+                             "parameters": 123})
+    fields[count] = 4
+    cfg = module.config_from_fields(fields)
+    assert getattr(cfg, count) == 4 and cfg.experts_held == (0, 1, 2, 3)
+    assert getattr(cfg, count + "_published") == 32 == cfg.router_experts
+    assert cfg.vocab_size_published == 1024
+    assert jnp.dtype(cfg.dtype) == jnp.float32
+    # a count the file states at the top is not overridden
+    fields[count + "_published"] = 16
+    assert getattr(module.config_from_fields(fields),
+                   count + "_published") == 16
+    # nor is a model without such fields told anything by the group
+    fields = dict(deepseek_v2.PRESETS["deepseek-v2-debug"],
+                  published={"n_routed_experts": 64, "vocab_size": 1024})
+    cfg = deepseek_v2.config_from_fields(fields)
+    assert cfg.n_routed_experts == 8
+    assert jnp.dtype(cfg.dtype) == jnp.bfloat16

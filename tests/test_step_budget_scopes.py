@@ -49,8 +49,9 @@ class Lowered:
     operation of the program's own functions (``main`` and the layer
     loops' ``closed_call`` bodies; a jitted helper such as ``jnp.take`` is
     the ``call`` into it, which carries the caller's scopes) in order as
-    ``(operation, scope path, innermost source file)``; ``bare`` the text
-    with every location stripped."""
+    ``(operation, scope path, source)``, the source ``file::function`` of
+    the innermost Python frame; ``bare`` the text with every location
+    stripped."""
 
     def __init__(self, text):
         defs = dict(_LOC_DEF.findall(text))
@@ -59,13 +60,16 @@ class Lowered:
             m = re.match(r'"([^"]*)"\(', defs.get(ref, ""))
             return m.group(1) if m else ""
 
-        def file_of(ref, depth=0):
+        def source_of(ref, depth=0, func=""):
             body = defs.get(ref, "")
             m = re.match(r'"([^"]*\.py)":', body)
             if m or depth > 20:
-                return m.group(1) if m else ""
+                return f"{m.group(1) if m else ''}::{func}"
+            name = re.match(r'"([^"]*)"\(#loc\d+\)$', body)
             inner = re.search(r"#loc\d+", body)
-            return file_of(inner.group(0), depth + 1) if inner else ""
+            return source_of(inner.group(0), depth + 1,
+                             name.group(1) if name else func) \
+                if inner else f"::{func}"
 
         self.ops, own = [], False
         for line in text.splitlines():
@@ -78,7 +82,7 @@ class Lowered:
             if own and use and op and not line.startswith("#loc"):
                 self.ops.append((op.group(1).strip('"'),
                                  path_of(use.group(1)),
-                                 file_of(use.group(1))))
+                                 source_of(use.group(1))))
         self.bare = "\n".join(
             _LOC_USE.sub("", line) for line in text.splitlines()
             if not line.startswith("#loc"))
@@ -102,9 +106,11 @@ def test_the_new_scopes_name_operations_and_change_none(name, Tc, monkeypatch):
     real = lower(name, Tc)
 
     # every operation traced in StepLayout is under step_layout, and no
-    # other; the mixed bucket's layout gathers (jnp.take)
+    # other; the mixed bucket's layout gathers (jnp.take).  (The module's
+    # ``step_frame`` computes a step's positions outside the scope, as the
+    # models did before it: StepLayout's own maps are what is named.)
     from_layout = [(op, path) for op, path, src in real.ops
-                   if src.endswith("models/step_layout.py")]
+                   if "models/step_layout.py::StepLayout." in src]
     assert from_layout and all(under(p, "step_layout")
                                for _, p in from_layout)
     assert sum(under(p, "step_layout") for _, p, _ in real.ops) \
