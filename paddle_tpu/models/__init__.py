@@ -8,11 +8,13 @@
   layer's K/V: rings), deepseek_v2 (latent attention on paged latents,
   experts without drops beside shared ones), longcat_flash (two latent
   sublayers a layer, a shortcut-connected expert layer, zero-compute
-  experts) and cohere2_moe (parallel blocks of window and global attention
-  and sigmoid-routed experts beside averaged shared ones).
+  experts), cohere2_moe (parallel blocks of window and global attention
+  and sigmoid-routed experts beside averaged shared ones) and mellum
+  (sequential blocks of window and YaRN full attention beside
+  softmax-routed experts, renormalised).
 - What those share: protocol (the engine's ``ServingModel``, the config
   reader), step_layout (a step's frame), paged (dense attention on pages),
-  mla, experts, rings.
+  mla, experts, rings, rotary.
 - gpt: GPT-2-style decoder (learned positions, fused QKV, GELU, tied head).
 - ernie: encoder pretraining family (MLM+NSP).
 - decoding: shared KV-cache autoregressive generation.

@@ -52,14 +52,12 @@ R x Wp, page, d]``, ``models/rings.py``): a window layer never holds more
 than its window, whatever the length of the request.  No recurrent state.
 A prefix-cache hit would skip tokens the rings never saw, and a rejected
 draft would overwrite ring entries still in the window: ``rings`` makes the
-engine refuse both.  The layers run as one scan over the periods, each a
-scan over its window layers and then its global layer, so the program holds
-two layer bodies whatever the depth.
+engine refuse both.  The layers run as ``rings.scan_periods``: one scan over
+the periods, each a scan over its window layers and then its global layer.
 """
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -68,10 +66,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import paged, protocol, rings
+from . import protocol, rings
 from .experts import route_top_k, routed_experts, shared_experts
 from .mla import rotate
-from .step_layout import step_frame
 
 __all__ = ["Cohere2MoeConfig", "PRESETS", "preset", "config_from_fields",
            "init_params", "param_count", "forward_pure", "forward_paged",
@@ -340,19 +337,10 @@ def _forward(cfg, params, ids, pos, live, attend, pools):
                           jnp.maximum(most, jnp.max(rows)),
                           held + jnp.sum(rows))
 
-    def period(state, p):
-        def window(state, j):
-            return layer(state, S * p + j, "window", (S - 1) * p + j), None
-
-        state, _ = lax.scan(window, state,
-                            jnp.arange(S - 1, dtype=jnp.int32))
-        return layer(state, S * p + S - 1, "global", p), None
-
     zero = jnp.zeros((), jnp.int32)
     with jax.named_scope("layers"):
-        (h, pools, counts), _ = lax.scan(
-            period, (h, pools, (zero,) * 3),
-            jnp.arange(cfg.num_global_layers, dtype=jnp.int32))
+        h, pools, counts = rings.scan_periods(
+            layer, (h, pools, (zero,) * 3), cfg.num_global_layers, S)
     with jax.named_scope("lm_head"):
         h = _layer_norm(h, params["norm_f"], eps)
         logits = jnp.einsum("td,vd->tv", h, params["embed"],
@@ -366,23 +354,7 @@ def forward_pure(cfg: Cohere2MoeConfig, params, input_ids):
     """Logits ``[B, S, V]`` float32 of whole sequences ``[B, S]``: no cache,
     plain masked attention (the window a mask)."""
     B, S = input_ids.shape
-    nh, nkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
-        cfg.head_dim
-    rep = nh // nkv
-    i = jnp.arange(S)
-    causal = i[None, :] <= i[:, None]
-    near = causal & (i[None, :] > i[:, None] - cfg.sliding_window)
-
-    def attend(kind, at, q, k, v, pools):
-        q = q.reshape(B, S, nkv, rep, d)
-        k, v = k.reshape(B, S, nkv, d), v.reshape(B, S, nkv, d)
-        s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
-                       preferred_element_type=jnp.float32) / math.sqrt(d)
-        s = jnp.where(near if kind == "window" else causal, s, -jnp.inf)
-        a = jnp.einsum("bgrqk,bkgd->bqgrd",
-                       jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
-        return a.reshape(B * S, nh * d), pools
-
+    attend = rings.masked_attend(cfg, B, S)
     pos = jnp.tile(jnp.arange(S, dtype=jnp.int32), B)
     logits, _, _ = _forward(cfg, params, input_ids.reshape(-1), pos,
                             jnp.ones((B * S,), bool), attend, None)
@@ -393,30 +365,8 @@ def forward_pure(cfg: Cohere2MoeConfig, params, input_ids):
 # serving: the engine's protocol
 # ---------------------------------------------------------------------------
 
-def init_cache(cfg: Cohere2MoeConfig, slots: int, num_pages: int,
-               page_size: int, kv_dtype):
-    """The cache of an engine with ``slots`` rows: the global layers' zeroed
-    page pools and the window layers' rings (and their null page)."""
-    protocol.refuse_narrow_pages(kv_dtype)
-    nkv, d = cfg.num_key_value_heads, cfg.head_dim
-    pool = (cfg.num_global_layers, nkv, num_pages, page_size, d)
-    return {"k_pages": jnp.zeros(pool, kv_dtype),
-            "v_pages": jnp.zeros(pool, kv_dtype),
-            **rings.init_rings(cfg.num_window_layers, nkv, slots,
-                               cfg.sliding_window, page_size, d, kv_dtype)}
-
-
-def cache_bytes(cfg: Cohere2MoeConfig, kv_dtype_bytes: int = 2,
-                page_size: int = 128) -> dict:
-    """What the cache costs: K/V bytes a token in the global layers, which
-    keep every token; a slot's rings in the window layers (``Wp`` pages of
-    ``page_size`` tokens each), whatever the length of the request in it;
-    and, whatever the engine's size, the rings' null page (``fixed``)."""
-    token = 2 * cfg.num_key_value_heads * cfg.head_dim * kv_dtype_bytes
-    ring = rings.ring_bytes(cfg.num_window_layers, token, cfg.sliding_window,
-                            page_size)
-    return {"per_token": cfg.num_global_layers * token, "scales_per_page": 0,
-            "per_slot": ring["per_slot"], "fixed": ring["fixed"]}
+init_cache = rings.init_cache
+cache_bytes = rings.cache_bytes
 
 
 def step_counts(cfg: Cohere2MoeConfig, seq_lens, q_lens) -> dict:
@@ -430,36 +380,14 @@ def step_counts(cfg: Cohere2MoeConfig, seq_lens, q_lens) -> dict:
 
 def forward_paged(cfg: Cohere2MoeConfig, params, tokens, cache, block_tables,
                   seq_lens, q_lens, step_tokens=None, device_counts=False):
-    """The engine's step (``protocol.ServingModel``) over ``cache``
-    (``init_cache``); ``device_counts``: the ``[3]`` of ``DEVICE_COUNTS``
-    third.  A global layer writes its K/V into the engine's pages through
-    ``block_tables`` and reads them there; a window layer writes and reads its
-    slot's ring through a table made here, with ``window=W``.  A request that
-    starts at position 0 rewrites every ring page before it reads it, so
-    admission into a used slot, preemption-replay and a rebuilt engine need no
-    reset.  Projections and the expert layer are per token, on the flat layout;
-    padding tokens write no K/V and are routed to no expert."""
-    R, Tc = tokens.shape
-    ring_table = rings.ring_table(R, block_tables.shape[1],
-                                  cfg.sliding_window,
-                                  cache["k_pages"].shape[3], Tc)
-    lay, ids, pos, live = step_frame(tokens, seq_lens, q_lens, step_tokens)
-
-    def attend(kind, at, q, k, v, pools):
-        window = kind == "window"
-        names = ("kw_pages", "vw_pages") if window else ("k_pages", "v_pages")
-        table = ring_table if window else block_tables
-        kv = paged.write((pools[names[0]], pools[names[1]]), lay.rows(k),
-                         lay.rows(v), table, seq_lens, q_lens, at)
-        out = paged.attend(lay.rows(q), kv, table, seq_lens, q_lens,
-                           layer=at,
-                           window=cfg.sliding_window if window else None,
-                           scope="attn_kernel")
-        return lay.flat(out), dict(pools, **dict(zip(names, kv)))
-
-    logits, pools, counts = _forward(cfg, params, ids, pos, live, attend,
-                                     dict(cache))
-    return lay.result(logits, pools, counts if device_counts else None)
+    """The engine's step (``protocol.ServingModel``): ``rings.serve_step``
+    over ``cache`` (``init_cache``: the global layers' pages, the window
+    layers' rings); ``device_counts``: the ``[3]`` of ``DEVICE_COUNTS``
+    third.  Projections and the expert layer are per token, on the flat
+    layout; padding tokens are routed to no expert."""
+    return rings.serve_step(cfg, partial(_forward, cfg, params), tokens,
+                            cache, block_tables, seq_lens, q_lens,
+                            step_tokens, device_counts)
 
 
 SERVING = protocol.ServingModel(

@@ -23,15 +23,13 @@ then a final RMSNorm and an untied head.
     o_i              = softmax_float32(s_i) v_i;   out = [o_i] W_o
 
 ``scale = (dn + dr)^-0.5 m^2`` with ``m = 0.1 mscale_all_dim ln(factor) + 1``
-under YaRN ``rope_scaling`` (1 without).  **YaRN**: pair j of ``dr / 2`` has
-``f_j = theta^(-2j / dr)``; ``low, high`` are the correction range of
-``beta_fast`` and ``beta_slow`` over ``original_max_position_embeddings``;
-``ramp_j = clip((j - low) / (high - low), 0, 1)``; the pair turns at ``f_j (1 -
-ramp_j) + (f_j / factor) ramp_j``; cos and sin are scaled by ``mscale(factor,
-mscale) / mscale(factor, mscale_all_dim)``, which is 1 where the two are
-equal.  A pair is lanes ``(j, j + dr/2)`` of the rotary part (the source's
-interleaved lanes ``(2j, 2j + 1)`` after a fixed permutation of W_q's and
-W_kva's rotary columns: the same model under seeded weights).
+under YaRN ``rope_scaling`` (1 without).  **YaRN** (``models/rotary.py``):
+the ``dr / 2`` pairs' frequencies blended with ``1 / factor`` of them over a
+ramp; cos and sin are scaled by ``mscale(factor, mscale) / mscale(factor,
+mscale_all_dim)``, which is 1 where the two are equal.  A pair is lanes
+``(j, j + dr/2)`` of the rotary part (the source's interleaved lanes ``(2j,
+2j + 1)`` after a fixed permutation of W_q's and W_kva's rotary columns: the
+same model under seeded weights).
 
 **Feed-forward.**  Layers ``< first_k_dense_replace``: SwiGLU of
 ``intermediate_size``.  The others (``models/experts.py``): ``p = softmax(h
@@ -73,7 +71,6 @@ of the absorption.
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -82,7 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import mla, protocol
+from . import mla, protocol, rotary
 from .experts import route_top_k, routed_experts, shared_experts
 from .mla import rms_norm as _rms_norm, swiglu as _swiglu
 from .protocol import layer_at as _layer_at
@@ -176,7 +173,7 @@ class DeepseekV2Config:
         scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
         rs = self.rope_scaling
         if rs and rs.get("mscale_all_dim"):
-            scale *= _yarn_mscale(rs["factor"], rs["mscale_all_dim"]) ** 2
+            scale *= rotary.yarn_mscale(rs["factor"], rs["mscale_all_dim"]) ** 2
         return scale
 
 
@@ -202,38 +199,11 @@ preset = partial(protocol.preset, DeepseekV2Config, PRESETS)
 config_from_fields = partial(protocol.config_from_fields, DeepseekV2Config)
 
 
-# ---------------------------------------------------------------------------
-# YaRN
-# ---------------------------------------------------------------------------
-
-def _yarn_mscale(factor: float, mscale: float) -> float:
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
 def rope_frequencies(cfg: DeepseekV2Config):
     """``(inv_freq [dr / 2] float32 numpy, what cos and sin are scaled
-    by)``: plain ``theta^(-2j/dr)`` without ``rope_scaling``, YaRN's blend of
-    them with ``1 / factor`` of them under it (module docstring)."""
-    dr, rs = cfg.qk_rope_head_dim, cfg.rope_scaling
-    j = np.arange(dr // 2, dtype=np.float64)
-    freq = cfg.rope_theta ** (-2.0 * j / dr)
-    if not rs:
-        return freq.astype(np.float32), 1.0
-    if rs.get("type", "yarn") != "yarn":
-        raise ValueError(f"rope_scaling of type {rs['type']!r}")
-
-    def correction_dim(rotations):
-        return (dr * math.log(rs["original_max_position_embeddings"]
-                              / (rotations * 2 * math.pi))
-                / (2 * math.log(cfg.rope_theta)))
-
-    low = max(math.floor(correction_dim(rs["beta_fast"])), 0)
-    high = min(math.ceil(correction_dim(rs["beta_slow"])), dr - 1)
-    ramp = np.clip((j - low) / max(high - low, 0.001), 0.0, 1.0)
-    inv_freq = freq * (1.0 - ramp) + freq / rs["factor"] * ramp
-    attn = (_yarn_mscale(rs["factor"], rs.get("mscale", 1.0))
-            / _yarn_mscale(rs["factor"], rs.get("mscale_all_dim", 0.0)))
-    return inv_freq.astype(np.float32), float(attn)
+    by)``: ``rotary.frequencies`` of the rope lanes under ``rope_scaling``."""
+    return rotary.frequencies(cfg.rope_theta, cfg.qk_rope_head_dim,
+                              cfg.rope_scaling)
 
 
 # ---------------------------------------------------------------------------

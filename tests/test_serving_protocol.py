@@ -23,7 +23,7 @@ import pytest
 
 from paddle_tpu import serving
 from paddle_tpu.models import (cohere2_moe, deepseek_v2, jamba, llama,
-                               longcat_flash, phi4flash, protocol)
+                               longcat_flash, mellum, phi4flash, protocol)
 from paddle_tpu.ops import pallas_ops
 from paddle_tpu.profiler import xmem
 
@@ -43,7 +43,7 @@ MODELS = {
 PRESETS = {llama: "llama-debug", jamba: "jamba-debug",
            phi4flash: "phi4flash-debug", deepseek_v2: "deepseek-v2-debug",
            longcat_flash: "longcat-flash-debug",
-           cohere2_moe: "cohere2-moe-debug"}
+           cohere2_moe: "cohere2-moe-debug", mellum: "mellum-debug"}
 
 
 @pytest.fixture(autouse=True)
@@ -156,7 +156,7 @@ def test_every_model_is_served_through_one_record(module):
 
 
 @pytest.mark.parametrize("module", [jamba, phi4flash, deepseek_v2,
-                                    longcat_flash, cohere2_moe],
+                                    longcat_flash, cohere2_moe, mellum],
                          ids=lambda m: m.__name__.rsplit(".", 1)[-1])
 def test_pages_of_one_byte_are_refused_where_no_step_writes_scales(module):
     cfg = module.preset(PRESETS[module])
